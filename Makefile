@@ -6,12 +6,6 @@
 
 CXX      ?= g++
 CXXFLAGS ?= -O2 -g -std=c++20 -fPIC -Wall -Wextra -Wno-unused-parameter -pthread
-# g++ 10 gates C++20 coroutines behind -fcoroutines (11+ turn them on with
-# -std=c++20 alone; clang rejects the flag) — probe instead of hardcoding.
-# := so the compiler probe runs ONCE, not on every $(CXXFLAGS) expansion.
-COROUTINE_FLAG := $(shell echo 'int main(){}' | $(CXX) -std=c++20 \
-    -fcoroutines -x c++ - -o /dev/null 2>/dev/null && echo -fcoroutines)
-CXXFLAGS += $(COROUTINE_FLAG)
 LDFLAGS  ?= -shared -pthread
 
 SRC := $(wildcard src/cc/butil/*.cc) \

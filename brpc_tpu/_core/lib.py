@@ -28,12 +28,22 @@ _libpath = os.path.join(_here, "libbrpc_core.so")
 
 
 def _build_if_needed() -> None:
-    if os.path.exists(_libpath) and \
-            os.path.exists(os.path.join(_here, "_fastrpc.so")):
+    """Build the native core where the checkout has none (the .so
+    files are git-ignored, so a fresh copy always builds, ~10 s).  The
+    compiler's output goes to stderr, and a failed build raises HERE:
+    loading whatever half of the pair an earlier or interrupted build
+    left behind would run a library that does not match the sources."""
+    built = (_libpath, os.path.join(_here, "_fastrpc.so"))
+    if all(os.path.exists(p) for p in built):
         return
     repo = os.path.dirname(os.path.dirname(_here))
-    subprocess.run(["make", "-j8"], cwd=repo, check=True,
-                   stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    # make's stdout joins its stderr on OUR stderr (fd 2): a program's
+    # stdout may be a result someone parses
+    proc = subprocess.run(["make", "-j8"], cwd=repo, stdout=2)
+    if proc.returncode != 0 or not all(os.path.exists(p) for p in built):
+        raise ImportError(
+            f"building the native core failed (`make -j8` in {repo} "
+            f"exited {proc.returncode}); the compiler's output is above")
 
 
 _build_if_needed()

@@ -163,10 +163,19 @@ class Block:
 
 
 class BlockPool:
-    """Per-device slab pool of HBM blocks."""
+    """Per-device slab pool of HBM blocks.  ``classes`` x
+    ``blocks_per_class`` is the pool's geometry: the per-device rail
+    pools (:func:`get_block_pool`) use the reference's three classes;
+    a KV cache whose page outgrows them brings a pool cut to its page
+    (``models.runner.make_store_for``)."""
 
-    def __init__(self, device=None):
+    def __init__(self, device=None, *, classes=BLOCK_CLASSES,
+                 blocks_per_class: int = _ARENA_BLOCKS_PER_CLASS):
+        from brpc_tpu.ici.mesh import ensure_compile_cache
+        ensure_compile_cache()
         self.device = device or jax.devices()[0]
+        self.classes = tuple(sorted(classes))
+        self.blocks_per_class = int(blocks_per_class)
         self._lock = threading.Lock()
         # one device buffer per slot: replaced wholesale on put() so slots
         # are independent (XLA owns the physical pages; keeping per-slot
@@ -175,11 +184,11 @@ class BlockPool:
         self._free: dict[int, list[int]] = {}
         self._allocated = Adder()
         self._freed = Adder()
-        for cls in BLOCK_CLASSES:
+        for cls in self.classes:
             with jax.default_device(self.device):
                 zero = jnp.zeros((cls,), jnp.uint8)
-            self._slots[cls] = [zero] * _ARENA_BLOCKS_PER_CLASS
-            self._free[cls] = list(range(_ARENA_BLOCKS_PER_CLASS))
+            self._slots[cls] = [zero] * self.blocks_per_class
+            self._free[cls] = list(range(self.blocks_per_class))
 
     def alloc(self, nbytes: int) -> Block:
         """Smallest class that fits (AllocBlock, block_pool.h:76-88)."""
@@ -190,7 +199,7 @@ class BlockPool:
             # out of slots, so callers walk their real fallback paths
             raise MemoryError(
                 f"injected HBM block exhaustion ({nbytes}B)")
-        for cls in BLOCK_CLASSES:
+        for cls in self.classes:
             if nbytes <= cls:
                 with self._lock:
                     if self._free[cls]:
@@ -199,7 +208,7 @@ class BlockPool:
                         return Block(self, cls, slot)
         raise MemoryError(
             f"no free HBM block for {nbytes}B "
-            f"(classes {BLOCK_CLASSES}, {_ARENA_BLOCKS_PER_CLASS}/class)")
+            f"(classes {self.classes}, {self.blocks_per_class}/class)")
 
     def free(self, block: Block) -> None:
         with self._lock:
@@ -212,8 +221,8 @@ class BlockPool:
                 "device": str(self.device),
                 "classes": {str(cls): {
                     "free": len(self._free[cls]),
-                    "total": _ARENA_BLOCKS_PER_CLASS,
-                } for cls in BLOCK_CLASSES},
+                    "total": self.blocks_per_class,
+                } for cls in self.classes},
                 "allocated": self._allocated.get_value(),
                 "freed": self._freed.get_value(),
             }

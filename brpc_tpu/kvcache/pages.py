@@ -88,7 +88,6 @@ class PagePool:
         if pool is None:
             from brpc_tpu.ici.block_pool import get_block_pool
             pool = get_block_pool(device)
-        from brpc_tpu.ici.block_pool import BLOCK_CLASSES
         if page_bytes % page_tokens:
             raise ValueError("page_bytes must be a multiple of page_tokens")
         self.kv_bytes_per_token = page_bytes // page_tokens
@@ -98,10 +97,10 @@ class PagePool:
         self.page_bytes = int(page_bytes)
         self.page_tokens = int(page_tokens)
         self.block_class = next(
-            (c for c in BLOCK_CLASSES if c >= page_bytes), None)
+            (c for c in pool.classes if c >= page_bytes), None)
         if self.block_class is None:
             raise ValueError(f"page_bytes {page_bytes} exceeds the largest "
-                             f"block class {BLOCK_CLASSES[-1]}")
+                             f"block class {pool.classes[-1]}")
         self.pages_per_block = self.block_class // self.page_bytes
         self.max_blocks = int(max_blocks)
         self.name = name

@@ -6,8 +6,8 @@ Streaming Framework argues for a dedicated bulk-buffer path beside the
 RPC control plane.  This module is both, applied to the paged KV
 cache: a radix prefix's pages (plus the tree metadata that makes them
 meaningful — token runs, per-chunk fingerprints, refcounts at source)
-ship over the DCN bridge's zero-copy offer/pull fabric (2.15x
-host-serialized, BENCH_r05) and splice into the destination
+ship over the DCN bridge's zero-copy offer/pull fabric and splice
+into the destination
 :class:`~brpc_tpu.kvcache.KVCacheStore` as COMMITTED radix nodes, so
 the destination prefix-hits state it never computed.
 

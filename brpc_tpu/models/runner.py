@@ -301,6 +301,23 @@ def place_runner_params(params: dict, mesh) -> dict:
             for k, v in params.items()}
 
 
+def _float32_matmuls(fn):
+    """Trace ``fn`` with every matmul at full float32 precision.  The
+    weights and the cache are float32, but a TPU's default for float32
+    operands is ONE bfloat16 pass (~3 significant digits): the paged
+    path and the dense reference then round differently wherever their
+    shapes differ, and a greedy argmax over a 50k vocabulary flips at
+    the first near-tie.  This is the one place the model's precision
+    is decided — the jitted paged programs and the dense reference
+    both trace under it, kernels and reference einsums included."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        import jax
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return traced
+
+
 def _posenc(pos, dm: int):
     """Parameter-free sinusoidal position encoding (deterministic, so
     the dense reference and the paged path agree by construction)."""
@@ -323,6 +340,7 @@ def _mlp(x, w1, w2):
     return jax.nn.gelu(x @ w1) @ w2
 
 
+@_float32_matmuls
 def dense_forward(params: dict, cfg: TransformerConfig, tokens,
                   positions, use_flash: bool = True):
     """The DENSE reference forward: full causal self-attention over the
@@ -350,6 +368,33 @@ def dense_forward(params: dict, cfg: TransformerConfig, tokens,
     return _rms(h) @ params["emb"].T
 
 
+_DENSE_PAD = 128
+
+
+@functools.cache
+def _dense_last_logits():
+    import jax
+
+    def last(params, tokens, positions, n, *, cfg):
+        return dense_forward(params, cfg, tokens, positions)[0, n - 1]
+    return jax.jit(last, static_argnames=("cfg",))
+
+
+def dense_logits(params: dict, cfg: TransformerConfig,
+                 tokens: Sequence[int]):
+    """The dense reference's logits ``[vocab]`` for the token that
+    follows ``tokens``.  The sequence is padded to a multiple of
+    ``_DENSE_PAD``: attention is causal, so padding behind the last
+    real position cannot reach it, and a generation compiles once per
+    128 tokens of length instead of once per token."""
+    n = len(tokens)
+    s = -(-n // _DENSE_PAD) * _DENSE_PAD
+    toks = np.zeros((1, s), np.int32)
+    toks[0, :n] = tokens
+    pos = np.arange(s, dtype=np.int32)[None]
+    return _dense_last_logits()(params, toks, pos, np.int32(n), cfg=cfg)
+
+
 def dense_generate(params: dict, cfg: TransformerConfig,
                    prompt: Sequence[int], max_new_tokens: int) -> list:
     """Greedy decode with NO cache: the full sequence recomputes every
@@ -358,10 +403,7 @@ def dense_generate(params: dict, cfg: TransformerConfig,
     import jax.numpy as jnp
     out = [int(t) for t in prompt]
     for _ in range(max_new_tokens):
-        toks = jnp.asarray([out], jnp.int32)
-        pos = jnp.arange(len(out), dtype=jnp.int32)[None]
-        logits = dense_forward(params, cfg, toks, pos)
-        out.append(int(jnp.argmax(logits[0, -1])))
+        out.append(int(jnp.argmax(dense_logits(params, cfg, out))))
     return out[len(prompt):]
 
 
@@ -378,7 +420,47 @@ def _kv_view(arena_u8, cfg: TransformerConfig, page_tokens: int):
 
 def _jit(fn):
     import jax
-    return jax.jit(fn, static_argnames=("cfg", "page_tokens", "backend"))
+    return jax.jit(_float32_matmuls(fn),
+                   static_argnames=("cfg", "page_tokens", "backend",
+                                    "mesh"))
+
+
+def _attention(mesh, backend):
+    """``paged_attention`` for the jitted programs: as it is on one
+    device, and HEAD-PARALLEL under an explicit ``shard_map`` over a
+    ``tp`` mesh — each chip attends its own query heads over its own
+    K/V heads of the (replicated) arena, which is the layout the
+    sharded q/k/v projections already produce.  GSPMD cannot do this
+    by itself: the chip's compiler refuses to partition a Mosaic
+    kernel automatically."""
+    from jax.sharding import PartitionSpec as P
+
+    from brpc_tpu.ici.collective import shard_map
+    from brpc_tpu.ops.paged_attention import paged_attention
+    attn = functools.partial(paged_attention, backend=backend)
+    if mesh is None or mesh.shape["tp"] == 1:
+        return attn
+    heads = P(None, "tp", None)          # q, extra_k/v, out: [N, H, D]
+    kv_heads = P(None, None, "tp", None)  # pages, local_k/v: [.., Hkv, D]
+    optional = {"extra_k": heads, "extra_v": heads, "local_k": kv_heads,
+                "local_v": kv_heads, "local_mask": P()}
+
+    def sharded(q, k_pages, v_pages, tables, lengths, **kw):
+        names = [n for n in optional if kw.get(n) is not None]
+
+        def per_chip(q, k_pages, v_pages, tables, lengths, *rest):
+            return attn(q, k_pages, v_pages, tables, lengths,
+                        **dict(zip(names, rest)))
+        # runs while the jitted program TRACES (once per compile), never
+        # per call
+        # brpc-check: allow(jit-hot-path)
+        return shard_map(
+            per_chip, mesh,
+            in_specs=(heads, kv_heads, kv_heads, P(), P(),
+                      *(optional[n] for n in names)),
+            out_specs=heads)(q, k_pages, v_pages, tables, lengths,
+                             *(kw[n] for n in names))
+    return sharded
 
 
 @functools.cache
@@ -388,12 +470,11 @@ def _jits():
     import jax
     import jax.numpy as jnp
 
-    from brpc_tpu.ops.paged_attention import paged_attention
-
-    def embed(params, tokens, positions, *, cfg, page_tokens, backend):
+    def embed(params, tokens, positions, *, cfg, page_tokens, backend,
+              mesh):
         return params["emb"][tokens] + _posenc(positions, cfg.d_model)
 
-    def proj(params, h, l, *, cfg, page_tokens, backend):
+    def proj(params, h, l, *, cfg, page_tokens, backend, mesh):
         n = h.shape[0]
         x = _rms(h)
         q = (x @ params["wq"][l]).reshape(n, cfg.n_heads, cfg.head_dim)
@@ -404,16 +485,17 @@ def _jits():
         return q, k, v
 
     def attend(params, h, q, arena_u8, tables, lengths, l, *,
-               cfg, page_tokens, backend):
+               cfg, page_tokens, backend, mesh):
         kv = _kv_view(arena_u8, cfg, page_tokens)
-        o = paged_attention(q, kv[:, :, l, 0], kv[:, :, l, 1],
-                            tables, lengths, backend=backend)
+        o = _attention(mesh, backend)(q, kv[:, :, l, 0], kv[:, :, l, 1],
+                                      tables, lengths)
         h = h + o.reshape(h.shape[0], cfg.n_heads * cfg.head_dim) \
             @ params["wo"][l]
         return h + _mlp(_rms(h), params["w1"][l], params["w2"][l])
 
     def step(params, tokens, positions, tables, arena_u8, *,
-             cfg, page_tokens, backend):
+             cfg, page_tokens, backend, mesh):
+        paged_attention = _attention(mesh, backend)
         s = tokens.shape[0]
         qpos = positions - 1      # the query position (see contract)
         kv = _kv_view(arena_u8, cfg, page_tokens)
@@ -435,8 +517,7 @@ def _jits():
             # EXCLUDE it and extra_k/extra_v supply the value computed
             # right here
             o = paged_attention(q, kv[:, :, l, 0], kv[:, :, l, 1],
-                                tables, qpos, extra_k=k, extra_v=v,
-                                backend=backend)
+                                tables, qpos, extra_k=k, extra_v=v)
             h = h + o.reshape(s, cfg.n_heads * cfg.head_dim) \
                 @ params["wo"][l]
             h = h + _mlp(_rms(h), params["w1"][l], params["w2"][l])
@@ -448,10 +529,10 @@ def _jits():
             axis=2)                     # [S, L, 2, Hkv, D]
         rows_u8 = jax.lax.bitcast_convert_type(
             kv_rows, jnp.uint8).reshape(s, cfg.kv_bytes_per_token)
-        return nxt, rows_u8
+        return nxt, rows_u8, logits
 
     def verify(params, tokens, positions, tables, base_len, mask,
-               arena_u8, *, cfg, page_tokens, backend):
+               arena_u8, *, cfg, page_tokens, backend, mesh):
         """Draft-tree verify (ISSUE 11): every row of every slot in ONE
         paged-attention call.  The arena part covers each slot's
         MATERIALIZED keys (per-row ``base_len`` — draft pages in the
@@ -460,6 +541,7 @@ def _jits():
         LOCAL BLOCK under the ancestry ``mask`` — the multi-key
         generalization of the decode step's self-key merge, so a slot
         with zero drafts reduces exactly to a plain step row."""
+        paged_attention = _attention(mesh, backend)
         s, k1 = tokens.shape
         r = s * k1
         qpos = positions.reshape(r) - 1    # engine position convention
@@ -481,7 +563,7 @@ def _jits():
                 q, kv[:, :, l, 0], kv[:, :, l, 1], tables, base_len,
                 local_k=k.reshape(s, k1, cfg.n_kv_heads, cfg.head_dim),
                 local_v=v.reshape(s, k1, cfg.n_kv_heads, cfg.head_dim),
-                local_mask=mask, backend=backend)
+                local_mask=mask)
             h = h + o.reshape(r, cfg.n_heads * cfg.head_dim) \
                 @ params["wo"][l]
             h = h + _mlp(_rms(h), params["w1"][l], params["w2"][l])
@@ -503,10 +585,21 @@ def make_store_for(cfg: TransformerConfig, *, page_tokens: int = 8,
                    max_blocks: int = 8, pool=None, device=None,
                    commit_live_pages: bool = False, name: str = "kv"):
     """A KVCacheStore whose page geometry matches ``cfg``'s packed
-    K/V slots (``vector_kv=True`` — the runner owns materialization)."""
+    K/V slots (``vector_kv=True`` — the runner owns materialization).
+
+    At real widths one token slot is ``L*2*Hkv*D*4`` bytes (256 KiB at
+    16 layers of 16x128), so a page outgrows the rail's largest block
+    class; the cache then leases from a pool of its own whose one
+    class is a page: ``max_blocks`` pages, ``max_blocks * page_bytes``
+    of cache."""
+    from brpc_tpu.ici.block_pool import BLOCK_CLASSES, BlockPool
     from brpc_tpu.kvcache import KVCacheStore
+    page_bytes = page_tokens * cfg.kv_bytes_per_token
+    if pool is None and page_bytes > BLOCK_CLASSES[-1]:
+        pool = BlockPool(device, classes=(page_bytes,),
+                         blocks_per_class=max_blocks)
     return KVCacheStore(
-        pool, device, page_bytes=page_tokens * cfg.kv_bytes_per_token,
+        pool, device, page_bytes=page_bytes,
         page_tokens=page_tokens, max_blocks=max_blocks,
         commit_live_pages=commit_live_pages, vector_kv=True, name=name)
 
@@ -524,7 +617,8 @@ class TransformerRunner(ModelRunner):
                  store=None, mesh=None,
                  attn_backend: Optional[str] = None,
                  name: str = "model"):
-        import jax
+        from brpc_tpu.ici.mesh import ensure_compile_cache
+        ensure_compile_cache()
         self.cfg = cfg
         self.kv_bytes_per_token = cfg.kv_bytes_per_token
         self.name = name
@@ -538,10 +632,16 @@ class TransformerRunner(ModelRunner):
             sh = getattr(params.get("wq"), "sharding", None)
             self.mesh = getattr(sh, "mesh", None)
             self.params = params
+        tp = 1 if self.mesh is None else self.mesh.shape["tp"]
+        if cfg.n_heads % tp or cfg.n_kv_heads % tp:
+            raise ValueError(
+                f"tp={tp} must divide n_heads ({cfg.n_heads}) and "
+                f"n_kv_heads ({cfg.n_kv_heads}): attention runs "
+                f"head-parallel over the mesh")
         self.store = None
         self._mu = threading.Lock()
-        # backend=None lets ops/paged_attention pick (pallas on TPU,
-        # gather on CPU) at TRACE time, inside the shared jits
+        # backend=None lets ops/paged_attention pick (the kernel on a
+        # TPU, gather elsewhere) at TRACE time, inside the shared jits
         self._backend = attn_backend
         self._fns = _jits()
         if store is not None:
@@ -549,7 +649,7 @@ class TransformerRunner(ModelRunner):
 
     def _statics(self) -> dict:
         return {"cfg": self.cfg, "page_tokens": self.store.page_tokens,
-                "backend": self._backend}
+                "backend": self._backend, "mesh": self.mesh}
 
     # ---- binding / validation ----
 
@@ -600,19 +700,40 @@ class TransformerRunner(ModelRunner):
         from jax.sharding import NamedSharding, PartitionSpec as P
         return jax.device_put(arena, NamedSharding(self.mesh, P()))
 
-    def step(self, tokens, positions, pages):
+    def _step_args(self, tokens, positions, pages) -> tuple:
         import jax.numpy as jnp
+        return (self.params, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(self._flat_tables(pages)), self._arena())
+
+    def _step(self, tokens, positions, pages):
+        return self._fns["step"](
+            *self._step_args(tokens, positions, pages), **self._statics())
+
+    def compile_step(self, num_slots: int, max_pages_per_slot: int):
+        """Compile the decode step ahead of time at an engine's fixed
+        shapes, against the bound store's arena, and return the
+        compiled program: its ``memory_analysis()`` is what a
+        deployment sizes its cache from, and with the persistent
+        compile cache the first real step finds the program there."""
+        slots = np.zeros((num_slots,), np.int32)
+        pages = np.full((num_slots, max_pages_per_slot), -1, np.int32)
+        return self._fns["step"].lower(
+            *self._step_args(slots, slots, pages),
+            **self._statics()).compile()
+
+    def step(self, tokens, positions, pages):
         if fault.ENABLED and fault.hit(
                 "model.step_compute", runner=self.name) is not None:
             raise RuntimeError("injected model step-compute failure")
-        tables = self._flat_tables(pages)
-        arena = self._arena()
-        nxt, rows = self._fns["step"](self.params,
-                                      jnp.asarray(tokens, jnp.int32),
-                                      jnp.asarray(positions, jnp.int32),
-                                      jnp.asarray(tables), arena,
-                                      **self._statics())
+        nxt, rows, _ = self._step(tokens, positions, pages)
         return np.asarray(nxt), np.asarray(rows)
+
+    def step_logits(self, tokens, positions, pages):
+        """The decode step's logits ``[num_slots, vocab]`` before the
+        argmax, from the SAME compiled program :meth:`step` runs —
+        what a check against :func:`dense_logits` compares."""
+        return self._step(tokens, positions, pages)[2]
 
     def verify(self, tokens, positions, tables, base_len, mask):
         import jax.numpy as jnp

@@ -144,23 +144,34 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_k: int, scale: float,
 def flash_attention(q, k, v, blk_q: int = 256, blk_k: int = 256,
                     causal: bool = False,
                     interpret: Optional[bool] = None):
-    """Blockwise (flash) attention as a Pallas TPU kernel.  Falls back
-    to interpret mode off-TPU so the same code path tests on the virtual
-    CPU mesh.  Shapes [B, S, H, D] -> [B, S, H, D].  GQA/MQA K/V are
-    expanded up front (the kernel's grid is per query-head).  causal=True
-    skips K blocks above each q block's diagonal entirely (~2x fewer
-    FLOPs) and position-masks only the straddling blocks — measured
-    numbers live in BENCH_DEVICE_SESSION_r05.json session4 (v5 lite,
-    B4 S4096 H8 D128: 69.7 vs 23.6 TFLOP/s non-causal, 4.1x on
-    causal)."""
+    """Blockwise (flash) attention as a Pallas TPU kernel; interpret
+    mode off-TPU so the same code path tests on the virtual CPU mesh.
+    Shapes [B, S, H, D] -> [B, S, H, D].  GQA/MQA K/V are expanded up
+    front (the kernel's grid is per query-head).  causal=True skips K
+    blocks above each q block's diagonal entirely (~2x fewer FLOPs)
+    and position-masks only the straddling blocks.
+
+    A length the blocks do not divide is PADDED when causal (padding
+    sits behind every real position, so no real query can see it, and
+    the padded rows are cut off again) and REFUSED when not: a padded
+    key would need a mask this kernel does not have, and handing the
+    call to ``local_attention`` instead would report a kernel that
+    never ran."""
     from jax.experimental import pallas as pl
 
     k, v = _expand_kv(q, k, v)
-    b, s, h, d = q.shape
-    blk_q = min(blk_q, s)
-    blk_k = min(blk_k, s)
-    if s % blk_q or s % blk_k:
-        return local_attention(q, k, v, causal=causal)
+    b, s_in, h, d = q.shape
+    blk_q = min(blk_q, s_in)
+    blk_k = min(blk_k, s_in)
+    s = -(-s_in // math.lcm(blk_q, blk_k)) * math.lcm(blk_q, blk_k)
+    if s != s_in:
+        if not causal:
+            raise ValueError(
+                f"flash_attention: sequence length {s_in} is not a "
+                f"multiple of blocks ({blk_q}, {blk_k}) and the call "
+                f"is not causal; pad the inputs or pick dividing blocks")
+        pad = ((0, 0), (0, s - s_in), (0, 0), (0, 0))
+        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     scale = 1.0 / math.sqrt(d)
@@ -180,8 +191,9 @@ def flash_attention(q, k, v, blk_q: int = 256, blk_k: int = 256,
         out_specs=pl.BlockSpec((None, blk_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)[:, :s_in]
 
 
 # ---- ring attention (sequence parallel, exact) -----------------------------
@@ -239,11 +251,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False):
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     # the loop's ppermute makes carries device-varying over the mesh axis;
     # mark the constant initials to match (shard_map vma typing)
-    try:
-        o0, m0, l0 = (lax.pcast(x, (axis_name,), to="varying")
-                      for x in (o0, m0, l0))
-    except (AttributeError, TypeError):  # older jax: untyped carries
-        pass
+    o0, m0, l0 = (lax.pcast(x, (axis_name,), to="varying")
+                  for x in (o0, m0, l0))
     o, m, l, _, _ = lax.fori_loop(0, n, step, (o0, m0, l0, k, v))
     l = jnp.where(l == 0.0, 1.0, l)          # rows with no visible keys
     return (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)

@@ -19,8 +19,10 @@ Two backends, one contract:
     arena row the table names (the classic paged-attention pattern —
     the gather never materializes).  Online-softmax accumulation over
     the page axis, exactly the flash discipline of
-    ``ops/attention.py``.  ``interpret=True`` off-TPU keeps the kernel
-    testable on the virtual CPU mesh.
+    ``ops/attention.py``.  The TPU default; compiled by Mosaic there
+    (``tests/test_chip_compile.py`` keeps it lowering for a v5e), and
+    ``interpret=True`` off-TPU keeps it testable on the virtual CPU
+    mesh.
 
 Shapes (one query per row — decode steps batch rows across slots,
 prefill batches rows across suffix positions):
@@ -71,7 +73,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["paged_attention", "paged_attention_gather",
-           "paged_attention_pallas", "arena_kv_view"]
+           "paged_attention_pallas", "arena_kv_view", "default_backend"]
 
 
 def arena_kv_view(arena_u8, page_tokens: int, n_layers: int,
@@ -176,14 +178,27 @@ def paged_attention_gather(q, k_pages, v_pages, tables, lengths,
 # ---- pallas backend --------------------------------------------------------
 
 def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_ref, l_ref, *, page_tokens: int, scale: float,
-                  n_heads: int):
+                  o_ref, m_ref, l_ref, *, page_tokens: int, scale: float):
     """One (row, page) program: fold page ``tables[n, m]``'s K/V block
     into row n's online-softmax accumulator.  The page table and
     lengths ride SCALAR PREFETCH, so the BlockSpec index_map DMA'd
     k_ref/v_ref straight from the arena row the table names — no
     gathered copy of the K/V ever exists.  Outputs stay UNNORMALIZED
-    (o, m, l); the wrapper merges the optional self-key and divides."""
+    (o, m, l); the wrapper merges the optional self-key and divides.
+
+    Written for Mosaic (the chip's compiler), which tiles the LAST TWO
+    dims of every block over (8 sublanes, 128 lanes): the page block
+    keeps the arena's own ``[T, Hkv, D]`` layout and every value in
+    here has ``[Hkv, D]`` (or ``[Hkv, 1]``) as its minor dims.  One
+    query per head makes the score a matrix-VECTOR product, which the
+    MXU cannot batch over heads — so the head contraction is a VPU
+    multiply + lane reduce (``keepdims``: a squeezed ``[T, Hkv]``
+    would need a relayout), the page fold reduces over the untiled
+    leading T axis, and GQA is a static loop over the query heads of
+    each K/V head (``q_ref`` arrives ``[G, Hkv, D]``) instead of a
+    ``jnp.repeat`` of the page.  m/l are ``[G, Hkv, 1]``: a block
+    whose minor dims equal the array's is the form Mosaic accepts for
+    an accumulator this narrow."""
     from jax.experimental import pallas as pl
     n = pl.program_id(0)
     m_i = pl.program_id(1)
@@ -194,35 +209,28 @@ def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...].astype(jnp.float32) * scale          # [H, D]
     k = k_ref[...].astype(jnp.float32)                  # [T, Hkv, D]
     v = v_ref[...].astype(jnp.float32)
-    hkv = k.shape[1]
-    if hkv != n_heads:
-        k = jnp.repeat(k, n_heads // hkv, axis=1)
-        v = jnp.repeat(v, n_heads // hkv, axis=1)
-    s = jnp.einsum("hd,thd->ht", q, k,
-                   preferred_element_type=jnp.float32)  # [H, T]
     # mask: global key position of slot t in page m is m*T + t; valid
     # iff < lengths[n] AND the table entry is a real page (>= 0)
     kpos = m_i * page_tokens + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
+        jnp.int32, (k.shape[0], k.shape[1], 1), 0)
     valid = (kpos < len_ref[n]) & (tab_ref[n, m_i] >= 0)
-    s = jnp.where(valid, s, -jnp.inf)
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    blk_max = s.max(axis=-1)
-    m_new = jnp.maximum(m_prev, blk_max)
-    # all-masked-so-far rows keep -inf maxima; guard every exp
-    m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-    alpha = jnp.where(jnp.isneginf(m_prev), 0.0,
-                      jnp.exp(m_prev - m_safe))
-    p = jnp.exp(s - m_safe[:, None])
-    p = jnp.where(jnp.isneginf(s), 0.0, p)
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * alpha + p.sum(axis=-1)
-    o_ref[...] = o_ref[...] * alpha[:, None] + jnp.einsum(
-        "ht,thd->hd", p, v, preferred_element_type=jnp.float32)
+    for g in range(q_ref.shape[0]):
+        q = q_ref[g].astype(jnp.float32) * scale        # [Hkv, D]
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # [T, Hkv, 1]
+        s = jnp.where(valid, s, -jnp.inf)
+        m_prev = m_ref[g]                               # [Hkv, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=0))
+        # all-masked-so-far rows keep -inf maxima; guard every exp
+        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        alpha = jnp.where(jnp.isneginf(m_prev), 0.0,
+                          jnp.exp(m_prev - m_safe))
+        p = jnp.exp(s - m_safe[None])
+        p = jnp.where(jnp.isneginf(s), 0.0, p)
+        m_ref[g] = m_new
+        l_ref[g] = l_ref[g] * alpha + p.sum(axis=0)
+        o_ref[g] = o_ref[g] * alpha + jnp.sum(p * v, axis=0)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, tables, lengths,
@@ -235,38 +243,55 @@ def paged_attention_pallas(q, k_pages, v_pages, tables, lengths,
     p, t, hkv, _ = k_pages.shape
     mp = tables.shape[1]
     _check_local(extra_k, local_k, local_v, local_mask, n)
+    if h % hkv:
+        raise ValueError(f"n_heads ({h}) must be a multiple of "
+                         f"n_kv_heads ({hkv})")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     scale = 1.0 / math.sqrt(d)
+    g = h // hkv
+    # query head j attends K/V head j // g (the _expand_heads order):
+    # hand the kernel [G, Hkv, D] so each group index is one K/V-shaped
+    # slab, and undo the split on the way out
+
+    def split(x):
+        return x.reshape(n, hkv, g, -1).transpose(0, 2, 1, 3)
+
+    def merge(x):
+        return x.transpose(0, 2, 1, 3).reshape(n, h, -1)
+
+    def row(i, m, tab, ln):
+        return (i, 0, 0, 0)
+
+    def page(i, m, tab, ln):
+        return (jnp.clip(tab[i, m], 0, p - 1), 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # tables, lengths
         grid=(n, mp),
         in_specs=[
-            pl.BlockSpec((None, h, d), lambda i, m, tab, ln: (i, 0, 0)),
-            pl.BlockSpec((None, t, hkv, d),
-                         lambda i, m, tab, ln:
-                         (jnp.clip(tab[i, m], 0, p - 1), 0, 0, 0)),
-            pl.BlockSpec((None, t, hkv, d),
-                         lambda i, m, tab, ln:
-                         (jnp.clip(tab[i, m], 0, p - 1), 0, 0, 0)),
+            pl.BlockSpec((None, g, hkv, d), row),
+            pl.BlockSpec((None, t, hkv, d), page),
+            pl.BlockSpec((None, t, hkv, d), page),
         ],
         out_specs=[
-            pl.BlockSpec((None, h, d), lambda i, m, tab, ln: (i, 0, 0)),
-            pl.BlockSpec((None, h), lambda i, m, tab, ln: (i, 0)),
-            pl.BlockSpec((None, h), lambda i, m, tab, ln: (i, 0)),
+            pl.BlockSpec((None, g, hkv, d), row),
+            pl.BlockSpec((None, g, hkv, 1), row),
+            pl.BlockSpec((None, g, hkv, 1), row),
         ],
     )
     o, mx, l = pl.pallas_call(
-        functools.partial(_paged_kernel, page_tokens=t, scale=scale,
-                          n_heads=h),
+        functools.partial(_paged_kernel, page_tokens=t, scale=scale),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((n, h), jnp.float32),
-            jax.ShapeDtypeStruct((n, h), jnp.float32),
+            jax.ShapeDtypeStruct((n, g, hkv, d), jnp.float32),
+            jax.ShapeDtypeStruct((n, g, hkv, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, g, hkv, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(tables, lengths, q, k_pages, v_pages)
+        name="paged_attention",
+    )(tables, lengths, split(q), k_pages, v_pages)
+    o, mx, l = merge(o), merge(mx)[..., 0], merge(l)[..., 0]
     if extra_k is not None:
         # merge the self key into the accumulated (o, m, l) — one more
         # online-softmax fold, in plain jax
@@ -307,6 +332,12 @@ def paged_attention_pallas(q, k_pages, v_pages, tables, lengths,
 
 # ---- dispatcher ------------------------------------------------------------
 
+def default_backend() -> str:
+    """The backend ``paged_attention(backend=None)`` runs: the kernel
+    on a TPU, the gather everywhere else."""
+    return "pallas" if jax.default_backend() == "tpu" else "gather"
+
+
 def paged_attention(q, k_pages, v_pages, tables, lengths,
                     extra_k=None, extra_v=None,
                     local_k=None, local_v=None, local_mask=None,
@@ -315,9 +346,12 @@ def paged_attention(q, k_pages, v_pages, tables, lengths,
     """Paged attention (see module docstring).  ``backend`` picks
     "gather" (pure jax — the default off-TPU so the CPU tier-1 path
     never touches the pallas interpreter) or "pallas" (the TPU kernel;
-    ``interpret=True`` runs it on CPU for equivalence tests)."""
+    ``interpret=True`` runs it on CPU for equivalence tests).  On a
+    TPU the kernel is compiled for the chip or the call fails with the
+    compiler's refusal: nothing here substitutes the gather backend or
+    interpret mode for a kernel that would not lower."""
     if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "gather"
+        backend = default_backend()
     if backend == "gather":
         return paged_attention_gather(q, k_pages, v_pages, tables,
                                       lengths, extra_k, extra_v,

@@ -58,6 +58,8 @@ class ShardedEmbeddingTable:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from brpc_tpu.ici.collective import shard_map
+        from brpc_tpu.ici.mesh import ensure_compile_cache
+        ensure_compile_cache()
         if mode not in ("psum", "ring"):
             raise ValueError(f"mode must be psum|ring, got {mode!r}")
         if mesh is None:

@@ -93,12 +93,16 @@ class EmbeddingShardServer:
                  dim: int, *, seed: int = 0,
                  table: Optional[np.ndarray] = None,
                  dense_params: Optional[dict] = None,
-                 mesh=None,
+                 mesh=None, device=None,
                  key_buckets: Sequence[int] = DEFAULT_KEY_BUCKETS,
                  applied_cap: int = 65536,
                  name: str = "ps"):
         import jax
         import jax.numpy as jnp
+        from brpc_tpu.ici.mesh import ensure_compile_cache
+        ensure_compile_cache()
+        if mesh is not None and device is not None:
+            raise ValueError("pass mesh= or device=, not both")
         self._jax, self._jnp = jax, jnp
         self.shard_index = int(shard_index)
         self.n_shards = int(n_shards)
@@ -124,7 +128,10 @@ class EmbeddingShardServer:
                 self._rows = jax.device_put(
                     rows, NamedSharding(mesh, P()))
         else:
-            self._rows = jnp.asarray(rows)
+            # the rows (and the slots, which follow them) live on the
+            # one device this shard was given — N shards of one
+            # process are N chips only if each names its own
+            self._rows = jax.device_put(rows, device or jax.devices()[0])
         self.mesh = mesh
         # dense parameters (the non-embedding rest of the model); the
         # CLIENT routes each name to its owner shard by hash

@@ -84,8 +84,11 @@ def spawn_router(wal_path: str, replica_addrs: Sequence[str], *,
     cfg = dict(cfg)
     cfg["wal"] = str(wal_path)
     cfg["replicas"] = [str(a) for a in replica_addrs]
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # one process per chip: the router routes and never holds a device,
+    # so its child is forced off the accelerator even when the parent
+    # selected one — a child that opened the parent's chip would fail
+    # or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     proc = subprocess.Popen(

@@ -855,9 +855,17 @@ def zipf_key_sampler(vocab: int, s: float, seed: int = 0):
 
 
 def spin_up_psserve(n_shards: int, *, vocab: int = 1024, dim: int = 32,
-                    max_delay_us: int = 1000, name_prefix: str = "press"):
+                    max_delay_us: int = 1000, name_prefix: str = "press",
+                    devices=None, table=None):
     """In-process sharded parameter-server fleet + a PartitionChannel
-    over it (shared by --embedding mode and bench.py embedding)."""
+    over it (shared by --embedding mode and bench.py embedding).
+    ``devices`` places shard i on ``devices[i]`` — one shard per chip;
+    without it every shard's rows land on the first device.  ``table``
+    is the full [vocab, dim] table the shards slice (default: each
+    shard draws the seed-0 table itself)."""
+    if devices is not None and len(devices) != n_shards:
+        raise ValueError(f"{n_shards} shards need {n_shards} devices, "
+                         f"got {len(devices)}")
     from brpc_tpu.psserve import EmbeddingShardServer, register_psserve
     from brpc_tpu.rpc.combo_channels import PartitionChannel
     from brpc_tpu.serving.telemetry import register_telemetry
@@ -865,8 +873,10 @@ def spin_up_psserve(n_shards: int, *, vocab: int = 1024, dim: int = 32,
     servers, svcs, shards = [], [], []
     pc = PartitionChannel(n_shards)
     for i in range(n_shards):
-        sh = EmbeddingShardServer(i, n_shards, vocab, dim, seed=0,
-                                  name=f"{name_prefix}_ps")
+        sh = EmbeddingShardServer(
+            i, n_shards, vocab, dim, seed=0, table=table,
+            device=None if devices is None else devices[i],
+            name=f"{name_prefix}_ps")
         shards.append(sh)
         s = brpc.Server()
         svcs.append(register_psserve(s, sh, max_delay_us=max_delay_us,

@@ -36,6 +36,7 @@ srv.run_until_interrupt()
 
 def main():
     env = dict(os.environ)
+    # one process per chip: the parent may hold it, so the child gets the CPU
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     child = subprocess.Popen([sys.executable, "-c", CHILD],

@@ -67,6 +67,7 @@ srv.run_until_interrupt()
 
 def spawn(code, *args):
     env = dict(os.environ)
+    # one process per chip: the parent may hold it, so children get the CPU
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen([sys.executable, "-c", code, *args],
                             stdout=subprocess.PIPE,

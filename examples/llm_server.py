@@ -16,9 +16,8 @@ What it shows:
 Browse http://127.0.0.1:<port>/kvcache while it runs for pages/hit
 rate, or /serving for the slot map.
 
-Run forced-CPU (the paged kernel's gather backend) with
-BRPC_FORCE_CPU=1; on a TPU the same code takes the pallas
-scalar-prefetch kernel path.
+With JAX_PLATFORMS=cpu the paged attention runs its gather backend;
+on a TPU the same code takes the pallas scalar-prefetch kernel.
 """
 import json
 import os
@@ -28,9 +27,6 @@ import threading
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
-
-if os.environ.get("BRPC_FORCE_CPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 import brpc_tpu as brpc
 from brpc_tpu.models.runner import (TransformerConfig, TransformerRunner,

@@ -10,15 +10,12 @@ Two things in one demo:
 
 Run on the virtual mesh:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-  BRPC_FORCE_CPU=1 python examples/moe_expert_parallel.py
+  JAX_PLATFORMS=cpu python examples/moe_expert_parallel.py
 """
 import os, sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
-
-if os.environ.get("BRPC_FORCE_CPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np

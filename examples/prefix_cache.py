@@ -23,9 +23,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 
-if os.environ.get("BRPC_FORCE_CPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 import brpc_tpu as brpc
 from brpc_tpu.kvcache import KVCacheStore
 from brpc_tpu.serving import DecodeEngine, register_serving

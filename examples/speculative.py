@@ -16,9 +16,8 @@ What it shows:
      one HTTP GET away; see examples/llm_server.py for the served
      variant).
 
-Run forced-CPU (the paged kernel's gather backend) with
-BRPC_FORCE_CPU=1; on a TPU the same code takes the pallas
-scalar-prefetch kernel path.
+With JAX_PLATFORMS=cpu the paged attention runs its gather backend;
+on a TPU the same code takes the pallas scalar-prefetch kernel.
 """
 import os
 import sys
@@ -28,9 +27,6 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
-
-if os.environ.get("BRPC_FORCE_CPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 from brpc_tpu.models.runner import (TransformerConfig, TransformerRunner,
                                     dense_generate, init_runner_params,

@@ -25,10 +25,7 @@ def _mesh():
 
 
 def _run_sharded(fn, q, k, v, **kw):
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.4.38 exposes it under experimental only
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = _mesh()
     spec = P(None, "sp", None, None)
 
@@ -96,15 +93,24 @@ def test_flash_attention_causal_matches_full():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_flash_attention_indivisible_seq_falls_back():
-    """S not divisible by the block sizes routes to local_attention —
-    with the causal flag FORWARDED (a silently non-causal fallback would
-    be a correctness bug, not a perf one)."""
+def test_flash_attention_indivisible_seq_pads_when_causal():
+    """S not divisible by the block sizes is padded up to them and the
+    padding cut off again: causal masking keeps every real position
+    blind to it, so the KERNEL still runs and still matches."""
     q, k, v = _qkv(seed=8, s=100)
     ref = local_attention(q, k, v, causal=True)
     out = flash_attention(q, k, v, blk_q=64, blk_k=64, causal=True)
+    assert out.shape == q.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_flash_attention_indivisible_seq_raises_when_not_causal():
+    """Without a causal mask a padded key would be attended to: the
+    call is refused by name, never handed to local_attention."""
+    q, k, v = _qkv(seed=8, s=100)
+    with pytest.raises(ValueError, match="100"):
+        flash_attention(q, k, v, blk_q=64, blk_k=64)
 
 
 def test_flash_attention_causal_uneven_blocks():
@@ -148,10 +154,7 @@ def test_sequence_parallel_exact_across_mesh_sizes(n, fn):
     """Regression: Ulysses' head reassembly interleaved wrongly for any
     n < heads (invisible at n == heads where h/n == 1) — every op must be
     exact on every mesh size, causal on."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.4.38 exposes it under experimental only
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     q, k, v = _qkv(seed=10 + n)
     mesh = Mesh(np.array(jax.devices()[:n]), ("sp",))
     spec = P(None, "sp", None, None)

@@ -1,0 +1,163 @@
+"""The main path's device programs, compiled for a v5e that is described
+and not attached (guide on-chip-measurement, section 2, rehearsal 3).
+
+Interpret mode hides what the chip's compiler refuses: the paged kernel
+passed every interpret-mode test for eleven PRs and was refused by
+Mosaic at every shape.  These compiles cost seconds and no chip time,
+and they guard every later PR.  A compile that passes is not a chip
+run: results and times come from ``chip_smoke.py`` on the chip.
+
+The topology is described inside a fixture, after a test of this file
+has started — never at import, in a ``skipif`` or in a ``parametrize``
+argument — because only one process may load the TPU's library.  All
+of these tests live in this one file for the same reason.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from brpc_tpu.models.runner import _jits, init_runner_params
+from brpc_tpu.ops.attention import flash_attention
+from brpc_tpu.ops.paged_attention import paged_attention_pallas
+
+PAGE_TOKENS = chip_smoke.SERVING_SIZES["page_tokens"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct on the first described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    return make
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without a chip (the next one warns
+    and compiles again): keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_kv_heads", [16, 4])
+def test_paged_kernel_lowers_at_serving_widths(sds, n_kv_heads):
+    """(H 16, Hkv 16, D 128) is what the smoke serves; (16, 4, 128) is
+    the GQA shape.  Both lower, so the dispatcher needs no refusal."""
+    n, h, d, pages, max_pages = 8, 16, 128, 256, 64
+    kv = sds((pages, PAGE_TOKENS, n_kv_heads, d), jnp.float32)
+    compiled = jax.jit(functools.partial(
+        paged_attention_pallas, interpret=False)).lower(
+            sds((n, h, d), jnp.float32), kv, kv,
+            sds((n, max_pages), jnp.int32), sds((n,), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_paged_kernel_lowers_with_local_block(sds):
+    """The speculative-verify form: 8 slots x 5 rows, a local key block
+    merged outside the kernel."""
+    s, k1, h, d, pages, max_pages = 8, 5, 16, 128, 256, 64
+    kv = sds((pages, PAGE_TOKENS, h, d), jnp.float32)
+    local = sds((s, k1, h, d), jnp.float32)
+
+    def verify(q, k, v, tables, lengths, lk, lv, mask):
+        return paged_attention_pallas(q, k, v, tables, lengths,
+                                      local_k=lk, local_v=lv,
+                                      local_mask=mask, interpret=False)
+    compiled = jax.jit(verify).lower(
+        sds((s * k1, h, d), jnp.float32), kv, kv,
+        sds((s * k1, max_pages), jnp.int32), sds((s * k1,), jnp.int32),
+        local, local, sds((s, k1, k1), jnp.bool_)).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_kernel_lowers_at_2048(sds, dtype):
+    x = sds((1, 2048, 16, 128), dtype)
+    compiled = jax.jit(functools.partial(
+        flash_attention, causal=True, interpret=False)).lower(
+            x, x, x).compile()
+    assert _has_kernel(compiled)
+
+
+def test_flash_kernel_lowers_padded_and_at_full_precision(sds):
+    """The dense reference's shape: one padded 128-token block, traced
+    under the model's float32 matmul precision."""
+    x = sds((1, 100, 16, 128), jnp.float32)
+
+    def attend(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return flash_attention(q, k, v, causal=True, interpret=False)
+    compiled = jax.jit(attend).lower(x, x, x).compile()
+    assert _has_kernel(compiled)
+
+
+def test_rail_programs_compile_at_a_4mb_block(sds):
+    """The rail's stage / unstage / slice / splice / copy programs at the
+    stream's chunk size (ici/block_pool.py, ici/endpoint.py, rail.py)."""
+    from brpc_tpu.ici import block_pool, endpoint, rail
+    nbytes = 4 * 1024 * 1024
+    chunk = sds((nbytes // 4,), jnp.float32)
+    raw = sds((nbytes,), jnp.uint8)
+    offset = sds((), jnp.int32)
+    block_pool._stage.lower(chunk, nbytes).compile()
+    block_pool._unstage.lower(raw, "float32", (nbytes // 4,)).compile()
+    block_pool._slice_bytes.lower(raw, offset, 64 * 1024).compile()
+    block_pool._splice_bytes.lower(
+        raw, sds((64 * 1024,), jnp.uint8), offset).compile()
+    endpoint._device_copy.lower(chunk).compile()
+    endpoint._multi_copy.lower(*[chunk] * 4).compile()
+    rail._slice_chunk.lower(raw, offset, 2 * 1024 * 1024).compile()
+    rail._cat.lower([raw, raw]).compile()
+
+
+def test_full_width_decode_step_compiles_and_fits(sds, monkeypatch):
+    """The runner's ``step`` at the smoke's shapes, kernel included.  Its
+    memory analysis is what ``chip_smoke.CACHE_BLOCKS`` was sized from
+    (the temporaries are ~5x the cache, ROADMAP S1)."""
+    # the dispatcher asks jax which backend is live; steer it to the
+    # branch the chip takes
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = chip_smoke.full_width_config()
+    slots = chip_smoke.SERVING_SIZES["num_slots"]
+    max_pages = chip_smoke.SERVING_SIZES["max_pages_per_slot"]
+    page_bytes = PAGE_TOKENS * cfg.kv_bytes_per_token
+    params = {k: sds(v.shape, v.dtype) for k, v in jax.eval_shape(
+        lambda: init_runner_params(cfg)).items()}
+    compiled = _jits()["step"].lower(
+        params, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, max_pages), jnp.int32),
+        sds((chip_smoke.CACHE_BLOCKS, page_bytes), jnp.uint8),
+        cfg=cfg, page_tokens=PAGE_TOKENS, backend=None, mesh=None).compile()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    cache = chip_smoke.CACHE_BLOCKS * page_bytes
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes + cache
+    assert need <= (1.0 - chip_smoke.MEMORY_HEADROOM) * 16 * 2**30, \
+        f"decode step needs {need / 1e9:.2f} GB of a 16 GiB chip"

@@ -21,6 +21,78 @@ def test_virtual_mesh_has_8_devices():
     assert mesh.shape["chip"] == 8
 
 
+class TestDeviceFor:
+    """ici://<slice>/<chip> names a chip this process has, or nothing:
+    an index past the device count used to wrap onto chip 0."""
+
+    def test_index_in_range_is_that_device(self):
+        from brpc_tpu.ici import device_for
+        assert device_for(3) == jax.devices()[3]
+
+    @pytest.mark.parametrize("index", [8, 11, -1])
+    def test_index_out_of_range_raises(self, index):
+        from brpc_tpu.ici import device_for
+        with pytest.raises(ValueError, match="8 device"):
+            device_for(index)
+
+    def test_ici_channel_to_a_missing_chip_raises(self):
+        with pytest.raises(ValueError, match="out of range"):
+            IciChannel("ici://slice0/8")
+
+
+class TestCompileCache:
+    """One function places jax's persistent compile cache: nowhere when
+    the environment already did, else a fixed directory in the checkout."""
+
+    @pytest.fixture()
+    def cache_config(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_variable_set_means_the_code_sets_nothing(self, monkeypatch,
+                                                      cache_config):
+        from brpc_tpu.ici import mesh
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(mesh.COMPILE_CACHE_ENV, "/somewhere/else")
+        assert mesh.ensure_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_variable_unset_means_the_fixed_checkout_path(
+            self, monkeypatch, cache_config):
+        import os
+
+        from brpc_tpu.ici import mesh
+        monkeypatch.delenv(mesh.COMPILE_CACHE_ENV, raising=False)
+        jax.config.update("jax_compilation_cache_dir", None)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert mesh.ensure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert mesh.ensure_compile_cache() == want      # idempotent
+        assert mesh.REPO_COMPILE_CACHE_DIR == want
+
+    def test_the_first_touches_of_jax_place_it(self, monkeypatch,
+                                               cache_config):
+        """A BlockPool (the rail and the KV cache), a PS shard and a
+        runner each call it; none needs a caller to remember."""
+        from brpc_tpu.ici import mesh
+        from brpc_tpu.psserve import EmbeddingShardServer
+        monkeypatch.delenv(mesh.COMPILE_CACHE_ENV, raising=False)
+        for first_touch in (BlockPool,
+                            lambda: EmbeddingShardServer(0, 1, 64, 8)):
+            jax.config.update("jax_compilation_cache_dir", None)
+            first_touch()
+            assert jax.config.jax_compilation_cache_dir \
+                == mesh.REPO_COMPILE_CACHE_DIR
+
+    def test_the_cache_directory_is_git_ignored(self):
+        import os
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+
 class TestBlockPool:
     def test_alloc_classes_and_roundtrip(self):
         pool = get_block_pool()
@@ -33,6 +105,33 @@ class TestBlockPool:
         big = pool.alloc(100_000)
         assert big.nbytes == 2 * 1024 * 1024
         big.free()
+
+    def test_a_pool_cut_to_its_pages_takes_what_the_rail_classes_cannot(
+            self):
+        """A full-width KV page (4 MiB) outgrows the largest rail class;
+        its cache brings a pool whose one class is a page."""
+        from brpc_tpu.models.runner import TransformerConfig, make_store_for
+        with pytest.raises(MemoryError):
+            get_block_pool().alloc(4 * 1024 * 1024)
+        pool = BlockPool(classes=(4 * 1024 * 1024,), blocks_per_class=3)
+        blocks = [pool.alloc(4 * 1024 * 1024) for _ in range(3)]
+        assert pool.stats()["classes"] == {
+            str(4 * 1024 * 1024): {"free": 0, "total": 3}}
+        with pytest.raises(MemoryError):
+            pool.alloc(1)
+        for b in blocks:
+            b.free()
+        cfg = TransformerConfig(d_model=64, n_layers=16, n_heads=16,
+                                n_kv_heads=16, head_dim=128, d_ff=64)
+        store = make_store_for(cfg, page_tokens=16, max_blocks=2,
+                               name="t_ici_bigpage")
+        try:
+            pp = store.pagepool
+            assert pp.page_bytes == 4 * 1024 * 1024
+            assert (pp.block_class, pp.pages_per_block) == (pp.page_bytes, 1)
+            assert pp.arena().shape == (2, pp.page_bytes)
+        finally:
+            store.close()
 
     def test_exhaustion_and_stats(self):
         pool = BlockPool()

@@ -254,40 +254,3 @@ def test_ship_many_power_of_two_decomposition(monkeypatch):
     for i, o in enumerate(out):
         np.testing.assert_array_equal(np.asarray(o), np.asarray(arrs[i]))
     assert all(s <= r._MAX_ARITY for s in sizes)
-
-
-class TestBdpWindow:
-    """rail._window_for sizes the credit window to measured completion
-    RTT x target bandwidth (the rdma SQ/window discipline): floor 256MB
-    on fast links, cap 2GB on pathological ones — a fixed window caps
-    any link at window/RTT (measured: 256MB on a 64ms tunnel = 2 GB/s)."""
-
-    def test_fast_link_gets_floor(self, monkeypatch):
-        from brpc_tpu.ici import rail as r
-        monkeypatch.setattr(r, "_completion_rtt", lambda dev: 1e-6)
-        assert r._window_for(object()) == r._RAIL_WINDOW_FLOOR
-
-    def test_slow_link_scales_with_bdp(self, monkeypatch):
-        from brpc_tpu.ici import rail as r
-        # 16ms RTT x 32 GB/s = 512MB: between floor and cap
-        monkeypatch.setattr(r, "_completion_rtt", lambda dev: 0.016)
-        assert r._window_for(object()) == int(0.016 * r._RAIL_TARGET_BW)
-
-    def test_pathological_link_hits_cap(self, monkeypatch):
-        from brpc_tpu.ici import rail as r
-        monkeypatch.setattr(r, "_completion_rtt", lambda dev: 0.5)
-        assert r._window_for(object()) == r._RAIL_WINDOW_CAP
-
-    def test_probe_failure_falls_back_to_floor(self, monkeypatch):
-        from brpc_tpu.ici import rail as r
-
-        def boom(dev):
-            raise RuntimeError("no device")
-        monkeypatch.setattr(r, "_completion_rtt", boom)
-        assert r._window_for(object()) == r._RAIL_WINDOW_FLOOR
-
-    def test_real_probe_on_cpu_returns_sane_window(self):
-        import jax
-        from brpc_tpu.ici import rail as r
-        w = r._window_for(jax.devices()[0])
-        assert r._RAIL_WINDOW_FLOOR <= w <= r._RAIL_WINDOW_CAP

@@ -7,11 +7,7 @@ loop is built on ``sys.monitoring`` (PEP 669): LINE events over every
 code object in ``brpc_tpu.rpc.h2`` and ``brpc_tpu.rpc.hpack``, with the
 callback returning ``sys.monitoring.DISABLE`` after the first hit of
 each line — so steady-state overhead is near zero and anything the
-callback reports IS new global coverage.  On interpreters older than
-3.12 (``sys.monitoring`` absent — this image ships 3.10) the tool
-transparently degrades to the :class:`SettraceTracker` fallback: same
-corpus decisions, slower per exec (the result dict's
-``coverage_backend`` names which one ran).  An input that lights up a
+callback reports IS new global coverage.  An input that lights up a
 new line joins the corpus; mutations are the classic menu (bit flips,
 byte splices, truncations, frame-header-aware length/type/flag
 smashing, cross-member splices).
@@ -105,72 +101,6 @@ class CoverageTracker:
         mon = sys.monitoring
         mon.register_callback(TOOL_ID, mon.events.LINE, None)
         mon.free_tool_id(TOOL_ID)
-
-
-class SettraceTracker:
-    """Pre-PEP-669 fallback (``sys.monitoring`` is 3.12+; this image
-    runs 3.10): ``sys.settrace`` line events over the same code-object
-    set, same contract as :class:`CoverageTracker` (``hits`` holds
-    exactly the not-yet-seen coverage, ``take_new``/``close``).
-
-    settrace has no per-line DISABLE, so the cost model is different:
-    the global hook prunes to target code objects at call time, and a
-    frame whose code object is fully covered returns ``None`` from its
-    local trace to stop line events for that FRAME — steady state pays
-    one dict probe per call instead of near-zero, roughly 3-5x slower
-    per exec than the monitoring backend but with identical corpus
-    growth decisions."""
-
-    BACKEND = "settrace"
-
-    def __init__(self, modules):
-        self.hits: set = set()
-        self.total_lines = 0
-        self._want: dict = {}   # id(code) -> unhit line set
-        for module in modules:
-            for code in _iter_code_objects(module):
-                try:
-                    lines = set(ln for _, _, ln in code.co_lines() if ln)
-                except Exception:
-                    continue
-                if lines and id(code) not in self._want:
-                    self._want[id(code)] = lines
-                    self.total_lines += len(lines)
-        self._prev = sys.gettrace()
-        sys.settrace(self._global)
-
-    def _global(self, frame, event, arg):
-        if event == "call" and id(frame.f_code) in self._want:
-            return self._local
-        return None
-
-    def _local(self, frame, event, arg):
-        if event == "line":
-            want = self._want.get(id(frame.f_code))
-            if want:
-                ln = frame.f_lineno
-                if ln in want:
-                    want.discard(ln)
-                    self.hits.add((id(frame.f_code), ln))
-                if not want:
-                    return None   # fully covered: mute this frame
-        return self._local
-
-    def take_new(self) -> int:
-        n = len(self.hits)
-        self.hits.clear()
-        return n
-
-    def close(self):
-        sys.settrace(self._prev)
-
-
-def make_tracker(modules):
-    """The best line tracker this interpreter offers: PEP 669
-    monitoring on 3.12+, the settrace fallback otherwise."""
-    if hasattr(sys, "monitoring"):
-        return CoverageTracker(modules)
-    return SettraceTracker(modules)
 
 
 def make_conn():
@@ -332,7 +262,7 @@ def fuzz(execs: int, seed: int = 7, log=print,
     from brpc_tpu.rpc import h2 as h2m
     from brpc_tpu.rpc import hpack as hpack_m
 
-    tracker = make_tracker([h2m, hpack_m])
+    tracker = CoverageTracker([h2m, hpack_m])
     rng = random.Random(seed)
     corpus = list(seeds(base_only=base_seeds_only))
     covered = 0
@@ -361,7 +291,7 @@ def fuzz(execs: int, seed: int = 7, log=print,
                 f"corpus {len(corpus)}, {r:.0f}/s")
     tracker.close()
     return {"execs": min(execs, i + 1 if execs else 0),
-            "coverage_backend": getattr(tracker, "BACKEND", "monitoring"),
+            "coverage_backend": tracker.BACKEND,
             "covered_lines": covered,
             "total_lines": tracker.total_lines,
             "corpus_size": len(corpus),

@@ -12,8 +12,7 @@ real?" into a decision rule with no magic tolerance constant:
     the runs don't even overlap.
 
 Usage:
-    python tools/perf_diff.py BENCH_r05.json BENCH_r06.json
-    python tools/perf_diff.py BENCH_r05.json BENCH_DETAILS.json
+    python tools/perf_diff.py BENCH_r03.json BENCH_DETAILS.json
 
 Accepts either the driver's round wrapper ({"tail": "...detail name:
 {...} lines..."}) or a plain details JSON (BENCH_DETAILS.json, or the
