@@ -1,0 +1,9 @@
+"""Layer serving/engine: the median over ALL gaps between consecutive
+tokens of a stream as the client received them: what a decode step costs
+a user while nothing stalls it.  A steadier statistic beside
+``tokens_per_s``, which it should move."""
+from benchmarks.harness import readers
+
+
+def compute(run):
+    return readers.token_gap_percentile_ms(run, 50.0)
