@@ -14,7 +14,6 @@ the arena slice.
 """
 from __future__ import annotations
 
-import functools
 import threading
 from dataclasses import dataclass
 
@@ -33,8 +32,12 @@ host_stage_count = Adder("blockpool_host_stages")
 host_read_count = Adder("blockpool_host_reads")
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _stage(x, cls: int):
+# Each jitted function below carries a name of its own: a trace names a
+# device program after the Python function (``jit_blockpool_stage``), and
+# ``jit__stage`` would say nothing of whose it is.  The module-level
+# names other modules import (``_stage``, ``_slice_bytes``...) stay.
+
+def blockpool_stage(x, cls: int):
     """Reinterpret a tensor's bytes as uint8 and pad into a block-class
     buffer — entirely on device (no host bounce).  Runs on the source
     array's device; the output is always a fresh buffer."""
@@ -47,8 +50,10 @@ def _stage(x, cls: int):
     return jax.lax.dynamic_update_slice(out, flat, (0,))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _unstage(buf, dtype_name: str, shape: tuple):
+_stage = jax.jit(blockpool_stage, static_argnums=(1,))
+
+
+def blockpool_unstage(buf, dtype_name: str, shape: tuple):
     """Rebuild a tensor from a block's byte buffer, on device."""
     dt = np.dtype(dtype_name)
     n = int(np.prod(shape, dtype=np.int64)) if shape else 1
@@ -61,20 +66,28 @@ def _unstage(buf, dtype_name: str, shape: tuple):
     return jax.lax.bitcast_convert_type(
         raw.reshape(n, dt.itemsize), dt).reshape(shape)
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _slice_bytes(buf, off, nbytes: int):
+
+_unstage = jax.jit(blockpool_unstage, static_argnums=(1, 2))
+
+
+def blockpool_slice_bytes(buf, off, nbytes: int):
     """Read nbytes out of a block buffer at a dynamic byte offset, on
     device (the page-granularity read half of splice)."""
     return jax.lax.dynamic_slice(buf, (off,), (nbytes,))
 
 
-@jax.jit
-def _splice_bytes(buf, piece, off):
+_slice_bytes = jax.jit(blockpool_slice_bytes, static_argnums=(2,))
+
+
+def blockpool_splice_bytes(buf, piece, off):
     """Write `piece` into a block buffer at a dynamic byte offset, on
     device — the rest of the buffer is untouched, so several sub-block
     regions (KV pages) can share one block without clobbering each
     other the way a wholesale put() would."""
     return jax.lax.dynamic_update_slice(buf, piece, (off,))
+
+
+_splice_bytes = jax.jit(blockpool_splice_bytes)
 
 
 # size classes, mirroring the reference's 8KB/64KB/2MB (block_pool.cpp:52)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from brpc_tpu import rpcz
 from brpc_tpu.bvar import Adder, LatencyRecorder
 from brpc_tpu.ici.mesh import get_mesh
 
@@ -31,6 +32,8 @@ def shard_map(f, mesh, in_specs, out_specs):
 
 
 _lowered_calls = Adder("ici_collective_calls")
+# the time of a lowered call from program lookup to its result being
+# ready on the mesh (``_run``): a latency, not the time of an enqueue
 _lowered_latency = LatencyRecorder("ici_collective")
 
 
@@ -60,13 +63,31 @@ class CollectiveGroup:
         says.  A caller's array is usually COMMITTED to the one chip
         that produced it, and a program spanning the mesh refuses such
         an argument; the broadcast (or the split) is the fan-out."""
-        return jax.device_put(x, NamedSharding(self.mesh, spec))
+        with rpcz.stage("collective.place") as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=rpcz.payload_bytes(x))
+            return jax.device_put(x, NamedSharding(self.mesh, spec))
+
+    def _run(self, key, build, placed):
+        """Look the program up (building it on first use), launch it on
+        the placed request and wait for the result: the lowered call's
+        latency ends when the merged result is ready on the mesh."""
+        import time
+        t0 = time.monotonic()
+        with rpcz.stage("collective.run") as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(cache_hit=int(key in self._cache))
+            out = jax.block_until_ready(self._get(key, build)(placed))
+        _lowered_calls.add(1)
+        _lowered_latency.add(int((time.monotonic() - t0) * 1e6))
+        return out
 
     # ---- ParallelChannel lowering: same request to every chip ----
 
     def parallel_apply(self, fn: Callable, x, merge: str = "stack"):
         """Broadcast x, run fn per chip, merge: "stack" | "sum" | "concat"
-        | "none" (leave per-chip results sharded)."""
+        | "none" (leave per-chip results sharded).  Returns when the
+        result is ready."""
         axis = self.axis
 
         def build():
@@ -87,20 +108,16 @@ class CollectiveGroup:
                            out_specs=out_spec)
             return jax.jit(sm)
 
-        import time
-        t0 = time.monotonic()
         # keyed by the fn OBJECT (kept alive by the cache): id() keys could
         # be reused after GC and serve a stale compiled program
-        out = self._get(("par", fn, merge), build)(self._place(x, P()))
-        _lowered_calls.add(1)
-        _lowered_latency.add(int((time.monotonic() - t0) * 1e6))
-        return out
+        return self._run(("par", fn, merge), build, self._place(x, P()))
 
     # ---- PartitionChannel lowering: shard the request ----
 
     def partition_apply(self, fn: Callable, x, merge: str = "concat"):
         """Shard x along axis 0 across chips, run fn per shard, merge:
-        "concat" | "sum" | "none" (keep sharded)."""
+        "concat" | "sum" | "none" (keep sharded).  Returns when the
+        result is ready."""
         axis = self.axis
 
         def build():
@@ -114,13 +131,8 @@ class CollectiveGroup:
             return jax.jit(shard_map(per_chip, self.mesh,
                                      in_specs=in_spec, out_specs=out_spec))
 
-        import time
-        t0 = time.monotonic()
-        out = self._get(("part", fn, merge), build)(
-            self._place(x, P(axis)))
-        _lowered_calls.add(1)
-        _lowered_latency.add(int((time.monotonic() - t0) * 1e6))
-        return out
+        return self._run(("part", fn, merge), build,
+                         self._place(x, P(axis)))
 
     # ---- primitives for the ici_performance ladder ----
 

@@ -19,7 +19,6 @@ with an on-device unstage.  The payload never exists as host bytes —
 """
 from __future__ import annotations
 
-import functools
 import itertools
 import threading
 import time
@@ -28,6 +27,7 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
+from brpc_tpu import rpcz
 from brpc_tpu.bvar import Adder
 from brpc_tpu.ici.block_pool import (BLOCK_CLASSES, Block, _stage, _unstage,
                                      get_block_pool)
@@ -80,15 +80,20 @@ def lookup(endpoint) -> object | None:
 # staging: device arrays <-> BlockPool slots, entirely on device
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _slice_chunk(flat, offset, size: int):
+# named for the trace (``jit_rail_slice_chunk``, ``jit_rail_cat``), as
+# the copy programs of ici/endpoint.py are
+
+def rail_slice_chunk(flat, offset, size: int):
     return jax.lax.dynamic_slice(flat, (offset,), (size,))
 
 
-@jax.jit
-def _cat(bufs):
+def rail_cat(bufs):
     import jax.numpy as jnp
     return jnp.concatenate(bufs)
+
+
+_slice_chunk = jax.jit(rail_slice_chunk, static_argnums=(2,))
+_cat = jax.jit(rail_cat)
 
 
 @dataclass
@@ -277,14 +282,16 @@ def _norm(ticket) -> str:
 
 def claim(ticket):
     """Pop the ticket and rebuild device arrays (frees the blocks)."""
-    ticket = _norm(ticket)
-    with _reg_lock:
-        item = _registry.pop(ticket, None)
-    if item is None:
-        raise KeyError(f"rail ticket {ticket!r} expired or already claimed")
-    entries, single, _ = item
-    arrays = [e.unstage() for e in entries]
-    return arrays[0] if single else arrays
+    with rpcz.stage("rail.claim"):
+        ticket = _norm(ticket)
+        with _reg_lock:
+            item = _registry.pop(ticket, None)
+        if item is None:
+            raise KeyError(
+                f"rail ticket {ticket!r} expired or already claimed")
+        entries, single, _ = item
+        arrays = [e.unstage() for e in entries]
+        return arrays[0] if single else arrays
 
 
 def withdraw(ticket) -> None:
@@ -382,7 +389,19 @@ def ship_many(objs, target_device) -> list[str]:
     are unchanged.  Dispatch is host work per program, so this is the
     difference between per-message and per-batch transfer cost (the h2
     frame-coalescing story, applied to tensors)."""
+    with rpcz.stage("rail.ship") as stg:
+        tickets, nbytes, programs = _ship_many(objs, target_device)
+        if stg is not rpcz.NOOP_STAGE:
+            stg.set(bytes=nbytes, programs=programs, cross_device=int(
+                source_device(objs[0]) != target_device))
+        return tickets
+
+
+def _ship_many(objs, target_device) -> tuple[list[str], int, int]:
+    """``ship_many``'s work; also the bytes shipped and the dispatches
+    (endpoint sends) they took."""
     ep = _endpoint_for(target_device)
+    shipped = programs = 0
     # (payload idx, array, nbytes): jax.Array.nbytes is a COMPUTED
     # property (prod(shape) * itemsize per access) — cache it once per
     # array; the run-packing loop below reads it repeatedly
@@ -410,6 +429,8 @@ def ship_many(objs, target_device) -> list[str]:
                 per_obj[oi].append(_Entry(moved, str(np.dtype(a.dtype)),
                                           tuple(a.shape), a_nbytes))
                 rail_bytes.add(a_nbytes)
+                shipped += a_nbytes
+                programs += 1
                 i += 1
                 continue
             # whole-array fast path: group a window-fitting run of arrays
@@ -436,10 +457,12 @@ def ship_many(objs, target_device) -> list[str]:
                 sub = [x for _, x, _ in run[j:j + k]]
                 moved_run.extend(ep.send_batch(sub) if k > 1
                                  else [ep.send(sub[0])])
+                programs += 1
                 j += k
             for (roi, _, src_nb), m in zip(run, moved_run):
                 per_obj[roi].append(_DirectEntry(m, src_nb))
                 rail_bytes.add(src_nb)
+            shipped += run_bytes
             i += len(run)
     except Exception:
         for es in per_obj:
@@ -447,7 +470,8 @@ def ship_many(objs, target_device) -> list[str]:
                 e.free()
         raise
     rail_payloads.add(len(objs))
-    return [deposit(es, single) for es, single in zip(per_obj, singles)]
+    return ([deposit(es, single) for es, single in zip(per_obj, singles)],
+            shipped, programs)
 
 
 # ---------------------------------------------------------------------------

@@ -418,9 +418,20 @@ def _kv_view(arena_u8, cfg: TransformerConfig, page_tokens: int):
                          cfg.n_kv_heads, cfg.head_dim)
 
 
-def _jit(fn):
+def _jit(fn, scope: str):
+    """``fn`` jitted under a stable name: the program is
+    ``jit_<scope, dots as underscores>`` and every operation in it is
+    named under ``jax.named_scope(scope)``, so a trace's reduction finds
+    the decode step and each prefill stage after a refactor."""
     import jax
-    return jax.jit(_float32_matmuls(fn),
+    inner = _float32_matmuls(fn)
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(scope):
+            return inner(*args, **kwargs)
+    scoped.__name__ = scoped.__qualname__ = scope.replace(".", "_")
+    return jax.jit(scoped,
                    static_argnames=("cfg", "page_tokens", "backend",
                                     "mesh"))
 
@@ -576,9 +587,11 @@ def _jits():
             kv_rows, jnp.uint8).reshape(s, k1, cfg.kv_bytes_per_token)
         return nxt.reshape(s, k1), rows_u8
 
-    return {"embed": _jit(embed), "proj": _jit(proj),
-            "attend": _jit(attend), "step": _jit(step),
-            "verify": _jit(verify)}
+    return {"embed": _jit(embed, "runner.prefill.embed"),
+            "proj": _jit(proj, "runner.prefill.proj"),
+            "attend": _jit(attend, "runner.prefill.attend"),
+            "step": _jit(step, "runner.decode_step"),
+            "verify": _jit(verify, "runner.verify")}
 
 
 def make_store_for(cfg: TransformerConfig, *, page_tokens: int = 8,

@@ -352,13 +352,18 @@ def paged_attention(q, k_pages, v_pages, tables, lengths,
     interpret mode for a kernel that would not lower."""
     if backend is None:
         backend = default_backend()
-    if backend == "gather":
-        return paged_attention_gather(q, k_pages, v_pages, tables,
-                                      lengths, extra_k, extra_v,
-                                      local_k, local_v, local_mask)
-    if backend == "pallas":
+    if backend not in ("gather", "pallas"):
+        raise ValueError(f"unknown paged_attention backend {backend!r}")
+    # every operation of the call (the kernel, the gathers, the self-key
+    # fold) is named under one scope, inside whatever scope the caller
+    # traces it in: a trace can tell the call's device time from the
+    # rest of the step
+    with jax.named_scope("ops.paged_attention"):
+        if backend == "gather":
+            return paged_attention_gather(q, k_pages, v_pages, tables,
+                                          lengths, extra_k, extra_v,
+                                          local_k, local_v, local_mask)
         return paged_attention_pallas(q, k_pages, v_pages, tables,
                                       lengths, extra_k, extra_v,
                                       local_k, local_v, local_mask,
                                       interpret=interpret)
-    raise ValueError(f"unknown paged_attention backend {backend!r}")

@@ -18,7 +18,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
-from brpc_tpu import errors
+from brpc_tpu import errors, rpcz
 from brpc_tpu.butil.endpoint import EndPoint, str2endpoint
 from brpc_tpu.rpc import meta as M
 from brpc_tpu.rpc.controller import Controller, OneShotEvent
@@ -254,10 +254,12 @@ class _CallState:
     __slots__ = ("cntl", "channel", "meta_template", "body", "done",
                  "deadline_timer", "backup_timer", "sids", "sid_attempts",
                  "tried_servers", "pooled_conns", "short_conns", "rail_obj",
-                 "rail_tickets", "rail_fallback_cache")
+                 "rail_tickets", "rail_fallback_cache", "span")
 
-    def __init__(self, cntl, channel, meta_template, body, done):
+    def __init__(self, cntl, channel, meta_template, body, done,
+                 span=rpcz.NULL_SPAN):
         self.cntl = cntl
+        self.span = span      # the call's client span (rpcz)
         self.channel = channel
         self.meta_template = meta_template
         self.body = body
@@ -374,6 +376,14 @@ class CallManager:
     def _on_response(self, meta: M.RpcMeta, body) -> None:
         with self._lock:
             st = self._pending.get(meta.correlation_id)
+        with rpcz.span_scope(st.span if st is not None else rpcz.NULL_SPAN), \
+                rpcz.stage("rpc.client.on_response", meta.correlation_id):
+            self._complete(st, meta, body)
+
+    def _complete(self, st: Optional[_CallState], meta: M.RpcMeta,
+                  body) -> None:
+        """A response frame against the pending call it names: retry,
+        fail or decode, then ``_finish``."""
         if st is None:
             # stale attempt after completion — dropped; a rail ticket riding
             # it must be freed now, not left to the registry TTL
@@ -531,6 +541,11 @@ class CallManager:
         cntl = st.cntl
         import time
         cntl.latency_us = int(time.monotonic() * 1e6) - cntl._start_us
+        span = st.span
+        if span is not rpcz.NULL_SPAN:
+            span.remote_side = cntl.remote_side
+            span.error_code = cntl.error_code
+            rpcz.submit(span)
         if st.rail_tickets:
             # free staged payloads of attempts the server never claimed
             # (timeouts, failed sockets); claim is an atomic pop, so a
@@ -649,8 +664,41 @@ class Channel:
              _sync_join: bool = False) -> Controller:
         """Issue an RPC.  With done=None this is async-with-join: the
         returned controller has an event; use .join() or call_sync()."""
-        import time
         cntl = cntl or Controller()
+        cid = cntl.correlation_id = next(_cid_counter)
+        span = rpcz.child_span("client", service, method_name)
+        with rpcz.span_scope(span), rpcz.stage("rpc.client.call", cid) as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=rpcz.payload_bytes(request))
+            return self._start(service, method_name, request, cntl, done,
+                               serializer, response_serializer, span,
+                               _sync_join)
+
+    def call_sync(self, service: str, method_name: str, request: Any = b"",
+                  serializer: str = "raw", cntl: Controller | None = None,
+                  response_serializer: str | None = None) -> Any:
+        cntl = cntl or Controller()
+        cid = cntl.correlation_id = next(_cid_counter)
+        span = rpcz.child_span("client", service, method_name)
+        # the stage ends when the reply is in the caller's hands
+        with rpcz.span_scope(span), rpcz.stage("rpc.client.call", cid) as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=rpcz.payload_bytes(request))
+            self._start(service, method_name, request, cntl, None,
+                        serializer, response_serializer, span,
+                        sync_join=True)
+            cntl.join()
+        cntl.raise_if_failed()
+        return cntl.response
+
+    def _start(self, service: str, method_name: str, request: Any,
+               cntl: Controller, done, serializer: str,
+               response_serializer: str | None, span,
+               sync_join: bool = False) -> Controller:
+        """Build the call's state and send its first attempt.  The
+        caller has named the call (``cntl.correlation_id``) and made its
+        client span."""
+        import time
         opts = self.options
         if cntl.timeout_ms is None:
             cntl.timeout_ms = opts.timeout_ms
@@ -658,7 +706,6 @@ class Channel:
             cntl.max_retry = opts.max_retry
         if cntl.backup_request_ms is None:
             cntl.backup_request_ms = opts.backup_request_ms
-        cntl.correlation_id = next(_cid_counter)
         cntl._start_us = int(time.monotonic() * 1e6)
         if done is None:
             cntl._done_event = OneShotEvent()
@@ -724,23 +771,24 @@ class Channel:
                 meta.user_fields[M.F_SDEV] = rail.device_advert(
                     stream.device)
 
-        # rpcz span (the sampled bit rides a meta flag so the callee
-        # inherits the trace-root decision instead of re-rolling)
-        from brpc_tpu.rpcz import current_trace_ctx
-        tid, sid_, smp = current_trace_ctx()
-        meta.trace_id = cntl.trace_id = tid
-        meta.span_id = cntl.span_id = sid_
-        if tid and smp:
+        # the client span is the callee's parent; the sampled bit rides
+        # a meta flag so the callee inherits the trace-root decision
+        # instead of re-rolling.  With rpcz off the null span reads as
+        # zeros and the frame stays on the native fast path.
+        meta.trace_id = cntl.trace_id = span.trace_id
+        meta.span_id = cntl.span_id = span.span_id
+        if span.trace_id and span.sampled:
             meta.flags |= M.FLAG_TRACE_SAMPLED
+        span.request_size = len(body)
 
-        st = _CallState(cntl, self, meta, body, done)
+        st = _CallState(cntl, self, meta, body, done, span)
         st.rail_obj = rail_obj
         mgr = CallManager.instance()
         mgr.register(st)
 
         t = Transport.instance()
         if cntl.timeout_ms and cntl.timeout_ms > 0:
-            if _sync_join:
+            if sync_join:
                 # call_sync joins immediately: the joining thread IS the
                 # deadline timer (join() computes the remaining budget from
                 # _start_us and fires on_deadline itself) — saves a native
@@ -756,15 +804,6 @@ class Channel:
                                          lambda: self._issue_backup(st))
         self._issue(st)
         return cntl
-
-    def call_sync(self, service: str, method_name: str, request: Any = b"",
-                  serializer: str = "raw", **kw) -> Any:
-        cntl = kw.pop("cntl", None)
-        cntl = self.call(service, method_name, request, cntl=cntl,
-                         serializer=serializer, _sync_join=True, **kw)
-        cntl.join()
-        cntl.raise_if_failed()
-        return cntl.response
 
     def _issue(self, st: _CallState) -> None:
         """Send the current attempt.  On immediate failure, walk the retry

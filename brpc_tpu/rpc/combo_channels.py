@@ -21,8 +21,8 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Optional, Sequence
 
-from brpc_tpu import errors
-from brpc_tpu.rpc.channel import Channel, ChannelOptions
+from brpc_tpu import errors, rpcz
+from brpc_tpu.rpc.channel import Channel, ChannelOptions, _cid_counter
 from brpc_tpu.rpc.controller import Controller, OneShotEvent
 
 
@@ -124,7 +124,6 @@ class ParallelChannel:
         handed to the merger."""
         from brpc_tpu.ici.channel import device_service_registry
         import time
-        import jax
         fn = device_service_registry().get((service, method))
         if fn is None:
             cntl.set_failed(errors.ENOMETHOD,
@@ -133,14 +132,17 @@ class ParallelChannel:
             merge = "sum" if isinstance(self.response_merger, SumMerger) \
                 else "stack"
             t0 = time.monotonic()
+            cntl.correlation_id = cntl.correlation_id or next(_cid_counter)
             try:
-                group = _collective_group_for(
-                    [ch.device for ch, _ in self._channels])
-                out = group.parallel_apply(fn, request, merge=merge)
-                out = jax.block_until_ready(out)  # real latency + surfaced
-                                                  # device-side failures
-                if merge == "stack":
-                    out = self.response_merger.merge(list(out))
+                with rpcz.stage("combo.call_lowered", cntl.correlation_id):
+                    group = _collective_group_for(
+                        [ch.device for ch, _ in self._channels])
+                    # returns with the result ready: real latency, and
+                    # device-side failures surface here
+                    out = group.parallel_apply(fn, request, merge=merge)
+                    if merge == "stack":
+                        with rpcz.stage("combo.merge"):
+                            out = self.response_merger.merge(list(out))
                 cntl.response = out
             except Exception as e:
                 cntl.set_failed(errors.EINTERNAL,

@@ -13,7 +13,7 @@ import threading
 import time
 from typing import Any, Callable, Optional
 
-from brpc_tpu import errors
+from brpc_tpu import errors, rpcz
 from brpc_tpu.rpc import meta as M
 
 
@@ -261,6 +261,10 @@ class Controller:
         (deadline disabled) this waits indefinitely."""
         if self._done_event is None:
             return
+        with rpcz.stage("rpc.client.wait", self.correlation_id):
+            self._wait_done(extra_timeout_s)
+
+    def _wait_done(self, extra_timeout_s: float) -> None:
         if not self.timeout_ms or self.timeout_ms <= 0:
             self._done_event.wait()
             return

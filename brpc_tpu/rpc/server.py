@@ -753,6 +753,11 @@ class Server:
     def _process_request(self, sid: int, meta: M.RpcMeta, body,
                          pre_accepted: bool = False) -> None:
         """ProcessRpcRequest analog (baidu_rpc_protocol.cpp:398)."""
+        with rpcz.stage("rpc.server.process", meta.correlation_id):
+            self._serve_request(sid, meta, body, pre_accepted)
+
+    def _serve_request(self, sid: int, meta: M.RpcMeta, body,
+                       pre_accepted: bool) -> None:
         start = time.monotonic()
         if self._stopping and not pre_accepted:
             self._respond_error(sid, meta, errors.ELOGOFF)
@@ -828,7 +833,8 @@ class Server:
                 # rail registry (ici/rail.py) — the frame carried only the
                 # ticket, no body bytes exist
                 from brpc_tpu.ici import rail
-                request = rail.claim(meta.user_fields[M.F_TICKET])
+                with rpcz.span_scope(span):
+                    request = rail.claim(meta.user_fields[M.F_TICKET])
                 span.request_size = 0
             else:
                 # fast-path bodies arrive as IOBuf-backed memoryviews
@@ -882,13 +888,14 @@ class Server:
         # paying a closure + once-guard lock per request.
         cntl._done_factory = lambda: self._make_server_done(
             sid, meta, span, cntl, spec, status, start, rail_src)
-        traced = span is not rpcz.NULL_SPAN
-        if traced:  # with rpcz off, skip the contextvar pair per request
-            rpcz.set_current_span(span)
         if self._session_pool is not None:
             cntl.session_data = self._session_pool.borrow()
         try:
-            response = spec.fn(cntl, request)
+            # with rpcz off the scope is the shared no-op: no context
+            # variable is touched per request
+            with rpcz.span_scope(span), \
+                    rpcz.stage("rpc.server.handler", meta.correlation_id):
+                response = spec.fn(cntl, request)
         except Exception as e:
             if cntl._deferred:
                 # defer() transferred response ownership to done(); the
@@ -903,8 +910,6 @@ class Server:
                                    start, rail_src, None, exc=e)
             return
         finally:
-            if traced:
-                rpcz.set_current_span(None)
             if self._session_pool is not None:
                 # deferred handlers must not rely on session_data after
                 # returning: the pooled object goes back with the handler
@@ -941,7 +946,20 @@ class Server:
         """Response path + accounting (SendRpcResponse analog,
         baidu_rpc_protocol.cpp:187).  Runs exactly once per accepted
         request — inline for plain handlers, from done() for deferred
-        ones."""
+        ones (then on the thread that called done)."""
+        try:
+            with rpcz.span_scope(span), \
+                    rpcz.stage("rpc.server.respond", meta.correlation_id):
+                self._respond(sid, meta, span, cntl, spec, status, start,
+                              rail_src, response, exc)
+        finally:
+            # after the stage has ended: its phase is in the span
+            span.end_us = rpcz.now_us()
+            rpcz.submit(span)
+
+    def _respond(self, sid: int, meta: M.RpcMeta, span, cntl, spec, status,
+                 start: float, rail_src, response,
+                 exc: Exception | None) -> None:
         # completion consumes the lazy done factory: a handler that
         # already responded and calls defer() afterwards now fails
         # loudly in defer() instead of minting a fresh once-guard and
@@ -1052,8 +1070,6 @@ class Server:
             if self._limiter is not None:
                 self._limiter.on_responded(error_code, latency_us)
             span.error_code = error_code
-            span.end_us = rpcz.now_us()
-            rpcz.submit(span)
             self._inflight_dec()
 
     def _ship_rail_response(self, sid: int, meta: M.RpcMeta, span, cntl,

@@ -35,7 +35,7 @@ import logging
 import threading
 from typing import Callable, Optional
 
-from brpc_tpu import errors, fault
+from brpc_tpu import errors, fault, rpcz
 from brpc_tpu.bvar import Adder
 from brpc_tpu.rpc import meta as M
 from brpc_tpu.rpc.transport import Transport
@@ -194,34 +194,43 @@ class Stream:
             nbytes = sum(a.nbytes for a in arrays)
         if self._closed or self._close_sent:
             raise errors.RpcError(errors.EEOF, "stream closed")
-        with self._window_cv:
-            deadline = None
-            while (self._produced + nbytes - self._remote_consumed
-                   > self.max_buf_size):
-                if self._closed:
-                    raise errors.RpcError(errors.EEOF, "stream closed")
-                import time
-                if deadline is None:
-                    if timeout_s is None:
-                        deadline = float("inf")
-                    else:
-                        deadline = time.monotonic() + timeout_s
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    raise errors.RpcError(
-                        errors.EOVERCROWDED,
-                        f"stream window full ({self.max_buf_size}B)")
-                self._window_cv.wait(min(remaining, 1.0))
-            self._produced += nbytes
-            seq = self._send_seq
-            self._send_seq += 1
-            if self._sid is None or self.remote_id is None:
-                self._pending.append((seq, kind, payload))
-                return
-        if kind == "bytes":
-            self._send_data(payload, seq)
-        else:
-            self._send_tensor(payload, seq)
+        with rpcz.stage("stream.write") as stg:
+            with self._window_cv:
+                if (self._produced + nbytes - self._remote_consumed
+                        > self.max_buf_size):
+                    with rpcz.stage("stream.credit_wait"):
+                        self._wait_credit(nbytes, timeout_s)
+                self._produced += nbytes
+                seq = self._send_seq
+                self._send_seq += 1
+                if self._sid is None or self.remote_id is None:
+                    self._pending.append((seq, kind, payload))
+                    return
+            if stg is not rpcz.NOOP_STAGE:
+                # the id the frame is addressed to: the receiver's stages
+                # of this message carry the same one
+                stg.set(cid=f"{self.remote_id}:{seq}", bytes=nbytes)
+            if kind == "bytes":
+                self._send_data(payload, seq)
+            else:
+                self._send_tensor(payload, seq)
+
+    def _wait_credit(self, nbytes: int, timeout_s: float | None) -> None:
+        """Park (``_window_cv`` held) until the peer's feedback leaves
+        room for ``nbytes``."""
+        import time
+        deadline = float("inf") if timeout_s is None \
+            else time.monotonic() + timeout_s
+        while (self._produced + nbytes - self._remote_consumed
+               > self.max_buf_size):
+            if self._closed:
+                raise errors.RpcError(errors.EEOF, "stream closed")
+            remaining = deadline - time.monotonic()
+            if remaining <= 0:
+                raise errors.RpcError(
+                    errors.EOVERCROWDED,
+                    f"stream window full ({self.max_buf_size}B)")
+            self._window_cv.wait(min(remaining, 1.0))
 
     def _send_data(self, data: bytes, seq: int) -> None:
         rc = Transport.instance().write_frame(
@@ -309,7 +318,8 @@ class Stream:
             # arrival order, mirroring the seq==0 CLOSE fallback
             if self.handler is not None:
                 try:
-                    self.handler.on_received_messages(self, [payload])
+                    with rpcz.stage("stream.handler"):
+                        self.handler.on_received_messages(self, [payload])
                 except Exception:
                     logging.exception("stream handler raised")
             self._ack(nbytes)
@@ -397,7 +407,8 @@ class Stream:
                     return
             if ready and self.handler is not None:
                 try:
-                    self.handler.on_received_messages(self, ready)
+                    with rpcz.stage("stream.handler"):
+                        self.handler.on_received_messages(self, ready)
                 except Exception:
                     # a raising handler must not wedge the drain loop
                     # (_delivering would stay True forever)
@@ -411,6 +422,10 @@ class Stream:
                 return
 
     def _ack(self, nbytes: int) -> None:
+        with rpcz.stage("stream.ack"):
+            self._note_consumed(nbytes)
+
+    def _note_consumed(self, nbytes: int) -> None:
         with self._mu:
             self._consumed_local += nbytes
             threshold = min(self.max_buf_size,
@@ -472,7 +487,6 @@ def _tensor_send_loop(wref, q) -> None:
     to the Stream between batches).  Exits on the close sentinel, when
     the stream dies, or when the weakref clears — whichever comes first."""
     import queue as _qm
-    from brpc_tpu.ici import rail
     while True:
         try:
             item = q.get(timeout=5.0)
@@ -500,46 +514,58 @@ def _tensor_send_loop(wref, q) -> None:
             # stream gone / transport dead: nothing was shipped yet for
             # this batch, so dropping it leaks no tickets
             return
-        tickets = None
-        try:
-            tickets = rail.ship_many([obj for _, obj in batch],
-                                     s.peer_device)
-        except Exception:
-            logging.exception("stream rail ship failed; host fallback")
-        if tickets is not None:
-            # ticket frames are tiny (meta only, empty bodies): ship the
-            # whole batch as ONE socket write — one ctypes crossing and
-            # one write-stack push instead of len(batch), ordering
-            # preserved.  Tiny frames can never trip the per-write
-            # EOVERCROWDED bound the way coalesced big bodies would.
-            frames = []
-            for k, (seq, obj) in enumerate(batch):
-                frames.append((M.RpcMeta.encode_stream_data(
-                    s.remote_id, seq, ticket=tickets[k],
-                    src_dev=str(rail.source_device(obj).id)), b""))
-            if Transport.instance().write_frames(s._sid, frames) != 0:
-                for t in tickets:       # atomic pops: no double-free
-                    rail.withdraw(t)
-                s._on_closed_internal()
+        with rpcz.stage("stream.send") as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(cid=f"{s.remote_id}:{batch[0][0]}",
+                        chunks=len(batch))
+            if not _send_tensor_batch(s, batch):
                 return
-        else:
-            # host fallback: bodies are full serialized tensors — write
-            # per frame so each passes the overcrowded bound on its own
-            # and no giant contiguous join is materialized
-            from brpc_tpu.rpc.serialization import get_serializer
-            for seq, obj in batch:
-                meta = M.RpcMeta(msg_type=M.MSG_STREAM_DATA,
-                                 stream_id=s.remote_id, stream_seq=seq)
-                rail.rail_fallbacks.add(1)
-                body, meta.tensor_header = \
-                    get_serializer("tensor").encode(obj)
-                if Transport.instance().write_frame(
-                        s._sid, meta.encode(), body) != 0:
-                    s._on_closed_internal()
-                    return
         if stop:
             return
         del s    # drop the strong ref while parked in q.get
+
+
+def _send_tensor_batch(s: "Stream", batch: list) -> bool:
+    """One turn of the tensor sender: ship the batch over the rail as
+    one dispatch and write its ticket frames (or fall back to host
+    frames).  False when the stream died under it."""
+    from brpc_tpu.ici import rail
+    tickets = None
+    try:
+        tickets = rail.ship_many([obj for _, obj in batch], s.peer_device)
+    except Exception:
+        logging.exception("stream rail ship failed; host fallback")
+    if tickets is not None:
+        # ticket frames are tiny (meta only, empty bodies): ship the
+        # whole batch as ONE socket write — one ctypes crossing and
+        # one write-stack push instead of len(batch), ordering
+        # preserved.  Tiny frames can never trip the per-write
+        # EOVERCROWDED bound the way coalesced big bodies would.
+        frames = []
+        for k, (seq, obj) in enumerate(batch):
+            frames.append((M.RpcMeta.encode_stream_data(
+                s.remote_id, seq, ticket=tickets[k],
+                src_dev=str(rail.source_device(obj).id)), b""))
+        if Transport.instance().write_frames(s._sid, frames) != 0:
+            for t in tickets:       # atomic pops: no double-free
+                rail.withdraw(t)
+            s._on_closed_internal()
+            return False
+        return True
+    # host fallback: bodies are full serialized tensors — write per
+    # frame so each passes the overcrowded bound on its own and no
+    # giant contiguous join is materialized
+    from brpc_tpu.rpc.serialization import get_serializer
+    for seq, obj in batch:
+        meta = M.RpcMeta(msg_type=M.MSG_STREAM_DATA,
+                         stream_id=s.remote_id, stream_seq=seq)
+        rail.rail_fallbacks.add(1)
+        body, meta.tensor_header = get_serializer("tensor").encode(obj)
+        if Transport.instance().write_frame(
+                s._sid, meta.encode(), body) != 0:
+            s._on_closed_internal()
+            return False
+    return True
 
 
 class StreamRegistry:
@@ -624,22 +650,27 @@ class StreamRegistry:
         if s._sid is None:
             s.bind(sid)
         if meta.msg_type == M.MSG_STREAM_DATA:
-            try:
-                payload, nbytes = _decode_data_frame(meta, body)
-            except Exception:
-                # an expired ticket / corrupt tensor header poisons the
-                # SEQUENCE (a message is unrecoverably lost): close
-                logging.exception("stream data frame undecodable")
-                s._on_closed_internal()
-                return
-            s._on_data(payload, nbytes, meta.stream_seq)
-            if dup:
-                # injected transport-level redelivery: the duplicate must
-                # be dropped by the reorder layer and its bytes counted
-                # (reorder_replay_bytes_dropped), never delivered twice
+            with rpcz.stage("stream.on_data") as stg:
+                if stg is not rpcz.NOOP_STAGE:
+                    stg.set(cid=f"{meta.stream_id}:{meta.stream_seq}")
+                try:
+                    payload, nbytes = _decode_data_frame(meta, body)
+                except Exception:
+                    # an expired ticket / corrupt tensor header poisons
+                    # the SEQUENCE (a message is unrecoverably lost): close
+                    logging.exception("stream data frame undecodable")
+                    s._on_closed_internal()
+                    return
                 s._on_data(payload, nbytes, meta.stream_seq)
+                if dup:
+                    # injected transport-level redelivery: the duplicate
+                    # must be dropped by the reorder layer and its bytes
+                    # counted (reorder_replay_bytes_dropped), never
+                    # delivered twice
+                    s._on_data(payload, nbytes, meta.stream_seq)
         elif meta.msg_type == M.MSG_STREAM_FEEDBACK:
-            s._on_feedback(meta.stream_offset)
+            with rpcz.stage("stream.on_feedback"):
+                s._on_feedback(meta.stream_offset)
         elif meta.msg_type == M.MSG_STREAM_CLOSE:
             s._on_close_frame(meta.stream_seq)
 

@@ -19,7 +19,7 @@ from brpc_tpu._core import (ACCEPTED_CB, FAILED_CB, H2_EVENT_CB, IOBuf,
                             MSG_THRIFT, MSG_TRPC, REQUEST_CB, RESPONSE_CB,
                             TASK_CB, core, core_init)
 from brpc_tpu._core import _fastrpc
-from brpc_tpu import fault
+from brpc_tpu import fault, rpcz
 
 
 def _apply_send_fault(sid: int, payload):
@@ -406,9 +406,12 @@ class Transport:
                           content_type=content_type or "")
             return eng.write_plain(
                 Transport._pack_trpc(m.encode(), bytes(body)))
-        return _fastrpc.send_request(sid, cid, attempt, service, method,
-                                     timeout_ms or 0, compress, content_type,
-                                     body)
+        with rpcz.stage("net.write", cid) as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=len(body))
+            return _fastrpc.send_request(sid, cid, attempt, service, method,
+                                         timeout_ms or 0, compress,
+                                         content_type, body)
 
     @staticmethod
     def send_response(sid: int, cid: int, attempt: int, error_code: int,
@@ -428,9 +431,12 @@ class Transport:
                           content_type=content_type or "")
             return eng.write_plain(
                 Transport._pack_trpc(m.encode(), bytes(body)))
-        return _fastrpc.send_response(sid, cid, attempt, error_code,
-                                      error_text or "", content_type or "",
-                                      body)
+        with rpcz.stage("net.write", cid) as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=len(body))
+            return _fastrpc.send_response(sid, cid, attempt, error_code,
+                                          error_text or "",
+                                          content_type or "", body)
 
     def write_frame(self, sid: int, meta: bytes, body: bytes = b"",
                     body_iobuf: IOBuf | None = None) -> int:
@@ -447,9 +453,12 @@ class Transport:
             if body_iobuf is not None:
                 full += body_iobuf.to_bytes()
             return eng.write_plain(self._pack_trpc(bytes(meta), full))
-        return core.brpc_socket_write_frame(
-            sid, meta, len(meta), body, len(body),
-            body_iobuf.handle if body_iobuf is not None else None)
+        with rpcz.stage("net.write") as stg:
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=len(meta) + len(body))
+            return core.brpc_socket_write_frame(
+                sid, meta, len(meta), body, len(body),
+                body_iobuf.handle if body_iobuf is not None else None)
 
     def write_frames(self, sid: int, frames: list[tuple[bytes, bytes]]
                      ) -> int:
@@ -463,9 +472,12 @@ class Transport:
         checked against the per-write EOVERCROWDED bound as one unit and
         is materialized contiguously — big bodies should go per-frame
         (the stream sender coalesces ticket frames only)."""
-        payload = b"".join(self._pack_trpc(bytes(m), bytes(b))
-                           for m, b in frames)
-        return self.write_raw(sid, payload)
+        with rpcz.stage("net.write") as stg:
+            payload = b"".join(self._pack_trpc(bytes(m), bytes(b))
+                               for m, b in frames)
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(bytes=len(payload), frames=len(frames))
+            return self.write_raw(sid, payload)
 
     def write_raw(self, sid: int, data: bytes) -> int:
         if fault.ENABLED:

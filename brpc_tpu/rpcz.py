@@ -15,6 +15,7 @@ import itertools
 import contextvars
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -74,6 +75,11 @@ class Span:
     kind: str = "server"   # server | client | batch | prefill | decode |
     #                        generation | device (serving/DCN stage spans)
     annotations: list = field(default_factory=list)
+    # stages of the call path that ran under this span (``stage``):
+    # (name, start_us, dur_us, cpu_us or None), appended as each ends — the
+    # received / start-callback / start-send / sent stamps of the
+    # reference's span (src/brpc/span.h) as named intervals
+    phases: list = field(default_factory=list)
     # head-sampling decision, made ONCE at the trace root and inherited
     # by every child (per-TRACE sampling: a kept trace has no holes)
     sampled: bool = True
@@ -116,6 +122,7 @@ class _NullSpan:
     remote_side = ""
     kind = ""
     annotations = ()
+    phases = ()
     sampled = True
     recovered_from = 0
     migrated_from = 0
@@ -207,6 +214,216 @@ def current_trace_ctx() -> tuple[int, int, bool]:
     if s is None or not s.trace_id:
         return 0, 0, True
     return s.trace_id, s.span_id, s.sampled
+
+
+# ---- stages: ONE stamping primitive for the call path, two sinks ----
+#
+# ``with rpcz.stage("rail.ship", cid): ...`` names one interval of one
+# call on the thread that runs it.  Where it goes depends on what is
+# listening, never on a flag of its own:
+#
+#   * a ``jax.profiler`` session is active: the stage is a
+#     ``TraceAnnotation`` in the profiler's own trace, so it sits on the
+#     device trace's clock by construction and an idle chip can be put
+#     down to the host layer that held it (benchmarks/harness/
+#     program_spans.py reads them);
+#   * rpcz is on and the current span is sampled: the stage is appended
+#     to that span's ``phases`` and shows on /rpcz?trace_id=;
+#   * neither (the state every end-to-end number is measured in): the
+#     one shared ``NOOP_STAGE`` comes back — no object is made, no clock
+#     is read.
+#
+# Every stage carries ``cid``: the call's correlation id (or
+# ``"<stream id>:<seq>"``).  A stage opened without one takes its
+# thread's enclosing stage's, so the layers under the RPC layer (rail,
+# endpoint, transport) need not be handed an id to be found by it.
+
+# Stages that open a request's timeline on their thread.  They also
+# carry ``mono_us`` (``time.monotonic_ns() // 1000`` at entry), which
+# lets a reader place anything stamped on the monotonic clock on the
+# profiler's axis (median offset over the roots).
+ROOT_STAGES = frozenset((
+    "rpc.client.call", "rpc.server.process", "stream.write",
+    "combo.call_lowered"))
+# Stages opened at the top of a native upcall: they carry
+# ``queue_wait_us`` / ``queue_depth``, the native core's sample of how
+# long the frame waited between being cut from the read buffer and this
+# line, and how many upcalls were queued at the cut (net/rpc.h).
+UPCALL_STAGES = frozenset((
+    "rpc.server.process", "rpc.client.on_response", "stream.on_data",
+    "stream.on_feedback"))
+# Stages in which the thread is parked, not working (``wait=1``): a
+# reader leaves them out of a layer's time and of the idle attribution.
+WAIT_STAGES = frozenset(("rpc.client.wait", "stream.credit_wait"))
+# Stages that stamp ``cpu_us`` (the thread's CPU time inside them): the
+# ones that are outermost on their thread, so that together they cover
+# the call path once.  Not every stage: ``time.thread_time_ns`` is a
+# system call, 6 us a call on the v5e's host (PERF.md, PR 25), and two
+# of them on each of an echo's 26 stages cost a third of the calls
+# completed while a trace was on.
+CPU_STAGES = frozenset((
+    "rpc.client.call", "rpc.client.on_response", "rpc.server.process",
+    "stream.write", "stream.send", "stream.on_data",
+    "combo.call_lowered"))
+_ROOT, _UPCALL, _WAIT, _CPU = 1, 2, 4, 8
+_STAGE_KIND: dict = {}       # name -> the flags of the sets it is in
+for _flag, _names in ((_ROOT, ROOT_STAGES), (_UPCALL, UPCALL_STAGES),
+                      (_WAIT, WAIT_STAGES), (_CPU, CPU_STAGES)):
+    for _name in _names:
+        _STAGE_KIND[_name] = _STAGE_KIND.get(_name, 0) | _flag
+
+_stage_tls = threading.local()
+_TraceAnnotation = None
+
+
+def _profiling() -> bool:
+    """Is a ``jax.profiler`` session recording?  Nobody can have started
+    one before ``jax.profiler`` was imported, and this module imports
+    nothing of jax itself (another thread may be in the middle of
+    importing it); once the profiler's module is there the name is
+    rebound to its own check (``TraceAnnotation.is_enabled``, tens of
+    nanoseconds)."""
+    global _profiling, _TraceAnnotation
+    annotation = getattr(sys.modules.get("jax.profiler"),
+                         "TraceAnnotation", None)
+    if annotation is None:
+        return False
+    _TraceAnnotation = annotation
+    _profiling = annotation.is_enabled
+    return _profiling()
+
+
+class _NoopStage:
+    """What ``stage`` returns while nothing listens: one shared object,
+    a context manager that does nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **stats) -> None:
+        pass
+
+
+NOOP_STAGE = _NoopStage()
+
+
+def _upcall_wait():
+    from brpc_tpu import native_path
+    fb = native_path._fastrpc_mod()
+    return fb.upcall_wait() if fb is not None else None
+
+
+class _Stage:
+    __slots__ = ("name", "_ta", "_span", "_prev_cid", "_start_us", "_cpu0")
+
+    def __init__(self, name: str, cid, stats: dict, profiling: bool):
+        tls = _stage_tls
+        prev = self._prev_cid = getattr(tls, "cid", 0)
+        if cid:
+            tls.cid = stats["cid"] = cid
+        elif prev:
+            stats["cid"] = prev
+        self.name = name
+        kind = _STAGE_KIND.get(name, 0)
+        if kind:
+            if kind & _ROOT:
+                stats["mono_us"] = time.monotonic_ns() // 1000
+            if kind & _UPCALL:
+                sample = _upcall_wait()
+                if sample is not None:
+                    stats["queue_wait_us"], stats["queue_depth"] = sample
+            if kind & _WAIT:
+                stats["wait"] = 1
+        self._cpu0 = kind & _CPU
+        self._ta = _TraceAnnotation(name, **stats) if profiling else None
+        self._span = None
+        if _enabled:
+            span = _current_span.get()
+            if span is not None and span is not NULL_SPAN and span.sampled:
+                self._span = span
+
+    def __enter__(self):
+        if self._span is not None:
+            self._start_us = now_us()
+        if self._ta is not None:
+            self._ta.__enter__()
+        if self._cpu0:      # truthy from here on: read again at exit
+            self._cpu0 = time.thread_time_ns() or 1
+        return self
+
+    def set(self, **stats) -> None:
+        """Stats known only once the work is under way (``cid`` among
+        them, where the call is named inside its own stage)."""
+        if "cid" in stats:
+            _stage_tls.cid = stats["cid"]
+        if self._ta is not None:
+            self._ta.set_metadata(**stats)
+
+    def __exit__(self, *exc):
+        cpu_us = None
+        if self._cpu0:
+            cpu_us = (time.thread_time_ns() - self._cpu0) // 1000
+            if self._ta is not None:
+                self._ta.set_metadata(cpu_us=cpu_us)
+        if self._ta is not None:
+            self._ta.__exit__(*exc)
+        if self._span is not None:
+            self._span.phases.append(
+                (self.name, self._start_us, now_us() - self._start_us,
+                 cpu_us))
+        _stage_tls.cid = self._prev_cid
+        return False
+
+
+def stage(name: str, cid=0, **stats):
+    """One named interval of one call (see the section comment)."""
+    profiling = _profiling()
+    if not (profiling or _enabled):
+        return NOOP_STAGE
+    return _Stage(name, cid, stats, profiling)
+
+
+class _SpanScope:
+    """``span`` is the current span inside the block; what was current
+    before is current again after it."""
+    __slots__ = ("_span", "_token")
+
+    def __init__(self, span: Span):
+        self._span = span
+
+    def __enter__(self):
+        self._token = _current_span.set(self._span)
+        return self._span
+
+    def __exit__(self, *exc):
+        _current_span.reset(self._token)
+        return False
+
+
+def span_scope(span):
+    """Make ``span`` current for a block, so that the stages run in it
+    land in its ``phases`` and nested client calls join its trace.  The
+    shared no-op for the null span: with rpcz off no context variable
+    is touched."""
+    if span is NULL_SPAN:
+        return NOOP_STAGE
+    return _SpanScope(span)
+
+
+def payload_bytes(obj) -> int:
+    """Size of a request or reply for a stage's ``bytes``: device
+    arrays by ``nbytes`` (lists summed), byte strings by length, else
+    0.  Call it only where a stage is live."""
+    if isinstance(obj, (list, tuple)):
+        return sum(payload_bytes(o) for o in obj)
+    n = getattr(obj, "nbytes", None)
+    if n is not None:
+        return int(n)
+    return len(obj) if isinstance(obj, (bytes, bytearray, memoryview)) else 0
 
 
 # ---- on-disk SpanDB (reference span.h:227-230 keeps rpcz spans in an
@@ -307,8 +524,9 @@ def load_disk_spans(limit: int = 200,
                     if trace_id is not None and \
                             rec.get("trace_id") != trace_id:
                         continue
-                    ann = [tuple(a) for a in rec.pop("annotations", [])]
-                    seg.append(Span(annotations=ann, **rec))
+                    span = span_from_dict(rec)
+                    if span is not None:
+                        seg.append(span)
         except OSError:
             continue
         out = seg + out
@@ -540,7 +758,8 @@ def span_to_dict(span: Span) -> dict:
         "error_code": span.error_code, "kind": span.kind,
         "recovered_from": span.recovered_from,
         "migrated_from": span.migrated_from,
-        "annotations": list(span.annotations)}
+        "annotations": list(span.annotations),
+        "phases": [list(p) for p in span.phases]}
 
 
 def span_from_dict(rec: dict) -> Span | None:
@@ -549,9 +768,10 @@ def span_from_dict(rec: dict) -> Span | None:
     try:
         rec = dict(rec)
         ann = [tuple(a) for a in rec.pop("annotations", ())]
+        phases = [tuple(ph) for ph in rec.pop("phases", ())]
         rec.pop("sampled", None)
         rec.pop("seq", None)
-        return Span(annotations=ann, **rec)
+        return Span(annotations=ann, phases=phases, **rec)
     except (TypeError, ValueError, AttributeError):
         return None
 
@@ -600,8 +820,9 @@ def trace_tree(spans: list[Span]) -> list[tuple[int, int, Span]]:
 
 def format_trace(spans: list[Span], indent: str = "  ") -> str:
     """Human-readable timeline for ONE trace: tree-ordered spans with
-    relative start offsets, per-span latency, recovery links, and the
-    annotations at their relative timestamps."""
+    relative start offsets, per-span latency, recovery links, the
+    stages that ran under each span (``phases``) and the annotations,
+    both at their offsets from the trace's start."""
     tree = trace_tree(spans)
     if not tree:
         return "no spans\n"
@@ -620,6 +841,11 @@ def format_trace(spans: list[Span], indent: str = "  ") -> str:
             f"{pad}+{off}us [{s.kind}] {s.service}.{s.method} "
             f"span={s.span_id} {s.latency_us}us{err}{link}"
             + (f" peer={s.remote_side}" if s.remote_side else ""))
+        for name, start, dur, cpu in sorted(s.phases,
+                                            key=lambda ph: ph[1]):
+            lines.append(f"{pad}{indent}|+{max(0, start - t0)}us {name} "
+                         f"{dur}us"
+                         + (f" (cpu {cpu}us)" if cpu is not None else ""))
         for t, msg in s.annotations:
             lines.append(f"{pad}{indent}@+{max(0, t - t0)}us {msg}")
     return "\n".join(lines) + "\n"

@@ -251,6 +251,16 @@ struct EventSampler {
 
   void note(const void* leaf_addr, int skip_frames, int64_t clock_every) {
     const int64_t ev = events.fetch_add(1, std::memory_order_relaxed);
+    // While the SIGPROF sampler is armed, take no stack here: backtrace()
+    // walks libgcc's registered-frame list under its object_mutex (long,
+    // once XLA's CPU JIT has registered hundreds of modules), and a
+    // SIGPROF that lands on this thread inside it runs prof_handler's own
+    // backtrace() against the mutex this thread holds.  The thread never
+    // returns, every later backtrace() in the process queues behind it,
+    // and one of those is a Python thread holding the interpreter lock
+    // (seen as a whole-suite wedge in test_native_profiler: 8 threads on
+    // libgcc_s's mutex, 130 on the GIL).
+    if (g_running.load(std::memory_order_relaxed)) return;
     // hot-event instances (block allocs) only consult the clock every
     // Nth event, keeping steady-state cost at one relaxed atomic; rare-
     // event instances (contention) pass 1 and check every time

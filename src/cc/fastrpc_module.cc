@@ -351,6 +351,15 @@ PyObject* py_iobuf_bytes(PyObject*, PyObject* args) {
   return out;
 }
 
+// upcall_wait() -> (wait_us, queue_depth) of the upcall this thread is
+// running, or None outside one (net/rpc.h, UpcallTicket).
+PyObject* py_upcall_wait(PyObject*, PyObject*) {
+  int64_t wait_us = 0;
+  int64_t depth = 0;
+  if (!brpc::CurrentUpcallWait(&wait_us, &depth)) Py_RETURN_NONE;
+  return Py_BuildValue("(LL)", (long long)wait_us, (long long)depth);
+}
+
 // ---- native span queue (ISSUE 9: off-thread rpcz recording) ----
 //
 // rpcz.submit used to pay two Python lock acquisitions (speed-limit
@@ -623,6 +632,8 @@ PyMethodDef kMethods[] = {
      "Address of the C response trampoline (for brpc_connect_rpc)."},
     {"iobuf_bytes", py_iobuf_bytes, METH_VARARGS,
      "iobuf_bytes(handle, pos=0, n=-1) -> bytes (single copy)"},
+    {"upcall_wait", py_upcall_wait, METH_NOARGS,
+     "(wait_us, queue_depth) of the running upcall's frame, or None."},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -474,6 +474,45 @@ int64_t UsercodePending() {
 }
 double UsercodeEmaUs() { return py_ema_us(); }
 
+// ---- upcall lane sample (rpc.h) ----
+
+namespace {
+std::atomic<int64_t> g_upcalls_queued{0};
+thread_local int64_t tls_upcall_cut_us = 0;
+thread_local int64_t tls_upcall_depth = 0;
+}  // namespace
+
+UpcallTicket::UpcallTicket()
+    : cut_us(butil::cpuwide_time_us()),
+      depth(g_upcalls_queued.fetch_add(1, std::memory_order_relaxed)),
+      queued(true) {}
+
+UpcallTicket::~UpcallTicket() {
+  if (queued) g_upcalls_queued.fetch_sub(1, std::memory_order_relaxed);
+}
+
+UpcallScope::UpcallScope(UpcallTicket* t)
+    : _prev_cut_us(tls_upcall_cut_us), _prev_depth(tls_upcall_depth) {
+  if (t->queued) {
+    t->queued = false;
+    g_upcalls_queued.fetch_sub(1, std::memory_order_relaxed);
+  }
+  tls_upcall_cut_us = t->cut_us;
+  tls_upcall_depth = t->depth;
+}
+
+UpcallScope::~UpcallScope() {
+  tls_upcall_cut_us = _prev_cut_us;
+  tls_upcall_depth = _prev_depth;
+}
+
+bool CurrentUpcallWait(int64_t* wait_us, int64_t* depth) {
+  if (tls_upcall_cut_us == 0) return false;
+  *wait_us = butil::cpuwide_time_us() - tls_upcall_cut_us;
+  *depth = tls_upcall_depth;
+  return true;
+}
+
 // ---- dispatch ----
 
 namespace {
@@ -545,14 +584,15 @@ struct PendingFastRequest {
   butil::IOBuf* body;
   RequestCallback cb;
   void* user;
-  int64_t submit_us;  // queue-wait measurement (admission control)
+  UpcallTicket ticket;  // stamped at the cut: the lane's queue-wait sample
 };
 
 void run_fast_request_task(void* arg) {
   auto* p = (PendingFastRequest*)arg;
   // the controlled variable: how long this request sat in the lane
-  // before its upcall began
-  py_ema_update(double(butil::cpuwide_time_us() - p->submit_us));
+  // before its upcall began (the same sample the upcall can read)
+  py_ema_update(double(butil::cpuwide_time_us() - p->ticket.cut_us));
+  UpcallScope scope(&p->ticket);
   ParsedMeta m;
   if (ParseMeta(p->meta.data(), p->meta.size(), &m)) {
     RequestHeader hdr;
@@ -585,10 +625,12 @@ struct PendingFastResponse {
   butil::IOBuf* body;
   ResponseCallback cb;
   void* user;
+  UpcallTicket ticket;
 };
 
 void run_fast_response_task(void* arg) {
   auto* p = (PendingFastResponse*)arg;
+  UpcallScope scope(&p->ticket);
   ParsedMeta m;
   if (ParseMeta(p->meta.data(), p->meta.size(), &m)) {
     RequestHeader hdr;
@@ -705,15 +747,19 @@ bool TryDispatchTrpc(SocketId sid, const SocketOptions& opts, const char* meta,
       g_python_fast_calls.fetch_add(1, std::memory_order_relaxed);
       const int64_t t0 = butil::cpuwide_time_us();
       auto* owned = new butil::IOBuf(std::move(*body));
-      cb(sid, &hdr, owned, g_request_user.load());  // callee owns body
+      {
+        // no lane: the sample is the little there is between cut and call
+        UpcallTicket ticket;
+        UpcallScope scope(&ticket);
+        cb(sid, &hdr, owned, g_request_user.load());  // callee owns body
+      }
       py_ema_update(double(butil::cpuwide_time_us() - t0));
       return true;
     }
     g_py_pending.fetch_add(1, std::memory_order_relaxed);
     auto* p = new PendingFastRequest{sid, std::string(meta, meta_len),
                                      new butil::IOBuf(std::move(*body)), cb,
-                                     g_request_user.load(),
-                                     butil::cpuwide_time_us()};
+                                     g_request_user.load()};
     // one executor task per message (the "one bthread per message" rule,
     // input_messenger.cpp:175-213): a blocking handler must not
     // head-of-line-block other requests.  (A serialized global lane was
@@ -741,6 +787,8 @@ bool TryDispatchTrpc(SocketId sid, const SocketOptions& opts, const char* meta,
     if (opts.response_inline) {
       RequestHeader hdr;
       fill_header(&hdr, m);
+      UpcallTicket ticket;
+      UpcallScope scope(&ticket);
       opts.on_response(sid, &hdr, body, opts.response_user);  // borrowed
       body->clear();
       return true;

@@ -217,6 +217,39 @@ bool UsercodeInline();
 // uses to estimate how long a request sat behind this sweep's handlers.
 void NoteDispatchSweepStart();
 
+// ---- upcall lane sample (rpcz stages: queue_wait_us / queue_depth) ----
+// Taken when a frame is cut from the socket's read buffer, carried with
+// the queued task, and published to the worker thread for the length of
+// the Python upcall: the upcall can then ask how long its frame waited
+// (executor or FIFO lane, the hop onto the worker, the wait for the
+// interpreter lock up to the asking line) and how many upcalls were
+// queued when it was cut.  One clock (cpuwide_time_us), so the interval
+// needs no mapping onto any other.
+struct UpcallTicket {
+  int64_t cut_us;
+  int64_t depth;   // upcalls queued, this one not counted, at the cut
+  bool queued;
+  UpcallTicket();
+  ~UpcallTicket();  // a task dropped before it ran leaves the queue too
+  UpcallTicket(const UpcallTicket&) = delete;
+  UpcallTicket& operator=(const UpcallTicket&) = delete;
+};
+
+// Around the callback, on the thread that runs it.  Nests: an upcall
+// made from inside another restores the outer sample when it ends.
+class UpcallScope {
+ public:
+  explicit UpcallScope(UpcallTicket* t);
+  ~UpcallScope();
+
+ private:
+  int64_t _prev_cut_us;
+  int64_t _prev_depth;
+};
+
+// From inside an upcall; false when this thread runs none.
+bool CurrentUpcallWait(int64_t* wait_us, int64_t* depth);
+
 struct SocketOptions;
 
 // Socket::DispatchMessages hook for MSG_TRPC.  Returns true if the message

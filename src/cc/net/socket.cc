@@ -484,10 +484,12 @@ struct PendingMessage {
   butil::IOBuf* body;
   MessageCallback cb;
   void* user;
+  UpcallTicket ticket;  // stamped here, at the cut (net/rpc.h)
 };
 
 static void run_message_task(void* arg) {
   auto* m = (PendingMessage*)arg;
+  UpcallScope scope(&m->ticket);
   m->cb(m->sid, m->kind, m->meta.data(), m->meta.size(), m->body, m->user);
   delete m;  // callback owns *body (freed via C ABI)
 }

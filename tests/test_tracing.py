@@ -209,11 +209,15 @@ class TestWireTraceJoin:
             ch.call_sync("_Echo", "Say", {}, serializer="json")
             rpcz.set_current_span(None)
             rpcz.submit(root)
-            spans = _wait_spans(root.trace_id, 2)
+            spans = _wait_spans(root.trace_id, 3)
             kinds = {s.kind for s in spans}
             assert "server" in kinds, spans
+            # root -> the channel's client span -> the server span
+            call_span = next(s for s in spans if s.kind == "client"
+                             and s.parent_span_id == root.span_id)
+            assert (call_span.service, call_span.method) == ("_Echo", "Say")
             server_span = next(s for s in spans if s.kind == "server")
-            assert server_span.parent_span_id == root.span_id
+            assert server_span.parent_span_id == call_span.span_id
             # and an UNSAMPLED root's trace leaves nothing server-side
             unroot = rpcz.new_span("client", "press", "Say",
                                    sampled=False)
@@ -498,8 +502,10 @@ class TestDcnTraceJoin:
             assert "device" in kinds, spans      # remote execution span
             dev = next(s for s in spans if s.kind == "device")
             assert dev.service == "TraceSvc" and dev.method == "Inc"
-            client = next(s for s in spans if s.kind == "client")
-            assert client.parent_span_id == root.span_id
+            # the DCN client span hangs off the caller's span (the host
+            # channel's own client span hangs off the DCN one)
+            assert any(s.kind == "client"
+                       and s.parent_span_id == root.span_id for s in spans)
         finally:
             srv.stop()
             srv.join()
