@@ -13,6 +13,27 @@ The credit window survives unchanged: in-flight bytes are bounded, and
 drainer thread that feeds the same bvar counters the socket path uses.
 No handshake is needed inside one process/slice; cross-host setup arrives
 with the DCN path in a later round.
+
+Senders do not wait on each other.  A send reserves credit under `_mu` (a
+critical section of three statements, with no call in it), then calls into
+the runtime with NO lock held, then hands a completion entry to the drainer
+through a `SimpleQueue`.  The jit call and `device_put` release the
+interpreter lock while the runtime works; a mutex held across them turned
+ten busy threads into a convoy (8 ms of wall around 0.6 ms of CPU per
+send, PERF.md PR 25/26).  The price is that the completion queue is in no
+particular order, so nothing is inferred from order: every entry is
+confirmed by itself, and credit goes back only for transfers observed
+complete.  Confirming means `is_ready()` first: it does not give up the
+interpreter lock, where `block_until_ready()` does even on a ready array
+and then waits its turn for it behind every busy thread (9.8 ms a call
+against 0.3 us on the v5e's host with four threads spinning, PERF.md PR
+26).  And every sender confirms what is ready itself before it reserves:
+the drainer, one more thread in the queue for the interpreter lock, gives
+credit back milliseconds after the transfer is done, which at 17 GB/s of
+credit flow filled the 256 MB window with copies long since complete.
+The drainer stays for what no later send would see: it brings
+`inflight_bytes` to 0 after the last send, and it parks on transfers
+that are really in flight.
 """
 from __future__ import annotations
 
@@ -32,6 +53,9 @@ _recv_bytes = Adder("ici_recv_bytes")
 _same_device_copies = Adder("ici_same_device_copies")
 _cross_device_moves = Adder("ici_cross_device_moves")
 _transfer_latency = LatencyRecorder("ici_transfer")
+# sends that entered dispatch while another send of the same endpoint was
+# inside it: above 0 means senders really overlap in the runtime
+_send_overlapped = Adder("ici_send_overlapped")
 
 DEFAULT_WINDOW_BYTES = 64 * 1024 * 1024
 
@@ -97,15 +121,19 @@ class IciEndpoint:
         self.window_bytes = window_bytes
         self._mu = threading.Lock()
         self._cv = threading.Condition(self._mu)
-        # serializes dispatch + completion-enqueue so the completion queue
-        # is in dispatch order — the batch drain's tail-sync relies on it
-        self._dispatch_mu = threading.Lock()
         self._inflight = 0
+        # senders parked on a full window (under _mu): nobody to wake is
+        # the common case, and notify_all is Python run under the lock
+        self._window_waiters = 0
+        # idents of the threads between their credit reservation and the
+        # end of their dispatch: set.add / discard / len are atomic, so
+        # the overlap counter costs the send path no second lock
+        self._dispatching: set = set()
         self._closed = False
         # single long-lived completion drainer (the "poll-cq" thread);
-        # started lazily on the first send
-        import queue
-        self._completions: "queue.Queue" = queue.Queue()
+        # started lazily on the first send.  SimpleQueue: the sender's
+        # put takes no Python-level mutex or condition
+        self._completions = queue_mod.SimpleQueue()
         self._drainer: Optional[threading.Thread] = None
 
     def _ensure_drainer(self) -> None:
@@ -117,36 +145,86 @@ class IciEndpoint:
                         name=f"ici-cq-{self.device.id}")
                     self._drainer.start()
 
+    @staticmethod
+    def _confirm_ready(batch) -> tuple[int, list]:
+        """Confirm, without blocking, the entries of `batch` whose every
+        transfer is complete.  Returns their bytes and the entries still
+        in flight.  Nothing is inferred from an entry's place: senders
+        dispatch and enqueue with no lock between them, so queue order
+        says nothing of dispatch order."""
+        freed = 0
+        pending = []
+        for entry in batch:
+            outs, nbytes, _ = entry
+            try:
+                ready = all(out.is_ready() for out in outs)
+            except Exception:  # transfer failure: free the window anyway
+                ready = True
+            if ready:
+                _recv_bytes.add(nbytes)
+                freed += nbytes
+            else:
+                pending.append(entry)
+        return freed, pending
+
+    def _release_window(self, nbytes: int) -> None:
+        if nbytes <= 0:
+            return
+        with self._mu:
+            self._inflight -= nbytes
+            waiters = self._window_waiters
+        if waiters:
+            with self._cv:
+                self._cv.notify_all()
+
     def _drain_completions(self) -> None:
+        q = self._completions
         while True:
-            item = self._completions.get()
+            item = q.get()
             if item is None:
                 return
-            # batch drain: collect everything already queued and host-sync
-            # only the NEWEST — send() dispatches AND enqueues under
-            # _dispatch_mu, so queue order == dispatch order, and one
-            # device completes d2d copies in dispatch order; the tail's
-            # readiness therefore implies the whole batch's.  This turns N
-            # host syncs into one per drain cycle.
-            batch, stop = _collect_batch(self._completions, item)
-            out, _, t0 = batch[-1]
-            try:
-                out.block_until_ready()
-            except Exception:  # transfer failure: free the window anyway
-                pass
-            # only the tail's completion was actually observed — record
-            # one latency sample per drain cycle rather than charging
-            # every earlier chunk the full batch duration
-            _transfer_latency.add(int((time.monotonic() - t0) * 1e6))
-            total = 0
-            for _, nbytes, _ in batch:
+            # one cycle: confirm whatever is complete already and give
+            # its credit back at once, then park on what is still in
+            # flight, entry by entry.  One device completes same-engine
+            # copies in order, so at most the first of them really parks.
+            batch, stop = _collect_batch(q, item)
+            freed, pending = self._confirm_ready(batch)
+            self._release_window(freed)
+            for outs, nbytes, _ in pending:
+                for out in outs:
+                    try:
+                        out.block_until_ready()
+                    except Exception:  # transfer failure: free the window anyway
+                        pass
                 _recv_bytes.add(nbytes)
-                total += nbytes
-            with self._cv:
-                self._inflight -= total
-                self._cv.notify_all()
+                self._release_window(nbytes)
+            # one latency sample per drain cycle (the newest entry's),
+            # rather than charging every chunk the full batch duration
+            _transfer_latency.add(
+                int((time.monotonic() - batch[-1][2]) * 1e6))
             if stop:
                 return
+
+    def _reclaim_ready(self) -> int:
+        """A sender's own look at the completion queue: confirm what is
+        complete, put the rest (and a close sentinel) back.  Never
+        blocks.  Returns the bytes confirmed; the caller gives them back
+        to the window."""
+        q = self._completions
+        try:
+            first = q.get_nowait()
+        except queue_mod.Empty:
+            return 0
+        if first is None:
+            q.put(None)
+            return 0
+        batch, stop = _collect_batch(q, first)
+        freed, pending = self._confirm_ready(batch)
+        for entry in pending:
+            q.put(entry)
+        if stop:
+            q.put(None)
+        return freed
 
     def _transfer(self, array: jax.Array) -> jax.Array:
         """One async transfer to self.device that provably produces a
@@ -164,36 +242,73 @@ class IciEndpoint:
         return jax.device_put(array, self.device)
 
     def _reserve_window(self, nbytes: int, timeout_s: float, stg) -> None:
-        """Block until `nbytes` of credit is available, then reserve it —
-        the EAGAIN discipline of RdmaEndpoint's SQ/window check
+        """Reserve `nbytes` of credit, blocking while the window lacks
+        them, and count the caller into the dispatch — the EAGAIN
+        discipline of RdmaEndpoint's SQ/window check
         (rdma_endpoint.h:235-240).  Shared by send and send_batch so the
-        credit protocol has exactly one implementation.  The caller's
-        stage ``stg`` is told how long the wait was (0 when credit was
-        there)."""
+        credit protocol has exactly one implementation; every return is
+        paired with one `_end_dispatch`.  The caller's stage ``stg`` is
+        told how long the wait was (0 when credit was there) and how many
+        other sends were inside the dispatch when this one entered."""
+        waited_us = (0 if self._try_reserve(nbytes)
+                     else self._wait_for_window(nbytes, timeout_s))
+        overlap = len(self._dispatching)
+        self._dispatching.add(threading.get_ident())
+        if overlap:
+            _send_overlapped.add(1)
+        if stg is not rpcz.NOOP_STAGE:
+            stg.set(waited_window_us=waited_us, overlap=overlap)
+
+    def _try_reserve(self, nbytes: int) -> bool:
+        # every sender first confirms what has completed since anyone
+        # last looked (a few `is_ready()` calls, no lock, no blocking):
+        # under ten busy threads the drainer waits milliseconds for its
+        # turn at the interpreter lock, and credit that only it gives
+        # back fills the window with transfers long since done
+        freed = self._reclaim_ready()
+        # the bare lock around plain statements: no call, so no point at
+        # which the holder can be made to give up the interpreter lock
+        # with every other sender queued behind it
+        with self._mu:
+            self._inflight -= freed
+            room = self._inflight + nbytes <= self.window_bytes
+            if room:
+                self._inflight += nbytes
+            wake = freed and self._window_waiters
+        if wake:
+            with self._cv:
+                self._cv.notify_all()
+        return room
+
+    def _wait_for_window(self, nbytes: int, timeout_s: float) -> int:
+        """The window is full of transfers not yet seen complete: park
+        until a release makes room, and look at the completion queue
+        again after every wake.  Returns the microseconds it took."""
         t_in = time.monotonic()
         deadline = t_in + timeout_s
-        waited = False
-        with self._cv:
-            while self._inflight + nbytes > self.window_bytes:
-                waited = True
-                if self._closed:
-                    raise RuntimeError("endpoint closed")
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    raise TimeoutError(
-                        f"ICI window full ({self.window_bytes}B)")
-                self._cv.wait(min(remaining, 1.0))
-            self._inflight += nbytes
-        if stg is not rpcz.NOOP_STAGE:
-            stg.set(waited_window_us=int((time.monotonic() - t_in) * 1e6)
-                    if waited else 0)
+        while True:
+            with self._cv:
+                if self._inflight + nbytes > self.window_bytes:
+                    if self._closed:
+                        raise RuntimeError("endpoint closed")
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        raise TimeoutError(
+                            f"ICI window full ({self.window_bytes}B)")
+                    self._window_waiters += 1
+                    try:
+                        self._cv.wait(min(remaining, 1.0))
+                    finally:
+                        self._window_waiters -= 1
+            if self._try_reserve(nbytes):
+                return max(1, int((time.monotonic() - t_in) * 1e6))
 
-    def _release_window(self, nbytes: int) -> None:
-        if nbytes <= 0:
-            return
-        with self._cv:
-            self._inflight -= nbytes
-            self._cv.notify_all()
+    def _end_dispatch(self, release: int = 0) -> None:
+        """The caller is out of the runtime.  `release` is the credit no
+        completion entry carries (a dispatch that failed): handed back
+        here, or failed sends would shrink the window for good."""
+        self._dispatching.discard(threading.get_ident())
+        self._release_window(release)
 
     def send(self, array: jax.Array, timeout_s: float = 30.0) -> jax.Array:
         """Start an async transfer of `array` to this endpoint's device;
@@ -207,23 +322,20 @@ class IciEndpoint:
         self._reserve_window(nbytes, timeout_s, stg)
         t0 = time.monotonic()
         try:
-            with self._dispatch_mu:
-                if fault.ENABLED and fault.hit(
-                        "ici.send", device=self.device.id) is not None:
-                    # injected transfer failure BEFORE dispatch: the
-                    # except below must release the window reservation
-                    raise RuntimeError("injected ici transfer fault")
-                # dispatch and enqueue atomically: with concurrent senders
-                # the completion queue must mirror device dispatch order,
-                # or the drainer's tail-sync would free window credit for
-                # transfers that are still in flight
-                out = self._transfer(array)  # async ICI DMA / HBM copy
-                self._completions.put((out, nbytes, t0))
+            if fault.ENABLED and fault.hit(
+                    "ici.send", device=self.device.id) is not None:
+                # injected transfer failure BEFORE dispatch: the except
+                # below must release the window reservation
+                raise RuntimeError("injected ici transfer fault")
+            # no lock from here to the put: other senders of this
+            # endpoint dispatch alongside, and the drainer confirms each
+            # entry by itself
+            out = self._transfer(array)  # async ICI DMA / HBM copy
+            self._completions.put(((out,), nbytes, t0))
         except Exception:
-            # release the window reservation or failed sends would shrink
-            # the window permanently
-            self._release_window(nbytes)
+            self._end_dispatch(release=nbytes)
             raise
+        self._end_dispatch()
         _send_bytes.add(nbytes)
         _send_count.add(1)
         self._ensure_drainer()
@@ -235,16 +347,16 @@ class IciEndpoint:
         return out
 
     def send_batch(self, arrays, timeout_s: float = 30.0) -> list:
-        """Transfer a batch of arrays with ONE dispatch and ONE completion
-        record.  Same-device arrays ride a single pre-compiled multi-copy
-        program (_multi_copy); cross-device arrays ride one device_put of
-        the whole list.  The window is reserved for the batch total, so
-        size batches <= window_bytes (larger batches raise).
+        """Transfer a batch of arrays with ONE dispatch per group: the
+        same-device arrays ride a single pre-compiled multi-copy program
+        (_multi_copy), the cross-device arrays one device_put of the whole
+        list.  The window is reserved for the batch total, so size batches
+        <= window_bytes (larger batches raise).
 
         This is the pipe's fast path: per-chunk Python dispatch and
-        per-chunk completion observation — the costs that capped r2's
-        ladder at ~5 GB/s while the chip streams 670 — are amortized over
-        the batch."""
+        per-chunk completion records — the costs that capped r2's ladder
+        at ~5 GB/s while the chip streams 670 — are amortized over the
+        batch.  Like send, it holds no lock while the runtime dispatches."""
         arrays = list(arrays)
         if not arrays:
             return []
@@ -269,41 +381,44 @@ class IciEndpoint:
                     "ici.send", device=self.device.id) is not None:
                 # nothing queued yet: the except releases the full total
                 raise RuntimeError("injected ici transfer fault")
-            with self._dispatch_mu:
-                same = []
-                cross = []
-                for i, a in enumerate(arrays):
-                    try:
-                        is_same = a.devices() == {self.device}
-                    except Exception:
-                        is_same = False
-                    (same if is_same else cross).append(i)
-                outs = [None] * len(arrays)
-                # one completion entry per dispatch group (compiled copies
-                # and device_put DMAs may ride different engines, so one
-                # group's tail cannot vouch for the other's)
-                if same:
-                    copied = _multi_copy(*[arrays[i] for i in same])
-                    for i, c in zip(same, copied):
-                        outs[i] = c
-                    _same_device_copies.add(len(same))
-                    same_bytes = sum(arrays[i].nbytes for i in same)
-                    self._completions.put((copied[-1], same_bytes, t0))
-                    queued += same_bytes
-                if cross:
-                    moved = jax.device_put([arrays[i] for i in cross],
-                                           self.device)
-                    for i, m in zip(cross, moved):
-                        outs[i] = m
-                    _cross_device_moves.add(len(cross))
-                    cross_bytes = sum(arrays[i].nbytes for i in cross)
-                    self._completions.put((moved[-1], cross_bytes, t0))
-                    queued += cross_bytes
+            same = []
+            cross = []
+            for i, a in enumerate(arrays):
+                try:
+                    is_same = a.devices() == {self.device}
+                except Exception:
+                    is_same = False
+                (same if is_same else cross).append(i)
+            outs = [None] * len(arrays)
+            # each group's entry is queued as soon as the group is
+            # dispatched, so a failure of the second group leaves the
+            # first one's credit with the drainer, which observes it
+            if same:
+                copied = _multi_copy(*[arrays[i] for i in same])
+                for i, c in zip(same, copied):
+                    outs[i] = c
+                _same_device_copies.add(len(same))
+                same_bytes = sum(arrays[i].nbytes for i in same)
+                # one program: its outputs become ready together, so one
+                # of them stands for all
+                self._completions.put(((copied[-1],), same_bytes, t0))
+                queued += same_bytes
+            if cross:
+                moved = jax.device_put([arrays[i] for i in cross],
+                                       self.device)
+                for i, m in zip(cross, moved):
+                    outs[i] = m
+                _cross_device_moves.add(len(cross))
+                cross_bytes = sum(arrays[i].nbytes for i in cross)
+                # one DMA per array: every one is confirmed
+                self._completions.put((tuple(moved), cross_bytes, t0))
+                queued += cross_bytes
         except Exception:
-            self._release_window(total - queued)
+            self._end_dispatch(release=total - queued)
             if queued:
                 self._ensure_drainer()   # someone must observe the queued part
             raise
+        self._end_dispatch()
         _send_bytes.add(total)
         _send_count.add(len(arrays))
         self._ensure_drainer()
@@ -396,6 +511,7 @@ def link_stats() -> dict:
         "recv_bytes": _recv_bytes.get_value(),
         "same_device_copies": _same_device_copies.get_value(),
         "cross_device_moves": _cross_device_moves.get_value(),
+        "send_overlapped": _send_overlapped.get_value(),
         "transfer_avg_us": round(_transfer_latency.latency(), 1),
         "transfer_p99_us": round(_transfer_latency.latency_percentile(0.99), 1),
         "devices": [str(d) for d in jax.devices()],

@@ -38,9 +38,11 @@ class TensorStream:
         if self._closed.is_set():
             raise RuntimeError("stream closed")
         with self._write_mu:
-            # dispatch + enqueue atomically so _q mirrors dispatch order —
-            # the drainer's batch tail-sync depends on it (endpoint.py has
-            # the same discipline for its completion queue)
+            # dispatch + enqueue atomically so _q mirrors dispatch order:
+            # the consumer is fed from it, and the drainer's batch
+            # tail-sync depends on it.  This is the stream's own lock
+            # (one pipe, one order); the endpoint under it takes none
+            # across a dispatch and infers nothing from order
             out = self.endpoint.send(array)
             self._q.put(("tensor", out, 0, None))
 

@@ -169,19 +169,263 @@ class TestEndpointAndStream:
             # single send larger than the whole window can never fit
             ep.send(jnp.zeros(4096, jnp.uint8), timeout_s=0.2)
 
-    def test_tensor_stream_ordered(self):
-        dev = jax.devices()[3]
-        got = []
-        ts = TensorStream(dev, consumer=lambda a: got.append(int(a[0])))
-        for i in range(20):
-            ts.write(jnp.full((256,), i, jnp.int32))
-        ts.close(wait=True)
-        assert got == list(range(20))
+    @pytest.mark.parametrize("n_streams", [1, 2])
+    def test_tensor_stream_ordered(self, n_streams):
+        """Each stream's consumer sees its writer's order, also with a
+        second stream's writer dispatching at the same time."""
+        got = [[] for _ in range(n_streams)]
+        streams = [TensorStream(jax.devices()[3 + k],
+                                consumer=lambda a, g=got[k]: g.append(int(a[0])))
+                   for k in range(n_streams)]
+        start = threading.Barrier(n_streams)
+
+        def writer(ts):
+            start.wait(10)
+            for i in range(20):
+                ts.write(jnp.full((256,), i, jnp.int32))
+
+        threads = [threading.Thread(target=writer, args=(ts,))
+                   for ts in streams]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        for ts in streams:
+            ts.close(wait=True)
+        assert got == [list(range(20))] * n_streams
 
     def test_link_stats_exported(self):
         st = link_stats()
         assert st["send_count"] > 0
+        assert "send_overlapped" in st
         assert len(st["devices"]) == 8
+
+
+def _settles(ep, timeout_s=10.0):
+    """Window credit returns on the drainer, asynchronously to the sends."""
+    deadline = time.monotonic() + timeout_s
+    while ep.inflight_bytes and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return ep.inflight_bytes == 0
+
+
+class _FakeTransfer:
+    """A completion entry's output: records who looked at it, and is in
+    flight until `complete()`."""
+
+    def __init__(self, looked, name, ready=True):
+        self._looked, self._name = looked, name
+        self._ready = threading.Event()
+        if ready:
+            self._ready.set()
+
+    def is_ready(self):
+        self._looked.append(("is_ready", self._name))
+        return self._ready.is_set()
+
+    def block_until_ready(self):
+        self._looked.append(("block", self._name))
+        assert self._ready.wait(10)
+
+    def complete(self):
+        self._ready.set()
+
+
+class TestUnserialisedSend:
+    """Senders of one endpoint dispatch side by side (no endpoint-wide
+    lock across the runtime call), so the completion queue is in no
+    order and the drainer confirms every entry by itself."""
+
+    N_THREADS, N_SENDS, CHUNK, WINDOW = 8, 50, 128 * 1024, 1 << 20
+
+    def test_two_senders_are_inside_the_dispatch_at_once(self, monkeypatch):
+        from brpc_tpu.ici import endpoint as endpoint_mod
+        dev = jax.devices()[0]
+        ep = IciEndpoint(dev)
+        real_copy = endpoint_mod._device_copy
+        both_inside = threading.Barrier(2)
+
+        def copy_when_both_are_in(x):
+            both_inside.wait(10)    # BrokenBarrierError under a send lock
+            return real_copy(x)
+
+        monkeypatch.setattr(endpoint_mod, "_device_copy",
+                            copy_when_both_are_in)
+        xs = [jax.device_put(jnp.full((512,), i, jnp.int32), dev)
+              for i in range(2)]
+        outs, errors = [None, None], []
+
+        def sender(i):
+            try:
+                outs[i] = ep.send(xs[i])
+            except Exception as e:      # noqa: BLE001 - reported below
+                errors.append(e)
+
+        before = link_stats()["send_overlapped"]
+        threads = [threading.Thread(target=sender, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        try:
+            assert not errors, errors
+            for i in range(2):
+                np.testing.assert_array_equal(np.asarray(outs[i]),
+                                              np.asarray(xs[i]))
+            # the second to reserve its credit found the first inside
+            assert link_stats()["send_overlapped"] >= before + 1
+            assert not ep._dispatching
+            assert _settles(ep)
+        finally:
+            ep.close()
+
+    def _hammer(self, ep, dev):
+        """8 threads x 50 sends of 128 KiB into a 1 MiB window: even
+        threads copy on the endpoint's own device, odd ones move from
+        another, every fifth send of a thread is a batch of two.
+        Returns (per-thread outputs, errors, highest in-flight read)."""
+        devs = jax.devices()
+        srcs = [jax.device_put(jnp.full((self.CHUNK,), t + 1, jnp.uint8),
+                               dev if t % 2 == 0 else devs[1 + t % 7])
+                for t in range(self.N_THREADS)]
+        outs = [[] for _ in range(self.N_THREADS)]
+        errors = [[] for _ in range(self.N_THREADS)]
+        peaks = [0] * (self.N_THREADS + 1)
+        done = threading.Event()
+
+        def sender(t):
+            for i in range(self.N_SENDS):
+                try:
+                    if i % 5 == 4:
+                        outs[t].extend(ep.send_batch([srcs[t]] * 2))
+                    else:
+                        outs[t].append(ep.send(srcs[t]))
+                except RuntimeError as e:
+                    errors[t].append(e)
+                peaks[t] = max(peaks[t], ep.inflight_bytes)
+
+        def watcher():
+            while not done.is_set():
+                peaks[-1] = max(peaks[-1], ep.inflight_bytes)
+
+        threads = [threading.Thread(target=sender, args=(t,))
+                   for t in range(self.N_THREADS)]
+        w = threading.Thread(target=watcher)
+        w.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        done.set()
+        w.join(10)
+        return srcs, outs, errors, max(peaks)
+
+    def test_window_holds_under_eight_concurrent_senders(self):
+        dev = jax.devices()[0]
+        ep = IciEndpoint(dev, window_bytes=self.WINDOW)
+        try:
+            srcs, outs, errors, peak = self._hammer(ep, dev)
+            assert not any(errors), errors
+            assert 0 < peak <= self.WINDOW
+            assert _settles(ep)
+            assert not ep._dispatching
+            for t, src in enumerate(srcs):
+                assert len(outs[t]) == self.N_SENDS + self.N_SENDS // 5
+                src_ptr = src.unsafe_buffer_pointer()
+                ptrs = set()
+                for o in outs[t]:
+                    assert o.devices() == {dev}
+                    assert np.all(np.asarray(o) == t + 1)
+                    ptrs.add(o.unsafe_buffer_pointer())
+                # every reply in a buffer of its own
+                assert src_ptr not in ptrs and len(ptrs) == len(outs[t])
+        finally:
+            ep.close()
+
+    def test_injected_send_faults_leave_no_credit_behind(self):
+        from brpc_tpu import fault
+        dev = jax.devices()[0]
+        ep = IciEndpoint(dev, window_bytes=self.WINDOW)
+        plan = fault.FaultPlan(26).on("ici.send", fault.ERROR, times=-1,
+                                      prob=0.3)
+        try:
+            with fault.injected(plan):
+                _, outs, errors, peak = self._hammer(ep, dev)
+            n_failed = sum(len(e) for e in errors)
+            assert n_failed == plan.injected["ici.send"] > 0
+            assert any(outs)
+            assert peak <= self.WINDOW
+            assert _settles(ep), f"{ep.inflight_bytes}B of credit leaked"
+            assert not ep._dispatching
+        finally:
+            ep.close()
+
+    def test_drainer_confirms_every_entry_whatever_the_queue_order(self):
+        ep = IciEndpoint(jax.devices()[0], window_bytes=1 << 20)
+        looked = []
+        # dispatch order a, b, c, d; c is still in flight, and d's entry
+        # never reaches the queue (its sender is still inside the runtime)
+        a, b = (_FakeTransfer(looked, n) for n in "ab")
+        c = _FakeTransfer(looked, "c", ready=False)
+        ep._inflight = 100 + 200 + 300 + 400
+        now = time.monotonic()
+        # queued b, c, a: under a tail-sync, a ready `a` at the tail
+        # would have vouched for c
+        ep._completions.put(((b,), 200, now))
+        ep._completions.put(((c,), 300, now))
+        ep._completions.put(((a,), 100, now))
+        ep._ensure_drainer()
+        try:
+            deadline = time.monotonic() + 5
+            while ("block", "c") not in looked and time.monotonic() < deadline:
+                time.sleep(0.01)
+            # every queued entry was looked at by itself ...
+            assert {n for _, n in looked} == {"a", "b", "c"}
+            # ... the drainer parks on the one in flight, and only what
+            # it saw complete went back to the window meanwhile
+            assert ("block", "c") in looked
+            assert ("block", "a") not in looked
+            assert ("block", "b") not in looked
+            time.sleep(0.1)
+            assert ep.inflight_bytes == 300 + 400
+            c.complete()
+            deadline = time.monotonic() + 5
+            while ep.inflight_bytes != 400 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            # d's credit is still held: nobody saw it complete
+            assert ep.inflight_bytes == 400
+        finally:
+            ep._inflight = 0
+            ep.close()
+
+    def test_full_window_sender_confirms_ready_entries_itself(self):
+        """No drainer running: a sender that finds the window full takes
+        the credit of the completed entries and leaves the others (and
+        their credit) where they were."""
+        dev = jax.devices()[0]
+        ep = IciEndpoint(dev, window_bytes=1024)
+        looked = []
+        done = _FakeTransfer(looked, "done")
+        flying = _FakeTransfer(looked, "flying", ready=False)
+        ep._inflight = 1024
+        now = time.monotonic()
+        ep._completions.put(((flying,), 512, now))
+        ep._completions.put(((done,), 512, now))
+        ep._drainer = threading.current_thread()    # keep the real one away
+        try:
+            x = jax.device_put(jnp.zeros(512, jnp.uint8), dev)
+            y = ep.send(x, timeout_s=5)
+            y.block_until_ready()
+            assert ("is_ready", "done") in looked
+            assert not any(kind == "block" for kind, _ in looked)
+            # flying's 512 and the new send's 512
+            assert ep._inflight == 1024
+            assert ep._completions.qsize() == 2     # flying, and y's entry
+            assert not ep._dispatching
+        finally:
+            ep._drainer = None
+            ep.close()
 
 
 class TestRealByteMovement:

@@ -293,8 +293,8 @@ def test_every_stage_stamps_its_cpu_time_and_waits_are_marked(reduction):
         assert s.wait == (s.name in rpcz.WAIT_STAGES)
     ship = reduction["by_name"]["rail.ship"][0]
     assert ship.stats["bytes"] > 0 and ship.stats["programs"] >= 1
-    assert "waited_window_us" in \
-        reduction["by_name"]["ici.endpoint.send"][0].stats
+    send_stats = reduction["by_name"]["ici.endpoint.send"][0].stats
+    assert "waited_window_us" in send_stats and "overlap" in send_stats
     assert reduction["by_name"]["collective.run"][-1].stats["cache_hit"] == 1
 
 
