@@ -32,7 +32,7 @@ from typing import Optional
 
 import numpy as np
 
-from brpc_tpu import errors
+from brpc_tpu import errors, rpcz
 from brpc_tpu.bvar import Adder, LatencyRecorder
 from brpc_tpu.psserve.shard import owners_for, shard_bounds
 
@@ -205,6 +205,13 @@ class PSClient:
 
     def lookup(self, keys) -> np.ndarray:
         """rows [n, dim] for GLOBAL keys, reassembled in key order."""
+        return self.lookup_versioned(keys)[0]
+
+    def lookup_versioned(self, keys) -> tuple[np.ndarray, dict[int, int]]:
+        """``lookup`` with the version each partition answered with:
+        (rows, {partition: version}).  A partition's rows are its table
+        after exactly that many updates; the lowered backends answer as
+        partition 0."""
         import time
         keys = np.asarray(keys, np.int64)
         if keys.ndim != 1:
@@ -213,7 +220,8 @@ class PSClient:
             raise ValueError(f"keys outside [0, {self.vocab})")
         t0 = time.monotonic()
         if self._lowered is not None:
-            rows, _ver = self._lowered.lookup(keys)
+            rows, ver = self._lowered.lookup(keys)
+            versions = {0: int(ver)}
         else:
             tbl = self._ici_table()
             if tbl is not None:
@@ -221,22 +229,30 @@ class PSClient:
                 # program, no socket — same client API, same rows
                 rows, ver = tbl.lookup(keys)
                 self._note_ici(ver, acked=False)
+                versions = {0: int(ver)}
             else:
-                split = self._split(keys)
-                resp = self._fan_out(
-                    split, "Lookup",
-                    lambda part, pos: {"keys": keys[pos].tolist()},
-                    lambda part, pos: {"keys": keys[pos]})
-                rows = np.empty((keys.shape[0], self.dim), np.float32)
-                for part, pos in split.items():
-                    r = resp[part]
-                    rows[pos] = np.asarray(r["rows"], np.float32)
-                    self._note_version(part, int(r.get("version", 0)))
+                with rpcz.stage("ps.client.call", keys=int(keys.size)):
+                    rows, versions = self._lookup_rpc(keys)
         with self._mu:
             self.n_lookups += 1
         CLIENT_LOOKUPS.add(1)
         LOOKUP_LATENCY.add(int((time.monotonic() - t0) * 1e6))
-        return rows
+        return rows, versions
+
+    def _lookup_rpc(self, keys: np.ndarray) -> tuple[np.ndarray, dict]:
+        split = self._split(keys)
+        resp = self._fan_out(
+            split, "Lookup",
+            lambda part, pos: {"keys": keys[pos].tolist()},
+            lambda part, pos: {"keys": keys[pos]})
+        rows = np.empty((keys.shape[0], self.dim), np.float32)
+        versions = {}
+        for part, pos in split.items():
+            r = resp[part]
+            rows[pos] = np.asarray(r["rows"], np.float32)
+            versions[part] = int(r.get("version", 0))
+            self._note_version(part, versions[part])
+        return rows, versions
 
     # ---- Update ----
 
@@ -301,6 +317,14 @@ class PSClient:
                 self.n_updates += 1
             CLIENT_UPDATES.add(1)
             return {0: ver}
+        with rpcz.stage("ps.client.call", keys=int(keys.size)):
+            out = self._update_rpc(keys, grads, spec, token)
+        with self._mu:
+            self.n_updates += 1
+        CLIENT_UPDATES.add(1)
+        return out
+
+    def _update_rpc(self, keys, grads, spec, token) -> dict[int, int]:
         split = self._split(keys)
 
         def make_json(part, pos):
@@ -333,9 +357,6 @@ class PSClient:
             ver = int(r["version"])
             out[part] = ver
             self._note_ack(part, ver)
-        with self._mu:
-            self.n_updates += 1
-        CLIENT_UPDATES.add(1)
         return out
 
     # ---- dense Pull/Push ----

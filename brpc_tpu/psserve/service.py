@@ -36,7 +36,7 @@ from typing import Optional
 
 import numpy as np
 
-from brpc_tpu import errors, fault
+from brpc_tpu import errors, fault, rpcz
 from brpc_tpu.bvar import Adder
 from brpc_tpu.rpc.service import Service, method
 from brpc_tpu.psserve.shard import EmbeddingShardServer
@@ -101,6 +101,16 @@ class PSService(Service):
         # the BINARY update path's batcher (uint8 records, no float64
         # packing); None falls back to direct per-request apply
         self._update_tb = update_record_batcher
+
+    def warm(self, optimizer=None) -> None:
+        """Set-up's explicit entry (``EmbeddingShardServer.warm``) at
+        the batch sizes this service's lookup batcher forms: after it a
+        window of lookups, and of updates that carry ``optimizer`` (or,
+        with None, plain scatter-adds), compiles nothing and allocates
+        no slot."""
+        b = self._lookup_b
+        self.shard.warm(optimizer,
+                        batch_buckets=b.batch_buckets if b else ())
 
     @staticmethod
     def _count_wire(cntl, binary: bool) -> None:
@@ -170,6 +180,10 @@ class PSService(Service):
 
     @method(request="json", response="json")
     def Lookup(self, cntl, req):
+        with rpcz.stage("ps.server.lookup"):
+            return self._lookup(cntl, req)
+
+    def _lookup(self, cntl, req):
         self._count_wire(cntl, binary=False)
         keys = (req or {}).get("keys")
         if keys is None:
@@ -206,20 +220,23 @@ class PSService(Service):
 
         def transform(row):
             # row: [n_keys, D] trimmed by the batcher's padded-output
-            # scatter; version read at COMPLETION so any update acked
-            # before this lookup's batch executed is covered.  Hot-key
+            # scatter; the version is the one the batch's gather ran at
+            # (read under the shard lock beside the gather, on this
+            # thread), so it is the state these rows show and covers
+            # any update acked before the lookup was issued.  Hot-key
             # and counter accounting happens HERE — only lookups that
             # were actually served shape the histogram (a shed/ELIMIT
             # reject never runs the transform), matching the unbatched
             # path
-            shard._note_hot(local)
-            with shard._mu:
-                ver = shard.version
-                shard.n_lookups += 1
-            from brpc_tpu.psserve.shard import LOOKUPS, LOOKUP_KEYS
-            LOOKUPS.add(1)
-            LOOKUP_KEYS.add(int(row.shape[0]))
-            return {"rows": np.asarray(row).tolist(), "version": ver}
+            with rpcz.stage("ps.server.lookup", keys=int(row.shape[0])):
+                ver = shard.gathered_version()
+                shard._note_hot(local)
+                with shard._mu:
+                    shard.n_lookups += 1
+                from brpc_tpu.psserve.shard import LOOKUPS, LOOKUP_KEYS
+                LOOKUPS.add(1)
+                LOOKUP_KEYS.add(int(row.shape[0]))
+                return {"rows": np.asarray(row).tolist(), "version": ver}
 
         self._lookup_b.submit(cntl, local, transform=transform)
         return None     # deferred: the batch drainer completes the RPC
@@ -228,6 +245,10 @@ class PSService(Service):
 
     @method(request="json", response="json")
     def Update(self, cntl, req):
+        with rpcz.stage("ps.server.update"):
+            return self._update(cntl, req)
+
+    def _update(self, cntl, req):
         self._count_wire(cntl, binary=False)
         req = req or {}
         keys = req.get("keys")
@@ -334,6 +355,10 @@ class PSService(Service):
 
     @method(request="tensorframe", response="tensorframe")
     def LookupT(self, cntl, req):
+        with rpcz.stage("ps.server.lookup"):
+            return self._lookup_t(cntl, req)
+
+    def _lookup_t(self, cntl, req):
         self._count_wire(cntl, binary=True)
         keys = (req or {}).get("keys")
         if keys is None or not isinstance(keys, np.ndarray) \
@@ -371,20 +396,25 @@ class PSService(Service):
         def transform(row):
             # identical accounting to the JSON transform; the response
             # rows ride out as raw float32 bytes, never a list
-            shard._note_hot(local)
-            with shard._mu:
-                ver = shard.version
-                shard.n_lookups += 1
-            from brpc_tpu.psserve.shard import LOOKUPS, LOOKUP_KEYS
-            LOOKUPS.add(1)
-            LOOKUP_KEYS.add(int(row.shape[0]))
-            return {"rows": np.asarray(row), "version": ver}
+            with rpcz.stage("ps.server.lookup", keys=int(row.shape[0])):
+                ver = shard.gathered_version()
+                shard._note_hot(local)
+                with shard._mu:
+                    shard.n_lookups += 1
+                from brpc_tpu.psserve.shard import LOOKUPS, LOOKUP_KEYS
+                LOOKUPS.add(1)
+                LOOKUP_KEYS.add(int(row.shape[0]))
+                return {"rows": np.asarray(row), "version": ver}
 
         self._lookup_b.submit(cntl, local, transform=transform)
         return None
 
     @method(request="tensorframe", response="tensorframe")
     def UpdateT(self, cntl, req):
+        with rpcz.stage("ps.server.update"):
+            return self._update_t(cntl, req)
+
+    def _update_t(self, cntl, req):
         self._count_wire(cntl, binary=True)
         req = req or {}
         keys = req.get("keys")
@@ -534,13 +564,13 @@ def register_psserve(server, shard: EmbeddingShardServer, *,
             max_batch_size=max_batch_size, max_delay_us=max_delay_us,
             length_buckets=shard.key_buckets,
             dtype=np.int64, padded_output=True, eager=eager,
-            name=f"ps_lookup_{safe}")
+            name=f"ps_lookup_{safe}", stage_prefix="ps")
         update_b = DynamicBatcher(
             shard.update_batch_fn,
             max_batch_size=max_batch_size, max_delay_us=max_delay_us,
             length_buckets=shard.update_length_buckets(),
             dtype=np.float64, padded_output=False, eager=eager,
-            name=f"ps_update_{safe}")
+            name=f"ps_update_{safe}", stage_prefix="ps")
         # the binary wire's update batcher: uint8 records, byte-length
         # buckets — coalesces UpdateT exactly like Update, against the
         # same shard lock and applied set
@@ -549,7 +579,7 @@ def register_psserve(server, shard: EmbeddingShardServer, *,
             max_batch_size=max_batch_size, max_delay_us=max_delay_us,
             length_buckets=shard.update_record_buckets(),
             dtype=np.uint8, padded_output=False, eager=eager,
-            name=f"ps_updatet_{safe}")
+            name=f"ps_updatet_{safe}", stage_prefix="ps")
     svc = PSService(shard, lookup_batcher=lookup_b,
                     update_batcher=update_b,
                     update_record_batcher=update_tb)

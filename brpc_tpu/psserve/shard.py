@@ -24,12 +24,14 @@ the DynamicBatcher coalesces under (service.py).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import OrderedDict
 from typing import Optional, Sequence
 
 import numpy as np
 
+from brpc_tpu import rpcz
 from brpc_tpu.bvar import Adder
 from brpc_tpu.butil.lockprof import InstrumentedLock
 
@@ -77,6 +79,12 @@ def init_embedding_table(vocab: int, dim: int, seed: int = 0) -> np.ndarray:
     also the test oracle's starting point."""
     rng = np.random.default_rng(seed)
     return (rng.standard_normal((vocab, dim)) * 0.02).astype(np.float32)
+
+
+def _dup_keys(keys: np.ndarray) -> int:
+    """How many of ``keys`` repeat an earlier one (a stage's stat;
+    computed only while something listens)."""
+    return int(keys.size - np.unique(keys).size)
 
 
 def _bucket_up(n: int, buckets: Sequence[int]) -> int:
@@ -158,9 +166,21 @@ class EmbeddingShardServer:
         # hot-key histogram (bounded: prune to the top half at 4096)
         self._hot: dict[int, int] = {}
 
-        # one jit each; bucket padding bounds the compile count
-        self._gather = jax.jit(lambda t, k: t[k])
-        self._scatter = jax.jit(lambda t, k, g: t.at[k].add(g))
+        # one jit each; bucket padding bounds the compile count.  The
+        # functions are named so that the programs are (``jit_ps_gather``
+        # / ``jit_ps_scatter`` in a device trace)
+        def ps_gather(t, k):
+            return t[k]
+
+        def ps_scatter(t, k, g):
+            return t.at[k].add(g)
+
+        self._gather = jax.jit(ps_gather)
+        self._scatter = jax.jit(ps_scatter)
+        # the version the calling thread's last ``lookup_batch_fn``
+        # gathered at (the batcher runs the response transforms on the
+        # thread that ran the batch, right after it)
+        self._gathered = threading.local()
         # CPU fast path (ISSUE 13): with no device mesh, a bucketed
         # gather is a plain numpy fancy-index over a zero-copy view of
         # the jax array — bit-identical to the jitted gather, without
@@ -194,15 +214,41 @@ class EmbeddingShardServer:
                 f"got keys outside the range")
         return keys - self.lo
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """``with self._mu``, the wait for it as its own stage."""
+        with rpcz.stage("ps.shard.lock_wait"):
+            self._mu.acquire()
+        try:
+            yield
+        finally:
+            self._mu.release()
+
+    def _gather_rows(self, k: np.ndarray, **stats) -> np.ndarray:
+        """The gather of (bucket-padded) local keys ``k`` as host rows,
+        under ``self._mu``: the dispatch and the device-to-host pull are
+        a stage each."""
+        if self._cpu_fast:
+            with rpcz.stage("ps.shard.gather", **stats):
+                return np.asarray(self._rows)[k]
+        with rpcz.stage("ps.shard.gather", **stats):
+            out = self._gather(self._rows, k)
+        with rpcz.stage("ps.shard.fetch"):
+            return np.asarray(out)
+
     def _note_hot(self, local_keys: np.ndarray) -> None:
-        uniq, counts = np.unique(local_keys, return_counts=True)
-        with self._mu:      # RLock: callers inside the lock re-enter
-            hot = self._hot
-            for k, c in zip(uniq.tolist(), counts.tolist()):
-                hot[k + self.lo] = hot.get(k + self.lo, 0) + c
-            if len(hot) > 4096:
-                keep = sorted(hot.items(), key=lambda kv: -kv[1])[:2048]
-                self._hot = dict(keep)
+        with rpcz.stage("ps.shard.note_hot") as st:
+            uniq, counts = np.unique(local_keys, return_counts=True)
+            st.set(keys=int(local_keys.size),
+                   dup_keys=int(local_keys.size - uniq.size))
+            with self._mu:      # RLock: callers inside the lock re-enter
+                hot = self._hot
+                for k, c in zip(uniq.tolist(), counts.tolist()):
+                    hot[k + self.lo] = hot.get(k + self.lo, 0) + c
+                if len(hot) > 4096:
+                    keep = sorted(hot.items(),
+                                  key=lambda kv: -kv[1])[:2048]
+                    self._hot = dict(keep)
 
     # ---- direct (unbatched) entry points ----
 
@@ -212,18 +258,18 @@ class EmbeddingShardServer:
         local = self._to_local(keys)
         n = local.shape[0]
         b = _bucket_up(max(n, 1), self.key_buckets)
-        with self._mu:
+        with self._locked():
             # the gather must FINISH under the lock: the fused
             # optimizer apply donates the table buffer and overwrites
             # it in place (see the lock-discipline note in __init__) —
             # the fancy-index / forced gather below returns a copy, so
             # nothing aliasing the table leaves the critical section
             if self._cpu_fast:
-                rows = np.asarray(self._rows)[local]
+                rows = self._gather_rows(local, keys=n, bucket=b)
             else:
                 padded = np.zeros((b,), np.int64)
                 padded[:n] = local
-                rows = np.asarray(self._gather(self._rows, padded))[:n]
+                rows = self._gather_rows(padded, keys=n, bucket=b)[:n]
             ver = self.version
             self.n_lookups += 1
             self._note_hot(local)
@@ -241,7 +287,7 @@ class EmbeddingShardServer:
         if grads.shape != (local.shape[0], self.dim):
             raise ValueError(f"grads shape {grads.shape} != "
                              f"({local.shape[0]}, {self.dim})")
-        with self._mu:
+        with self._locked():
             if update_id is not None and update_id in self._applied:
                 self.n_dup_updates += 1
                 DUP_UPDATES.add(1)
@@ -262,7 +308,10 @@ class EmbeddingShardServer:
         pg = np.zeros((b, self.dim), np.float32)
         pk[:n] = local
         pg[:n] = grads          # padded rows add 0 to row 0: a no-op
-        self._rows = self._scatter(self._rows, pk, pg)
+        with rpcz.stage("ps.shard.apply", keys=n, bucket=b) as st:
+            if st is not rpcz.NOOP_STAGE:
+                st.set(dup_keys=_dup_keys(local))
+            self._rows = self._scatter(self._rows, pk, pg)
         self.version += 1
 
     # ---- the fused co-located optimizer apply (ISSUE 17) ----
@@ -294,7 +343,6 @@ class EmbeddingShardServer:
         if grads.shape != (local.shape[0], self.dim):
             raise ValueError(f"grads shape {grads.shape} != "
                              f"({local.shape[0]}, {self.dim})")
-        fn = fused_apply(spec.kind)
         n = local.shape[0]
         b = _bucket_up(max(n, 1), self.key_buckets)
         pk = np.zeros((b,), np.int64)
@@ -307,21 +355,15 @@ class EmbeddingShardServer:
         pk[:n] = local
         pg[:n] = grads
         pv[:n] = 1.0
-        with self._mu:
+        with self._locked():
             if update_id is not None and update_id in self._applied:
                 self.n_dup_updates += 1
                 DUP_UPDATES.add(1)
                 return self._applied[update_id], True
-            self._ensure_slots_locked(spec)
-            s = self._slots
-            if spec.kind == "sgdm":
-                self._rows, s["m"] = fn(
-                    self._rows, s["m"], pk, pg, pv,
-                    spec.lr, spec.momentum)
-            else:
-                self._rows, s["m"], s["v"], s["t"] = fn(
-                    self._rows, s["m"], s["v"], s["t"], pk, pg, pv,
-                    spec.lr, spec.beta1, spec.beta2, spec.eps)
+            with rpcz.stage("ps.shard.apply", keys=n, bucket=b) as st:
+                if st is not rpcz.NOOP_STAGE:
+                    st.set(dup_keys=_dup_keys(local))
+                self._fused_apply_locked(spec, pk, pg, pv)
             self.version += 1
             ver = self.version
             if update_id is not None:
@@ -337,6 +379,67 @@ class EmbeddingShardServer:
         OPT_UPDATES.add(1)
         UPDATE_KEYS.add(int(n))
         return ver, False
+
+    def _fused_apply_locked(self, spec, pk, pg, pv) -> None:
+        """One fused scatter+step over bucket-padded keys (``pv`` 0 on
+        the padding), the slots allocated on first use."""
+        from brpc_tpu.train.optimizer import fused_apply
+        fn = fused_apply(spec.kind)
+        self._ensure_slots_locked(spec)
+        s = self._slots
+        if spec.kind == "sgdm":
+            self._rows, s["m"] = fn(
+                self._rows, s["m"], pk, pg, pv,
+                spec.lr, spec.momentum)
+        else:
+            self._rows, s["m"], s["v"], s["t"] = fn(
+                self._rows, s["m"], s["v"], s["t"], pk, pg, pv,
+                spec.lr, spec.beta1, spec.beta2, spec.eps)
+
+    def warm(self, optimizer=None, batch_buckets: Sequence[int] = ()
+             ) -> None:
+        """Set-up's explicit entry: compile every program a serving
+        window can meet and allocate the optimizer slots, changing
+        nothing (the lazy paths stay for callers that never call this).
+        Lookups: the gather at every key bucket, alone and as a batch
+        of every ``batch_buckets`` size (the service passes its
+        batcher's).  Updates: with ``optimizer`` (an ``OptimizerSpec``
+        or its wire dict) the slots of that kind and its fused apply at
+        every key bucket; without, the plain scatter-add at every key
+        bucket, alone and flattened over every batch size.  Each
+        program runs once on all-padding input (key 0, gradient 0,
+        ``valid`` 0), which leaves rows, slots and version as they
+        were."""
+        spec = None
+        if optimizer is not None:
+            from brpc_tpu.train.optimizer import OptimizerSpec
+            spec = OptimizerSpec.from_wire(optimizer)
+        def padding(b):
+            return (np.zeros((b,), np.int64),
+                    np.zeros((b, self.dim), np.float32),
+                    np.zeros((b,), np.float32))
+
+        with self._mu:
+            if spec is not None:
+                # fresh slots are uncommitted arrays, an apply's outputs
+                # committed ones, and jit keys on that: one apply first,
+                # so that every bucket below compiles against the slots
+                # a window will hand it
+                self._fused_apply_locked(spec, *padding(self.key_buckets[0]))
+            for b in self.key_buckets:
+                shapes = [(b,)] + [(n, b) for n in batch_buckets]
+                if not self._cpu_fast:
+                    for shape in shapes:
+                        self._gather(self._rows, np.zeros(shape, np.int64))
+                if spec is not None:
+                    self._fused_apply_locked(spec, *padding(b))
+                    continue
+                for shape in shapes:
+                    flat = int(np.prod(shape))
+                    self._rows = self._scatter(
+                        self._rows, np.zeros((flat,), np.int64),
+                        np.zeros((flat, self.dim), np.float32))
+            self._jax.block_until_ready((self._rows, self._slots))
 
     def snapshot_slots(self) -> dict:
         """Current optimizer slot tables as numpy (tests compare
@@ -398,14 +501,19 @@ class EmbeddingShardServer:
         # the service handler — this fn sees bucket-padded rows and
         # cannot tell live from padding
         k = np.asarray(padded, np.int64)
-        with self._mu:
+        with self._locked():
             # complete the gather under the lock — the fused optimizer
             # apply donates and overwrites the table in place, so the
             # zero-copy view must not be read outside the critical
             # section (the fancy-index result is a fresh array)
-            if self._cpu_fast:
-                return np.asarray(self._rows)[k]
-            return np.asarray(self._gather(self._rows, k))
+            self._gathered.version = self.version
+            return self._gather_rows(k, keys=int(k.size),
+                                     bucket=int(k.shape[-1]))
+
+    def gathered_version(self) -> int:
+        """The version the calling thread's last ``lookup_batch_fn``
+        gathered at: what a batched lookup's rows show, exactly."""
+        return self._gathered.version
 
     # Update rows pack (update_id, then per key [key, grad...]) into ONE
     # float64 vector: [uid, k0, g0_0..g0_{D-1}, k1, g1_0..].  float64
@@ -494,7 +602,7 @@ class EmbeddingShardServer:
         [version, dup_flag] per row.  uid 0 marks batch padding."""
         B = keys.shape[0]
         acks = np.zeros((B, 2), np.float64)
-        with self._mu:
+        with self._locked():
             # dedup against the applied set AND within this batch: a
             # retry can land in the SAME batch as its original (reply
             # lost before the batch formed) — both rows would pass the
@@ -524,9 +632,11 @@ class EmbeddingShardServer:
             # ONE compiled scatter for the whole batch (compile per
             # (batch bucket, key bucket) pair); dup/padding rows are
             # zeroed above so they contribute nothing
-            self._rows = self._scatter(
-                self._rows, keys.reshape(-1),
-                grads.reshape(-1, self.dim))
+            with rpcz.stage("ps.shard.apply", keys=int(keys.size),
+                            bucket=int(keys.shape[-1])):
+                self._rows = self._scatter(
+                    self._rows, keys.reshape(-1),
+                    grads.reshape(-1, self.dim))
             for uid, i in first_row.items():
                 self.version += 1
                 self._record_applied_locked(uid, self.version)

@@ -254,7 +254,8 @@ UPCALL_STAGES = frozenset((
     "stream.on_feedback"))
 # Stages in which the thread is parked, not working (``wait=1``): a
 # reader leaves them out of a layer's time and of the idle attribution.
-WAIT_STAGES = frozenset(("rpc.client.wait", "stream.credit_wait"))
+WAIT_STAGES = frozenset(("rpc.client.wait", "stream.credit_wait",
+                         "ps.batcher.wait", "ps.shard.lock_wait"))
 # Stages that stamp ``cpu_us`` (the thread's CPU time inside them): the
 # ones that are outermost on their thread, so that together they cover
 # the call path once.  Not every stage: ``time.thread_time_ns`` is a
@@ -264,7 +265,7 @@ WAIT_STAGES = frozenset(("rpc.client.wait", "stream.credit_wait"))
 CPU_STAGES = frozenset((
     "rpc.client.call", "rpc.client.on_response", "rpc.server.process",
     "stream.write", "stream.send", "stream.on_data",
-    "combo.call_lowered"))
+    "combo.call_lowered", "ps.client.call", "ps.batcher.run"))
 _ROOT, _UPCALL, _WAIT, _CPU = 1, 2, 4, 8
 _STAGE_KIND: dict = {}       # name -> the flags of the sets it is in
 for _flag, _names in ((_ROOT, ROOT_STAGES), (_UPCALL, UPCALL_STAGES),

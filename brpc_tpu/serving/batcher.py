@@ -200,7 +200,8 @@ class DynamicBatcher:
                  name: str = "default",
                  dtype=np.float32,
                  padded_output: Optional[bool] = None,
-                 eager: bool = False):
+                 eager: bool = False,
+                 stage_prefix: Optional[str] = None):
         if max_batch_size < 1:
             raise ValueError("max_batch_size must be >= 1")
         # a ModelRunner instance cannot exist unless its module is
@@ -220,6 +221,14 @@ class DynamicBatcher:
             batch_fn = (batch_fn.score_with_offsets
                         if prefix_cache is not None else batch_fn.score)
         self.batch_fn = batch_fn
+        # the owner's prefix for this batcher's stages (rpcz.stage):
+        # ``<prefix>.batcher.run`` around one batch on the thread that
+        # runs it, ``<prefix>.batcher.wait`` a marker per member that
+        # carries how long it was queued (``queue_delay_us``; no thread
+        # is parked for a queued request, so there is no interval to
+        # stamp).  None: no stages
+        self._stage_run = stage_prefix and f"{stage_prefix}.batcher.run"
+        self._stage_wait = stage_prefix and f"{stage_prefix}.batcher.wait"
         self.max_batch_size = int(max_batch_size)
         self.max_delay_us = int(max_delay_us)
         self.batch_buckets = tuple(sorted(
@@ -615,7 +624,9 @@ class DynamicBatcher:
         t_cpu0 = time.thread_time()
         self._fn_cpu_s = 0.0
         try:
-            self._run_batch_inner(batch)
+            with (rpcz.stage(self._stage_run, members=len(batch))
+                  if self._stage_run else rpcz.NOOP_STAGE):
+                self._run_batch_inner(batch)
         finally:
             hostcpu.add("batch_formation",
                         (time.thread_time() - t_cpu0 - self._fn_cpu_s)
@@ -639,6 +650,9 @@ class DynamicBatcher:
             else:
                 qd_us = int((now - p.enqueue_t) * 1e6)
                 self.queue_delay_rec.add(qd_us)
+                if self._stage_wait:
+                    with rpcz.stage(self._stage_wait, queue_delay_us=qd_us):
+                        pass
                 if p.span is not rpcz.NULL_SPAN:
                     p.span.annotate(f"batch formed: queue_delay_us={qd_us}"
                                     f" members={len(batch)}")

@@ -210,8 +210,13 @@ def fused_apply(kind: str):
 
     The state arrays (rows + slots) are DONATED: the program writes
     them in place instead of materialising four table-sized outputs
-    per wave, so the wave cost is the gradient scatter plus
-    O(bucket) slot math, not O(vocab) copies.  Callers must treat the
+    per wave.  A wave is still O(vocab): duplicate keys accumulate in
+    ``g_acc``, a zeroed temporary of the table's own shape
+    (``jnp.zeros_like(rows)``), and in ``cnt``, one float a row, both
+    made and filled anew every wave, so each wave writes a table's
+    worth of zeros beside the O(bucket) scatter and slot math (PERF.md
+    section 7).  The programs are named (``jit_ps_sgdm_apply`` /
+    ``jit_ps_adam_apply`` in a device trace).  Callers must treat the
     inputs as consumed and keep every other reader of those buffers
     behind the owner's lock (the shard does; ``oracle_apply`` passes
     throwaway copies).  The step math itself runs on the GATHERED
@@ -232,7 +237,7 @@ def fused_apply(kind: str):
         import jax.numpy as jnp
 
         if kind == "sgdm":
-            def _sgdm(rows, m, keys, grads, valid, lr, mu):
+            def ps_sgdm_apply(rows, m, keys, grads, valid, lr, mu):
                 g_acc = jnp.zeros_like(rows).at[keys].add(
                     grads * valid[:, None])
                 cnt = jnp.zeros((rows.shape[0],), jnp.float32
@@ -245,10 +250,10 @@ def fused_apply(kind: str):
             # _FUSED; the early return above keeps the hot path
             # construction-free
             # brpc-check: allow(jit-hot-path)
-            fn = jax.jit(_sgdm, donate_argnums=(0, 1))
+            fn = jax.jit(ps_sgdm_apply, donate_argnums=(0, 1))
         else:
-            def _adam(rows, m, v, t, keys, grads, valid,
-                      lr, b1, b2, eps):
+            def ps_adam_apply(rows, m, v, t, keys, grads, valid,
+                              lr, b1, b2, eps):
                 g_acc = jnp.zeros_like(rows).at[keys].add(
                     grads * valid[:, None])
                 cnt = jnp.zeros((rows.shape[0],), jnp.float32
@@ -258,9 +263,9 @@ def fused_apply(kind: str):
                     g_acc[keys], cnt[keys] > 0.0, lr, b1, b2, eps)
                 return (rows.at[keys].set(rk), m.at[keys].set(mk),
                         v.at[keys].set(vk), t.at[keys].set(tk))
-            # once per process, cached in _FUSED (see _sgdm above)
+            # once per process, cached in _FUSED (see the sgdm branch)
             # brpc-check: allow(jit-hot-path)
-            fn = jax.jit(_adam, donate_argnums=(0, 1, 2, 3))
+            fn = jax.jit(ps_adam_apply, donate_argnums=(0, 1, 2, 3))
         _FUSED[kind] = fn
         return fn
 
