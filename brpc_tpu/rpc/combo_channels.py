@@ -13,8 +13,9 @@ selective_channel.{h,cpp}, partition_channel.{h,cpp}; SURVEY.md §2.5).
 TPU-native lowering: when every sub-channel targets an ICI endpoint in the
 local mesh, ParallelChannel/PartitionChannel execute as ONE jitted
 shard_map over the device mesh — the fan-out becomes a broadcast/shard and
-the merge becomes a collective (psum / all_gather), never touching sockets
-(SURVEY.md §5.8 target).  See brpc_tpu/ici/collective.py.
+the fan-in a collective inside the program (psum / all_gather), never
+touching sockets or host memory (SURVEY.md §5.8 target).  See
+brpc_tpu/ici/collective.py.
 """
 from __future__ import annotations
 
@@ -45,6 +46,20 @@ def _collective_group_for(devices):
             g = CollectiveGroup(Mesh(_np.array(devices), ("chip",)))
             _collective_groups[key] = g
         return g
+
+
+def _caller_device(request):
+    """The chip a lowered call's responses belong on: the one the request
+    is committed to, else the process's default device."""
+    import jax
+    if isinstance(request, jax.Array) and request.committed:
+        devices = request.devices()
+        if len(devices) == 1:
+            return next(iter(devices))
+    dev = jax.config.jax_default_device
+    # unset, or a platform's name
+    return dev if isinstance(dev, jax.Device) else \
+        jax.local_devices(backend=dev)[0]
 
 
 class SubCall:
@@ -119,9 +134,15 @@ class ParallelChannel:
                       done: Callable | None) -> Controller:
         """All targets are chips in the local mesh: run the fan-out as ONE
         jitted shard_map — broadcast + per-chip service fn + collective
-        merge (SURVEY.md §5.8 lowering).  The merge is "sum" when the
-        ResponseMerger is SumMerger, else per-chip results are stacked and
-        handed to the merger."""
+        fan-in (SURVEY.md §5.8 lowering).  With a SumMerger the program
+        ends in ``psum`` and its replicated result is the response.  With
+        any other merger it ends in ``all_gather``, and the merger is
+        handed one ``jax.Array`` per channel, in channel order: chip i's
+        ``fn(request)``, its shape and dtype, in a buffer of its own,
+        committed to the caller's chip (``_caller_device``).  Those are
+        that chip's replicas of the gathered rows as the program left
+        them; only a caller outside the mesh costs a device-to-device
+        move.  No byte of a response passes through host memory."""
         from brpc_tpu.ici.channel import device_service_registry
         import time
         fn = device_service_registry().get((service, method))
@@ -141,8 +162,13 @@ class ParallelChannel:
                     # device-side failures surface here
                     out = group.parallel_apply(fn, request, merge=merge)
                     if merge == "stack":
-                        with rpcz.stage("combo.merge"):
-                            out = self.response_merger.merge(list(out))
+                        with rpcz.stage("combo.merge") as stg:
+                            rows, moved = group.fan_in(
+                                out, _caller_device(request))
+                            if stg is not rpcz.NOOP_STAGE:
+                                stg.set(rows=len(rows), moved=moved,
+                                        bytes=rpcz.payload_bytes(rows))
+                            out = self.response_merger.merge(rows)
                 cntl.response = out
             except Exception as e:
                 cntl.set_failed(errors.EINTERNAL,

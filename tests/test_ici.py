@@ -517,8 +517,8 @@ class TestCollective:
         g = CollectiveGroup()
         x = jnp.ones((4, 8), jnp.float32)
         stacked = g.parallel_apply(lambda t: t * 2, x, merge="stack")
-        assert stacked.shape == (8, 4, 8)
-        np.testing.assert_allclose(np.asarray(stacked), 2.0)
+        assert [r.shape for r in stacked] == [(4, 8)] * 8
+        np.testing.assert_allclose(np.stack(stacked), 2.0)
         summed = g.parallel_apply(lambda t: t * 2, x, merge="sum")
         assert summed.shape == (4, 8)
         np.testing.assert_allclose(np.asarray(summed), 16.0)  # 8 chips × 2

@@ -298,6 +298,60 @@ def test_every_stage_stamps_its_cpu_time_and_waits_are_marked(reduction):
     assert reduction["by_name"]["collective.run"][-1].stats["cache_hit"] == 1
 
 
+def test_the_merge_stage_says_what_it_handed_the_merger(reduction):
+    merges = reduction["by_name"]["combo.merge"]
+    assert len(merges) == N_FAN
+    for s in merges:
+        assert s.parent.name == "combo.call_lowered"
+        # four rows of the request's size, chip 0 in the mesh: none moved
+        assert s.stats["rows"] == 4 and s.stats["moved"] == 0
+        assert s.stats["bytes"] == 4 * (1 << 16) * 4
+
+
+def test_a_caller_outside_the_mesh_reads_as_moved_rows(tmp_path):
+    register_device_service("StageFan", "Apply", _fan)
+    fan = brpc.ParallelChannel()
+    for i in range(4, 8):
+        fan.add_channel(IciChannel(f"ici://slice0/{i}"))
+    x = jax.device_put(jnp.arange(1 << 10, dtype=jnp.uint32),
+                       jax.devices()[0])
+    fan.call_sync("StageFan", "Apply", x)                     # compile
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    t0 = time.monotonic()
+    try:
+        out = fan.call_sync("StageFan", "Apply", x)
+    finally:
+        t1 = time.monotonic()
+        jax.profiler.stop_trace()
+    assert all(o.devices() == {jax.devices()[0]} for o in out)
+    red = ps.reduce(trace.find_xplane(str(tmp_path)), t0, t1)
+    (merge,) = red["by_name"]["combo.merge"]
+    assert merge.parent.name == "combo.call_lowered"
+    assert {"rows": 4, "moved": 4, "bytes": 4 * x.nbytes}.items() \
+        <= merge.stats.items()
+
+
+def test_the_fan_in_counter_counts_one_fan_in_a_lowered_call():
+    from brpc_tpu.bvar import find_exposed
+    fan_ins = find_exposed("ici_collective_fan_ins")
+    calls = find_exposed("ici_collective_calls")
+    register_device_service("StageFan", "Apply", _fan)
+    fan = brpc.ParallelChannel()
+    for i in range(4):
+        fan.add_channel(IciChannel(f"ici://slice0/{i}"))
+    x = jnp.arange(1 << 10, dtype=jnp.uint32)
+    fan.call_sync("StageFan", "Apply", x)
+    before, n0 = fan_ins.get_value(), calls.get_value()
+    for _ in range(3):
+        fan.call_sync("StageFan", "Apply", x)
+    after = fan_ins.get_value()
+    assert calls.get_value() - n0 == 3
+    assert after["in_place"] - before["in_place"] == 3
+    assert after["moved"] == before["moved"]
+
+
 # ---- sink 2: the rpcz span -------------------------------------------------
 
 def test_rpcz_has_a_client_and_a_server_span_with_their_phases(states):
