@@ -68,16 +68,14 @@ kvcache: $(LIB) $(PYEXT)
 
 # Recovery suite (README "Fault tolerance & degradation"): engine
 # supervision, crash/wedge failover over the surviving KV cache,
-# degradation ladder, flapping-replica quarantine.  CPU jit path; the
-# timed recovery rung runs via `python bench.py` (recovery section).
+# degradation ladder, flapping-replica quarantine.  CPU jit path.
 recovery: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_supervisor.py -q
 
 # Migration suite (README "Cross-host data plane"): KV page migration
 # over the _kvmig wire — export/splice round-trips, rollback on
 # mid-splice faults, offer-table bounds, migrate-on-rebalance, the
-# /migration console page.  CPU jit path; the timed migrate-vs-
-# recompute rung runs via `python bench.py migrate`.
+# /migration console page.  CPU jit path.
 migrate: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_migrate.py -q
 
@@ -90,90 +88,65 @@ disagg: $(LIB) $(PYEXT)
 # Cluster suite (README "Cluster front door"): the ClusterRouter —
 # resumable client sessions (drop/reconnect, replica kill, router
 # restart), prefix-affinity routing with quarantine remap, and the
-# 4-level overload gradient's ordering proof.  CPU jit path; the timed
-# router-vs-direct rung runs via `python bench.py cluster` and feeds
-# the same perf_diff gate `make bench` ends with.
+# 4-level overload gradient's ordering proof.  CPU jit path.
 cluster: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_router.py -q
 
 # Durable control plane (README "Durable control plane", ISSUE 16):
 # the session-WAL suite (write-ahead discipline, torn tails,
-# compaction, adoption) plus the timed WAL-tax / crash->first-token
-# rung (3-trial median+spread, feeds the same perf_diff gate `make
-# bench` ends with).  CPU jit path.
+# compaction, adoption).  CPU jit path.
 durable: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_session_wal.py -q
-	JAX_PLATFORMS=cpu python bench.py durable
 
 # Multi-model plane (README "Multi-model plane", ISSUE 18): the
 # deployment/catalog/canary suite (named deployments, (model, prefix)
 # routing, model-aware WAL adoption, lifecycle fencing, misroute
-# counters) plus the timed two-model-tax / 95-5-canary-split rung
-# (3-trial median+spread, feeds the same perf_diff gate `make bench`
-# ends with).  CPU jit path.
+# counters).  CPU jit path.
 multimodel: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_modelplane.py -q
-	JAX_PLATFORMS=cpu python bench.py multimodel
 
 # Fleet telemetry plane (README "Fleet telemetry", ISSUE 20): the
-# collector/SLO/stitching suite, then the collection-overhead rung —
-# front-door generations/s with the 20 Hz collector+SLO tick off vs
-# on (<=2% acceptance, 3-trial median+spread, perf_diff gated).
+# collector/SLO/stitching suite.
 telemetry: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_telemetry.py -q
-	JAX_PLATFORMS=cpu python bench.py telemetry
 
 # Real model serving (README "Real model serving", ISSUE 10): the
 # paged-attention equivalence suite (gather + pallas-interpret vs the
-# dense reference at page boundaries / COW forks / evict-readmit), the
-# ModelRunner protocol + TransformerRunner end-to-end tests, then the
-# timed runner-vs-harness tokens/s rung (3-trial median+spread, feeds
-# perf_diff).  CPU jit path throughout.
+# dense reference at page boundaries / COW forks / evict-readmit) and
+# the ModelRunner protocol + TransformerRunner end-to-end tests.  CPU
+# jit path throughout.
 model: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_paged_attention.py \
 	    tests/test_model_runner.py -q
-	JAX_PLATFORMS=cpu python bench.py model
 
 # Parameter server (README "Parameter server", ISSUE 12): the sharded
 # embedding service — PSClient bit-identity vs the dense oracle at
 # partition counts 1/2/4/8 (RPC fan-out AND collective lowering),
-# batcher coalescing, idempotent updates — then the timed
-# batched-vs-unbatched + framework-vs-raw-collectives rung (3-trial
-# median+spread, feeds perf_diff).
+# batcher coalescing, idempotent updates.
 psserve: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_psserve.py -q
-	JAX_PLATFORMS=cpu python bench.py embedding
 
 # Training plane (README "Training plane", ISSUE 17): the
 # trainer-in-the-loop suite — fused co-located optimizer bit-identity
 # vs the dense oracle at partitions 1/2/4 (RPC AND lowered),
 # retried-wave exactly-once, bounded-staleness gating, arbiter shed
-# ordering — then the timed wire-optimizer vs pull-compute-push rung
-# (wire >= baseline beyond spread is the acceptance bar) plus the
-# serving-coexistence tokens/s ratio (3-trial median+spread, feeds
-# the same perf_diff gate `make bench` ends with).
+# ordering.
 train: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_train.py -q
-	JAX_PLATFORMS=cpu python bench.py train
 
 # Binary tensor wire (README "Binary tensor wire", ISSUE 13): the
 # frame identity/golden/fuzz suite + PS bit-identity over tensorframe
-# vs JSON vs the dense oracle + the ICI fast path, then the embedding
-# bench rung's serializer axis (json vs tensorframe vs lowered,
-# tax_reduction_x >= 5x beyond spread is the acceptance bar).
+# vs JSON vs the dense oracle + the ICI fast path.
 tensorframe: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_tensorframe.py \
 	  tests/test_fuzz_parsers.py::test_fuzz_tensorframe_frames -q
-	JAX_PLATFORMS=cpu python bench.py embedding
 
 # Speculative decoding (README "Speculative decoding", ISSUE 11): the
 # identity suite (spec output == plain greedy at depths 2/4/8 — cold,
-# warm, mixed slots, draft trees, through Serving.Generate), the
-# draft-lease/fork lifecycle units, then the timed plain-vs-spec
-# tokens/s rung (3-trial interleaved median+spread, feeds perf_diff).
+# warm, mixed slots, draft trees, through Serving.Generate) and the
+# draft-lease/fork lifecycle units.
 speculative: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python -m pytest tests/test_speculative.py -q
-	JAX_PLATFORMS=cpu python bench.py speculative
 
 # Tracing suite (README "Observability"): rpcz generation tracing —
 # per-trace head sampling, span-tree timelines, TTFT/ITL math, trace
@@ -187,44 +160,11 @@ trace: $(LIB) $(PYEXT)
 hotspots: $(LIB) $(PYEXT)
 	JAX_PLATFORMS=cpu python tools/hotspots_burst.py
 
-# Per-stage host micro-benchmark suite (bench.py microbench): frame
-# pump, batch assembly, radix prefix match, page alloc/release, emit
-# fan-out, span submit, host-us-per-token, stream scaling, sampler
-# overhead — CPU-valid, 3-trial median+spread.  The de-GIL'd stages
-# publish a native-vs-python A/B per round (ISSUE 9, README "Native
-# host path").
-microbench: $(LIB) $(PYEXT)
-	JAX_PLATFORMS=cpu python bench.py microbench
-
-# De-GIL perf gate (ISSUE 9): run the per-stage host microbench suite,
-# nest its output under "microbench" to match the round wrappers'
-# detail tree, and perf_diff it against the freshest BENCH_r*.json —
-# exits 1 on any beyond-spread regression, so the per-stage trajectory
-# (emit_fanout, batch_assembly, span_submit, host_us_per_token and
-# their native_speedup A/Bs) gates future PRs by default.  Wire this
-# next to `make test` in a verify loop; MICROBENCH.json is the
-# sidecar a later round can diff against directly.
-perf: $(LIB) $(PYEXT)
-	JAX_PLATFORMS=cpu python bench.py microbench \
-	    | python -c "import json,sys; json.dump({'microbench': \
-	    json.load(sys.stdin)}, open('MICROBENCH.json','w'), indent=1)"
-	JAX_PLATFORMS=cpu python bench.py model \
-	    | python -c "import json,sys; json.dump({'model': \
-	    json.load(sys.stdin)}, open('MODELBENCH.json','w'), indent=1)"
-	python tools/perf_diff.py \
-	    "$$(ls BENCH_r*.json 2>/dev/null | sort -V | tail -1)" \
-	    MICROBENCH.json
-	python tools/perf_diff.py \
-	    "$$(ls BENCH_r*.json 2>/dev/null | sort -V | tail -1)" \
-	    MODELBENCH.json
-
 # brpc-check (ISSUE 14, README "Static analysis"): the repo-invariant
 # AST analysis suite — lock-order cycles, bounded-decode discipline,
 # one-compile-per-bucket jit, the fault-site registry, InstrumentedLock
 # hygiene, wedge hygiene — against the committed CHECK_BASELINE.json.
-# Runs in a few seconds; exits 1 on any NON-baseline finding.  Also
-# `make bench`'s preflight, so perf rounds can't ride on eroded
-# invariants.
+# Runs in a few seconds; exits 1 on any NON-baseline finding.
 check:
 	python tools/brpc_check.py
 
@@ -236,17 +176,6 @@ check:
 # hunt.
 wedge-hunt: $(LIB) $(PYEXT)
 	python tools/wedge_hunt.py
-
-# Full bench run ending in a delta-vs-previous-round table: perf_diff
-# compares the freshest BENCH_r*.json against this run's
-# BENCH_DETAILS.json and flags beyond-spread regressions (the leading
-# `-` keeps the table from failing the build; run perf_diff directly
-# for the gating exit code).
-bench: $(LIB) $(PYEXT) check
-	python bench.py
-	-python tools/perf_diff.py \
-	    "$$(ls BENCH_r*.json 2>/dev/null | sort -V | tail -1)" \
-	    BENCH_DETAILS.json
 
 # Sanitizer stress targets (VERDICT r2 task 7; reference fights lock-free
 # races with stress tests + sanitizer builds, SURVEY.md §5.3).  The whole
@@ -324,6 +253,6 @@ stress:
 	./build/stress_plain
 
 .PHONY: all clean test chaos serving kvcache recovery migrate disagg \
-    cluster durable model speculative trace hotspots microbench perf \
-    bench tsan tsan-core asan stress check ring-stress wedge-hunt \
+    cluster durable model speculative trace hotspots \
+    tsan tsan-core asan stress check ring-stress wedge-hunt \
     psserve tensorframe train multimodel telemetry

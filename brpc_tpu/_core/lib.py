@@ -287,17 +287,6 @@ _sigs = {
                                        ctypes.POINTER(ctypes.c_double),
                                        ctypes.POINTER(ctypes.c_double),
                                        ctypes.POINTER(ctypes.c_double)]),
-    # native client pump against an EXISTING server (Python handlers):
-    # port, service, method, conns, inflight, total, payload_len,
-    # out: success qps, p50, p99, err_frac (sheds/errors; nullable)
-    "brpc_bench_pump": (ctypes.c_int, [ctypes.c_int, ctypes.c_char_p,
-                                       ctypes.c_char_p, ctypes.c_int,
-                                       ctypes.c_int, ctypes.c_uint64,
-                                       ctypes.c_int,
-                                       ctypes.POINTER(ctypes.c_double),
-                                       ctypes.POINTER(ctypes.c_double),
-                                       ctypes.POINTER(ctypes.c_double),
-                                       ctypes.POINTER(ctypes.c_double)]),
     # usercode admission control (net/rpc.h; latency-budget ELIMIT sheds)
     "brpc_set_usercode_budget_us": (None, [ctypes.c_int64]),
     "brpc_usercode_budget_us": (ctypes.c_int64, []),

@@ -11,8 +11,7 @@ per-stage Adders, and roll up into ONE honest headline:
         across all serving stages / tokens emitted
 
 The native frame pump runs no Python and cannot be thread_time()'d
-from here; its cost is measured by the ``frame_pump`` microbench rung
-(bench.py microbench) instead.  ``model_compute`` (the jit'd
+from here.  ``model_compute`` (the jit'd
 prefill/step calls) is deliberately EXCLUDED from the per-token
 rollup: the metric exists to size the de-GIL prize (ROADMAP item 4),
 which is host bookkeeping, not model math.

@@ -6,10 +6,12 @@ hundreds), each tracking BOTH error rate and latency:
 
 - error isolation: short error EMA > 50% or long error EMA > 20%;
 - latency isolation: the short latency EMA exceeding LATENCY_RATIO x the
-  long (baseline) latency EMA isolates the endpoint even with a 0% error
-  rate — a replica that silently got 5x slower is broken in every way that
-  matters (the reference folds latency into "error cost" for the same
-  effect).
+  long (baseline) latency EMA, at the end of a run of
+  MIN_SHORT_LATENCY_SAMPLES successes each over twice the baseline,
+  isolates the endpoint even with a 0% error rate — a replica that
+  silently got 5x slower is broken in every way that matters (the
+  reference folds latency into "error cost" for the same effect); a slow
+  call or two among fast ones (another method, a collection pause) is not.
 
 Isolation hands the endpoint to the health checker with a hold duration
 that doubles per consecutive isolation (100ms -> 30s cap, mirroring the
@@ -65,9 +67,10 @@ class CircuitBreaker:
     LATENCY_RATIO = 4.0
     MIN_BASELINE_US = 200
     MIN_LATENCY_SAMPLES = 32      # long-window baseline maturity
-    MIN_SHORT_LATENCY_SAMPLES = 8  # short window must have real evidence —
-    # without this, the first slow success after a reset/revival seeds the
-    # short EMA to its full value and instantly re-isolates on one sample
+    # a SUSTAINED slowdown: this many suspect successes (over 2x the
+    # baseline) in a row; the short EMA gives its newest sample 30%, and
+    # two 5 ms calls over a 0.6 ms baseline isolated a healthy endpoint
+    MIN_SHORT_LATENCY_SAMPLES = 8
     # isolation hold: doubles per consecutive isolation (reference
     # min/max isolation_duration_ms)
     BASE_HOLD_S = 0.1
@@ -110,6 +113,7 @@ class CircuitBreaker:
                         or l.ema_latency == 0.0
                         or latency_us <= 2 * l.ema_latency):
                     l.add_latency(self.LONG_DECAY, latency_us)
+                    s.lat_samples = 0   # ends the run of slow ones
             if s.samples >= self.MIN_SAMPLES and (
                     s.ema_error > self.SHORT_THRESHOLD or
                     l.ema_error > self.LONG_THRESHOLD):

@@ -500,50 +500,6 @@ def run_disagg_press(prefill_addr: str, decode_addr: str, request,
     return summary
 
 
-def spin_up_cluster(n_replicas: int, *, page_tokens: int = 8,
-                    step_delay_s: float = 0.0, num_slots: int = 8,
-                    max_blocks: int = 64, page_bytes: int = 512,
-                    max_pages_per_slot: int = 64,
-                    name_prefix: str = "cluster",
-                    commit_live_pages: bool = False,
-                    replicate_sessions: bool = False,
-                    max_sessions: int = 256,
-                    timeout_ms: int = 20_000):
-    """Build an in-process cluster: N serving replicas (paged KV store +
-    decode engine + server with the Serving and ``_kvmig`` services)
-    behind a :class:`~brpc_tpu.serving.ClusterRouter` exposed on its own
-    router server.  The step function is plain numpy (CPU-valid), each
-    step optionally sleeping ``step_delay_s`` so generations are
-    decode-bound.  Shared by ``--cluster`` press mode and ``bench.py
-    cluster`` (which differ only in knobs: the press turns on
-    ``commit_live_pages``/``replicate_sessions`` to exercise resume
-    under a replica kill; the bench leaves replication off so the
-    router-overhead number isn't polluted by page shipping).
-
-    Returns ``(replicas, router, rsrv, raddr)`` with ``replicas`` a
-    list of ``(store, engine, server, addr)``; tear down with
-    :func:`tear_down_cluster`."""
-    from brpc_tpu.serving import (ClusterRouter, ReplicaHandle,
-                                  register_router)
-
-    replicas = spin_up_replicas(
-        n_replicas, page_tokens=page_tokens, step_delay_s=step_delay_s,
-        num_slots=num_slots, max_blocks=max_blocks,
-        page_bytes=page_bytes, max_pages_per_slot=max_pages_per_slot,
-        name_prefix=name_prefix, commit_live_pages=commit_live_pages)
-    router = ClusterRouter(
-        [ReplicaHandle(addr, name=f"{name_prefix}_{i}", engine=eng,
-                       store=store, server=srv)
-         for i, (store, eng, srv, addr) in enumerate(replicas)],
-        page_tokens=page_tokens, replicate_sessions=replicate_sessions,
-        max_sessions=max_sessions, name=f"{name_prefix}_router",
-        timeout_ms=timeout_ms)
-    rsrv = brpc.Server()
-    register_router(rsrv, router)
-    rsrv.start("127.0.0.1", 0)
-    return replicas, router, rsrv, f"127.0.0.1:{rsrv.port}"
-
-
 def spin_up_replicas(n_replicas: int, *, page_tokens: int = 8,
                      step_delay_s: float = 0.0, num_slots: int = 8,
                      max_blocks: int = 64, page_bytes: int = 512,
@@ -551,8 +507,8 @@ def spin_up_replicas(n_replicas: int, *, page_tokens: int = 8,
                      name_prefix: str = "cluster",
                      commit_live_pages: bool = False,
                      prefill_cost_per_token_s: float = 0.0):
-    """The replica half of :func:`spin_up_cluster`: N serving replicas
-    (paged KV store + decode engine) each exposing the Serving,
+    """N serving replicas (paged KV store + decode engine with a plain
+    numpy step function, CPU-valid) each exposing the Serving,
     ``_kvmig`` AND ``_cluster`` services — so they work behind an
     in-process router (ISSUE 8 shape) or a remote-only SUBPROCESS
     router (ISSUE 16: address-only handles, floor pushes over the
@@ -561,8 +517,8 @@ def spin_up_replicas(n_replicas: int, *, page_tokens: int = 8,
     ``prefill_cost_per_token_s`` adds a prefill stage whose cost
     scales with the (bucket-padded) UNCACHED suffix — the real-model
     cost shape where a prefix-cache hit buys skipped compute, so
-    benches measuring warmth effects (``bench.py durable``) see them
-    at true proportions instead of one flat-priced vectorized call.
+    warmth effects show at true proportions instead of one flat-priced
+    vectorized call.
 
     Returns a list of ``(store, engine, server, addr)``; tear down
     with :func:`tear_down_replicas`."""
@@ -624,16 +580,6 @@ def tear_down_replicas(replicas) -> None:
             pass
         store.clear()
         store.close()
-
-
-def tear_down_cluster(replicas, router, rsrv,
-                      timeout_s: float = 3.0) -> None:
-    """Close everything :func:`spin_up_cluster` built (replicas that
-    were already killed mid-run tear down quietly)."""
-    router.close(timeout_s=timeout_s)
-    rsrv.stop()
-    rsrv.join()
-    tear_down_replicas(replicas)
 
 
 # ---------------------------------------------------------------------------
@@ -858,7 +804,7 @@ def spin_up_psserve(n_shards: int, *, vocab: int = 1024, dim: int = 32,
                     max_delay_us: int = 1000, name_prefix: str = "press",
                     devices=None, table=None):
     """In-process sharded parameter-server fleet + a PartitionChannel
-    over it (shared by --embedding mode and bench.py embedding).
+    over it (shared by --embedding mode and chip_smoke.py's PS phase).
     ``devices`` places shard i on ``devices[i]`` — one shard per chip;
     without it every shard's rows land on the first device.  ``table``
     is the full [vocab, dim] table the shards slice (default: each
@@ -1160,12 +1106,25 @@ def run_cluster_press(n_replicas: int, request,
     per-level shed counts.  ``kill_replica_after=S`` kills one replica
     mid-run so the resume path runs under load.  CPU-valid: the step
     function is plain numpy."""
-    from brpc_tpu.serving import RouterClient
+    from brpc_tpu.serving import (ClusterRouter, ReplicaHandle,
+                                  RouterClient, register_router)
 
-    replicas, router, rsrv, raddr = spin_up_cluster(
-        n_replicas, page_tokens=8, commit_live_pages=True,
-        replicate_sessions=True, max_sessions=max(64, 8 * threads),
-        name_prefix="press_cl", timeout_ms=timeout_ms)
+    # live pages committed and sessions replicated, so that a replica
+    # kill mid-run exercises resume
+    replicas = spin_up_replicas(n_replicas, page_tokens=8,
+                                name_prefix="press_cl",
+                                commit_live_pages=True)
+    router = ClusterRouter(
+        [ReplicaHandle(addr, name=f"press_cl_{i}", engine=eng,
+                       store=store, server=srv)
+         for i, (store, eng, srv, addr) in enumerate(replicas)],
+        page_tokens=8, replicate_sessions=True,
+        max_sessions=max(64, 8 * threads), name="press_cl_router",
+        timeout_ms=timeout_ms)
+    rsrv = brpc.Server()
+    register_router(rsrv, router)
+    rsrv.start("127.0.0.1", 0)
+    raddr = f"127.0.0.1:{rsrv.port}"
     if slo:
         # --slo (ISSUE 20): observe-only burn-rate evaluation riding
         # the collector ticks — a single-model press has no canary
@@ -1286,7 +1245,10 @@ def run_cluster_press(n_replicas: int, request,
                   + (" BURNING" if b.get("burning") else ""),
                   file=sys.stderr)
     print(json.dumps(summary), file=out)
-    tear_down_cluster(replicas, router, rsrv)
+    router.close(timeout_s=3.0)
+    rsrv.stop()
+    rsrv.join()
+    tear_down_replicas(replicas)
     return summary
 
 

@@ -298,7 +298,7 @@ class MixedWorkloadHarness:
                  zipf_s: float = 1.0, gen_workers: int = 1,
                  gen_tokens: int = 16, train_workers: int = 2,
                  train_steps: int = 6, optimizer=None,
-                 trainer_mode: str = "wire", max_lag: int = 1,
+                 max_lag: int = 1,
                  min_duration_s: float = 0.0, seed: int = 0,
                  arbiter: Optional[TrafficArbiter] = None,
                  pressure_fn=None, timeout_ms: int = 10_000,
@@ -337,7 +337,7 @@ class MixedWorkloadHarness:
             steps=int(train_steps),
             optimizer=optimizer or OptimizerSpec("sgdm", lr=0.5,
                                                  momentum=0.5),
-            mode=trainer_mode, max_lag=int(max_lag),
+            max_lag=int(max_lag),
             arbiter=self.arbiter, seed=self.seed,
             name=f"{self.name}_trainer")
         self.trainer.seed_dense(self._dense0)

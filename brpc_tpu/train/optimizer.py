@@ -271,7 +271,7 @@ def fused_apply(kind: str):
 
 
 # ---------------------------------------------------------------------------
-# the dense single-host oracle (tests; trainer's pull-compute-push mode)
+# the dense single-host oracle (tests)
 # ---------------------------------------------------------------------------
 
 def zero_slots(spec: OptimizerSpec, vocab: int, dim: int) -> dict:

@@ -5,9 +5,9 @@ The training loop is the seed example (examples/embedding_server.py)
 grown into a real multi-worker trainer: every gather rides
 ``PS.Lookup`` (batched, tensorframe wire), every sparse gradient rides
 ``PS.Update`` carrying an :class:`~brpc_tpu.train.OptimizerSpec` so
-the scatter AND the momentum/Adam slot step run fused ON the shard
-(mode="wire"), dense parameters live in the service (``Pull``/``Push``
-per step), and a periodic Pull-based eval proves loss decreases
+the scatter AND the momentum/Adam slot step run fused ON the shard,
+dense parameters live in the service (``Pull``/``Push`` per step), and
+a periodic Pull-based eval proves loss decreases
 through the service — the model the trainer ever sees is the one the
 shards hold.
 
@@ -23,12 +23,6 @@ Update waves heal like any PS client: a failed wave re-issues with its
 optimizer's applied-id discipline means a retried wave can never
 double-step momentum.  Fault site ``train.update_wave`` injects wave
 failures (chaos scenario 18 kills a live shard instead).
-
-``mode="pull_compute_push"`` is the bench baseline the fused path is
-measured against: optimizer slots live AT THE TRAINER (host numpy),
-each wave computes the slot step host-side and ships the resulting
-row DELTAS as a plain scatter-add — the classic parameter-server
-shape "RPC Considered Harmful" argues against.
 """
 from __future__ import annotations
 
@@ -47,8 +41,6 @@ WAVES = Adder("train_waves")
 WAVE_RETRIES = Adder("train_wave_retries")
 EVALS = Adder("train_evals")
 
-MODES = ("wire", "pull_compute_push")
-
 
 class DataParallelTrainer:
     """N worker threads pulling minibatches, computing grads locally,
@@ -57,7 +49,7 @@ class DataParallelTrainer:
     def __init__(self, client, cfg=None, *, n_workers: int = 2,
                  steps: int = 8,
                  optimizer: Optional[OptimizerSpec] = None,
-                 mode: str = "wire", max_lag: int = 1,
+                 max_lag: int = 1,
                  sync: bool = False, lr_dense: float = 0.5,
                  eval_every: int = 0, wave_max_retry: int = 4,
                  retry_backoff_s: float = 0.05, arbiter=None,
@@ -66,8 +58,6 @@ class DataParallelTrainer:
         import jax.numpy as jnp
         from brpc_tpu.models.parameter_server import (PSConfig, _block,
                                                       make_example_batch)
-        if mode not in MODES:
-            raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
         self.client = client
         self.cfg = cfg or PSConfig(
             vocab=client.vocab, d_model=client.dim,
@@ -82,7 +72,6 @@ class DataParallelTrainer:
         self.steps = int(steps)
         self.optimizer = optimizer or OptimizerSpec(
             "sgdm", lr=0.5, momentum=0.5)
-        self.mode = mode
         self.max_lag = 0 if sync else int(max_lag)
         self.sync = bool(sync) or self.max_lag == 0
         self.lr_dense = float(lr_dense)
@@ -107,10 +96,6 @@ class DataParallelTrainer:
         self.n_paced = 0
         self.loss_history: list = []
         self.step_losses: list = []
-
-        # pull-compute-push mode's HOST-side slots (the baseline the
-        # fused co-located path is benched against)
-        self._host_slots: dict = {}
 
         # the seed model's loss over gathered rows + dense params —
         # jitted ONCE here (never per call)
@@ -233,11 +218,8 @@ class DataParallelTrainer:
                     raise errors.RpcError(
                         errors.EINTERNAL,
                         "injected train.update_wave fault")
-                if self.mode == "wire":
-                    cli.update(keys, grads, update_token=tok,
-                               optimizer=self.optimizer)
-                else:
-                    self._pull_compute_push(cli, keys, grads, tok)
+                cli.update(keys, grads, update_token=tok,
+                           optimizer=self.optimizer)
                 with self._mu:
                     self.n_waves += 1
                 WAVES.add(1)
@@ -252,39 +234,6 @@ class DataParallelTrainer:
                 if attempt >= self.wave_max_retry:
                     raise
                 time.sleep(self.retry_backoff_s * (attempt + 1))
-
-    def _pull_compute_push(self, cli, keys, grads, tok) -> None:
-        """The baseline wave: slot math at the HOST, deltas on the
-        wire.  Duplicate keys accumulate first (what the fused path's
-        scatter does), then one plain scatter-add update ships the
-        stepped rows' deltas."""
-        spec = self.optimizer
-        uniq, inv = np.unique(keys, return_inverse=True)
-        g_acc = np.zeros((uniq.shape[0], self.client.dim), np.float32)
-        np.add.at(g_acc, inv, grads)
-        with self._mu:
-            hs = self._host_slots
-            if "m" not in hs:
-                hs["m"] = np.zeros((self.client.vocab, self.client.dim),
-                                   np.float32)
-                if spec.kind == "adam":
-                    hs["v"] = np.zeros_like(hs["m"])
-                    hs["t"] = np.zeros((self.client.vocab,), np.float32)
-            if spec.kind == "sgdm":
-                m = spec.momentum * hs["m"][uniq] + g_acc
-                hs["m"][uniq] = m
-                delta = -spec.lr * m
-            else:
-                t = hs["t"][uniq] + 1.0
-                m = spec.beta1 * hs["m"][uniq] + \
-                    (1.0 - spec.beta1) * g_acc
-                v = spec.beta2 * hs["v"][uniq] + \
-                    (1.0 - spec.beta2) * g_acc * g_acc
-                hs["t"][uniq], hs["m"][uniq], hs["v"][uniq] = t, m, v
-                delta = -spec.lr * (m / (1.0 - spec.beta1 ** t[:, None])) \
-                    / (np.sqrt(v / (1.0 - spec.beta2 ** t[:, None]))
-                       + spec.eps)
-        cli.update(uniq, delta.astype(np.float32), update_token=tok)
 
     # ---- eval (Pull-based: the model scored is the SERVICE's) ----
 
@@ -367,7 +316,6 @@ class DataParallelTrainer:
             if self._errors:
                 raise self._errors[0][1]
             return {
-                "mode": self.mode,
                 "optimizer": self.optimizer.to_wire(),
                 "workers": self.n_workers,
                 "steps": self.steps,
@@ -403,7 +351,6 @@ class DataParallelTrainer:
         with self._mu:
             return {
                 "name": self.name,
-                "mode": self.mode,
                 "waves": self.n_waves,
                 "wave_retries": self.n_wave_retries,
                 "io_retries": self.n_io_retries,

@@ -21,8 +21,7 @@
 //     events, so a dead thread's tail is still dumpable.
 //   * Near-zero hot-path cost: one relaxed enabled-flag load, one TLS
 //     pointer read, four relaxed atomic stores and a vDSO clock read —
-//     no locks, no allocation, no syscalls.  Gated <2% on the echo and
-//     emit_fanout bench rungs (bench.py microbench "flight_recorder").
+//     no locks, no allocation, no syscalls.
 //   * Torn-read-proof dumps: each slot carries a seqlock version word
 //     (odd while the owner writes, even when complete), so a dump
 //     taken WHILE every thread keeps writing returns only consistent

@@ -129,12 +129,12 @@ extern "C" {
 namespace {
 using namespace brpc;
 
-// Client pump core shared by the self-contained echo bench and the
-// external-server pump: `conns` pipelined connections to 127.0.0.1:port,
-// `inflight` frames outstanding each, p50/p99 from send-timestamp cids.
+// Client pump core of the self-contained echo bench: `conns` pipelined
+// connections to 127.0.0.1:port, `inflight` frames outstanding each,
+// p50/p99 from send-timestamp cids.
 int run_pump(int port, const char* service, const char* method, int conns,
              int inflight, uint64_t total, int payload_len, double* qps_out,
-             double* p50_us, double* p99_us, double* err_frac = nullptr) {
+             double* p50_us, double* p99_us) {
   // Heap-allocated: on the timeout path, in-flight responses can still
   // hit bench_on_response on dispatcher threads after we return, so the
   // state must outlive this frame — it is intentionally leaked then.
@@ -188,11 +188,10 @@ int run_pump(int port, const char* service, const char* method, int conns,
   const uint64_t completed = st.done.load();
   const uint64_t errs = st.errs.load();
   const double wall_s = (t1 - t0) / 1e6;
-  // qps counts SUCCESSFUL responses only; sheds are reported as err_frac
+  // qps counts SUCCESSFUL responses only
   if (qps_out)
     *qps_out = (completed > errs ? completed - errs : 0) /
                (wall_s > 0 ? wall_s : 1e-9);
-  if (err_frac) *err_frac = completed > 0 ? double(errs) / completed : 0.0;
   const uint64_t n = std::min<uint64_t>(st.lat_idx.load(), st.lat_us.size());
   if (n > 0) {
     std::vector<uint32_t> lats(st.lat_us.begin(), st.lat_us.begin() + n);
@@ -240,22 +239,6 @@ int brpc_bench_echo(int conns, int inflight, uint64_t total, int payload_len,
   Socket::SetFailed(listener, 0);
   MethodRegistry::global()->Unregister("BenchEcho", "Echo");
   return rc;
-}
-
-// Pump an EXISTING server (e.g. a Python-handler service on `port`) with
-// the same native client: measures the SERVER's dispatch + handler path
-// with zero client-side Python cost — the reference's C++-client
-// methodology (docs/cn/benchmark.md) pointed at user handlers.
-int brpc_bench_pump(int port, const char* service, const char* method,
-                    int conns, int inflight, uint64_t total, int payload_len,
-                    double* qps_out, double* p50_us, double* p99_us,
-                    double* err_frac) {
-  if (port <= 0 || service == nullptr || method == nullptr || conns <= 0 ||
-      inflight <= 0 || total == 0 || payload_len < 0 || payload_len > 4096) {
-    return -1;
-  }
-  return run_pump(port, service, method, conns, inflight, total, payload_len,
-                  qps_out, p50_us, p99_us, err_frac);
 }
 
 }  // extern "C"

@@ -198,9 +198,12 @@ class MethodRegistry {
 void SetRequestCallback(RequestCallback cb, void* user);
 
 // Usercode admission control (reference ELIMIT fail-fast semantics with a
-// time-denominated bound): when a budget is set and the estimated wait
-// for the GIL-serialized Python lane (pending x EMA upcall time) exceeds
-// it, new requests are answered ELIMIT natively instead of queueing.
+// time-denominated bound): when a budget is set, more than two upcalls
+// are pending and the lane's measured queue wait (an EMA of frame cut to
+// upcall start, UpcallTicket::cut_us; inline mode: position in the sweep
+// x an EMA of handler time) exceeds it, new requests are answered ELIMIT
+// natively instead of queueing.  The estimate is the process's: every
+// queued upcall feeds it, with or without a budget.
 void SetUsercodeLatencyBudgetUs(int64_t us);  // 0 disables (default)
 int64_t UsercodeLatencyBudgetUs();
 int64_t UsercodeShedCount();

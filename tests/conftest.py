@@ -68,6 +68,42 @@ def _quiet_naming_refresh_noise():
     yield
 
 
+@pytest.fixture(autouse=True)
+def _restore_process_globals():
+    """Put back, around every test, the process-wide state one test
+    leaves and a later one reads: whether rpcz records, and its span
+    ring (emptied before a test that starts with rpcz off, so a module
+    that turns it on for all its tests keeps its spans between them);
+    the health checker's broken endpoints with their probe threads (one
+    a dead port, waking every second for the rest of the run) and the
+    circuit breaker's windows over them (ports come round again)."""
+    from brpc_tpu import rpcz
+    from brpc_tpu.policy import circuit_breaker, health_check
+    was_on, rate = rpcz.enabled(), rpcz.sample_rate()
+    if not was_on:
+        rpcz.flush()
+        with rpcz._collect_lock:
+            rpcz._collected.clear()
+    yield
+    rpcz.set_enabled(was_on, rate)
+    health_check.reset_all()
+    with circuit_breaker._breaker_mu:
+        circuit_breaker._breaker = None
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _collect_dead_servers():
+    """A full pass of the collector after every module (after every
+    test it cost 124 ms a test and a run twice its time): 512 native ``LatencyRecorder`` slots
+    serve the process, a stopped server's recorders hold theirs in
+    reference cycles until such a pass, 575-697 were alive from
+    ``test_psserve`` on, and every new recorder then dropped its
+    records and read zeros (ROADMAP D20)."""
+    yield
+    import gc
+    gc.collect()
+
+
 # ---------------------------------------------------------------------------
 # suite-stall watchdog (ISSUE 15)
 # ---------------------------------------------------------------------------

@@ -138,6 +138,23 @@ class TestCircuitBreakerLatency:
                 break
         assert isolated == [ep]
 
+    def test_slow_calls_among_fast_ones_do_not_isolate(self):
+        """A healthy replica answers pulls in ~0.6 ms and generations
+        in ~5 ms on one endpoint: pairs of slow successes between fast
+        ones are a mix of methods, not a degradation (two of them
+        tripped the short EMA and the endpoint flapped for good)."""
+        from brpc_tpu.butil.endpoint import str2endpoint
+        cb = self._fresh()
+        isolated = []
+        cb.mark_as_broken = lambda ep: isolated.append(ep)
+        ep = str2endpoint("10.0.0.8:80")
+        for _ in range(100):
+            cb.on_call_end(ep, 0, latency_us=600)
+        for _ in range(50):
+            for lat in (5400, 5400, 500, 600, 700, 500):
+                cb.on_call_end(ep, 0, latency_us=lat)
+        assert not isolated
+
     def test_error_rate_still_isolates(self):
         from brpc_tpu.butil.endpoint import str2endpoint
         cb = self._fresh()

@@ -1366,19 +1366,6 @@ class TestAdviceRegressions:
         assert sm.reorder_replays_dropped.get_value() == c0 + 1
         assert sm.reorder_replay_bytes_dropped.get_value() == b0 + 3
 
-    def test_bench_wedge_deadline_is_per_batch(self):
-        """The mid-batch wedge check must measure from the CURRENT
-        batch's start, not the whole timed region's (ADVICE r5)."""
-        import bench
-        region_t0, now = 0.0, 200.0      # region older than the deadline
-        batch_t0 = 195.0                 # current batch is 5s old
-        assert not bench._batch_wedged(batch_t0, now), \
-            "healthy late batch misflagged as wedged"
-        assert bench._batch_wedged(
-            batch_t0, batch_t0 + bench.WEDGE_TIMEOUT_S + 1)
-        # the old bug, kept as documentation: region-relative time flags
-        assert bench._batch_wedged(region_t0, now)
-
 
 # ---------------------------------------------------------------------------
 # scenario 9: serving layer — mid-batch failure + KV slot lease failure
@@ -2856,9 +2843,14 @@ def test_multimodel_warm_replica_kill_same_model_failover(seed):
                 assert wait_until(
                     lambda s=store: s.stats()["live_seqs"] == 0, 15), \
                     f"seed {seed}: leaked live sequences on a survivor"
-                store.clear()
+                # the router's ship thread still pushes the B sessions'
+                # pages at the dead victim, and a push pins its source
+                # pages while it is on the wire
+                assert wait_until(
+                    lambda s=store: (s.clear(),
+                                     s.pagepool.blocks_leased())[1] == 0,
+                    15), f"seed {seed}: leaked blocks on a survivor"
                 store.pagepool.assert_consistent()
-                assert store.pagepool.blocks_leased() == 0
     finally:
         tear_down_multimodel_cluster(replicas, router, rsrv)
     # after the engines close, every request's native emit ring must be

@@ -226,8 +226,15 @@ def standby_pair():
     ssrv.start("127.0.0.1", 0)
     standby_addr = f"127.0.0.1:{ssrv.port}"
 
+    def paced_step(tokens, positions, pages):
+        # the tests kill the primary between two tokens: at full speed
+        # the rest of a generation is microseconds away and a starved
+        # main thread found it finished ("errs[0] is None")
+        time.sleep(0.02)
+        return _step(tokens, positions, pages)
+
     pstore = _mk_store("pr_store", commit_live_pages=True)
-    peng = DecodeEngine(_step, num_slots=4, store=pstore,
+    peng = DecodeEngine(paced_step, num_slots=4, store=pstore,
                         max_pages_per_slot=32, name="pr_eng")
     sync = StandbySync(pstore, standby_addr, submit_fn=peng.submit,
                        name="pr_sync")

@@ -72,7 +72,13 @@ def test_overwrite_accounting_in_thread_table():
     assert rows, "no thread row with the emitted event count"
     assert rows[0]["dropped"] == n - RING_CAP
     assert rows[0]["last"] == "probe"
-    assert not rows[0]["live"]   # the emitter thread has exited
+    # the emitter has exited, but Thread.join() returns before the OS
+    # thread has run the destructor that retires its ring (TlsHolder)
+    deadline = time.monotonic() + 5
+    while rows[0]["live"] and time.monotonic() < deadline:
+        time.sleep(0.005)
+        rows = [t for t in flight.threads() if t["events"] == n]
+    assert rows and not rows[0]["live"]
     assert rows[0]["age_us"] >= 0
 
 
@@ -86,10 +92,8 @@ def test_concurrent_writers_with_dump_while_writing():
     tags = [0xC0 + i for i in range(4)]
     ts = [guard.start_thread(core.brpc_flight_selftest_emit, per, tg)
           for tg in tags]
-    dumps = 0
     poll_deadline = time.monotonic() + 60
-    while any(t.is_alive() for t in ts) and \
-            time.monotonic() < poll_deadline:
+    while True:   # at least once: a starved poller can find them done
         evs = flight.events(512)
         by_tid = {}
         for e in evs:
@@ -101,10 +105,11 @@ def test_concurrent_writers_with_dump_while_writing():
                  f"b={e['b']} after {prev}")
             by_tid[e["tid"]] = e["b"]
         flight.threads()   # table reads race the writers too
-        dumps += 1
+        if not any(t.is_alive() for t in ts) or \
+                time.monotonic() >= poll_deadline:
+            break
     for t in ts:
         guard.join_thread(t, what="flight concurrent writer")
-    assert dumps > 0
     delta = flight.stats()["events"] - before
     assert delta >= len(tags) * per
 

@@ -154,6 +154,10 @@ class TestUnaryRpc:
         channel.call_sync("EchoService", "Echo", {"msg": "m"},
                           serializer="json")
         st = server.method_statuses[("EchoService", "Echo")]
+        # the server counts a call after it has written the reply
+        deadline = time.monotonic() + 5
+        while st.latency_rec.count() < 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
         assert st.latency_rec.count() >= 1
         assert st.latency_rec.latency_percentile(0.5) > 0
 

@@ -212,6 +212,15 @@ class TestCrashRecovery:
                 sup.submit([9, 9, 9, 9], 8, lambda t: None,
                            lambda e: (errbox.append(e), ev.set()))
                 assert ev.wait(60), "request hung after supervisor gave up"
+                # a request still queued at a takeover is answered ELOGOFF
+                # before the budget is spent: keep the engine stepping
+                deadline = time.monotonic() + 60
+                while sup.stats()["state"] != "failed" and \
+                        time.monotonic() < deadline:
+                    again = threading.Event()
+                    sup.submit([9, 9, 9, 9], 8, lambda t: None,
+                               lambda e: again.set())
+                    assert again.wait(60), "request hung mid-restart"
             assert errbox and errbox[0] is not None
             assert errbox[0].code in (errors.EINTERNAL, errors.ELOGOFF)
             assert sup.stats()["state"] == "failed"

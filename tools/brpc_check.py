@@ -9,7 +9,7 @@
     python tools/brpc_check.py --write-fault-registry
     python tools/brpc_check.py --list-passes
 
-`make check` runs the plain form; it is also `make bench`'s preflight.
+`make check` runs the plain form.
 Exit codes: 0 clean (baseline-frozen findings allowed), 1 new findings
 or a broken parse.
 """
