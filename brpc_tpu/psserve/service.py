@@ -216,30 +216,24 @@ class PSService(Service):
                 if claimed:
                     self._release_bypass(b)
 
-        shard = self.shard
-
-        def transform(row):
-            # row: [n_keys, D] trimmed by the batcher's padded-output
-            # scatter; the version is the one the batch's gather ran at
-            # (read under the shard lock beside the gather, on this
-            # thread), so it is the state these rows show and covers
-            # any update acked before the lookup was issued.  Hot-key
-            # and counter accounting happens HERE — only lookups that
-            # were actually served shape the histogram (a shed/ELIMIT
-            # reject never runs the transform), matching the unbatched
-            # path
-            with rpcz.stage("ps.server.lookup", keys=int(row.shape[0])):
-                ver = shard.gathered_version()
-                shard._note_hot(local)
-                with shard._mu:
-                    shard.n_lookups += 1
-                from brpc_tpu.psserve.shard import LOOKUPS, LOOKUP_KEYS
-                LOOKUPS.add(1)
-                LOOKUP_KEYS.add(int(row.shape[0]))
-                return {"rows": np.asarray(row).tolist(), "version": ver}
-
-        self._lookup_b.submit(cntl, local, transform=transform)
+        self._submit_lookup(cntl, local, as_list=True)
         return None     # deferred: the batch drainer completes the RPC
+
+    def _submit_lookup(self, cntl, local, *, as_list: bool) -> None:
+        """Queue one lookup on the batcher.  Its transform only builds
+        the reply from what the batcher hands each member: the rows,
+        [n_keys, D] trimmed by the padded-output scatter, and the
+        version ``lookup_batch_done`` read for the whole batch (only
+        lookups that were served get there: a shed or ELIMIT reject
+        never runs either).  ``as_list``: the JSON wire's rows; the
+        binary wire's ride out as raw float32 bytes, never a list."""
+        def reply(served):
+            rows, ver = served
+            rows = np.asarray(rows)
+            return {"rows": rows.tolist() if as_list else rows,
+                    "version": ver}
+
+        self._lookup_b.submit(cntl, local, transform=reply)
 
     # ---- Update ----
 
@@ -391,22 +385,7 @@ class PSService(Service):
                 if claimed:
                     self._release_bypass(b)
 
-        shard = self.shard
-
-        def transform(row):
-            # identical accounting to the JSON transform; the response
-            # rows ride out as raw float32 bytes, never a list
-            with rpcz.stage("ps.server.lookup", keys=int(row.shape[0])):
-                ver = shard.gathered_version()
-                shard._note_hot(local)
-                with shard._mu:
-                    shard.n_lookups += 1
-                from brpc_tpu.psserve.shard import LOOKUPS, LOOKUP_KEYS
-                LOOKUPS.add(1)
-                LOOKUP_KEYS.add(int(row.shape[0]))
-                return {"rows": np.asarray(row), "version": ver}
-
-        self._lookup_b.submit(cntl, local, transform=transform)
+        self._submit_lookup(cntl, local, as_list=False)
         return None
 
     @method(request="tensorframe", response="tensorframe")
@@ -540,6 +519,26 @@ class PSService(Service):
         return self.shard.stats()
 
 
+def lookup_batch_done(shard: EmbeddingShardServer):
+    """The lookup batcher's per-batch completion for ``shard``: what a
+    batch of served lookups owes the shard's books (counters, hot keys)
+    paid in one pass over the members' live keys, and the one version
+    every reply of the batch carries.  It runs on the thread that ran
+    ``shard.lookup_batch_fn``, right after it, so that version is the
+    one the batch's gather ran at (read under the shard lock beside the
+    gather): the state these rows show, and it covers any update acked
+    before a member was issued."""
+    def done(items, lengths):
+        with rpcz.stage("ps.server.lookup", keys=int(sum(lengths))):
+            ver = shard.gathered_version()
+            shard.note_lookups(
+                len(items),
+                items[0] if len(items) == 1 else np.concatenate(items),
+                batched=True)
+            return ver
+    return done
+
+
 def register_psserve(server, shard: EmbeddingShardServer, *,
                      batch: bool = True, max_batch_size: int = 16,
                      max_delay_us: int = 1000, eager: bool = True,
@@ -564,7 +563,8 @@ def register_psserve(server, shard: EmbeddingShardServer, *,
             max_batch_size=max_batch_size, max_delay_us=max_delay_us,
             length_buckets=shard.key_buckets,
             dtype=np.int64, padded_output=True, eager=eager,
-            name=f"ps_lookup_{safe}", stage_prefix="ps")
+            name=f"ps_lookup_{safe}", stage_prefix="ps",
+            batch_done=lookup_batch_done(shard))
         update_b = DynamicBatcher(
             shard.update_batch_fn,
             max_batch_size=max_batch_size, max_delay_us=max_delay_us,

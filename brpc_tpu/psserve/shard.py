@@ -41,6 +41,9 @@ DEFAULT_KEY_BUCKETS = (8, 32, 128, 512)
 # /psserve page; these feed /brpc_metrics as psserve_*)
 LOOKUPS = Adder("psserve_lookups")
 LOOKUP_KEYS = Adder("psserve_lookup_keys")
+# batches of lookups whose books were paid in one pass (service.py's
+# per-batch completion); psserve_lookups beside it counts the lookups
+LOOKUP_BATCH_COMPLETIONS = Adder("psserve_lookup_batch_completions")
 UPDATES = Adder("psserve_updates")
 UPDATE_KEYS = Adder("psserve_update_keys")
 DUP_UPDATES = Adder("psserve_dup_updates")
@@ -158,13 +161,18 @@ class EmbeddingShardServer:
         self._slots: dict = {}
         # per-shard counters (process-wide Adders above aggregate)
         self.n_lookups = 0
+        self.n_lookup_batches = 0       # per-batch completions ...
+        self.n_batched_lookups = 0      # ... and the lookups they closed
         self.n_updates = 0
         self.n_opt_updates = 0
         self.n_dup_updates = 0
         self.n_pulls = 0
         self.n_pushes = 0
-        # hot-key histogram (bounded: prune to the top half at 4096)
-        self._hot: dict[int, int] = {}
+        # the lookups' books: reads per owned row (8 B a row, exact, on
+        # the host) and the n_lookup* counters above, under a lock of
+        # their own so that paying them never waits for an apply
+        self._hot = np.zeros((self.n_rows,), np.int64)
+        self._books_mu = InstrumentedLock("psserve.shard_books")
 
         # one jit each; bucket padding bounds the compile count.  The
         # functions are named so that the programs are (``jit_ps_gather``
@@ -237,18 +245,32 @@ class EmbeddingShardServer:
             return np.asarray(out)
 
     def _note_hot(self, local_keys: np.ndarray) -> None:
-        with rpcz.stage("ps.shard.note_hot") as st:
-            uniq, counts = np.unique(local_keys, return_counts=True)
-            st.set(keys=int(local_keys.size),
-                   dup_keys=int(local_keys.size - uniq.size))
-            with self._mu:      # RLock: callers inside the lock re-enter
-                hot = self._hot
-                for k, c in zip(uniq.tolist(), counts.tolist()):
-                    hot[k + self.lo] = hot.get(k + self.lo, 0) + c
-                if len(hot) > 4096:
-                    keep = sorted(hot.items(),
-                                  key=lambda kv: -kv[1])[:2048]
-                    self._hot = dict(keep)
+        """One read noted for every occurrence in ``local_keys``: one
+        array pass whatever their number (one call's keys or a whole
+        batch's, never padding)."""
+        with rpcz.stage("ps.shard.note_hot",
+                        keys=int(local_keys.size)) as st:
+            if st is not rpcz.NOOP_STAGE:
+                st.set(dup_keys=_dup_keys(local_keys))
+            with self._books_mu:
+                np.add.at(self._hot, local_keys, 1)
+
+    def note_lookups(self, lookups: int, local_keys: np.ndarray, *,
+                     batched: bool = False) -> None:
+        """What ``lookups`` served lookups owe the shard's books, paid
+        at once: ``local_keys`` are their live keys, concatenated.
+        ``batched``: they were one batch of the lookup batcher (its
+        per-batch completion calls this; ``lookup`` pays for itself)."""
+        self._note_hot(local_keys)
+        with self._books_mu:
+            self.n_lookups += lookups
+            if batched:
+                self.n_lookup_batches += 1
+                self.n_batched_lookups += lookups
+        LOOKUPS.add(lookups)
+        LOOKUP_KEYS.add(int(local_keys.size))
+        if batched:
+            LOOKUP_BATCH_COMPLETIONS.add(1)
 
     # ---- direct (unbatched) entry points ----
 
@@ -271,10 +293,7 @@ class EmbeddingShardServer:
                 padded[:n] = local
                 rows = self._gather_rows(padded, keys=n, bucket=b)[:n]
             ver = self.version
-            self.n_lookups += 1
-            self._note_hot(local)
-        LOOKUPS.add(1)
-        LOOKUP_KEYS.add(int(n))
+        self.note_lookups(1, local)
         return rows, ver
 
     def update(self, keys, grads, update_id: Optional[int] = None
@@ -374,7 +393,6 @@ class EmbeddingShardServer:
             # detection and means READ traffic — lookups track it, the
             # plain update path doesn't, and a trainer hammering its
             # own rows every wave must not masquerade as serving heat
-            # (it is also ~1ms of python dict loop per wave)
         UPDATES.add(1)
         OPT_UPDATES.add(1)
         UPDATE_KEYS.add(int(n))
@@ -497,9 +515,9 @@ class EmbeddingShardServer:
     # row 0 and is trimmed away by the batcher's padded-output scatter).
 
     def lookup_batch_fn(self, padded: np.ndarray) -> np.ndarray:
-        # per-request accounting (live-row counts, hot keys) happens in
-        # the service handler — this fn sees bucket-padded rows and
-        # cannot tell live from padding
+        # the batch's accounting (live-key counts, hot keys) happens in
+        # the service's per-batch completion — this fn sees
+        # bucket-padded rows and cannot tell live from padding
         k = np.asarray(padded, np.int64)
         with self._locked():
             # complete the gather under the lock — the fused optimizer
@@ -653,10 +671,25 @@ class EmbeddingShardServer:
     # ---- introspection (/psserve) ----
 
     def hot_keys(self, top: int = 10) -> list[tuple[int, int]]:
-        with self._mu:
-            return sorted(self._hot.items(), key=lambda kv: -kv[1])[:top]
+        """The ``top`` most-read owned keys as (global key, reads), most
+        read first and the lower key first among equals; exact."""
+        with self._books_mu:
+            hot = self._hot.copy()      # the selection runs unlocked
+        top = min(int(top), hot.size)
+        if top <= 0:
+            return []
+        kth = np.partition(hot, hot.size - top)[hot.size - top]
+        idx = np.flatnonzero(hot > kth)
+        if kth > 0:     # the ties at the cut, lowest keys first
+            idx = np.concatenate(
+                [idx, np.flatnonzero(hot == kth)[:top - idx.size]])
+        reads = hot[idx]
+        order = np.lexsort((idx, -reads))
+        return list(zip((idx[order] + self.lo).tolist(),
+                        reads[order].tolist()))
 
     def stats(self) -> dict:
+        hot_keys = self.hot_keys()      # its own lock, not the shard's
         with self._mu:
             return {
                 "name": self.name,
@@ -667,6 +700,10 @@ class EmbeddingShardServer:
                 "dim": self.dim,
                 "version": self.version,
                 "lookups": self.n_lookups,
+                "lookup_batch_completions": self.n_lookup_batches,
+                "lookups_per_completion": (
+                    round(self.n_batched_lookups / self.n_lookup_batches, 2)
+                    if self.n_lookup_batches else None),
                 "updates": self.n_updates,
                 "opt_updates": self.n_opt_updates,
                 "opt_slots": sorted(self._slots),
@@ -675,7 +712,7 @@ class EmbeddingShardServer:
                 "pushes": self.n_pushes,
                 "dense_params": sorted(self._dense),
                 "applied_ids": len(self._applied),
-                "hot_keys": self.hot_keys(),
+                "hot_keys": hot_keys,
                 "mesh": (dict(self.mesh.shape) if self.mesh is not None
                          else None),
             }

@@ -187,6 +187,16 @@ class DynamicBatcher:
     length_bucket]``, trimmed back to each request's raw length on
     scatter).  Supply a ``jax.jit``-wrapped function: because inputs are
     always bucket shapes, it compiles once per bucket and never again.
+
+    ``batch_done(items, lengths)``, where the owner hands one in, runs
+    once a batch on the thread that ran ``batch_fn``, after it returned
+    and before any row is scattered: ``items`` are the live members'
+    own arrays (no padding) and ``lengths`` their lengths, in row
+    order.  What a batch owes as a whole (its counters, one read of the
+    state its rows show) is paid there once, not once a member; each
+    member's result is then ``(row, shared)``, ``shared`` being what
+    ``batch_done`` returned.  If it raises, every live member completes
+    once with EINTERNAL, as after a failed ``batch_fn``.
     """
 
     def __init__(self, batch_fn: Callable, *,
@@ -201,7 +211,8 @@ class DynamicBatcher:
                  dtype=np.float32,
                  padded_output: Optional[bool] = None,
                  eager: bool = False,
-                 stage_prefix: Optional[str] = None):
+                 stage_prefix: Optional[str] = None,
+                 batch_done: Optional[Callable] = None):
         if max_batch_size < 1:
             raise ValueError("max_batch_size must be >= 1")
         # a ModelRunner instance cannot exist unless its module is
@@ -221,6 +232,7 @@ class DynamicBatcher:
             batch_fn = (batch_fn.score_with_offsets
                         if prefix_cache is not None else batch_fn.score)
         self.batch_fn = batch_fn
+        self.batch_done = batch_done
         # the owner's prefix for this batcher's stages (rpcz.stage):
         # ``<prefix>.batcher.run`` around one batch on the thread that
         # runs it, ``<prefix>.batcher.wait`` a marker per member that
@@ -774,20 +786,19 @@ class DynamicBatcher:
                 out = np.asarray(self.batch_fn(padded))
         except Exception as e:
             self._fn_cpu_s = time.thread_time() - t_fn_cpu
-            # a failed batch completes EVERY member exactly once with a
-            # definite error — never a hang, never a partial scatter
-            self.n_errors.add(n)
-            for p in live:
-                if self.limiter is not None:
-                    self.limiter.on_responded(errors.EINTERNAL, 0)
-                p.complete(errors.EINTERNAL,
-                           f"batch execution failed: "
-                           f"{type(e).__name__}: {e}", None)
+            self._fail_batch(live, "batch execution", e)
             return
         self._fn_cpu_s = time.thread_time() - t_fn_cpu
         dt = time.monotonic() - t0
         self._exec_ema_s = dt if self._exec_ema_s == 0.0 \
             else 0.7 * self._exec_ema_s + 0.3 * dt
+        if self.batch_done is not None:
+            try:
+                shared = self.batch_done([p.item for p in live],
+                                         [p.length for p in live])
+            except Exception as e:
+                self._fail_batch(live, "batch completion", e)
+                return
         trim = self.padded_output if self.padded_output is not None \
             else (out.ndim >= 2 and out.shape[-1] == lbucket)
         for i, p in enumerate(live):
@@ -796,7 +807,19 @@ class DynamicBatcher:
             if self.limiter is not None:
                 self.limiter.on_responded(0, lat_us)
             self.n_completed.add(1)
-            p.complete(0, "", row)
+            p.complete(0, "", row if self.batch_done is None
+                       else (row, shared))
+
+    def _fail_batch(self, live: list[_Pending], what: str,
+                    e: Exception) -> None:
+        """A failed batch completes EVERY member exactly once with a
+        definite error — never a hang, never a partial scatter."""
+        self.n_errors.add(len(live))
+        for p in live:
+            if self.limiter is not None:
+                self.limiter.on_responded(errors.EINTERNAL, 0)
+            p.complete(errors.EINTERNAL,
+                       f"{what} failed: {type(e).__name__}: {e}", None)
 
     # ---- lifecycle / introspection ----
 

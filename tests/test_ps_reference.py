@@ -200,9 +200,19 @@ def test_a_traced_run_reports_the_cells_per_layer_metrics(monkeypatch):
     # read the gather's device program and pull are silent here
     assert {"ps.client_self_us_per_call", "ps.server_self_us_per_call",
             "ps.shard_lock_wait_share", "ps.keys_per_program",
-            "ps.apply_roofline", "device.idle_share.ps"} \
+            "ps.apply_roofline", "device.idle_share.ps",
+            "ps.note_hot_us_per_lookup"} \
         <= set(r["metrics"])
     assert 0 < r["metrics"]["ps.apply_roofline"]["value"] < 100
+
+
+def test_the_hot_key_reader_finds_nothing_in_an_untraced_run():
+    """``ps.note_hot_us_per_lookup`` (PR 30) on a run with no trace, or
+    of a program without the stage: None, never a raise."""
+    run = {"traced": None, "records": {"calls": []}, "counters0": {},
+           "counters1": {}, "config": {"dim": 250}, "peaks": PEAKS}
+    metric = loader.load_metric("ps.note_hot_us_per_lookup")
+    assert metric.compute(run) is None
 
 
 # ---- the program's side: warm entry, versions, stages, names ---------------
@@ -349,7 +359,7 @@ def test_the_cells_metrics_are_declared_for_the_cell_alone():
         "ps.client_self_us_per_call", "ps.server_self_us_per_call",
         "ps.shard_lock_wait_share", "ps.fetch_us_per_lookup",
         "ps.keys_per_program", "ps.gather_roofline", "ps.apply_roofline",
-        "device.idle_share.ps"}
+        "device.idle_share.ps", "ps.note_hot_us_per_lookup"}
     assert all(m["workloads"] == ["ps_ycsb_b"] for m in mine.values())
     cell = loader.load_cell("ps_ycsb_b")
     assert {m["name"] for m in cell.end_to_end} == {

@@ -281,6 +281,137 @@ def test_lookup_batcher_coalesces_mixed_key_counts():
         _tear_down(servers, svcs, cli)
 
 
+# ---- what a served lookup owes the shard's books (PR 30) ----
+
+def _book_calls(seed=11, n=9):
+    """Seeded lookups with duplicates inside a call and across calls.
+    Key 0 is never asked for: it is the row padding gathers."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 12, size=int(k)).astype(np.int64)
+            for k in rng.integers(2, 30, size=n)]
+
+
+def _queue_behind_held_slot(b, calls):
+    """Run ``calls`` (thunks) so that they all wait behind the lookup
+    batcher's held execution slot and leave as ONE batch."""
+    from testutil import batcher_slot_held
+    ts = [threading.Thread(target=c) for c in calls]
+    with batcher_slot_held(b, len(calls)):
+        [t.start() for t in ts]
+    [t.join(60) for t in ts]
+    assert not any(t.is_alive() for t in ts)
+
+
+@pytest.mark.parametrize("path,wire", [
+    ("batched", "json"), ("batched", "tensorframe"),
+    ("bypass", "json"), ("bypass", "tensorframe"), ("direct", None)])
+def test_lookup_books_equal_on_every_path_and_wire(path, wire):
+    """The same lookups leave the same books however they were served:
+    one batch (one vectorised pass), one idle bypass each, or straight
+    ``shard.lookup``.  Every occurrence of a key is one read, every
+    lookup one lookup, and padding (row 0) is never counted."""
+    from brpc_tpu.bvar.variable import find_exposed
+    from brpc_tpu.psserve.shard import LOOKUP_BATCH_COMPLETIONS
+
+    def bvar(name):
+        return find_exposed(name).get_value()
+
+    calls = _book_calls()
+    reads = {}
+    for ks in calls:
+        for k in ks.tolist():
+            reads[k] = reads.get(k, 0) + 1
+    want_hot = sorted(reads.items(), key=lambda kv: (-kv[1], kv[0]))
+    dense = _oracle()
+    servers, svcs, shards, pc, cli = _spin_up(1)
+    sh, b = shards[0], svcs[0]._lookup_b
+    before = {n: bvar(n) for n in ("psserve_lookups",
+                                   "psserve_lookup_keys")}
+    completions = LOOKUP_BATCH_COMPLETIONS.get_value()
+    try:
+        got = {}
+
+        def served(i, ks):
+            c = PSClient(pc, vocab=V, dim=D, serializer=wire)
+            got[i] = c.lookup(ks)
+
+        if path == "direct":
+            for i, ks in enumerate(calls):
+                got[i] = sh.lookup(ks)[0]
+        elif path == "bypass":
+            for i, ks in enumerate(calls):
+                served(i, ks)
+            assert b.n_bypassed.get_value() == len(calls)
+            assert b.n_batches.get_value() == 0
+        else:
+            _queue_behind_held_slot(
+                b, [lambda i=i, ks=ks: served(i, ks)
+                    for i, ks in enumerate(calls)])
+            assert b.n_batches.get_value() == 1
+        for i, ks in enumerate(calls):
+            np.testing.assert_array_equal(got[i], np.asarray(dense[ks]))
+        assert sh.hot_keys(top=V) == want_hot
+        assert sh.hot_keys(top=3) == want_hot[:3]
+        assert sh.n_lookups == len(calls)
+        assert bvar("psserve_lookups") - before["psserve_lookups"] \
+            == len(calls)
+        assert bvar("psserve_lookup_keys") - before["psserve_lookup_keys"] \
+            == sum(ks.size for ks in calls)
+        st = sh.stats()
+        batches = 1 if path == "batched" else 0
+        assert LOOKUP_BATCH_COMPLETIONS.get_value() - completions == batches
+        assert st["lookup_batch_completions"] == batches
+        assert st["lookups_per_completion"] == \
+            (len(calls) if batches else None)
+        assert st["hot_keys"] == want_hot[:10]
+    finally:
+        _tear_down(servers, svcs, cli)
+
+
+@pytest.mark.parametrize("wire", ["json", "tensorframe"])
+def test_update_between_gather_and_completion_keeps_the_batch_version(wire):
+    """The version a batch's replies carry is the one its gather ran at
+    (read under the shard lock), not the shard's at completion: an
+    update that lands in between changes neither the rows nor the
+    version the members are told."""
+    table = init_embedding_table(V, D, seed=3)
+    sh = EmbeddingShardServer(0, 1, V, D, seed=3, name=f"ps_gap_{wire}")
+    gather = sh.lookup_batch_fn
+
+    def gather_then_update(padded):
+        out = gather(padded)
+        sh.update(np.array([5], np.int64), np.ones((1, D), np.float32))
+        return out
+
+    sh.lookup_batch_fn = gather_then_update  # before the service takes it
+    s = brpc.Server()
+    svc = register_psserve(s, sh, name=f"gap_{wire}")
+    s.start("127.0.0.1", 0)
+    pc = PartitionChannel(1)
+    pc.add_partition(0, brpc.Channel(f"127.0.0.1:{s.port}",
+                                     timeout_ms=5000, max_retry=0))
+    cli = PSClient(pc, vocab=V, dim=D, serializer=wire)
+    try:
+        got = {}
+
+        def one(i):
+            c = PSClient(pc, vocab=V, dim=D, serializer=wire)
+            got[i] = c.lookup_versioned(np.array([5, 9, 5], np.int64))
+
+        _queue_behind_held_slot(svc._lookup_b,
+                                [lambda i=i: one(i) for i in range(3)])
+        assert sh.version == 1          # the update did land
+        for rows, versions in got.values():
+            assert versions == {0: 0}
+            np.testing.assert_array_equal(rows, table[[5, 9, 5]])
+        # an idle lookup afterwards (bypass) sees the update
+        rows, versions = cli.lookup_versioned(np.array([5], np.int64))
+        assert versions == {0: 1}
+        np.testing.assert_array_equal(rows, table[[5]] + 1.0)
+    finally:
+        _tear_down([s], [svc], cli)
+
+
 def test_partition_retry_rotates_replica_under_lb():
     """lb= parity (ISSUE 8's SelectiveChannel surface on
     PartitionChannel): two replicas per partition, one dead — the

@@ -25,7 +25,7 @@ from brpc_tpu import errors
 from brpc_tpu.serving import (DecodeEngine, DynamicBatcher, ServingService,
                               register_serving)
 
-from testutil import wait_until
+from testutil import batcher_slot_held, wait_until
 
 
 def _sum_fn():
@@ -222,6 +222,127 @@ def test_batcher_survives_raising_completion_and_transform():
                   lambda code, text, result: 1 / 0)
         # the drainer survived: later traffic still completes
         assert float(b.submit_wait(np.ones((3,), np.float32))) == 3.0
+    finally:
+        b.close()
+
+
+# ---- the per-batch completion (``batch_done``, PR 30) ----
+
+@pytest.mark.parametrize("members", [1, 5])
+def test_batch_done_runs_once_a_batch_and_reaches_every_member(members):
+    """One call a batch, the members' own arrays (no padding) and their
+    lengths in row order; each member gets (its row, what it returned).
+    members=1 is the eager cut-through on the submitting thread."""
+    fn, _ = _sum_fn()
+    calls = []
+
+    def batch_done(items, lengths):
+        calls.append(([it.tolist() for it in items], list(lengths),
+                      threading.current_thread()))
+        return f"batch{len(calls)}"
+
+    b = DynamicBatcher(fn, max_batch_size=8, length_buckets=(16, 64),
+                       eager=True, batch_done=batch_done,
+                       name=f"t_done_{members}")
+    try:
+        lens = [3, 20, 1, 7, 40][:members]
+        if members == 1:
+            row, shared = b.submit_wait(np.full((3,), 2.0, np.float32))
+            assert float(row) == 6.0 and shared == "batch1"
+            assert calls[0][2] is threading.current_thread()
+        else:
+            got = {}
+            with batcher_slot_held(b, members):
+                for i, ln in enumerate(lens):
+                    b.enqueue(np.full((ln,), i + 1.0, np.float32),
+                              lambda c, t, r, i=i: got.setdefault(
+                                  i, (c, r)))
+            assert wait_until(lambda: len(got) == members, 10)
+            for i, ln in enumerate(lens):
+                code, (row, shared) = got[i]
+                assert code == 0 and shared == "batch1"
+                assert float(row) == (i + 1.0) * ln
+        assert len(calls) == 1
+        items, lengths, _thread = calls[0]
+        assert lengths == lens and [len(it) for it in items] == lens
+        assert b.n_batches.get_value() == 1
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("members", [1, 4])
+def test_raising_batch_done_fails_every_member_once_and_drainer_lives(
+        members):
+    """A per-batch completion that raises completes every live member
+    exactly once with EINTERNAL (as a failed batch_fn does); the next
+    batch is served."""
+    fn, _ = _sum_fn()
+    raised = []
+
+    def batch_done(items, lengths):
+        if not raised:
+            raised.append(len(items))
+            raise KeyError("books")
+        return "ok"
+
+    b = DynamicBatcher(fn, max_batch_size=8, length_buckets=(16,),
+                       eager=True, batch_done=batch_done,
+                       name=f"t_done_raises_{members}")
+    try:
+        fired = []
+        fire = lambda c, t, r: fired.append((c, t, r))      # noqa: E731
+        if members == 1:
+            b.enqueue(np.ones((4,), np.float32), fire)     # cut-through
+        else:
+            with batcher_slot_held(b, members):
+                for _ in range(members):
+                    b.enqueue(np.ones((4,), np.float32), fire)
+        assert wait_until(lambda: len(fired) >= members, 10)
+        time.sleep(0.05)
+        assert len(fired) == members and raised == [members]
+        for code, text, result in fired:
+            assert code == errors.EINTERNAL and result is None
+            assert "batch completion failed: KeyError" in text
+        assert b.n_errors.get_value() == members
+        assert b.n_completed.get_value() == 0
+        row, shared = b.submit_wait(np.ones((3,), np.float32))
+        assert float(row) == 3.0 and shared == "ok"
+    finally:
+        b.close()
+
+
+def test_batch_done_sees_no_member_shed_or_expired_before_formation():
+    """Only the members that reach the batch are in its books: one shed
+    at admission (brownout) and one whose deadline passed while it was
+    queued are in no call of batch_done."""
+    fn, _ = _sum_fn()
+    seen = []
+    b = DynamicBatcher(fn, max_batch_size=8, length_buckets=(16,),
+                       eager=True, name="t_done_live_only",
+                       batch_done=lambda items, lengths:
+                       seen.append(list(lengths)))
+    try:
+        out = {}
+
+        def fire(tag):
+            return lambda c, t, r: out.setdefault(tag, c)
+
+        assert b.try_claim_idle()
+        try:
+            b.enqueue(np.ones((2,), np.float32), fire("live2"))
+            b.enqueue(np.ones((5,), np.float32), fire("expires"),
+                      deadline_s=time.monotonic() + 0.02)
+            b.brownout = 1          # deadline-less arrivals are shed
+            b.enqueue(np.ones((9,), np.float32), fire("shed"))
+            b.brownout = 0
+            b.enqueue(np.ones((7,), np.float32), fire("live7"))
+            time.sleep(0.05)        # "expires" is now past its deadline
+        finally:
+            b.release_idle()
+        assert wait_until(lambda: len(out) == 4, 10)
+        assert out == {"live2": 0, "live7": 0,
+                       "expires": errors.ELIMIT, "shed": errors.ELIMIT}
+        assert seen == [[2, 7]]
     finally:
         b.close()
 
