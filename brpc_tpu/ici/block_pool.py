@@ -198,8 +198,10 @@ class BlockPool:
         self._allocated = Adder()
         self._freed = Adder()
         for cls in self.classes:
-            with jax.default_device(self.device):
-                zero = jnp.zeros((cls,), jnp.uint8)
+            # committed to the device, as every buffer a put() or a
+            # splice installs is: programs over slot buffers then see
+            # one placement whether a slot was written yet or not
+            zero = jax.device_put(np.zeros((cls,), np.uint8), self.device)
             self._slots[cls] = [zero] * self.blocks_per_class
             self._free[cls] = list(range(self.blocks_per_class))
 

@@ -39,6 +39,9 @@ def kvcache_snapshot() -> dict:
 
 
 from brpc_tpu.kvcache.pages import KVPage, PagePool  # noqa: E402,F401
+from brpc_tpu.kvcache.layered import (  # noqa: E402,F401
+    LayeredCache, LayeredSpec,
+)
 from brpc_tpu.kvcache.radix import RadixTree  # noqa: E402,F401
 from brpc_tpu.kvcache.store import (  # noqa: E402,F401
     KVCacheStore, KVSeq, RecoveryPin,

@@ -298,14 +298,22 @@ class PagePool:
         paged-attention kernel's K/V substrate.  Row assignment is
         stable per leased block (see module docstring), unleased rows
         read as zeros, so the shape (and thus the kernel's compilation)
-        never changes however blocks churn."""
+        never changes however blocks churn.  Every row of the stack is
+        COMMITTED to the pool's device (the zero row, a block's initial
+        buffer and every spliced buffer alike), so one stacking program
+        serves every pattern of leased and unleased rows: after the
+        first call, no lease, splice or release makes it compile
+        again."""
         import jax.numpy as jnp
         nbytes = self.pages_per_block * self.page_bytes
         with self._mu:
             if self._zero_row is None:
                 import jax
-                with jax.default_device(self.pool.device):
-                    self._zero_row = jnp.zeros((nbytes,), jnp.uint8)
+                # committed, like every spliced block buffer: a stack
+                # that mixes committed and uncommitted rows is another
+                # program for every pattern of leased rows
+                self._zero_row = jax.device_put(
+                    np.zeros((nbytes,), np.uint8), self.pool.device)
             by_row = {row: self._blocks[key][0]
                       for key, row in self._row_of.items()}
             # snapshot the slot buffers under the pool lock (Block.view

@@ -30,7 +30,8 @@ from brpc_tpu import fault
 
 
 class _Node:
-    __slots__ = ("chunk", "page", "children", "parent", "last_used")
+    __slots__ = ("chunk", "page", "children", "parent", "last_used",
+                 "snapshot")
 
     def __init__(self, chunk: tuple, page, parent: Optional["_Node"]):
         self.chunk = chunk              # page_tokens token ids
@@ -38,6 +39,10 @@ class _Node:
         self.children: dict[tuple, _Node] = {}
         self.parent = parent
         self.last_used = 0
+        # recurrent-state snapshot id (ISSUE 32): the state of the
+        # sequence after exactly this node's prefix, where the cache
+        # holds one; owned by the node, freed when the node is evicted
+        self.snapshot = None
 
 
 class RadixTree:
@@ -51,6 +56,9 @@ class RadixTree:
         self._root = _Node((), None, None)
         self._clock = itertools.count(1)
         self._nodes = 0
+        # called with a snapshot id when its node is evicted (the
+        # layered cache frees the state row); None: no snapshots
+        self.snapshot_free = None
 
     def _chunks(self, tokens: Sequence[int],
                 max_chunks: Optional[int] = None):
@@ -64,13 +72,16 @@ class RadixTree:
     # ---- lookup ----
 
     def match(self, tokens: Sequence[int], *,
-              max_chunks: Optional[int] = None) -> list:
+              max_chunks: Optional[int] = None,
+              snapshots: bool = False):
         """Longest cached prefix of `tokens`, in whole pages.  Returns
         the shared page handles in order; bumps LRU on the path.  The
-        caller refs the pages it keeps — match itself takes none."""
+        caller refs the pages it keeps — match itself takes none.
+        ``snapshots=True`` returns ``(pages, snapshot ids)``, one id
+        (or None) a matched node."""
         with self._mu:
             node = self._root
-            pages = []
+            pages, snaps = [], []
             now = next(self._clock)
             for chunk in self._chunks(tokens, max_chunks):
                 child = node.children.get(chunk)
@@ -78,8 +89,25 @@ class RadixTree:
                     break
                 child.last_used = now
                 pages.append(child.page)
+                snaps.append(child.snapshot)
                 node = child
-            return pages
+            return (pages, snaps) if snapshots else pages
+
+    def attach_snapshot(self, tokens: Sequence[int], n_chunks: int,
+                        snapshot) -> bool:
+        """Give the node that ends the first ``n_chunks`` chunks of
+        `tokens` the state snapshot ``snapshot``.  False (the caller
+        keeps the id) where the node is gone or already has one."""
+        with self._mu:
+            node = self._root
+            for chunk in self._chunks(tokens, n_chunks):
+                node = node.children.get(chunk)
+                if node is None:
+                    return False
+            if node is self._root or node.snapshot is not None:
+                return False
+            node.snapshot = snapshot
+            return True
 
     # ---- insert ----
 
@@ -142,6 +170,11 @@ class RadixTree:
                     del v.parent.children[v.chunk]
                 self._nodes -= len(victims)
                 pages = [v.page for v in victims]
+                if self.snapshot_free is not None:
+                    for v in victims:
+                        if v.snapshot is not None:
+                            self.snapshot_free(v.snapshot)
+                            v.snapshot = None
             if not pages:
                 break
             if span is not None and getattr(span, "trace_id", 0):

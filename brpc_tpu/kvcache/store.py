@@ -114,7 +114,8 @@ class KVSeq:
     must start — everything before it was served from shared pages."""
 
     __slots__ = ("seq_id", "tokens", "pages", "prefill_from", "retired",
-                 "span", "committed_full", "kv_filled")
+                 "span", "committed_full", "kv_filled", "state_row",
+                 "snaps")
 
     def __init__(self):
         self.seq_id = next(_seq_ids)
@@ -135,6 +136,11 @@ class KVSeq:
         # and every caching path caps at this cursor so the radix tree
         # can never serve a page whose tail slot was never written
         self.kv_filled = 0
+        # layered stores (ISSUE 32): the row of the recurrent-state
+        # array this sequence's state lives in, and the snapshots its
+        # prefill took and still owns: [(pages of prefix, snapshot row)]
+        self.state_row = None
+        self.snaps: list = []
         # the owning generation's rpcz span (ISSUE 5): KV events on this
         # sequence — COW, page-alloc retries, pressure evictions, detach
         # — annotate it.  NULL_SPAN when tracing is off: every annotate
@@ -156,11 +162,28 @@ class KVCacheStore:
                  page_bytes: int = 1024, page_tokens: int = 16,
                  max_blocks: int = 8, commit_live_pages: bool = False,
                  vector_kv: bool = False,
+                 layers=None,
                  name: str = "kv"):
         self.pagepool = PagePool(pool, device, page_bytes=page_bytes,
                                  page_tokens=page_tokens,
                                  max_blocks=max_blocks, name=name)
         self.radix = RadixTree(self.pagepool, name=name)
+        # per-layer-kind state beside the pages (ISSUE 32): ``layers``
+        # is a LayeredSpec; the K/V of the attention layers, their
+        # compressed-key index and the linear layers' recurrent state
+        # then live in ``self.layers``' persistent device arrays, page
+        # p at the pool's flat arena index p, and the pool's own block
+        # buffers hold nothing a model reads
+        self.layers = None
+        if layers is not None:
+            if not vector_kv:
+                raise ValueError("a layered store is vector_kv=True")
+            from brpc_tpu.kvcache.layered import LayeredCache
+            pp = self.pagepool
+            self.layers = LayeredCache(
+                layers, pp.max_blocks * pp.pages_per_block, pp.page_tokens,
+                pp.pool.device, name=name)
+            self.radix.snapshot_free = self.layers.free_row
         self.page_tokens = self.pagepool.page_tokens
         # vector-KV mode (ISSUE 10): pages hold REAL packed K/V vectors
         # written by a ModelRunner through write_kv, so the append path
@@ -229,7 +252,7 @@ class KVCacheStore:
             # against eviction: between match returning a tree-only
             # page (refs==1) and our ref, an evict could free it
             max_chunks = (len(prompt) - 1) // self.page_tokens
-            shared = self.radix.match(prompt, max_chunks=max_chunks)
+            shared, snap = self._match_usable(prompt, max_chunks)
             seq = KVSeq()
             if span is not None:
                 seq.span = span
@@ -237,6 +260,13 @@ class KVCacheStore:
                 self.pagepool.ref(p)
                 seq.pages.append(p)
         hit = len(shared) * self.page_tokens
+        if self.layers is not None:
+            try:
+                self._install_state(seq, snap)
+            except BaseException:
+                for p in seq.pages:
+                    self.pagepool.unref(p)
+                raise
         seq.tokens = prompt[:hit]
         seq.prefill_from = hit
         seq.kv_filled = hit     # cached pages hold materialized KV
@@ -256,6 +286,7 @@ class KVCacheStore:
             # a failed admit must not leak the refs already taken
             for p in seq.pages:
                 self.pagepool.unref(p)
+            self._free_state(seq)
             raise
         # count the hit only once the admit SUCCEEDS — a failed
         # admit skipped no compute and must not inflate hit-rate
@@ -345,7 +376,7 @@ class KVCacheStore:
                                     f"before KV write")
                             fresh = self._alloc_page(span=seq.span)
                             try:
-                                self.pagepool.copy_page(fresh, page)
+                                self._copy_page(fresh, page)
                             except BaseException:
                                 self.pagepool.unref(fresh)
                                 raise
@@ -377,6 +408,7 @@ class KVCacheStore:
     def fork(self, seq: KVSeq) -> KVSeq:
         """A second sequence sharing every page of `seq` (divergent
         continuations isolate via copy-on-write on extend)."""
+        self._no_state_copy("fork")
         with self._mu:
             if seq.retired:
                 raise RuntimeError(f"fork on retired seq {seq.seq_id}")
@@ -402,6 +434,7 @@ class KVCacheStore:
         :meth:`commit_draft` / ``write_kv_batch`` (accept)."""
         if not tokens:
             return
+        self._no_state_copy("speculate")
         with self._mu:
             if seq.retired:
                 raise RuntimeError(
@@ -473,9 +506,11 @@ class KVCacheStore:
                 if nfull:
                     self.radix.insert(seq.tokens[:nfull * self.page_tokens],
                                       seq.pages[:nfull])
+                    self._hand_over_snapshots(seq, nfull)
             for p in seq.pages:
                 self.pagepool.unref(p)
             seq.pages = []
+            self._free_state(seq)
             self.retired.add(1)
             self._live -= 1
 
@@ -498,6 +533,7 @@ class KVCacheStore:
             if nfull:
                 toks = seq.tokens[:nfull * self.page_tokens]
                 self.radix.insert(toks, seq.pages[:nfull])
+                self._hand_over_snapshots(seq, nfull)
                 # pin the pages the TREE actually holds (an already-
                 # cached chunk keeps the tree's page, not this seq's
                 # copy) — those are the ones a re-admit will match
@@ -508,6 +544,7 @@ class KVCacheStore:
             for p in seq.pages:
                 self.pagepool.unref(p)
             seq.pages = []
+            self._free_state(seq)
             self.detached.add(1)
             self.retired.add(1)
             self._live -= 1
@@ -544,6 +581,7 @@ class KVCacheStore:
         already holds keep their existing pages (the arriving copy is
         dropped — refcounts stay baseline).  Returns how many pages
         the tree newly retained."""
+        self._no_state_copy("import_prefix")
         tokens = [int(t) for t in tokens]
         nfull = len(tokens) // self.page_tokens
         payloads = list(payloads)
@@ -605,8 +643,105 @@ class KVCacheStore:
         generated token's slot never holds real vectors (it is never
         stepped), so a page it lands in must not be cached and later
         served as valid KV.  Harness mode: kv_filled == len(tokens),
-        identical behavior to before."""
-        return min(len(seq.tokens), seq.kv_filled) // self.page_tokens
+        identical behavior to before.  A layered store caches no page
+        past the deepest prefix it holds a state snapshot for (the one
+        it restored from, or one its prefill took): a hit needs the
+        recurrent state at its boundary, so deeper pages could never
+        be served."""
+        nfull = min(len(seq.tokens), seq.kv_filled) // self.page_tokens
+        if self.layers is not None:
+            deepest = max([seq.prefill_from // self.page_tokens]
+                          + [n for n, _ in seq.snaps])
+            nfull = min(nfull, deepest)
+        return nfull
+
+    # ---- layered stores: recurrent state beside the pages (ISSUE 32) ----
+
+    def _match_usable(self, tokens, max_chunks: int,
+                      count_miss: bool = True) -> tuple:
+        """``(pages, snapshot)``: the longest cached prefix this store
+        can SERVE.  A layered store serves a prefix only up to a node
+        that holds a state snapshot; matched pages past it are dropped
+        (their K/V is recomputed with the state), and a match that is
+        cut short so is a miss that counts (an admission's; a probe
+        counts nothing).  An admitting caller holds ``_mu``."""
+        if self.layers is None:
+            return self.radix.match(tokens, max_chunks=max_chunks), None
+        pages, snaps = self.radix.match(tokens, max_chunks=max_chunks,
+                                        snapshots=True)
+        depth = max((i + 1 for i, sn in enumerate(snaps)
+                     if sn is not None), default=0)
+        if count_miss and depth < len(pages):
+            self.layers.restore_misses.add(1)
+        return pages[:depth], (snaps[depth - 1] if depth else None)
+
+    def _install_state(self, seq: KVSeq, snapshot) -> None:
+        """Give an admitted sequence its state row: the hit's snapshot
+        restored into it, or zeros.  The snapshot's node cannot be
+        evicted meanwhile: the sequence holds a ref on its page."""
+        seq.state_row = self.layers.alloc_row()
+        if snapshot is not None:
+            self.layers.restore(seq.state_row, snapshot)
+        else:
+            self.layers.reset_row(seq.state_row)
+
+    def _free_state(self, seq: KVSeq) -> None:
+        if self.layers is None:
+            return
+        self.layers.free_row(seq.state_row)
+        seq.state_row = None
+        for _n, row in seq.snaps:
+            self.layers.free_row(row)
+        seq.snaps = []
+
+    def _hand_over_snapshots(self, seq: KVSeq, nfull: int) -> None:
+        """The snapshots a sequence's prefill took go to the radix nodes
+        that end their prefixes (now inserted); what the tree does not
+        take stays the sequence's, to be freed with it."""
+        kept = []
+        for n, row in seq.snaps:
+            if n <= nfull and self.radix.attach_snapshot(
+                    seq.tokens, n, row):
+                continue
+            kept.append((n, row))
+        seq.snaps = kept
+
+    def _no_state_copy(self, what: str) -> None:
+        if self.layers is not None:
+            raise NotImplementedError(
+                f"{what}: a layered store does not copy or ship "
+                f"recurrent state yet (ROADMAP R8)")
+
+    def _copy_page(self, dst: KVPage, src: KVPage) -> None:
+        self.pagepool.copy_page(dst, src)
+        if self.layers is not None:
+            flat = self.pagepool.flat_ids([dst.pid, src.pid])
+            self.layers.copy_page(flat[0], flat[1])
+
+    def snapshot_boundary(self, seq: KVSeq) -> int:
+        """The position (a whole number of pages) at which this
+        sequence's prefill should snapshot its state: the longest
+        prefix a re-admit of the same prompt could hit; 0 where that
+        lies at or before what was restored."""
+        b = (len(seq.tokens) - 1) // self.page_tokens * self.page_tokens
+        return b if b > seq.prefill_from else 0
+
+    def take_snapshot(self, seq: KVSeq, n_tokens: int) -> bool:
+        """Snapshot `seq`'s state row as the state after ``n_tokens``
+        (the runner calls this when its prefill stands exactly there).
+        False where no row is free: the prefix is then not cached."""
+        try:
+            row = self.layers.snapshot(seq.state_row)
+        except MemoryError:
+            return False
+        seq.snaps.append((n_tokens // self.page_tokens, row))
+        return True
+
+    def mark_filled(self, seq: KVSeq, upto: int) -> None:
+        """A runner that writes its own cache arrays (layered stores)
+        declares positions ``< upto`` materialized."""
+        if upto > seq.kv_filled:
+            seq.kv_filled = min(int(upto), len(seq.tokens))
 
     def _append(self, seq: KVSeq, token: int) -> None:
         self._append_run(seq, [token])
@@ -636,7 +771,7 @@ class KVCacheStore:
                             f"(refs={tail.refs}), copied before write")
                     fresh = self._alloc_page(span=seq.span)
                     try:
-                        self.pagepool.copy_page(fresh, tail)
+                        self._copy_page(fresh, tail)
                     except BaseException:
                         self.pagepool.unref(fresh)
                         raise
@@ -719,7 +854,8 @@ class KVCacheStore:
         if not tokens:
             return 0
         max_chunks = (len(tokens) - 1) // self.page_tokens
-        return len(self.radix.match(tokens, max_chunks=max_chunks)) \
+        return len(self._match_usable(tokens, max_chunks,
+                                      count_miss=False)[0]) \
             * self.page_tokens
 
     def acquire_prefix(self, tokens: Sequence[int], *,
@@ -782,6 +918,8 @@ class KVCacheStore:
         """Drop the cache and unpin this store's bvars (bound-method
         PassiveStatus would otherwise keep it alive in the registry)."""
         self.clear()
+        if self.layers is not None:
+            self.layers.close()
         from brpc_tpu.bvar.variable import find_exposed
         for n in self._bvar_names:
             v = find_exposed(n)
@@ -812,4 +950,6 @@ class KVCacheStore:
             "radix_nodes": self.radix.node_count(),
             "cached_tokens": self.radix.cached_tokens(),
             "pages": self.pagepool.stats(),
+            **({"layers": self.layers.stats()}
+               if self.layers is not None else {}),
         }

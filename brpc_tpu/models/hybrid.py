@@ -1,0 +1,799 @@
+"""HybridRunner — a model described by its configuration (ISSUE 32).
+
+Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
+``mixer_types`` names each layer's mixer (MiniCPM-SALA today):
+
+  ``minicpm4``        learned block-sparse attention (InfLLM-V2): K/V of
+                      these layers only live in bf16 pages, one selection
+                      block a page; a query past ``dense_len`` scores the
+                      compressed keys (an index beside the pages),
+                      selects ``topk`` blocks and attends to THAT page
+                      table (``ops.sparse_attention``); earlier queries
+                      attend to every page
+  ``lightning-attn``  linear attention: a float32 ``[H, D, D]`` state a
+                      layer a sequence (``ops.lightning``), restored from
+                      a snapshot on a radix hit
+
+around them learned RMS norms, per-head q/k norms, rotary positions on
+the lightning layers, output gates, a silu gated MLP, the muP scalings
+and an untied head.  The equations are those of
+``benchmarks/harness/reference_sala.py`` (the plain reference; the
+tier-1 tests hold this runner to it).
+
+The cache is the store's :class:`~brpc_tpu.kvcache.layered.LayeredCache`:
+three persistent device arrays that the two jitted programs here
+(``jit_runner_hybrid_step``, ``jit_runner_hybrid_prefill``) take
+DONATED and return updated, fixed shapes throughout.
+
+Position contract (the engine's): ``step(tok, pos)`` computes position
+``pos - 1`` (the token newest in the sequence) and WRITES its K/V and
+state; so prefill covers positions ``prefill_from .. len(prompt) - 2``
+only: a recurrent state must see every position exactly once.
+
+Precision: with ``param_dtype="bfloat16"`` weights and matmul inputs are
+bfloat16 (one MXU pass), accumulation, norms, softmax, selection scores
+and the recurrent state float32, cached K/V and compressed keys
+bfloat16.  ``param_dtype="float32"`` (the CPU tests) multiplies at
+``highest``.  ``control="low"`` is the benchmark's low-precision
+control and nothing a deployment sets: everything the configuration
+states in float32 and the program accumulates (every matmul's sum, the
+residual stream, the lightning state) then holds bfloat16 values, and
+the K/V pages the values of an int8 cache (scale 1/16).
+"""
+from __future__ import annotations
+
+import functools
+import math
+import threading
+from typing import Optional
+
+import numpy as np
+
+from brpc_tpu import fault
+from brpc_tpu.bvar import Adder
+from brpc_tpu.models.runner import ModelRunner, TransformerConfig
+
+SPARSE, LINEAR = "minicpm4", "lightning-attn"
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+def layer_shapes(cfg: TransformerConfig, kind: str) -> dict:
+    """``{name: (shape, fan_in or None for a norm weight)}`` of one
+    layer, in the order the seeded init draws them."""
+    dm, ff = cfg.d_model, cfg.d_ff
+    out = {"norm1": ((dm,), None), "norm2": ((dm,), None)}
+    if kind == SPARSE:
+        hd, kvd, d = (cfg.n_heads * cfg.head_dim,
+                      cfg.n_kv_heads * cfg.head_dim, cfg.head_dim)
+        out.update(wq=((dm, hd), dm), wk=((dm, kvd), dm),
+                   wv=((dm, kvd), dm), wg=((dm, hd), dm),
+                   wo=((hd, dm), hd), q_norm=((d,), None),
+                   k_norm=((d,), None))
+    else:
+        hd, d = cfg.lin_heads * cfg.lin_head_dim, cfg.lin_head_dim
+        out.update(wq=((dm, hd), dm), wk=((dm, hd), dm),
+                   wv=((dm, hd), dm), wg=((dm, hd), dm),
+                   wo=((hd, dm), hd), q_norm=((d,), None),
+                   k_norm=((d,), None), o_norm=((d,), None))
+    out.update(w_gate=((dm, ff), dm), w_up=((dm, ff), dm),
+               w_down=((ff, dm), ff))
+    return out
+
+
+def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
+    """Seeded parameters: matrices normal(0, 1/fan_in) in
+    ``cfg.param_dtype``, norm weights ``1 + 0.1 normal`` in float32,
+    the head scaled by ``d_model / dim_model_base`` so that the muP
+    division leaves logits of order one.  One jitted call a layer (the
+    float32 draw of a whole stacked tensor would not fit beside 10 GB
+    of weights)."""
+    import jax
+    import jax.numpy as jnp
+    key = key if key is not None else jax.random.PRNGKey(0)
+    dt = jnp.dtype(cfg.param_dtype)
+
+    def draw(key, shapes):
+        ks = jax.random.split(key, len(shapes))
+        out = {}
+        for k, (name, (shape, fan_in)) in zip(ks, shapes.items()):
+            x = jax.random.normal(k, shape, jnp.float32)
+            out[name] = (1.0 + 0.1 * x) if fan_in is None \
+                else (x / math.sqrt(fan_in)).astype(dt)
+        return out
+
+    ks = jax.random.split(key, cfg.n_layers + 1)
+    head_gain = cfg.d_model / cfg.dim_model_base if cfg.dim_model_base \
+        else 1.0
+    # built once a model at start-up, one program a kind of layer
+    # brpc-check: allow(jit-hot-path)
+    init = {kind: jax.jit(functools.partial(draw, shapes=shapes))
+            for kind, shapes in (
+        ("top", {"emb": ((cfg.vocab, cfg.d_model), cfg.d_model),
+                 "head": ((cfg.vocab, cfg.d_model),
+                          cfg.d_model / head_gain ** 2),
+                 "norm_f": ((cfg.d_model,), None)}),
+        (SPARSE, layer_shapes(cfg, SPARSE)),
+        (LINEAR, layer_shapes(cfg, LINEAR)))}
+    top = init["top"](ks[0])
+    top["layers"] = [init[kind](k)
+                     for kind, k in zip(cfg.mixer_types, ks[1:])]
+    return top
+
+
+# ---------------------------------------------------------------------------
+# the layer mathematics (traced inside the two programs below)
+# ---------------------------------------------------------------------------
+
+def _mm(x, w):
+    """``x W`` at the stated precision.  A bfloat16 weight takes its
+    input at bfloat16 and ONE pass, named so: the programs trace under
+    ``default_matmul_precision("highest")`` (for their float32
+    products), and a bfloat16 dot that inherits it is free to keep the
+    float32 input the cast came from and multiply it in six passes
+    (the compiler drops the cast as excess precision): slower, and not
+    the values the configuration states (PERF.md section 6, PR 32)."""
+    import jax
+    import jax.numpy as jnp
+    if w.dtype == jnp.bfloat16:
+        out = jnp.dot(_to_bf16(x), w, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.DEFAULT)
+    else:
+        out = jnp.dot(x, w, precision="highest")
+    return _acc(getattr(_TRACING, "control", ""), out)
+
+
+def _to_bf16(x):
+    """float32 -> bfloat16 at ONE defined point.  A bare cast lets the
+    chip's compiler carry bfloat16 backwards into the float32
+    arithmetic that made ``x`` (the norm's products, ``silu(g) * u``):
+    several roundings where the configuration states one.
+    ``reduce_precision`` is computed in float32 and kept; the cast after
+    it is exact."""
+    import jax
+    import jax.numpy as jnp
+    return jax.lax.reduce_precision(
+        x, exponent_bits=8, mantissa_bits=7).astype(jnp.bfloat16)
+
+
+def _rms(x, w, eps):
+    import jax
+    import jax.numpy as jnp
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * w
+
+
+def _rope(x, pos, theta):
+    """Rotary positions over the whole head, rotate-half convention:
+    ``x [N, H, D]``, ``pos [N]``."""
+    import jax.numpy as jnp
+    d = x.shape[-1]
+    inv = jnp.exp(-math.log(theta)
+                  * jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]    # [N, D/2]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def _cache_round(x, control):
+    """What the K/V pages hold of ``x``: bfloat16 values (under the
+    low-precision control, the values of an int8 cache)."""
+    import jax.numpy as jnp
+    if control == "low":
+        x = jnp.clip(jnp.round(x * 16.0), -127.0, 127.0) / 16.0
+    return _to_bf16(x)
+
+
+# the control a program is being TRACED under (``step`` / ``prefill`` set
+# it first thing; it is a static argument of both, so one value a trace)
+_TRACING = threading.local()
+
+
+def _acc(control, h):
+    """A float32 accumulator as the configuration states it: the
+    residual stream after an add, a matmul's float32 sum.  Under the
+    low-precision control it holds bfloat16 values."""
+    import jax
+    if control != "low":
+        return h
+    return jax.lax.reduce_precision(h, exponent_bits=8, mantissa_bits=7)
+
+
+def _mlp(p, h, cfg, rs):
+    import jax
+    x = _rms(h, p["norm2"], cfg.rms_eps)
+    return h + rs * _mm(jax.nn.silu(_mm(x, p["w_gate"]))
+                        * _mm(x, p["w_up"]), p["w_down"])
+
+
+def _logits(params, h, cfg):
+    x = _rms(h, params["norm_f"], cfg.rms_eps)
+    if cfg.dim_model_base:
+        x = x / (cfg.d_model / cfg.dim_model_base)
+    # the head is kept [vocab, d_model] like the embedding: the
+    # contraction then runs over the minor dimension of both operands,
+    # which is the layout the chip's compiler wants (a [d_model, vocab]
+    # head is copied transposed, 0.6 GB, every step)
+    import jax
+    w = params["head"]
+    if w.dtype == x.dtype:
+        return jax.lax.dot_general(x, w, (((1,), (1,)), ((), ())),
+                                   precision="highest")
+    return jax.lax.dot_general(_to_bf16(x), w,
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=x.dtype,
+                               precision=jax.lax.Precision.DEFAULT)
+
+
+def _qkv(p, x, heads, kv_heads, d, cfg):
+    n = x.shape[0]
+    q = _mm(x, p["wq"]).reshape(n, heads, d)
+    k = _mm(x, p["wk"]).reshape(n, kv_heads, d)
+    v = _mm(x, p["wv"]).reshape(n, kv_heads, d)
+    if cfg.qk_norm:
+        q = _rms(q, p["q_norm"], cfg.rms_eps)
+        k = _rms(k, p["k_norm"], cfg.rms_eps)
+    return q, k, v
+
+
+def _gate_out(p, x, o, gated):
+    import jax
+    if gated:
+        o = o * jax.nn.sigmoid(_mm(x, p["wg"]))
+    return _mm(o, p["wo"])
+
+
+def _select_tables(q, kcs, qpos, table, cfg, page_tokens):
+    """Selection for rows ``q [N, Hkv, G, D]`` of sequences whose page
+    tables are ``table [N, MPs]`` and compressed keys ``kcs [N, J, Hkv,
+    D]``: ``(arena pages, logical blocks) [N, Hkv, topk]``."""
+    import jax.numpy as jnp
+    from brpc_tpu.ops.sparse_attention import select_blocks
+    blocks = select_blocks(q, kcs, qpos, page_tokens=page_tokens,
+                           topk=cfg.sparse_topk,
+                           init_blocks=cfg.sparse_init_blocks,
+                           window=cfg.sparse_window)
+    pages = jnp.take_along_axis(
+        table[:, None, :], jnp.maximum(blocks, 0), axis=2)
+    return jnp.where(blocks >= 0, pages, -1), blocks
+
+
+def _kernel_order(kc_pages):
+    """``[N, pages, 4, Hkv, D]`` as the sequence's table gathers them
+    -> ``[N, J, Hkv, D]`` with kernel ``j`` at index ``j``: kernel ``j``
+    is kept at slot ``(j + 1) % 4`` of page ``(j + 1) // 4``, the page
+    its last key lies in."""
+    import jax.numpy as jnp
+    n, pages, per, hkv, d = kc_pages.shape
+    flat = kc_pages.reshape(n, pages * per, hkv, d)
+    return jnp.concatenate([flat[:, 1:], jnp.zeros_like(flat[:, :1])],
+                           axis=1)
+
+
+def _dense_tables(table, n_pages: int, width: int):
+    """The dense branch as a page table: the sequence's first
+    ``n_pages`` pages in order, padded to ``width``."""
+    import jax.numpy as jnp
+    n = table.shape[0]
+    have = min(n_pages, table.shape[1], width)
+    pad = jnp.full((n, width - have), -1, jnp.int32)
+    pages = jnp.concatenate([table[:, :have], pad], axis=1)
+    blocks = jnp.where(pages >= 0, jnp.arange(width, dtype=jnp.int32), -1)
+    return pages, blocks
+
+
+def _attend_rows(q, kv, ls, pages, blocks, lengths, backend):
+    """``q [N, Hkv, G, D]``, ``pages``/``blocks`` ``[N, Hkv, MP]``:
+    one kernel row a (position, K/V head)."""
+    import jax.numpy as jnp
+    from brpc_tpu.ops.sparse_attention import sparse_attend
+    n, hkv, g, d = q.shape
+    mp = pages.shape[-1]
+    o = sparse_attend(
+        q.reshape(n * hkv, g, d), kv, ls,
+        jnp.tile(jnp.arange(hkv, dtype=jnp.int32), n),
+        pages.reshape(n * hkv, mp), blocks.reshape(n * hkv, mp),
+        jnp.repeat(lengths, hkv), backend=backend)
+    return o.reshape(n, hkv * g * d)
+
+
+def _attend_positions(q4, kv, ls, tables, kcs, qpos, live, cfg, backend):
+    """The ``minicpm4`` mixer's attention for N positions, each with its
+    sequence's page table ``tables [N, MPs]`` and compressed keys ``kcs
+    [N, J, Hkv, D]``: positions with ``t + 1 <= dense_len`` attend to
+    every page (the sequence's own table IS their page table), the
+    others to the ``topk`` blocks they select.  Keys are read from the
+    arena only, the position's own included: write before you attend.
+    Returns ``(o [N, H * D], blocks selected a sparse position [N])``.
+    The wider (dense) table is built only where a live position needs
+    it."""
+    import jax
+    import jax.numpy as jnp
+    n, hkv = q4.shape[0], q4.shape[1]
+    t_page = kv.shape[4]
+    dense_row = qpos + 1 <= cfg.sparse_dense_len
+    dense_pages = -(-cfg.sparse_dense_len // t_page)
+    width = max(cfg.sparse_topk, dense_pages)
+    pages_s, blocks_s = _select_tables(q4, kcs, qpos, tables, cfg, t_page)
+    # a row that is not live (an idle slot, a bucket's padding) names no
+    # page: the kernel then fetches none (an unchanged block index is
+    # not fetched again) where it would fetch 64 pages to mask them all
+    pages_s = jnp.where(live[:, None, None], pages_s, -1)
+    tables = jnp.where(live[:, None], tables, -1)
+    lengths = jnp.where(live, qpos + 1, 0)
+
+    def with_dense(_):
+        pd, bd = _dense_tables(tables, dense_pages, width)
+        pad = jnp.full((n, hkv, width - cfg.sparse_topk), -1, jnp.int32)
+        ps = jnp.concatenate([pages_s, pad], axis=-1)
+        bs = jnp.concatenate([blocks_s, pad], axis=-1)
+        sel = dense_row[:, None, None]
+        return _attend_rows(q4, kv, ls, jnp.where(sel, pd[:, None, :], ps),
+                            jnp.where(sel, bd[:, None, :], bs), lengths,
+                            backend)
+
+    def all_sparse(_):
+        return _attend_rows(q4, kv, ls, pages_s, blocks_s, lengths, backend)
+    o = jax.lax.cond(jnp.any(dense_row & live), with_dense, all_sparse, None)
+    n_sel = jnp.where(dense_row | ~live, 0.0,
+                      (blocks_s >= 0).sum(axis=(1, 2)) / hkv)
+    return o, n_sel
+
+
+@functools.cache
+def _programs():
+    """The two jitted programs, built at the first runner.  Neither
+    reads or writes the K/V arena through XLA operations: on the chip
+    ``cache_write``, ``page_keys`` and the attention kernel are Pallas
+    calls (``ops.sparse_attention`` says why)."""
+    import jax
+    import jax.numpy as jnp
+    from brpc_tpu.ops.lightning import (lightning_chunk, lightning_decode,
+                                        log_decays)
+    from brpc_tpu.ops.sparse_attention import (KERNELS_PER_PAGE,
+                                               cache_write, compress_keys,
+                                               page_keys)
+
+    # ---- one decode position a slot --------------------------------------
+
+    def step(params, kv, kc, state, packed, *, cfg, backend, control,
+             logits_out):
+        """``packed [S, 4 + MPs]`` int32: a slot's token, position, state
+        row, whether it is live, then its page table.  ONE operand and
+        one result (``[3, S]`` float32: next token, its log-probability,
+        blocks selected) a step: every device array a step makes and
+        drops costs the engine thread a hand-off of the interpreter
+        lock under load (PERF.md section 7, entry 15)."""
+        _TRACING.control = control
+        tokens, positions, rows = packed[:, 0], packed[:, 1], packed[:, 2]
+        active, tables = packed[:, 3] > 0, packed[:, 4:]
+        t_page = kv.shape[4]
+        n_arena = kv.shape[3]
+        stride = t_page // KERNELS_PER_PAGE
+        s_n = tokens.shape[0]
+        hkv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        rs = cfg.residual_scale
+        qpos = jnp.maximum(positions - 1, 0)
+        page = jnp.take_along_axis(tables, (qpos // t_page)[:, None],
+                                   axis=1)[:, 0]
+        page = jnp.where(active & (page >= 0), page, n_arena)
+        # the kernel this position completes, if it does; it lives in
+        # the page its LAST key lies in (this position's): the page
+        # where it starts may be a shared prefix page, and what follows
+        # that differs by sequence
+        j = (qpos + 1) // stride - 2
+        done = active & ((qpos + 1) % stride == 0) & (j >= 0)
+        jc = jnp.maximum(j, 0)
+        page_a = jnp.take_along_axis(
+            tables, (jc // KERNELS_PER_PAGE)[:, None], axis=1)[:, 0]
+        win_at = (jc % KERNELS_PER_PAGE) * stride
+        round_state = "bfloat16" if control == "low" else None
+        h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
+        ls = ll = 0
+        n_sel = jnp.zeros((s_n,), jnp.float32)
+        for p, kind in zip(params["layers"], cfg.mixer_types):
+            x = _rms(h, p["norm1"], cfg.rms_eps)
+            if kind == SPARSE:
+                q, k, v = _qkv(p, x, cfg.n_heads, hkv, cfg.head_dim, cfg)
+                kv = cache_write(
+                    kv, ls, page, qpos % t_page,
+                    _cache_round(k, control)[:, :, None],
+                    _cache_round(v, control)[:, :, None], backend=backend)
+                # the last 2 x stride keys, from the (one or two) pages
+                # they lie in, as the cache now holds them
+                two = page_keys(kv, ls, jnp.concatenate([page_a, page]),
+                                backend=backend).astype(jnp.float32)
+                two = jnp.concatenate([two[:s_n], two[s_n:]], axis=2)
+                idx = win_at[:, None] + jnp.arange(2 * stride)[None, :]
+                mean = jnp.take_along_axis(
+                    two, idx[:, None, :, None], axis=2).mean(axis=2)
+                kc = kc.at[ls, jnp.where(done, page, n_arena),
+                           (jc + 1) % KERNELS_PER_PAGE].set(
+                    mean.astype(jnp.bfloat16), mode="drop")
+                kcs = _kernel_order(
+                    kc[ls][jnp.clip(tables, 0, n_arena - 1)])
+                o, sel = _attend_positions(
+                    q.reshape(s_n, hkv, g, cfg.head_dim), kv, ls, tables,
+                    kcs, qpos, active, cfg, backend)
+                n_sel = n_sel + sel
+                h = _acc(control, h + rs * _gate_out(
+                    p, x, o, cfg.attn_output_gate))
+                ls += 1
+            else:
+                hl, dl = cfg.lin_heads, cfg.lin_head_dim
+                q, k, v = _qkv(p, x, hl, hl, dl, cfg)
+                if cfg.lin_rope:
+                    q = _rope(q, qpos, cfg.rope_theta)
+                    k = _rope(k, qpos, cfg.rope_theta)
+                o, state = lightning_decode(
+                    state, rows, ll, q, k, v, log_decays(hl),
+                    scale=1.0 / math.sqrt(dl), round_state=round_state,
+                    backend=backend)
+                if cfg.lin_output_norm:
+                    o = _rms(o, p["o_norm"], cfg.rms_eps)
+                h = _acc(control, h + rs * _gate_out(
+                    p, x, o.reshape(s_n, hl * dl), cfg.lin_output_gate))
+                ll += 1
+            h = _acc(control, _mlp(p, h, cfg, rs))
+        logits = _logits(params, h, cfg)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logprob = jnp.take_along_axis(
+            jax.nn.log_softmax(logits, axis=-1), nxt[:, None], axis=1)[:, 0]
+        out = jnp.stack([nxt.astype(jnp.float32), logprob,
+                         n_sel / max(1, cfg.n_sparse)])
+        return (out, logits if logits_out else None, kv, kc, state)
+
+    # ---- one prefill chunk of one sequence -------------------------------
+
+    def prefill(params, kv, kc, state, packed, *, cfg, backend, control,
+                logits_out, max_pages):
+        """``packed`` int32: the chunk's start, its valid length, the
+        sequence's state row, its page table ``[MPs]``, then the
+        bucket's tokens (``MPs`` is the store's, static)."""
+        _TRACING.control = control
+        mps = max_pages
+        start, n_valid, row = packed[0], packed[1], packed[2]
+        table, tokens = packed[3:3 + mps], packed[3 + mps:]
+        t_page = kv.shape[4]
+        n_arena = kv.shape[3]
+        stride = t_page // KERNELS_PER_PAGE
+        c = tokens.shape[0]
+        hkv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        d = cfg.head_dim
+        rs = cfg.residual_scale
+        off = jnp.arange(c, dtype=jnp.int32)
+        qpos = start + off
+        valid = off < n_valid
+        mps = table.shape[0]
+        # the chunk's pages: it starts at a page boundary, and a page
+        # is written whole where it holds a valid position (what lies
+        # behind the last valid one is masked by every reader's length
+        # and overwritten by the positions that come)
+        n_pg = c // t_page
+        pg_l = start // t_page + jnp.arange(n_pg, dtype=jnp.int32)
+        page = table[jnp.clip(pg_l, 0, mps - 1)]
+        page = jnp.where((jnp.arange(n_pg) * t_page < n_valid)
+                         & (page >= 0), page, n_arena)
+        page_before = table[jnp.clip(start // t_page - 1, 0, mps - 1)]
+        round_state = "bfloat16" if control == "low" else None
+        # kernels this chunk completes: j = start/stride - 1 + i, each
+        # kept in the page its last key lies in
+        n_k = c // stride
+        jk = start // stride - 1 + jnp.arange(n_k, dtype=jnp.int32)
+        k_done = (jk >= 0) & (stride * jk + 2 * stride - 1
+                              < start + n_valid)
+        jkc = jnp.maximum(jk, 0)
+        k_page = table[jnp.clip((jkc + 1) // KERNELS_PER_PAGE, 0, mps - 1)]
+        k_page = jnp.where(k_done & (k_page >= 0), k_page, n_arena)
+        qs = min(c, 64)              # positions a call of the kernel
+        h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
+        ls = ll = 0
+        n_sel = jnp.zeros((c,), jnp.float32)
+        for p, kind in zip(params["layers"], cfg.mixer_types):
+            x = _rms(h, p["norm1"], cfg.rms_eps)
+            if kind == SPARSE:
+                q, k, v = _qkv(p, x, cfg.n_heads, hkv, d, cfg)
+                before = page_keys(kv, ls, page_before[None],
+                                   backend=backend)[0]       # [Hkv,T,D]
+                kv = cache_write(
+                    kv, ls, page, jnp.zeros((n_pg,), jnp.int32),
+                    _cache_round(k, control).reshape(
+                        n_pg, t_page, hkv, d).transpose(0, 2, 1, 3),
+                    _cache_round(v, control).reshape(
+                        n_pg, t_page, hkv, d).transpose(0, 2, 1, 3),
+                    backend=backend)
+                # the page before the chunk and the chunk's own keys, at
+                # the cache's values, in runs of ``stride``
+                ctx = jnp.concatenate(
+                    [before.transpose(1, 0, 2),
+                     _cache_round(k, control)], axis=0).astype(jnp.float32)
+                kern = compress_keys(
+                    ctx.reshape(-1, stride, hkv, d).mean(axis=1))
+                kern = kern[KERNELS_PER_PAGE - 1:
+                            KERNELS_PER_PAGE - 1 + n_k]
+                kc = kc.at[ls, k_page, (jkc + 1) % KERNELS_PER_PAGE].set(
+                    kern.astype(jnp.bfloat16), mode="drop")
+                kcs = _kernel_order(
+                    kc[ls][jnp.clip(table, 0, n_arena - 1)][None])
+
+                def block(args, ls=ls, kcs=kcs, kv=kv):
+                    qq, pp, vv = args
+                    return _attend_positions(
+                        qq, kv, ls, jnp.broadcast_to(table[None], (qs, mps)),
+                        jnp.broadcast_to(kcs, (qs,) + kcs.shape[1:]), pp,
+                        vv, cfg, backend)
+                o, sel = jax.lax.map(block, (
+                    q.reshape(c // qs, qs, hkv, g, d),
+                    qpos.reshape(c // qs, qs), valid.reshape(c // qs, qs)))
+                n_sel = n_sel + sel.reshape(c)
+                h = _acc(control, h + rs * _gate_out(
+                    p, x, o.reshape(c, hkv * g * d), cfg.attn_output_gate))
+                ls += 1
+            else:
+                hl, dl = cfg.lin_heads, cfg.lin_head_dim
+                q, k, v = _qkv(p, x, hl, hl, dl, cfg)
+                if cfg.lin_rope:
+                    q = _rope(q, qpos, cfg.rope_theta)
+                    k = _rope(k, qpos, cfg.rope_theta)
+                o, s_end = lightning_chunk(
+                    q, k, v, state[row, ll], log_decays(hl), n_valid,
+                    scale=1.0 / math.sqrt(dl), round_state=round_state)
+                state = state.at[row, ll].set(s_end)
+                if cfg.lin_output_norm:
+                    o = _rms(o, p["o_norm"], cfg.rms_eps)
+                h = _acc(control, h + rs * _gate_out(
+                    p, x, o.reshape(c, hl * dl), cfg.lin_output_gate))
+                ll += 1
+            h = _acc(control, _mlp(p, h, cfg, rs))
+        return (n_sel.sum() / max(1, cfg.n_sparse),
+                _logits(params, h, cfg) if logits_out else None,
+                kv, kc, state)
+
+    def named(fn, scope, *more_statics):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with jax.named_scope(scope), \
+                    jax.default_matmul_precision("highest"):
+                return fn(*args, **kw)
+        scoped.__name__ = scoped.__qualname__ = scope.replace(".", "_")
+        return jax.jit(scoped, static_argnames=(
+            "cfg", "backend", "control", "logits_out") + more_statics,
+            donate_argnames=("kv", "kc", "state"))
+    return {"step": named(step, "runner.hybrid_step"),
+            "prefill": named(prefill, "runner.hybrid_prefill", "max_pages")}
+
+
+# ---------------------------------------------------------------------------
+# the store and the runner
+# ---------------------------------------------------------------------------
+
+def layered_spec(cfg: TransformerConfig, state_rows: int):
+    from brpc_tpu.kvcache.layered import LayeredSpec
+    return LayeredSpec(
+        n_sparse=cfg.n_sparse, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, n_linear=cfg.n_linear,
+        n_lin_heads=cfg.lin_heads, lin_head_dim=cfg.lin_head_dim,
+        state_rows=int(state_rows))
+
+
+def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
+                       state_rows: int, device=None, name: str = "kv"):
+    """A :class:`KVCacheStore` for a described architecture: page ids,
+    refcounts and the radix tree as ever, ``cache_pages`` pages of
+    ``cfg.sparse_block`` tokens whose K/V (attention layers only),
+    compressed keys and ``state_rows`` recurrent-state rows live in the
+    store's :class:`LayeredCache`.  The pool's own blocks hold the
+    4-byte token-id stand-in only."""
+    from brpc_tpu.ici.block_pool import BlockPool
+    from brpc_tpu.kvcache import KVCacheStore
+    pt = cfg.sparse_block
+    if cfg.sparse_kernel != 2 * cfg.sparse_stride \
+            or pt != 4 * cfg.sparse_stride:
+        raise ValueError(
+            "the cache's compressed-key index needs kernel_size = 2 x "
+            "kernel_stride and block_size = 4 x kernel_stride")
+    per_block = 64
+    blocks = -(-int(cache_pages) // per_block)
+    pool = BlockPool(device, classes=(per_block * pt * 4,),
+                     blocks_per_class=blocks)
+    return KVCacheStore(pool, device, page_bytes=pt * 4, page_tokens=pt,
+                        max_blocks=blocks, vector_kv=True,
+                        layers=layered_spec(cfg, state_rows), name=name)
+
+
+class HybridRunner(ModelRunner):
+    """See the module docstring.  ``control`` is the benchmark's
+    low-precision control (``"low"``) and otherwise empty."""
+
+    wants_pages = True
+    wants_seqs = True          # step() is handed the slots' KVSeqs
+    has_prefill = True
+    chunked_prefill = True     # the engine cuts a long suffix to buckets
+    kv_bytes_per_token = 0     # the engine writes no K/V rows for it
+
+    def __init__(self, params: dict, cfg: TransformerConfig, *, store=None,
+                 backend: Optional[str] = None, control: str = "",
+                 name: str = "model"):
+        from brpc_tpu.ici.mesh import ensure_compile_cache
+        ensure_compile_cache()
+        if not cfg.mixer_types:
+            raise ValueError("HybridRunner serves a described "
+                             "architecture (cfg.mixer_types)")
+        self.cfg = cfg
+        self.params = params
+        self.name = name
+        self.store = None
+        self._backend = backend
+        self._control = control
+        self._mu = threading.Lock()
+        self._fns = _programs()
+        self.last_logprobs = None     # of the last step, a slot each
+        self._table_cache: dict = {}  # seq id -> (pages, arena indices)
+        safe = "".join(c if c.isalnum() else "_" for c in name)
+        self.sparse_selected = Adder(f"runner_{safe}_sparse_selected_blocks")
+        self.sparse_positions = Adder(f"runner_{safe}_sparse_positions")
+        self.dense_positions = Adder(f"runner_{safe}_dense_positions")
+        self.lightning_tokens = Adder(f"runner_{safe}_lightning_tokens")
+        self._bvar_names = [f"runner_{safe}_{n}" for n in (
+            "sparse_selected_blocks", "sparse_positions", "dense_positions",
+            "lightning_tokens")]
+        if store is not None:
+            self.bind(store)
+
+    def _statics(self) -> dict:
+        return {"cfg": self.cfg, "backend": self._backend,
+                "control": self._control}
+
+    def bind(self, store) -> None:
+        if store is None or getattr(store, "layers", None) is None:
+            raise ValueError("HybridRunner needs a layered KVCacheStore "
+                             "(make_layered_store)")
+        with self._mu:
+            if self.store is store:
+                return
+            if self.store is not None:
+                raise ValueError("runner already bound to a store")
+            if store.page_tokens != self.cfg.sparse_block:
+                raise ValueError(
+                    f"store pages hold {store.page_tokens} tokens, a "
+                    f"selection block {self.cfg.sparse_block}")
+            self.store = store
+
+    # ---- helpers ----
+
+    def _flat_tables(self, pages) -> np.ndarray:
+        pages = np.asarray(pages, np.int32)
+        flat = self.store.pagepool.flat_ids(pages.ravel().tolist())
+        return np.asarray(flat, np.int32).reshape(pages.shape)
+
+    def _slot_tables(self, pages, seqs) -> np.ndarray:
+        """The step's page tables as arena indices.  A sequence's table
+        only grows at its end while it decodes, so the translation of a
+        slot's row is kept until the row's page count changes: 8 x 520
+        lookups under the pool's lock every step were a millisecond of
+        the engine thread, a step in 64 a page long is not."""
+        pages = np.asarray(pages, np.int32)
+        out = np.full(pages.shape, -1, np.int32)
+        kept = self._table_cache
+        live = {}
+        for i, s in enumerate(seqs or ()):
+            if s is None:
+                continue
+            n = len(s.pages)
+            hit = kept.get(s.seq_id)
+            if hit is None or hit[0] != n:
+                hit = (n, self._flat_tables(pages[i]))
+            live[s.seq_id] = hit
+            out[i] = hit[1]
+        self._table_cache = live
+        return out
+
+    def _count(self, qpos, n_sel) -> None:
+        """Counters of the positions a program just computed."""
+        dense = int((qpos + 1 <= self.cfg.sparse_dense_len).sum())
+        self.dense_positions.add(dense)
+        self.sparse_positions.add(len(qpos) - dense)
+        self.sparse_selected.add(float(n_sel))
+        self.lightning_tokens.add(len(qpos))
+
+    # ---- the ModelRunner surface ----
+
+    def prefill_cuts(self, seq) -> list:
+        """Positions a prefill chunk must END at: where this sequence's
+        state is to be snapshot."""
+        b = self.store.snapshot_boundary(seq)
+        return [b] if b else []
+
+    def prefill(self, tokens, positions, pages, seq=None, n_valid=None,
+                logits: bool = False):
+        """One chunk: ``tokens`` bucket-padded, ``positions[0]`` its
+        start (a whole number of pages), ``n_valid`` its real length.
+        Never the prompt's last position: the first step computes
+        it.  ``logits=True`` (tests) returns the chunk's logits."""
+        import jax.numpy as jnp
+        if seq is None:
+            raise ValueError("HybridRunner.prefill needs the KVSeq")
+        start = int(positions[0])
+        limit = len(seq.tokens) - 1 - start
+        n = limit if n_valid is None else min(int(n_valid), limit)
+        if n <= 0:
+            return None
+        if start % self.store.page_tokens:
+            raise ValueError("a prefill chunk starts at a page boundary")
+        lay = self.store.layers
+        table = self._flat_tables(pages)
+        packed = np.concatenate([
+            np.asarray([start, n, seq.state_row], np.int32), table,
+            np.asarray(tokens, np.int32)])
+        with lay.lock:
+            n_sel, out, lay.kv, lay.kc, lay.state = self._fns["prefill"](
+                self.params, lay.kv, lay.kc, lay.state, jnp.asarray(packed),
+                logits_out=bool(logits), max_pages=len(table),
+                **self._statics())
+        self.store.mark_filled(seq, start + n)
+        self._count(start + np.arange(n), n_sel)
+        if start + n == self.store.snapshot_boundary(seq):
+            self.store.take_snapshot(seq, start + n)
+        return out
+
+    def _step(self, tokens, positions, pages, seqs, logits: bool = False):
+        """Dispatch one step; returns ``(result [3, S] on the device,
+        logits or None, which slots were live)``."""
+        import jax.numpy as jnp
+        lay = self.store.layers
+        n = len(tokens)
+        packed = np.empty((n, 4 + np.shape(pages)[1]), np.int32)
+        packed[:, 0], packed[:, 1] = tokens, positions
+        packed[:, 2], packed[:, 3] = lay.scratch_row, 0
+        for i, s in enumerate(seqs or ()):
+            if s is not None and s.state_row is not None:
+                packed[i, 2], packed[i, 3] = s.state_row, 1
+        packed[:, 4:] = self._slot_tables(pages, seqs)
+        with lay.lock:
+            out, lg, lay.kv, lay.kc, lay.state = self._fns["step"](
+                self.params, lay.kv, lay.kc, lay.state, jnp.asarray(packed),
+                logits_out=bool(logits), **self._statics())
+        return out, lg, packed[:, 3] > 0
+
+    def _ran_step(self, positions, seqs, active, out) -> np.ndarray:
+        """Fetch a step's result and book its positions: materialised,
+        and counted."""
+        out = np.asarray(out)
+        for i, s in enumerate(seqs or ()):
+            if active[i]:
+                self.store.mark_filled(s, int(positions[i]))
+        self._count(np.asarray(positions)[active] - 1, out[2][active].sum())
+        return out
+
+    def step(self, tokens, positions, pages, seqs=None):
+        if fault.ENABLED and fault.hit(
+                "model.step_compute", runner=self.name) is not None:
+            raise RuntimeError("injected model step-compute failure")
+        out, _, active = self._step(tokens, positions, pages, seqs)
+        out = self._ran_step(positions, seqs, active, out)
+        self.last_logprobs = out[1]
+        return out[0].astype(np.int32), None
+
+    def step_logits(self, tokens, positions, pages, seqs=None):
+        """The step's logits ``[slots, vocab]`` (it advances the cache
+        as :meth:`step` does)."""
+        out, logits, active = self._step(tokens, positions, pages, seqs,
+                                         logits=True)
+        self._ran_step(positions, seqs, active, out)
+        return logits
+
+    def verify(self, tokens, positions, tables, base_len, mask):
+        raise NotImplementedError(
+            "speculative verify needs a copy of the recurrent state a "
+            "draft branch (ROADMAP R8)")
+
+    def close(self) -> None:
+        from brpc_tpu.bvar.variable import find_exposed
+        for n in self._bvar_names:
+            v = find_exposed(n)
+            if v is not None:
+                v.hide()

@@ -221,6 +221,15 @@ def as_runner(step_fn=None, prefill_fn=None, *, runner=None, store=None,
 
 @dataclass(frozen=True)
 class TransformerConfig:
+    """What a served model is made of.  The defaults are the dense
+    stand-in (:class:`TransformerRunner`: GQA softmax attention in
+    every layer, gelu MLP, weightless norms, sinusoidal positions, tied
+    head, float32).  A published architecture fills the rest through
+    :func:`from_hf_config`: ``mixer_types`` names each held layer's
+    mixer (``"minicpm4"``: learned block-sparse attention over paged
+    K/V; ``"lightning-attn"``: linear attention with a recurrent
+    state) and :class:`~brpc_tpu.models.hybrid.HybridRunner` serves
+    it."""
     vocab: int = 128
     d_model: int = 32
     n_layers: int = 2
@@ -228,13 +237,132 @@ class TransformerConfig:
     n_kv_heads: int = 2
     head_dim: int = 8
     d_ff: int = 64
+    # ---- a described architecture (ISSUE 32); () is the stand-in ----
+    mixer_types: tuple = ()
+    depth_published: int = 0        # layers of the published model
+    layer_offset: int = 0           # published index of the first held
+    lin_heads: int = 0              # lightning heads (q, k and v alike)
+    lin_head_dim: int = 0
+    qk_norm: bool = False
+    attn_rope: bool = False
+    lin_rope: bool = True
+    rope_theta: float = 10000.0
+    attn_output_gate: bool = False
+    lin_output_gate: bool = False
+    lin_output_norm: bool = False
+    rms_eps: float = 1e-6
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    dim_model_base: int = 0         # logits / (d_model / dim_model_base)
+    tie_embeddings: bool = True
+    param_dtype: str = "float32"    # weights; "bfloat16": one MXU pass
+    # learned sparse attention (one selection block = one cache page)
+    sparse_block: int = 64
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """One token slot: all layers' K then V vectors, f32, the
-        token-major layout ``[n_layers, 2, n_kv_heads, head_dim]``
-        (``ops.paged_attention.arena_kv_view``)."""
+        """One token slot of the stand-in's cache: all layers' K then V
+        vectors, f32, the token-major layout ``[n_layers, 2,
+        n_kv_heads, head_dim]`` (``ops.paged_attention.arena_kv_view``).
+        A described architecture's cache allocates by layer kind
+        (``kvcache.layered``): bf16 K/V for its attention layers only,
+        and this is then that figure."""
+        if self.mixer_types:
+            return (self.n_sparse * 2 * self.n_kv_heads
+                    * self.head_dim * 2)
         return self.n_layers * 2 * self.n_kv_heads * self.head_dim * 4
+
+    @property
+    def n_sparse(self) -> int:
+        return sum(1 for m in self.mixer_types if m == "minicpm4")
+
+    @property
+    def n_linear(self) -> int:
+        return sum(1 for m in self.mixer_types if m == "lightning-attn")
+
+    @property
+    def residual_scale(self) -> float:
+        if not self.depth_published:
+            return 1.0
+        return self.scale_depth / math.sqrt(self.depth_published)
+
+    def layer_param_counts(self) -> dict:
+        """Parameters of one MLP, one mixer of each kind (norm weights
+        left out) and the embedding."""
+        dm = self.d_model
+        hd = self.n_heads * self.head_dim
+        kvd = self.n_kv_heads * self.head_dim
+        lin = self.lin_heads * self.lin_head_dim
+        return {"mlp": 3 * dm * self.d_ff,
+                "minicpm4": dm * hd * (2 + int(self.attn_output_gate))
+                + 2 * dm * kvd,
+                "lightning-attn": dm * lin * (4
+                                              + int(self.lin_output_gate)),
+                "embedding": self.vocab * dm}
+
+
+MIXER_KINDS = ("minicpm4", "lightning-attn")
+
+
+def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
+                   sparse: Optional[dict] = None,
+                   param_dtype: str = "bfloat16") -> TransformerConfig:
+    """A :class:`TransformerConfig` from a published ``config.json``'s
+    keys, taken verbatim (MiniCPM-SALA's today).  ``layers`` =
+    ``(first, count)`` holds a contiguous slice of the published
+    layers (a pipeline stage); ``sparse`` gives what the published
+    file does not carry (``kernel_size``, ``kernel_stride``,
+    ``block_size``, ``topk``, ``init_blocks``, ``window_size``,
+    ``dense_len``)."""
+    mixers = tuple(hf["mixer_types"])
+    unknown = sorted(set(mixers) - set(MIXER_KINDS))
+    if unknown:
+        raise ValueError(f"mixer kinds this runner has not: {unknown}")
+    if hf.get("hidden_act", "silu") != "silu":
+        raise ValueError("only the silu gated MLP is described")
+    first, count = layers if layers is not None else (0, len(mixers))
+    held = mixers[first:first + count]
+    if len(held) != count:
+        raise ValueError(f"layers {first}+{count} exceed {len(mixers)}")
+    sp = dict(sparse or {})
+    fields = {}
+    for key, name in (("block_size", "sparse_block"),
+                      ("kernel_size", "sparse_kernel"),
+                      ("kernel_stride", "sparse_stride"),
+                      ("topk", "sparse_topk"),
+                      ("init_blocks", "sparse_init_blocks"),
+                      ("window_size", "sparse_window"),
+                      ("dense_len", "sparse_dense_len")):
+        if key in sp:
+            fields[name] = int(sp.pop(key))
+    if sp:
+        raise ValueError(f"unknown sparse settings {sorted(sp)}")
+    return TransformerConfig(
+        vocab=int(hf["vocab_size"]), d_model=int(hf["hidden_size"]),
+        n_layers=count, n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]), d_ff=int(hf["intermediate_size"]),
+        mixer_types=held, depth_published=int(hf["num_hidden_layers"]),
+        layer_offset=first, lin_heads=int(hf["lightning_nh"]),
+        lin_head_dim=int(hf["lightning_head_dim"]),
+        qk_norm=bool(hf["qk_norm"]), attn_rope=bool(hf["attn_use_rope"]),
+        lin_rope=bool(hf["lightning_use_rope"]),
+        rope_theta=float(hf["rope_theta"]),
+        attn_output_gate=bool(hf["attn_use_output_gate"]),
+        lin_output_gate=bool(hf["use_output_gate"]),
+        lin_output_norm=bool(hf["use_output_norm"]),
+        rms_eps=float(hf["rms_norm_eps"]),
+        scale_emb=float(hf["scale_emb"]),
+        scale_depth=float(hf["scale_depth"]),
+        dim_model_base=int(hf["dim_model_base"]),
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        param_dtype=param_dtype, **fields)
 
 
 def init_runner_params(cfg: TransformerConfig, key=None) -> dict:

@@ -265,7 +265,8 @@ WAIT_STAGES = frozenset(("rpc.client.wait", "stream.credit_wait",
 CPU_STAGES = frozenset((
     "rpc.client.call", "rpc.client.on_response", "rpc.server.process",
     "stream.write", "stream.send", "stream.on_data",
-    "combo.call_lowered", "ps.client.call", "ps.batcher.run"))
+    "combo.call_lowered", "ps.client.call", "ps.batcher.run",
+    "serve.engine.step"))
 _ROOT, _UPCALL, _WAIT, _CPU = 1, 2, 4, 8
 _STAGE_KIND: dict = {}       # name -> the flags of the sets it is in
 for _flag, _names in ((_ROOT, ROOT_STAGES), (_UPCALL, UPCALL_STAGES),

@@ -204,15 +204,19 @@ def _make_emit_buf(cap: int):
 
 class _Request:
     __slots__ = ("req_id", "prompt", "max_new_tokens", "emit", "on_done",
-                 "buf", "t_submit", "trace", "speculative",
+                 "buf", "t_submit", "trace", "speculative", "logprobs",
                  "_done_fired", "_mu")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int,
                  emit: Callable[[int], None],
                  on_done: Optional[Callable], emit_buffer: int,
                  trace_ctx: Optional[tuple] = None,
-                 speculative: bool = True):
+                 speculative: bool = True, logprobs: bool = False):
         self.req_id = next(_req_ids)
+        # the served tokens' log-probabilities, one appended by the
+        # engine thread BEFORE its token is pushed and popped by the
+        # emitter as it delivers that token (None: not asked for)
+        self.logprobs = deque() if logprobs else None
         self.prompt = [int(t) for t in prompt]
         self.max_new_tokens = int(max_new_tokens)
         self.emit = emit
@@ -443,7 +447,7 @@ class DecodeEngine:
                on_done: Optional[Callable] = None, *,
                clamp: bool = True,
                trace_ctx: Optional[tuple] = None,
-               speculative: bool = True) -> int:
+               speculative: bool = True, logprobs: bool = False) -> int:
         """Queue a request; it is admitted into the step loop at the next
         step boundary with a free slot (in-flight requests are never
         restarted).  Returns the request id; terminal state arrives via
@@ -457,7 +461,11 @@ class DecodeEngine:
         pre- and post-crash decode spans share one trace).
         ``speculative=False`` opts this request out of draft proposals
         on a speculative engine (it rides the verify batch as a plain
-        zero-draft member; a no-draft engine ignores the flag)."""
+        zero-draft member; a no-draft engine ignores the flag).
+        ``logprobs=True`` asks for each served token's log-probability
+        (float32 log-softmax, at the runner's stated precision):
+        ``emit`` is then called ``emit(token, logprob)``; a runner that
+        computes none gives None."""
         limit = self.max_new_tokens_cap
         brownout = self.degraded_clamp
         if clamp and brownout is not None:
@@ -467,7 +475,8 @@ class DecodeEngine:
             limit = min(limit, int(brownout))
         req = _Request(prompt, min(int(max_new_tokens), limit),
                        emit, on_done, self.emit_buffer,
-                       trace_ctx=trace_ctx, speculative=speculative)
+                       trace_ctx=trace_ctx, speculative=speculative,
+                       logprobs=logprobs)
         if req.max_new_tokens <= 0:
             req.finish(errors.RpcError(errors.EREQUEST,
                                        "max_new_tokens must be > 0"))
@@ -620,7 +629,11 @@ class DecodeEngine:
                 req.finish(val)
                 return
             try:
-                req.emit(val)
+                with rpcz.stage("serve.emit"):
+                    if req.logprobs is None:
+                        req.emit(val)
+                    else:
+                        req.emit(val, req.logprobs.popleft())
             except Exception as e:
                 self._cancel(req, errors.RpcError(
                     errors.EINTERNAL,
@@ -647,8 +660,12 @@ class DecodeEngine:
                 continue
             t_cpu0 = time.thread_time()
             try:
-                for k in range(n):
-                    req.emit(int(out[k]))
+                with rpcz.stage("serve.emit", tokens=n):
+                    for k in range(n):
+                        if req.logprobs is None:
+                            req.emit(int(out[k]))
+                        else:
+                            req.emit(int(out[k]), req.logprobs.popleft())
             except Exception as e:
                 hostcpu.add("emit_fanout",
                             (time.thread_time() - t_cpu0) * 1e6)
@@ -692,14 +709,29 @@ class DecodeEngine:
         if not suffix:
             return
         n = len(suffix)
-        bucket = next((b for b in self.prefill_buckets if n <= b), n)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:n] = suffix
-        positions = slot.seq.prefill_from + np.arange(bucket,
-                                                      dtype=np.int32)
+        chunked = getattr(self.runner, "chunked_prefill", False)
         pages_row = np.full((self.max_pages_per_slot,), -1, np.int32)
         ids = slot.seq.page_ids()
         pages_row[:len(ids)] = ids[:self.max_pages_per_slot]
+        # the chunks: ONE bucket-padded call as ever; a chunked runner
+        # (one that carries state from chunk to chunk) gets a suffix
+        # longer than the largest bucket in pieces of at most that,
+        # cut also where it asks (a state snapshot's boundary), and is
+        # not given the prompt's last position (its first step computes
+        # it: a recurrent state sees every position once)
+        start = slot.seq.prefill_from
+        if chunked:
+            end = start + n - 1
+            cuts = sorted(c for c in self.runner.prefill_cuts(slot.seq)
+                          if start < c < end) + [end]
+            pieces = []
+            for cut in cuts:
+                while start < cut:
+                    k = min(cut - start, self.prefill_buckets[-1])
+                    pieces.append((start, k))
+                    start += k
+        else:
+            pieces = [(start, n)]
         # prefill child span: the cached/uncached split IS the story —
         # a cache hit is prefill compute skipped, and this span shows
         # exactly how much
@@ -710,12 +742,26 @@ class DecodeEngine:
                                   parent_span_id=slot.span.span_id,
                                   sampled=slot.span.sampled)
             pspan.annotate(f"prefill: cached={slot.seq.prefill_from} "
-                           f"uncached={n} bucket={bucket}")
+                           f"uncached={n} chunks={len(pieces)}")
         t0 = time.monotonic()
         t_fn_cpu = time.thread_time()
         try:
-            self.runner.prefill(padded, positions, pages_row,
-                                seq=slot.seq)
+            with rpcz.stage("serve.engine.prefill", tokens=n,
+                            hit_tokens=slot.seq.prefill_from,
+                            chunks=len(pieces)):
+                for at, k in pieces:
+                    bucket = next((b for b in self.prefill_buckets
+                                   if k <= b), k)
+                    padded = np.zeros((bucket,), np.int32)
+                    padded[:k] = slot.req.prompt[at:at + k]
+                    positions = at + np.arange(bucket, dtype=np.int32)
+                    if chunked:
+                        self.runner.prefill(padded, positions, pages_row,
+                                            seq=slot.seq, n_valid=k)
+                        self._touch_beat()    # a long prompt is progress
+                    else:
+                        self.runner.prefill(padded, positions, pages_row,
+                                            seq=slot.seq)
             self._prefill_fn_cpu_s = time.thread_time() - t_fn_cpu
         except Exception as e:
             self._prefill_fn_cpu_s = time.thread_time() - t_fn_cpu
@@ -873,7 +919,18 @@ class DecodeEngine:
             if fault.ENABLED and fault.hit(
                     "serving.step", name=self.name) is not None:
                 raise RuntimeError("injected decode step crash")
-            out, kv_rows = self.runner.step(tok, pos, pages)
+            with rpcz.stage("serve.engine.step", slots=len(active),
+                            tokens=len(active)):
+                if getattr(self.runner, "wants_seqs", False):
+                    # a runner that keeps per-sequence state beside the
+                    # pages (a state row) is handed the slots' KVSeqs
+                    seqs = [None] * self.num_slots
+                    for i, s in active:
+                        seqs[i] = s.seq
+                    out, kv_rows = self.runner.step(tok, pos, pages,
+                                                    seqs=seqs)
+                else:
+                    out, kv_rows = self.runner.step(tok, pos, pages)
         except Exception as e:
             if self._on_crash is not None:
                 # supervised: this is an ENGINE failure, not the
@@ -916,10 +973,14 @@ class DecodeEngine:
                     errors.EINTERNAL,
                     f"KV write failed: {type(e).__name__}: {e}"))
         deliver: list = []   # (slot index, slot, token) surviving
+        step_lp = getattr(self.runner, "last_logprobs", None)
         for i, s in active:
             if i in wrote_bad or self._slots[i] is not s:
                 continue    # an emitter cancelled it mid-step
             nxt = int(out[i])
+            if s.req.logprobs is not None:
+                s.req.logprobs.append(
+                    float(step_lp[i]) if step_lp is not None else None)
             s.last_token = nxt
             s.position += 1
             s.generated += 1

@@ -130,7 +130,9 @@ class ServingService(Service):
         max_new = int(req.get("max_new_tokens", 16))
         stream = cntl.accept_stream()
 
-        def emit(tok: int) -> None:
+        want_lp = bool(req.get("logprobs"))
+
+        def emit(tok: int, logprob=None) -> None:
             # emit runs on THIS request's emitter thread (the engine's
             # per-request bounded emit buffer), so a consumer that
             # stops draining its credit window stalls only itself: the
@@ -138,8 +140,15 @@ class ServingService(Service):
             # once this request's buffer overflows the engine cuts it
             # with EOVERCROWDED.  The bounded write keeps the emitter
             # itself from wedging forever on a dead-but-open peer.
-            stream.write(json.dumps({"token": tok}).encode(),
-                         timeout_s=2.0)
+            msg = {"token": tok}
+            if want_lp:
+                # the served token's log-probability (float32
+                # log-softmax at the runner's stated precision): what
+                # chat front ends ask for, and what a check against a
+                # reference can hold on every seed where a bare greedy
+                # token cannot
+                msg["logprob"] = logprob
+            stream.write(json.dumps(msg).encode(), timeout_s=2.0)
 
         def on_done(err) -> None:
             if err is None and model_key is not None \
@@ -204,6 +213,8 @@ class ServingService(Service):
             # (ISSUE 11); only forwarded when the client says so, so
             # engine-shaped submitters without the keyword still work
             kw["speculative"] = bool(req["speculative"])
+        if want_lp:
+            kw["logprobs"] = True
         rid = engine.submit(prompt, max_new, emit, on_done, **kw)
         resp = {"accepted": True, "req_id": rid, "prefix_hit": hit}
         if model_key is not None:

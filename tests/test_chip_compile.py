@@ -161,3 +161,121 @@ def test_full_width_decode_step_compiles_and_fits(sds, monkeypatch):
         + mem.output_size_in_bytes + cache
     assert need <= (1.0 - chip_smoke.MEMORY_HEADROOM) * 16 * 2**30, \
         f"decode step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+
+
+# ---- MiniCPM-SALA (ISSUE 32): the new kernels and the hybrid step -------
+
+def test_sparse_attend_and_state_kernels_lower_at_published_widths(sds):
+    """16 query heads a K/V head over 64 selected pages of 64 x 128
+    bf16 (decode: 16 rows; a prefill call: 128), and the lightning state
+    of 8 slots updated in place."""
+    from brpc_tpu.ops.lightning import lightning_decode_pallas
+    from brpc_tpu.ops.sparse_attention import sparse_attend_pallas
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    kv = sds((4, 2, 2, 256, 64, 128), bf16)
+    for n in (16, 128):
+        compiled = jax.jit(
+            lambda q, kv, hd, tab, bid, ln: sparse_attend_pallas(
+                q, kv, 2, hd, tab, bid, ln, interpret=False)).lower(
+            sds((n, 16, 128), f32), kv, sds((n,), i32), sds((n, 64), i32),
+            sds((n, 64), i32), sds((n,), i32)).compile()
+        assert _has_kernel(compiled)
+    qkv = sds((8, 32, 128), f32)
+    compiled = jax.jit(
+        lambda st, rows, q, k, v, ld: lightning_decode_pallas(
+            st, rows, 3, q, k, v, ld, scale=0.088, interpret=False),
+        donate_argnums=0).lower(
+        sds((10, 12, 32, 128, 128), f32), sds((8,), i32), qkv, qkv, qkv,
+        sds((32,), f32)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_cache_write_and_page_keys_lower(sds):
+    from brpc_tpu.ops.sparse_attention import cache_write, page_keys
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    kv = sds((4, 2, 2, 256, 64, 128), bf16)
+    for n, w in ((8, 1), (64, 64)):
+        blk = sds((n, 2, w, 128), bf16)
+        compiled = jax.jit(
+            lambda kv, pg, sl, k, v: cache_write(kv, 1, pg, sl, k, v,
+                                                 backend="mosaic"),
+            donate_argnums=0).lower(
+            kv, sds((n,), i32), sds((n,), i32), blk, blk).compile()
+        assert _has_kernel(compiled)
+    compiled = jax.jit(lambda kv, pg: page_keys(
+        kv, 1, pg, backend="mosaic")).lower(kv, sds((16,), i32)).compile()
+    assert _has_kernel(compiled)
+
+
+def _sala_programs(sds):
+    import json
+    import os
+    from brpc_tpu.models import hybrid
+    from brpc_tpu.models.runner import from_hf_config
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "minicpm_sala_l16_1chip.json")
+    with open(path) as f:
+        c = json.load(f)
+    cfg = from_hf_config(
+        dict(c, num_hidden_layers=c["published_num_hidden_layers"]),
+        layers=(c["first_published_layer"], c["num_hidden_layers"]),
+        sparse=c["assumed"]["sparse_config"]["value"],
+        param_dtype=c["param_dtype"])
+
+    def shaped(shapes):
+        return {n: sds(s, jnp.float32 if fan is None else jnp.bfloat16)
+                for n, (s, fan) in shapes.items()}
+    params = shaped({"emb": ((cfg.vocab, cfg.d_model), 1),
+                     "head": ((cfg.vocab, cfg.d_model), 1),
+                     "norm_f": ((cfg.d_model,), None)})
+    params["layers"] = [shaped(hybrid.layer_shapes(cfg, k))
+                        for k in cfg.mixer_types]
+    pages, rows = c["cache_pages"], c["state_rows"] + 2
+    caches = (sds((4, 2, 2, pages, 64, 128), jnp.bfloat16),
+              sds((4, pages, 4, 2, 128), jnp.bfloat16),
+              sds((rows, 12, 32, 128, 128), jnp.float32))
+    statics = dict(cfg=cfg, backend="mosaic", control="")
+    return c, hybrid._programs(), params, caches, statics
+
+
+def _arena_copies(compiled, pages: int) -> list:
+    """Copies of the whole K/V arena in a compiled program: what an XLA
+    gather, scatter or dynamic-update-slice over it costs on the chip
+    (0.3 GB each way; found in PR 32's first compile)."""
+    shape = f"bf16[4,2,2,{pages},64,128]"
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line and f"= {shape}" in line]
+
+
+def test_sala_decode_step_compiles_at_published_widths_and_fits(sds):
+    """``minicpm_sala_l16_1chip``'s decode step for a described v5e:
+    every kernel a custom call (12 state updates, the attention over
+    selected pages in both branches, the cache's writes and reads), no
+    copy of the arena, and weights + cache + temporaries inside the
+    chip."""
+    c, fns, params, caches, statics = _sala_programs(sds)
+    s, mp = c["num_slots"], c["max_pages_per_slot"]
+    i32 = jnp.int32
+    compiled = fns["step"].lower(
+        params, *caches, sds((s, 4 + mp), i32), logits_out=False,
+        **statics).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 12 + 4 * 4
+    assert _arena_copies(compiled, c["cache_pages"]) == []
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 10e9 < need <= 0.9 * 16 * 2**30, \
+        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+
+
+def test_sala_question_prefill_compiles_without_copying_the_arena(sds):
+    """The 64-token bucket: the one prefill shape inside the measured
+    window of ``sala_doc_turns``."""
+    c, fns, params, caches, statics = _sala_programs(sds)
+    i32 = jnp.int32
+    mp = c["max_pages_per_slot"]
+    compiled = fns["prefill"].lower(
+        params, *caches, sds((3 + mp + 64,), i32), logits_out=False,
+        max_pages=mp, **statics).compile()
+    assert _has_kernel(compiled)
+    assert _arena_copies(compiled, c["cache_pages"]) == []

@@ -513,10 +513,11 @@ def test_metric_reader_on_the_recorded_trace(states, monkeypatch, metric):
 
 def test_the_new_metrics_are_declared_for_the_cells_that_have_their_spans():
     bench = loader.load_benchmark()
-    # the parameter server's (``ps.*``) are tests/test_ps_reference.py's
+    # the parameter server's (``ps.*``) are tests/test_ps_reference.py's,
+    # the served model's (``sala.*``) benchmarks/tests/test_sala_cell.py's
     declared = {m["name"]: m for m in bench["per_layer"]
                 if m["source"] == "program_span"
-                and not m["name"].startswith("ps.")}
+                and not m["name"].startswith(("ps.", "sala."))}
     assert set(declared) == NEW_METRICS
     for name, m in declared.items():
         assert m["better"] == "lower"
