@@ -10,6 +10,13 @@ leased HBM blocks) and :class:`~brpc_tpu.kvcache.radix.RadixTree`
   extend(seq, t) -> one generated token's KV appended; allocates a new
                     page at page boundaries and copies-on-write when
                     the tail page is shared with the tree or a fork;
+  reserve_next(seq) -> the page of the NEXT position is put into the
+                    table (allocated, or copied if shared) before the
+                    token there is known: a decode step dispatched ahead
+                    writes K/V at it; the following extend fills it.  A
+                    reserved position is not a token: nothing that goes
+                    by ``tokens`` / ``kv_filled`` (the radix tree, retire,
+                    detach, export) can see it;
   fork(seq)      -> a second sequence sharing every page (speculative /
                     divergent continuations); divergence is isolated by
                     the extend-path COW;
@@ -303,6 +310,23 @@ class KVCacheStore:
             if seq.retired:
                 raise RuntimeError(f"extend on retired seq {seq.seq_id}")
             self._append(seq, int(token))
+
+    def reserve_next(self, seq: KVSeq) -> None:
+        """Make `seq`'s page table cover the position its NEXT token
+        will take, exclusively: a fresh page where that position starts
+        one, the tail page copied first where it is shared (the
+        :meth:`extend` path's own rule, run early).  For a decode step
+        dispatched before the token it reads is known on the host: the
+        step writes K/V at that position.  Nothing else changes: no
+        token, no ``kv_filled``, so no caching path can see the
+        position, and the ``extend`` that brings the token finds the
+        page in place.  Idempotent.  ``MemoryError`` as ``extend``'s
+        (pool exhausted, nothing evictable)."""
+        with self._mu:
+            if seq.retired:
+                raise RuntimeError(
+                    f"reserve_next on retired seq {seq.seq_id}")
+            self._own_page_of(seq, len(seq.tokens))
 
     def write_kv(self, seq: KVSeq, pos: int, rows, *,
                  final: bool = True) -> None:
@@ -746,6 +770,37 @@ class KVCacheStore:
     def _append(self, seq: KVSeq, token: int) -> None:
         self._append_run(seq, [token])
 
+    def _own_page_of(self, seq: KVSeq, pos: int) -> KVPage:
+        """The page `seq` may write position ``pos`` (its next) in: a
+        fresh one appended where ``pos`` starts a page the table lacks
+        (a reservation may have brought it already), else the tail
+        page, copied first where it is shared."""
+        pi = pos // self.page_tokens
+        if pi == len(seq.pages):
+            seq.pages.append(self._alloc_page(span=seq.span))
+            return seq.pages[pi]
+        tail = seq.pages[pi]
+        if tail.refs > 1:
+            # copy-on-write: the tail page is shared (radix tree or a
+            # forked sequence) — writing in place would corrupt the
+            # other holder's KV.  Copy device-to-device, swap our table
+            # entry, drop our ref on the shared page.
+            if seq.span is not rpcz.NULL_SPAN:
+                seq.span.annotate(
+                    f"kv cow: tail page {tail.pid} shared "
+                    f"(refs={tail.refs}), copied before write")
+            fresh = self._alloc_page(span=seq.span)
+            try:
+                self._copy_page(fresh, tail)
+            except BaseException:
+                self.pagepool.unref(fresh)
+                raise
+            seq.pages[pi] = fresh
+            self.pagepool.unref(tail)
+            self.cow.add(1)
+            return fresh
+        return tail
+
     def _append_run(self, seq: KVSeq, tokens: Sequence[int],
                     materialize: bool = True) -> None:
         """Append tokens in PAGE-SIZED runs: one device splice per page
@@ -755,29 +810,7 @@ class KVCacheStore:
         while idx < n:
             pos = len(seq.tokens)
             slot = pos % self.page_tokens
-            if slot == 0:
-                seq.pages.append(self._alloc_page(span=seq.span))
-            else:
-                tail = seq.pages[-1]
-                if tail.refs > 1:
-                    # copy-on-write: the tail page is shared (radix tree
-                    # or a forked sequence) — writing in place would
-                    # corrupt the other holder's KV.  Copy device-to-
-                    # device, swap our table entry, drop our ref on the
-                    # shared page.
-                    if seq.span is not rpcz.NULL_SPAN:
-                        seq.span.annotate(
-                            f"kv cow: tail page {tail.pid} shared "
-                            f"(refs={tail.refs}), copied before write")
-                    fresh = self._alloc_page(span=seq.span)
-                    try:
-                        self._copy_page(fresh, tail)
-                    except BaseException:
-                        self.pagepool.unref(fresh)
-                        raise
-                    seq.pages[-1] = fresh
-                    self.pagepool.unref(tail)
-                    self.cow.add(1)
+            page = self._own_page_of(seq, pos)
             k = min(self.page_tokens - slot, n - idx)
             run = [int(t) for t in tokens[idx:idx + k]]
             if not self.vector_kv:
@@ -786,7 +819,7 @@ class KVCacheStore:
                 # it entirely: the ModelRunner's write_kv fills the slot
                 # with real vectors (and skipping saves one splice per
                 # appended page)
-                self.pagepool.write(seq.pages[-1], slot, run)
+                self.pagepool.write(page, slot, run)
             seq.tokens.extend(run)
             idx += k
         if not materialize:

@@ -54,6 +54,9 @@ from brpc_tpu.bvar import Adder
 from brpc_tpu.models.runner import ModelRunner, TransformerConfig
 
 SPARSE, LINEAR = "minicpm4", "lightning-attn"
+# a slot's "live" column of the step program's operand: its token comes
+# from the host, or from the result of the step before on the device
+LIVE, FED = 1, 2
 
 
 # ---------------------------------------------------------------------------
@@ -360,16 +363,22 @@ def _programs():
 
     # ---- one decode position a slot --------------------------------------
 
-    def step(params, kv, kc, state, packed, *, cfg, backend, control,
+    def step(params, kv, kc, state, packed, prev, *, cfg, backend, control,
              logits_out):
         """``packed [S, 4 + MPs]`` int32: a slot's token, position, state
-        row, whether it is live, then its page table.  ONE operand and
-        one result (``[3, S]`` float32: next token, its log-probability,
-        blocks selected) a step: every device array a step makes and
-        drops costs the engine thread a hand-off of the interpreter
-        lock under load (PERF.md section 7, entry 15)."""
+        row, whether it is live (0 no, ``LIVE``, or ``FED``: live, and
+        its token is row 0 of ``prev``, the step before's result, not
+        ``packed[:, 0]``), then its page table.  ONE operand made on the
+        host and one result (``[3, S]`` float32: next token, its
+        log-probability, blocks selected) a step: every device array a
+        step makes and drops costs the engine thread a hand-off of the
+        interpreter lock under load (PERF.md section 7, entry 15).
+        ``FED`` is data: a step dispatched before the one ahead of it
+        was fetched is the same program as any other."""
         _TRACING.control = control
-        tokens, positions, rows = packed[:, 0], packed[:, 1], packed[:, 2]
+        positions, rows = packed[:, 1], packed[:, 2]
+        tokens = jnp.where(packed[:, 3] == FED,
+                           prev[0].astype(jnp.int32), packed[:, 0])
         active, tables = packed[:, 3] > 0, packed[:, 4:]
         t_page = kv.shape[4]
         n_arena = kv.shape[3]
@@ -610,8 +619,8 @@ class HybridRunner(ModelRunner):
     low-precision control (``"low"``) and otherwise empty."""
 
     wants_pages = True
-    wants_seqs = True          # step() is handed the slots' KVSeqs
     has_prefill = True
+    feeds_tokens = True        # a step can take tokens from the one before
     chunked_prefill = True     # the engine cuts a long suffix to buckets
     kv_bytes_per_token = 0     # the engine writes no K/V rows for it
 
@@ -631,8 +640,11 @@ class HybridRunner(ModelRunner):
         self._control = control
         self._mu = threading.Lock()
         self._fns = _programs()
-        self.last_logprobs = None     # of the last step, a slot each
-        self._table_cache: dict = {}  # seq id -> (pages, arena indices)
+        self._table_cache: dict = {}  # seq id -> (table's key, arena indices)
+        self._no_prev: dict = {}      # slots -> zeros [3, S] on the device
+        # prefill chunks dispatched and not counted yet: the engine
+        # thread does not wait for a prefill to run (see prefill)
+        self._uncounted: list = []
         safe = "".join(c if c.isalnum() else "_" for c in name)
         self.sparse_selected = Adder(f"runner_{safe}_sparse_selected_blocks")
         self.sparse_positions = Adder(f"runner_{safe}_sparse_positions")
@@ -683,10 +695,12 @@ class HybridRunner(ModelRunner):
         for i, s in enumerate(seqs or ()):
             if s is None:
                 continue
-            n = len(s.pages)
+            # what a table changes by while it decodes: a page more, or
+            # its tail page copied
+            key = (len(s.pages), s.pages[-1].pid if s.pages else -1)
             hit = kept.get(s.seq_id)
-            if hit is None or hit[0] != n:
-                hit = (n, self._flat_tables(pages[i]))
+            if hit is None or hit[0] != key:
+                hit = (key, self._flat_tables(pages[i]))
             live[s.seq_id] = hit
             out[i] = hit[1]
         self._table_cache = live
@@ -735,15 +749,26 @@ class HybridRunner(ModelRunner):
                 logits_out=bool(logits), max_pages=len(table),
                 **self._statics())
         self.store.mark_filled(seq, start + n)
-        self._count(start + np.arange(n), n_sel)
+        # counted when the next step dispatched completes: the chunk has
+        # run by then, and ``float(n_sel)`` here would hold the engine
+        # thread until the device has caught up with everything queued
+        self._uncounted.append((start + np.arange(n), n_sel))
         if start + n == self.store.snapshot_boundary(seq):
             self.store.take_snapshot(seq, start + n)
         return out
 
-    def _step(self, tokens, positions, pages, seqs, logits: bool = False):
-        """Dispatch one step; returns ``(result [3, S] on the device,
-        logits or None, which slots were live)``."""
+    def dispatch_step(self, tokens, positions, pages, seqs=None, prev=None,
+                      fed=None, logits: bool = False):
+        """Dispatch one step and return its handle (for
+        :meth:`complete_step`) without waiting for the device.  Where
+        ``fed[i]``, slot ``i``'s token is not ``tokens[i]`` but the one
+        the step ``prev`` (a handle, not yet completed perhaps) made for
+        it, read on the device."""
+        import jax
         import jax.numpy as jnp
+        if fault.ENABLED and fault.hit(
+                "model.step_compute", runner=self.name) is not None:
+            raise RuntimeError("injected model step-compute failure")
         lay = self.store.layers
         n = len(tokens)
         packed = np.empty((n, 4 + np.shape(pages)[1]), np.int32)
@@ -751,40 +776,54 @@ class HybridRunner(ModelRunner):
         packed[:, 2], packed[:, 3] = lay.scratch_row, 0
         for i, s in enumerate(seqs or ()):
             if s is not None and s.state_row is not None:
-                packed[i, 2], packed[i, 3] = s.state_row, 1
+                packed[i, 2], packed[i, 3] = s.state_row, LIVE
+        live = packed[:, 3] > 0
+        if fed is not None:
+            packed[live & np.asarray(fed, bool), 3] = FED
         packed[:, 4:] = self._slot_tables(pages, seqs)
+        if prev is not None:
+            before = prev["out"]
+        else:
+            before = self._no_prev.get(n)
+            if before is None:
+                # placed as a step's own result is (committed, beside the
+                # cache): the same program whether a step came before
+                before = self._no_prev[n] = jax.device_put(
+                    np.zeros((3, n), np.float32), lay.kv.sharding)
         with lay.lock:
             out, lg, lay.kv, lay.kc, lay.state = self._fns["step"](
                 self.params, lay.kv, lay.kc, lay.state, jnp.asarray(packed),
-                logits_out=bool(logits), **self._statics())
-        return out, lg, packed[:, 3] > 0
+                before, logits_out=bool(logits), **self._statics())
+        owed, self._uncounted = self._uncounted, []
+        return {"out": out, "logits": lg, "live": live, "seqs": seqs,
+                "positions": np.asarray(positions), "prefills": owed}
 
-    def _ran_step(self, positions, seqs, active, out) -> np.ndarray:
-        """Fetch a step's result and book its positions: materialised,
-        and counted."""
-        out = np.asarray(out)
-        for i, s in enumerate(seqs or ()):
-            if active[i]:
+    def complete_step(self, handle):
+        """Fetch a dispatched step's result and book its positions
+        (materialised, counted) and the prefill chunks dispatched before
+        it: ``(next tokens, None, their log-probabilities)``."""
+        out = np.asarray(handle["out"])
+        live, positions = handle["live"], handle["positions"]
+        for i, s in enumerate(handle["seqs"] or ()):
+            if live[i] and not s.retired:
                 self.store.mark_filled(s, int(positions[i]))
-        self._count(np.asarray(positions)[active] - 1, out[2][active].sum())
-        return out
+        for qpos, n_sel in handle["prefills"]:
+            self._count(qpos, n_sel)
+        self._count(positions[live] - 1, out[2][live].sum())
+        return out[0].astype(np.int32), None, out[1]
 
     def step(self, tokens, positions, pages, seqs=None):
-        if fault.ENABLED and fault.hit(
-                "model.step_compute", runner=self.name) is not None:
-            raise RuntimeError("injected model step-compute failure")
-        out, _, active = self._step(tokens, positions, pages, seqs)
-        out = self._ran_step(positions, seqs, active, out)
-        self.last_logprobs = out[1]
-        return out[0].astype(np.int32), None
+        nxt, rows, _ = self.complete_step(
+            self.dispatch_step(tokens, positions, pages, seqs))
+        return nxt, rows
 
     def step_logits(self, tokens, positions, pages, seqs=None):
         """The step's logits ``[slots, vocab]`` (it advances the cache
         as :meth:`step` does)."""
-        out, logits, active = self._step(tokens, positions, pages, seqs,
-                                         logits=True)
-        self._ran_step(positions, seqs, active, out)
-        return logits
+        handle = self.dispatch_step(tokens, positions, pages, seqs,
+                                    logits=True)
+        self.complete_step(handle)
+        return handle["logits"]
 
     def verify(self, tokens, positions, tables, base_len, mask):
         raise NotImplementedError(

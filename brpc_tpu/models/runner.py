@@ -85,6 +85,10 @@ class ModelRunner:
     wants_pages: bool = False
     kv_bytes_per_token: int = 0
     has_prefill: bool = False
+    # the runner's step can take a slot's token on the device from the
+    # step dispatched before it (``dispatch_step(prev=, fed=)``), so the
+    # engine may dispatch a step before it has fetched the one ahead
+    feeds_tokens: bool = False
     name: str = "runner"
 
     def bind(self, store) -> None:
@@ -108,6 +112,24 @@ class ModelRunner:
         (``[num_slots, kv_bytes_per_token]`` uint8, or None for
         token-harness runners)."""
         raise NotImplementedError
+
+    def dispatch_step(self, tokens, positions, pages, seqs=None, prev=None,
+                      fed=None):
+        """The engine's way into :meth:`step`, in two halves: start one
+        step and return a handle; :meth:`complete_step` turns the handle
+        into the step's result.  ``seqs`` are the slots' KVSeqs (None
+        where a slot is idle).  A runner that ``feeds_tokens`` returns
+        without waiting for the device and takes slot ``i``'s token,
+        where ``fed[i]``, from the step ``prev`` (a handle) on the
+        device; any other runs the whole step here."""
+        return self.step(tokens, positions, pages)
+
+    def complete_step(self, handle):
+        """``(next_tokens, kv_rows, logprobs)`` of a dispatched step:
+        :meth:`step`'s pair and the next tokens' log-probabilities
+        (None from a runner that computes none)."""
+        nxt, rows = handle
+        return nxt, rows, None
 
     def verify(self, tokens, positions, tables, base_len, mask):
         """Speculative-verify (ISSUE 11): score a whole draft tree in

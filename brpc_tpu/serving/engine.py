@@ -39,6 +39,29 @@ reuse, COW forks and crash recovery operate on real attention state.
 The legacy ``step_fn``/``prefill_fn`` protocols wrap in a
 ``LegacyFnRunner`` adapter with byte-identical behavior.
 
+One step in flight (ISSUE 33): an iteration of the plain step loop is
+(1) claim, admit and prefill the waiters, (2) DISPATCH the next step,
+(3) FETCH the oldest step in flight, the one wait for the device an
+iteration, (4) BOOK it (tokens into ``seq``, log-probabilities, gaps,
+the emit push, retires).  Over a runner that ``feeds_tokens`` (it takes
+a slot's token on the device from the step before) step k is fetched
+and booked AFTER step k+1 was dispatched, so the host's work of a step
+runs while the chip runs the next; over any other runner (2) and (3)
+are the same step.  One loop body either way: the lag is read from the
+runner.  What MAY lag the device by a step: everything the host keeps
+of a step's result (a slot's ``last_token`` / ``position`` /
+``generated``, ``seq.tokens``, ``kv_filled``, the radix tree, the
+client's stream), and with an ``eos_token`` the sight of the end (the
+one surplus step's token is dropped at its booking; its K/V write lies
+behind the sequence's length).  What must NEVER: a step is dispatched
+only with the page of every position it writes in the table
+(``KVCacheStore.reserve_next``), never for a slot whose step in flight
+makes its last token by count, and is booked at most once and only for
+the slot object it was dispatched for; and a slot is handed on
+(``close``, ``takeover``, a supervised crash) only once nothing is in
+flight and as booked, so that whoever rebuilds a sequence from it
+applies no step twice.
+
 Emission: each admitted request gets a BOUNDED emit buffer drained by
 its own emitter thread — the shared step loop never blocks in
 ``emit``.  A consumer that stops draining (stream credit exhausted,
@@ -260,7 +283,7 @@ class _Request:
 
 class _Slot:
     __slots__ = ("req", "block", "seq", "last_token", "position",
-                 "generated", "span", "t_install", "t_first_tok",
+                 "generated", "inflight", "span", "t_install", "t_first_tok",
                  "last_tok_t", "itl_n", "itl_sum_s", "itl_max_s",
                  "steps_run", "spec_steps", "spec_proposed",
                  "spec_accepted")
@@ -273,6 +296,10 @@ class _Slot:
         self.last_token = req.prompt[-1] if req.prompt else 0
         self.position = len(req.prompt)
         self.generated = 0
+        # steps dispatched for this slot and not booked yet (0 or 1):
+        # last_token / position / generated say what is BOOKED, and
+        # position + inflight, generated + inflight what is dispatched
+        self.inflight = 0
         self.span = span                      # per-slot decode span
         self.t_install = time.monotonic()
         self.t_first_tok = 0.0
@@ -284,6 +311,21 @@ class _Slot:
         self.spec_steps = 0                   # verify iterations of those
         self.spec_proposed = 0                # draft tokens proposed
         self.spec_accepted = 0                # draft tokens accepted
+
+
+class _Flight:
+    """One dispatched decode step until its result is booked: the slots
+    that rode it, index AND object (a slot cancelled, retired or given
+    to another request meanwhile fails the identity check at booking
+    and is dropped), the runner's handle, and whether another step was
+    in flight when this one was dispatched."""
+
+    __slots__ = ("members", "handle", "ahead")
+
+    def __init__(self, members: list, handle, ahead: bool):
+        self.members = members
+        self.handle = handle
+        self.ahead = ahead
 
 
 class _SpecPlan:
@@ -383,6 +425,14 @@ class DecodeEngine:
             if self.draft_len < 1:
                 raise ValueError("draft_len must be >= 1")
 
+        # how many steps the booking of a step may lag its dispatch:
+        # one where the runner can take a step's tokens on the device
+        # from the step before, else none (see _plain_step).  A draft
+        # engine's verify needs every slot booked
+        self._lag = 1 if self.runner.feeds_tokens \
+            and self._draft is None else 0
+        self._flying: deque = deque()   # steps dispatched, oldest first
+
         safe = re.sub(r"\W", "_", name)
         # record the EXACT names exposed here so close() hides only this
         # engine's variables — a prefix wildcard would also strip a
@@ -390,6 +440,7 @@ class DecodeEngine:
         from brpc_tpu.bvar.variable import exposed_variables
         pre = set(exposed_variables(f"serving_{safe}*"))
         self.steps = Adder(f"serving_{safe}_steps")
+        self.steps_ahead = Adder(f"serving_{safe}_steps_ahead")
         self.tokens_out = Adder(f"serving_{safe}_tokens")
         self.retired = Adder(f"serving_{safe}_retired")
         self.admit_errors = Adder(f"serving_{safe}_admit_errors")
@@ -849,6 +900,14 @@ class DecodeEngine:
         return table
 
     def _loop(self) -> None:
+        try:
+            self._run()
+        finally:
+            # however the loop ends (close, takeover, a crash), no step
+            # stays in flight behind it
+            self._discard_flying()
+
+    def _run(self) -> None:
         while True:
             self._touch_beat()
             with self._cv:
@@ -889,7 +948,7 @@ class DecodeEngine:
                     return
                 active = [(i, s) for i, s in enumerate(self._slots)
                           if s is not None]
-                if not active:
+                if not active and not self._flying:
                     if not self._waiters:
                         # bounded idle wait so the heartbeat keeps
                         # ticking: an idle-but-alive loop must stay
@@ -903,35 +962,68 @@ class DecodeEngine:
                 return
 
     def _plain_step(self, active) -> bool:
-        """One plain decode iteration (the no-draft path, byte-for-byte
-        the pre-ISSUE-11 loop body except that the per-slot KV row
-        writes ride ONE ``write_kv_batch``).  Returns False when the
-        loop must stop (supervised crash)."""
+        """One iteration of the plain decode path (no draft), in three
+        parts: DISPATCH the next step for every slot that still has a
+        token to make, FETCH the oldest step in flight once more than
+        ``_lag`` are, and BOOK the fetched step (tokens into ``seq``,
+        log-probabilities, gaps, the emit push, retires).  At lag 0 the
+        step dispatched is the step fetched: the loop every runner had.
+        At lag 1 step k is booked while the chip runs step k+1, whose
+        operands are what the host knows without k's result: positions
+        advance by one, the page of the position k's token will take is
+        reserved, and the token itself is read from k's result on the
+        device.  Returns False when the loop must stop (supervised
+        crash)."""
         t_cpu0 = time.thread_time()
+        flying = self._flying
+        ahead = bool(flying)
         tok = np.zeros((self.num_slots,), np.int32)
         pos = np.zeros((self.num_slots,), np.int32)
+        fed = np.zeros((self.num_slots,), bool)
+        seqs = [None] * self.num_slots
+        members = []
         for i, s in active:
-            tok[i] = s.last_token
-            pos[i] = s.position
-        pages = self._gather_page_tables(active)
+            if s.generated + s.inflight >= s.req.max_new_tokens:
+                continue    # the step in flight makes its last token
+            if s.inflight:
+                # its token is still on the device, and the step writes
+                # K/V where that token will sit: the page goes into the
+                # table now, the token at the booking
+                if s.seq is not None and not self._grow_kv(i, s):
+                    continue
+                fed[i] = True
+            else:
+                tok[i] = s.last_token
+            pos[i] = s.position + s.inflight
+            seqs[i] = s.seq
+            members.append((i, s))
+        pages = self._gather_page_tables(members)
+        due = None
         t_fn_cpu = time.thread_time()
         try:
             if fault.ENABLED and fault.hit(
                     "serving.step", name=self.name) is not None:
                 raise RuntimeError("injected decode step crash")
-            with rpcz.stage("serve.engine.step", slots=len(active),
-                            tokens=len(active)):
-                if getattr(self.runner, "wants_seqs", False):
-                    # a runner that keeps per-sequence state beside the
-                    # pages (a state row) is handed the slots' KVSeqs
-                    seqs = [None] * self.num_slots
-                    for i, s in active:
-                        seqs[i] = s.seq
-                    out, kv_rows = self.runner.step(tok, pos, pages,
-                                                    seqs=seqs)
-                else:
-                    out, kv_rows = self.runner.step(tok, pos, pages)
+            # one stage a device step, around its dispatch and the one
+            # blocking fetch of the iteration
+            with rpcz.stage("serve.engine.step", slots=len(members),
+                            tokens=len(members), ahead=int(ahead)) \
+                    if members else rpcz.NOOP_STAGE:
+                if members:
+                    handle = self.runner.dispatch_step(
+                        tok, pos, pages, seqs=seqs,
+                        prev=flying[-1].handle if flying else None, fed=fed)
+                    for _, s in members:
+                        s.inflight += 1
+                    flying.append(_Flight(members, handle, ahead))
+                if flying and (len(flying) > self._lag or not members):
+                    out, kv_rows, step_lp = self.runner.complete_step(
+                        flying[0].handle)
+                    due = flying[0]
         except Exception as e:
+            # a failed dispatch or fetch takes whatever else is in
+            # flight with it: nothing is booked after a failure
+            self._discard_flying()
             if self._on_crash is not None:
                 # supervised: this is an ENGINE failure, not the
                 # requests' — leave every slot intact for takeover
@@ -953,15 +1045,81 @@ class DecodeEngine:
                 s.req.buf.push_terminal(err)
             return True
         fn_cpu_s = time.thread_time() - t_fn_cpu
+        if due is not None:
+            # in flight until it is booked: takeover() waits for that
+            self._book(due, out, kv_rows, step_lp)
+            flying.popleft()
+        # per-stage host-CPU accounting (ISSUE 6): this iteration's
+        # step-loop bookkeeping minus the model step itself
+        hostcpu.add("decode_step",
+                    (time.thread_time() - t_cpu0 - fn_cpu_s) * 1e6)
+        hostcpu.add("model_compute", fn_cpu_s * 1e6)
+        return True
+
+    def _grow_kv(self, i: int, s: _Slot, token: Optional[int] = None) -> bool:
+        """Grow slot ``i``'s sequence: by ``token``, or (None) by the
+        page its next position needs, ahead of the token.  A failure
+        retires THAT request (pool exhausted and nothing evictable, or
+        the fixed page table outgrown: ELIMIT) and leaves the loop and
+        its step-mates running."""
+        try:
+            if token is None:
+                self.store.reserve_next(s.seq)
+            else:
+                self.store.extend(s.seq, token)
+        except MemoryError as e:
+            self._retire(i, errors.RpcError(
+                errors.ELIMIT, f"KV page alloc failed: {e}"))
+            return False
+        except Exception as e:
+            self._retire(i, errors.RpcError(
+                errors.EINTERNAL,
+                f"KV extend failed: {type(e).__name__}: {e}"))
+            return False
+        if len(s.seq.pages) > self.max_pages_per_slot:
+            self._retire(i, errors.RpcError(
+                errors.ELIMIT,
+                f"page table overflow "
+                f"(> {self.max_pages_per_slot} pages)"))
+            return False
+        return True
+
+    def _discard_flying(self) -> None:
+        """Drop every step in flight, unbooked: each is waited for, so
+        that nothing of this engine still runs on the device when its
+        slots are handed on, and its result reaches nobody (a slot's
+        ``seq`` and counts say what was booked; a sequence that goes on
+        elsewhere is rebuilt from them, so no step is applied twice)."""
+        while self._flying:
+            flight = self._flying.popleft()
+            try:
+                self.runner.complete_step(flight.handle)
+            except Exception:
+                pass    # the failure that brought us here, or its wake
+            for _, s in flight.members:
+                s.inflight = 0
+        with self._cv:
+            self._cv.notify_all()
+
+    def _book(self, flight: _Flight, out, kv_rows, step_lp) -> None:
+        """A fetched step's bookkeeping, slot by slot (see _plain_step).
+        A slot that an emitter cancelled, a failed reservation retired
+        or a new request took since the dispatch is dropped: its token
+        is delivered nowhere, appended nowhere and counted nowhere (with
+        an ``eos_token`` the end is seen one step late at lag 1, and
+        this is where the surplus step goes)."""
+        members = flight.members
         self.steps.add(1)
-        self.occupancy_rec.add(len(active))
+        if flight.ahead:
+            self.steps_ahead.add(1)
+        self.occupancy_rec.add(len(members))
         t_tok = time.monotonic()
         # the per-slot KV row writes ride ONE batched splice
         # (ISSUE 11): one H2D transfer + one I/O critical section
         # across every surviving slot instead of one per slot
         wrote_bad: set = set()
         if kv_rows is not None:
-            items = [(i, s) for i, s in active
+            items = [(i, s) for i, s in members
                      if self._slots[i] is s and s.seq is not None]
             fails = self.store.write_kv_batch(
                 [(s.seq, s.position - 1, kv_rows[i:i + 1])
@@ -973,10 +1131,10 @@ class DecodeEngine:
                     errors.EINTERNAL,
                     f"KV write failed: {type(e).__name__}: {e}"))
         deliver: list = []   # (slot index, slot, token) surviving
-        step_lp = getattr(self.runner, "last_logprobs", None)
-        for i, s in active:
+        for i, s in members:
+            s.inflight -= 1
             if i in wrote_bad or self._slots[i] is not s:
-                continue    # an emitter cancelled it mid-step
+                continue    # cancelled, retired or replaced mid-step
             nxt = int(out[i])
             if s.req.logprobs is not None:
                 s.req.logprobs.append(
@@ -1001,27 +1159,10 @@ class DecodeEngine:
                 if s.span is not rpcz.NULL_SPAN:
                     s.span.annotate(f"first token: ttft_us={ttft_us}")
             s.last_tok_t = t_tok
-            if s.seq is not None:
-                try:
-                    self.store.extend(s.seq, nxt)
-                except MemoryError as e:
-                    # pool exhausted and nothing evictable: THIS
-                    # request errors, the loop and its peers go on
-                    self._retire(i, errors.RpcError(
-                        errors.ELIMIT,
-                        f"KV page alloc failed: {e}"))
-                    continue
-                except Exception as e:
-                    self._retire(i, errors.RpcError(
-                        errors.EINTERNAL,
-                        f"KV extend failed: {type(e).__name__}: {e}"))
-                    continue
-                if len(s.seq.pages) > self.max_pages_per_slot:
-                    self._retire(i, errors.RpcError(
-                        errors.ELIMIT,
-                        f"page table overflow "
-                        f"(> {self.max_pages_per_slot} pages)"))
-                    continue
+            # pool exhausted and nothing evictable: THIS request
+            # errors, the loop and its peers go on
+            if s.seq is not None and not self._grow_kv(i, s, nxt):
+                continue
             deliver.append((i, s, nxt))
         # emit fan-out: ONE GIL-released native push across every
         # surviving slot's ring (ISSUE 9) — the per-token Python
@@ -1049,12 +1190,6 @@ class DecodeEngine:
                     (self.eos_token is not None
                      and nxt == self.eos_token):
                 self._retire(i, None)
-        # per-stage host-CPU accounting (ISSUE 6): this iteration's
-        # step-loop bookkeeping minus the model step itself
-        hostcpu.add("decode_step",
-                    (time.thread_time() - t_cpu0 - fn_cpu_s) * 1e6)
-        hostcpu.add("model_compute", fn_cpu_s * 1e6)
-        return True
 
     # ---- speculative decoding (ISSUE 11) ----
 
@@ -1563,6 +1698,16 @@ class DecodeEngine:
             for i in range(self.num_slots):
                 self._slots[i] = None
             waiters, self._waiters = list(self._waiters), deque()
+            if threading.current_thread() is not self._thread:
+                # the loop, if it still runs, ends the booking it is in
+                # and discards its step in flight on its way out: the
+                # slots are handed on as booked, with nothing of this
+                # engine left on the device (a loop wedged inside the
+                # device is not waited for long: what it comes back
+                # with fails the identity check)
+                self._cv.wait_for(
+                    lambda: not self._flying
+                    or not self._thread.is_alive(), timeout=1.0)
         return stolen, waiters
 
     def active_count(self) -> int:
@@ -1637,6 +1782,7 @@ class DecodeEngine:
             "slots": slot_map,
             "queued": queued,
             "steps": self.steps.get_value(),
+            "steps_ahead": self.steps_ahead.get_value(),
             "tokens": self.tokens_out.get_value(),
             "retired": self.retired.get_value(),
             "admit_errors": self.admit_errors.get_value(),

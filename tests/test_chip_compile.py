@@ -258,8 +258,8 @@ def test_sala_decode_step_compiles_at_published_widths_and_fits(sds):
     s, mp = c["num_slots"], c["max_pages_per_slot"]
     i32 = jnp.int32
     compiled = fns["step"].lower(
-        params, *caches, sds((s, 4 + mp), i32), logits_out=False,
-        **statics).compile()
+        params, *caches, sds((s, 4 + mp), i32), sds((3, s), jnp.float32),
+        logits_out=False, **statics).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 12 + 4 * 4
     assert _arena_copies(compiled, c["cache_pages"]) == []
     mem = compiled.memory_analysis()

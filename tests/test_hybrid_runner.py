@@ -403,3 +403,110 @@ def test_generate_with_and_without_logprobs(model):
         engine.close()
         runner.close()
         store.close()
+
+
+class _Gated(HybridRunner):
+    """Holds the engine inside the first admission until the test has
+    queued every request: who rides which step is then the same in every
+    run, and so is the order in which pages are taken."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.gate, self.parked = threading.Event(), threading.Event()
+
+    def prefill(self, *a, **kw):
+        self.parked.set()
+        assert self.gate.wait(60)
+        return super().prefill(*a, **kw)
+
+
+class _InARow(_Gated):
+    feeds_tokens = False      # the engine completes each step it dispatches
+
+
+class _Take:
+    def __init__(self):
+        self.tokens, self.logprobs, self.err = [], [], "UNSET"
+        self.done = threading.Event()
+
+    def emit(self, tok, lp):
+        self.tokens.append(tok)
+        self.logprobs.append(lp)
+
+    def on_done(self, err):
+        self.err = err
+        self.done.set()
+
+
+def _serve(model, cls, name, compiles):
+    """Three requests through a ``DecodeEngine``: prompts of 40, 44 and 47
+    tokens that decode across position 48, a page boundary (16-token
+    pages) and the switch from the dense to the sparse branch
+    (``dense_len`` 48), ending by count on different steps."""
+    cfg, _ref_cfg, params = model
+    store = make_layered_store(cfg, cache_pages=64, state_rows=8, name=name)
+    runner = cls(params, cfg, store=store, name=name)
+    engine = DecodeEngine(runner=runner, num_slots=4, store=store,
+                          max_pages_per_slot=MAX_PAGES,
+                          prefill_buckets=(16, 32), name=name)
+    try:
+        takes = [_Take() for _ in range(3)]
+        for k, (n, new, t) in enumerate(zip((40, 44, 47), (14, 9, 12),
+                                            takes)):
+            engine.submit(tokens_of(n, seed=70 + k), new, t.emit, t.on_done,
+                          logprobs=True)
+            if k == 0:      # the engine stays inside this admission
+                assert runner.parked.wait(20)
+        # what compiles from here on compiles inside the steps
+        seen = len(compiles)
+        runner.gate.set()
+        for t in takes:
+            assert t.done.wait(120) and t.err is None
+        assert engine.join_idle(20)
+        stats = engine.stats()
+        lay = store.layers
+        with lay.lock:
+            arrays = [np.asarray(x) for x in (lay.kv, lay.kc, lay.state)]
+        return {"tokens": [t.tokens for t in takes],
+                "logprobs": [t.logprobs for t in takes],
+                "arrays": arrays, "steps": stats["steps"],
+                "ahead": stats["steps_ahead"],
+                "compiled": compiles[seen:],
+                "counters": (runner.sparse_positions.get_value(),
+                             runner.dense_positions.get_value(),
+                             runner.sparse_selected.get_value())}
+    finally:
+        engine.close()
+        runner.close()
+        store.close()
+
+
+def test_a_step_in_flight_serves_what_steps_in_a_row_serve(model):
+    """The engine over a ``HybridRunner`` keeps one step in flight (the
+    runner feeds tokens on the device); over the same runner saying it
+    cannot, it runs ``step()``'s two halves in a row.  Same tokens and
+    log-probabilities to the bit, same cache arrays, same counters; and
+    the second run, in which the "take the token from the step before"
+    flag takes both values, compiles nothing: one program."""
+    import jax.monitoring
+    compiles = []
+    on = [True]
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(kw.get("fun_name"))
+        if on[0] and event == "/jax/core/compile/backend_compile_duration"
+        else None)
+    try:
+        row = _serve(model, _InARow, "t_inrow", compiles)
+        fly = _serve(model, _Gated, "t_inflight", compiles)
+    finally:
+        on[0] = False
+    assert row["ahead"] == 0 and fly["ahead"] >= fly["steps"] - 2 > 0
+    assert row["steps"] == fly["steps"]         # no surplus step
+    assert fly["tokens"] == row["tokens"]
+    assert [len(t) for t in fly["tokens"]] == [14, 9, 12]
+    assert fly["logprobs"] == row["logprobs"]       # bit for bit
+    for a, b in zip(fly["arrays"], row["arrays"]):
+        assert np.array_equal(a, b)
+    assert fly["counters"] == row["counters"]
+    assert fly["counters"][0] > 0 and fly["counters"][1] > 0
+    assert fly["compiled"] == [], fly["compiled"]

@@ -764,3 +764,86 @@ def test_speculate_vector_store_never_commits_unverified_tail():
         assert st.pagepool.blocks_leased() == 0
     finally:
         st.close()
+
+
+# ---------------------------------------------------------------------------
+# a position reserved ahead of its token (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("how", ["live_commit", "retire", "detach",
+                                 "export", "fills", "shared_tail",
+                                 "exhausted"])
+def test_a_reserved_position_is_a_page_and_nothing_else(how):
+    """``reserve_next`` puts the next position's page into the table and
+    is seen by nothing that goes by tokens: not the streaming commit,
+    retire-time caching, a detach's pin or an export; the extend that
+    follows finds the page in place."""
+    st = KVCacheStore(page_bytes=PB, page_tokens=PT, max_blocks=1,
+                      commit_live_pages=how == "live_commit",
+                      name=f"t_reserve_{how}")      # 8 KB / 64 B: 128 pages
+    try:
+        prompt = list(range(1, 2 * PT + 1))         # two whole pages
+        if how == "shared_tail":
+            prompt = prompt[:-1]                    # the tail has room
+        seq = st.admit(prompt)
+        allocs = st.pagepool.stats()["page_allocs"]
+        fork = st.fork(seq) if how == "shared_tail" else None
+        st.reserve_next(seq)
+        st.reserve_next(seq)                        # idempotent
+        assert st.pagepool.stats()["page_allocs"] == allocs + 1
+        assert len(seq.tokens) == len(prompt) == seq.kv_filled
+        if how == "shared_tail":
+            # the copy happened at the reservation, once; the fork keeps
+            # the page it shared
+            assert st.stats()["cow_forks"] == 1 and len(seq.pages) == 2
+            assert seq.pages[-1] is not fork.pages[-1]
+            st.extend(seq, 77)
+            assert st.stats()["cow_forks"] == 1
+            assert st.pagepool.read(seq.pages[-1]).tolist() \
+                == prompt[PT:] + [77]
+            assert st.pagepool.read(fork.pages[-1]).tolist()[:PT - 1] \
+                == prompt[PT:]
+            st.retire(fork, cache=False)
+            return
+        assert len(seq.pages) == 3                  # the page is there
+        if how == "live_commit":
+            # the tree holds the two full pages the admit committed, and
+            # the reservation added nothing to it
+            assert st.radix.cached_tokens() == 2 * PT
+            st.commit_draft(seq, len(seq.tokens))
+            assert st.radix.cached_tokens() == 2 * PT
+        elif how == "retire":
+            st.retire(seq)
+            assert st.radix.cached_tokens() == 2 * PT
+            assert st.pagepool.pages_in_use() == 2  # the third went back
+        elif how == "detach":
+            pin = st.detach(seq)
+            assert pin.tokens == 2 * PT and len(pin) == 2
+            assert st.pagepool.pages_in_use() == 2
+            pin.release()
+        elif how == "export":
+            st.retire(seq)
+            hit, pages = st.acquire_pages(prompt + [5, 5, 5, 5])
+            assert hit == 2 * PT and len(pages) == 2
+            st.release(pages)
+        elif how == "fills":
+            st.extend(seq, 77)                      # no second page
+            assert st.pagepool.stats()["page_allocs"] == allocs + 1
+            assert len(seq.pages) == 3 and seq.tokens[-1] == 77
+            assert st.pagepool.read(seq.pages[2]).tolist()[0] == 77
+        elif how == "exhausted":
+            hog = st.admit(list(range(500, 500 + 124 * PT)))
+            other = st.admit([9])                   # 128 of 128 in use
+            for _ in range(PT):
+                st.extend(seq, 77)                  # the reserved page, full
+            with pytest.raises(MemoryError):
+                st.reserve_next(seq)                # nothing evictable
+            assert len(seq.pages) == 3 and not seq.retired
+            st.extend(other, 10)                    # its neighbours go on
+            st.retire(hog, cache=False)
+            st.retire(other, cache=False)
+        if not seq.retired:
+            st.retire(seq, cache=False)
+        st.pagepool.assert_consistent()
+    finally:
+        st.close()

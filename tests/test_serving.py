@@ -22,6 +22,7 @@ import pytest
 
 import brpc_tpu as brpc
 from brpc_tpu import errors
+from brpc_tpu.models.runner import ModelRunner
 from brpc_tpu.serving import (DecodeEngine, DynamicBatcher, ServingService,
                               register_serving)
 
@@ -663,3 +664,299 @@ def test_console_serving_page(serving_server):
     assert st["num_slots"] == 4 and len(st["slots"]) == 4
     assert "shed" in snap["batchers"]["t_rpc"]
     assert "pad_waste_ratio" in snap["batchers"]["t_rpc"]
+
+
+# ---------------------------------------------------------------------------
+# one decode step in flight (ISSUE 33): the loop at lag 1 against lag 0
+# ---------------------------------------------------------------------------
+
+class _AheadStub(ModelRunner):
+    """A runner whose next token is a pure function of (token, position),
+    "run on the device" at dispatch (dispatch order is device order) and
+    handed over at completion.  It counts what the engine must not get
+    wrong: how often a (sequence, position) was applied, whether the
+    page of a position it writes was in the table, how many steps were
+    in flight."""
+
+    wants_pages = True
+    VOCAB = 251
+
+    def __init__(self, feeds: bool, page_tokens: int, fetch_raises_at=0):
+        self.feeds_tokens = feeds
+        self.page_tokens = page_tokens
+        self.fetch_raises_at = fetch_raises_at
+        self.dispatched = 0
+        self.fetched: set = set()
+        self.max_in_flight = 0
+        self.fed_steps = 0
+        self.applied: dict = {}     # (seq id, position) -> times applied
+        self.named: dict = {}       # seq id -> its prompt's first token
+        self.uncovered: list = []
+
+    @classmethod
+    def next_token(cls, tok, pos):
+        return (tok * 31 + pos * 7 + 3) % cls.VOCAB
+
+    @staticmethod
+    def logprob(tok, pos):
+        return -float((tok * 13 + pos) % 97) / 64.0
+
+    @classmethod
+    def chain(cls, prompt, n):
+        tok, out = prompt[-1], []
+        for pos in range(len(prompt), len(prompt) + n):
+            tok = cls.next_token(tok, pos)
+            out.append(tok)
+        return out
+
+    def in_flight(self):
+        return self.dispatched - len(self.fetched)
+
+    def dispatch_step(self, tokens, positions, pages, seqs=None, prev=None,
+                      fed=None):
+        tokens = np.array(tokens)
+        if fed is not None and fed.any():
+            assert self.feeds_tokens and prev is not None
+            tokens[fed] = prev["out"][fed]
+            self.fed_steps += 1
+        out = np.zeros((len(tokens),), np.int32)
+        lp = np.zeros((len(tokens),), np.float32)
+        for i, s in enumerate(seqs):
+            if s is None:
+                continue
+            q = int(positions[i]) - 1
+            key = (s.seq_id, q)
+            self.applied[key] = self.applied.get(key, 0) + 1
+            if s.tokens:
+                self.named[s.seq_id] = s.tokens[0]
+            if pages[i][q // self.page_tokens] < 0 and not s.retired:
+                self.uncovered.append(key)
+            out[i] = self.next_token(int(tokens[i]), int(positions[i]))
+            lp[i] = self.logprob(int(tokens[i]), int(positions[i]))
+        self.dispatched += 1
+        self.max_in_flight = max(self.max_in_flight, self.in_flight())
+        return {"out": out, "lp": lp, "n": self.dispatched}
+
+    def complete_step(self, handle):
+        if handle["n"] == self.fetch_raises_at:
+            self.fetched.add(handle["n"])
+            raise RuntimeError("injected fetch failure")
+        time.sleep(0.001)       # the loop spends its time at the fetch
+        self.fetched.add(handle["n"])
+        return handle["out"], None, handle["lp"]
+
+
+class _LpSink(_Sink):
+    def __init__(self, fail_at=0):
+        super().__init__()
+        self.logprobs = []
+        self.fail_at = fail_at
+
+    def emit(self, tok, lp):
+        self.tokens.append(tok)
+        self.logprobs.append(lp)
+        if len(self.tokens) == self.fail_at:
+            raise RuntimeError("consumer went away")
+
+
+def _run_ahead(case: str, feeds: bool) -> dict:
+    """One scenario on a fresh store and engine; what came of it."""
+    from brpc_tpu.kvcache import KVCacheStore
+    # small pages, so that every case crosses some; one that must run
+    # until it is stopped has room for 4,096 tokens
+    endless = case in ("close", "takeover")
+    pt = 64 if endless else 16 if case == "exhausted" else 4
+    name = f"t_ahead_{case}_{int(feeds)}"
+    # 8 KB blocks of 512 B pages: 16 pages a block
+    store = KVCacheStore(page_bytes=512, page_tokens=pt,
+                         max_blocks=8 if endless else 1, name=name)
+    stub = _AheadStub(feeds, pt,
+                      fetch_raises_at=4 if case.startswith("fetch") else 0)
+    crashes = []
+    kw = {}
+    if case == "fetch_raises_supervised":
+        kw["on_crash"] = lambda eng, exc: crashes.append(exc)
+    # requests: (prompt, max_new_tokens); a prompt's first token names it
+    a = [11, 5, 6, 7, 8, 9]
+    b = [12, 3, 4]
+    c = [13, 2, 2, 2, 2, 2, 2]
+    reqs = {"count": [(a, 9), (b, 5), (c, 1)],
+            "eos": [(a, 40), (b, 6)],
+            "cancel": [(a, 200), (b, 8)],
+            "reuse": [(a, 40), (b, 6), (c, 7)],
+            "page_start": [([14, 1, 1, 1], 9), ([15, 1, 1], 6)],
+            "shared_tail": [(a, 7)],
+            "exhausted": [([16] + [1] * 14, 30), (b, 12)],
+            "close": [(a, 10 ** 6)], "takeover": [(a, 10 ** 6)],
+            "fetch_raises_unsupervised": [(a, 30)],
+            "fetch_raises_supervised": [(a, 30)]}[case]
+    if case in ("eos", "reuse"):
+        kw["eos_token"] = _AheadStub.chain(a, 6)[-1]
+        assert kw["eos_token"] not in _AheadStub.chain(a, 5)
+        assert kw["eos_token"] not in _AheadStub.chain(b, 6) \
+            + _AheadStub.chain(c, 7)
+    ballast = forks = None
+    if case == "exhausted":
+        # 14 of the 16 pages held from outside: one each is left
+        ballast = store.admit(list(range(100, 100 + 14 * pt)))
+    if case == "shared_tail":
+        forks = []
+        admit = store.admit
+
+        def admit_shared(prompt, span=None):
+            seq = admit(prompt, span=span)
+            forks.append(store.fork(seq))   # holds the tail page too
+            return seq
+        store.admit = admit_shared
+    ended = {}          # a prompt's first token -> its seq as it retired
+    retire = store.retire
+
+    def retire_seen(seq, *, cache=True):
+        if not seq.retired and (forks is None or seq not in forks):
+            ended[seq.tokens[0]] = (list(seq.tokens), seq.kv_filled, cache)
+        retire(seq, cache=cache)
+    store.retire = retire_seen
+    eng = DecodeEngine(runner=stub, store=store, name=name,
+                       num_slots=1 if case == "reuse" else 3, **kw)
+    out = {"stub": stub}
+    try:
+        sinks = [_LpSink(fail_at=3 if case == "cancel" and i == 0 else 0)
+                 for i in range(len(reqs))]
+        for (prompt, n), s in zip(reqs, sinks):
+            eng.submit(prompt, n, s.emit, s.on_done, logprobs=True)
+        if endless:
+            assert wait_until(lambda: len(sinks[0].tokens) > 5, 20)
+            if feeds:
+                assert wait_until(lambda: stub.in_flight() == 2, 20)
+            if case == "close":
+                eng.close()
+            else:
+                stolen, _ = eng.takeover()
+                # handed on with nothing in flight, and as booked
+                assert [s.inflight for s in stolen] == [0]
+                assert len(stolen[0].seq.tokens) \
+                    == len(a) + stolen[0].generated
+                store.retire(stolen[0].seq, cache=False)
+                stolen[0].req.buf.push_terminal(
+                    errors.RpcError(errors.ELOGOFF, "taken over"))
+            out["in_flight_after"] = stub.in_flight()
+        if case == "fetch_raises_supervised":
+            # the slots stay as booked for whoever takes the engine over
+            assert wait_until(lambda: crashes, 10) and eng.crashed
+            stolen, _ = eng.takeover()
+            assert [s.inflight for s in stolen] == [0]
+            assert len(stolen[0].seq.tokens) == len(a) + stolen[0].generated
+            out["booked"] = list(stolen[0].seq.tokens)
+            store.retire(stolen[0].seq, cache=False)
+            stolen[0].req.buf.push_terminal(
+                errors.RpcError(errors.ELOGOFF, "taken over"))
+        for s in sinks:
+            assert s.done.wait(30)
+        if case == "fetch_raises_unsupervised":
+            # the loop lives: the next request is served whole
+            again = _LpSink()
+            eng.submit(b, 5, again.emit, again.on_done, logprobs=True)
+            assert again.done.wait(30) and again.err is None
+            assert again.tokens == _AheadStub.chain(b, 5)
+        assert eng.join_idle(10)
+        out["in_flight_idle"] = wait_until(
+            lambda: stub.in_flight() == 0, 10)
+        out["steps_ahead"] = eng.stats()["steps_ahead"]
+    finally:
+        eng.close()
+    out.update(
+        tokens=[s.tokens for s in sinks],
+        logprobs=[s.logprobs for s in sinks],
+        terminals=[s.err.code if s.err is not None else None
+                   for s in sinks],
+        ended=ended, cow=store.stats()["cow_forks"],
+        radix=(store.radix.node_count(), store.radix.cached_tokens(),
+               [store.probe(p + _AheadStub.chain(p, n)[:40])
+                for p, n in reqs]),
+        applications={p[0]: sum(v for (sid, _q), v in stub.applied.items()
+                                if stub.named.get(sid) == p[0])
+                      for p, _n in reqs})
+    for f in forks or ():
+        retire(f, cache=False)
+    if ballast is not None:
+        retire(ballast, cache=False)
+    out["leaked"] = store.pagepool.pages_in_use() - store.radix.node_count()
+    store.close()
+    return out
+
+
+AHEAD_CASES = ["count", "eos", "cancel", "reuse", "page_start",
+               "shared_tail", "exhausted", "close", "takeover",
+               "fetch_raises_unsupervised", "fetch_raises_supervised"]
+
+
+@pytest.mark.parametrize("case", AHEAD_CASES)
+def test_a_step_in_flight_changes_no_result(case):
+    """The same scenario through the same loop at lag 0 (the stub says
+    it cannot feed tokens) and at lag 1 (it can): what reaches the
+    clients, the sequences and the radix tree is the same, and what may
+    differ (a surplus step past an ``eos_token``, a page held a step
+    early) differs as the engine's docstring says."""
+    lag0, lag1 = _run_ahead(case, False), _run_ahead(case, True)
+    s0, s1 = lag0["stub"], lag1["stub"]
+    assert s0.max_in_flight == 1 and s0.fed_steps == 0
+    assert lag0["steps_ahead"] == 0
+    assert s1.max_in_flight == 2 and s1.fed_steps > 0
+    assert lag1["steps_ahead"] > 0
+    for r in (lag0, lag1):
+        assert r["in_flight_idle"], "a step was left in flight"
+        assert r["leaked"] == 0
+        assert r["stub"].uncovered == []
+        assert max(r["stub"].applied.values()) == 1, "a step applied twice"
+    same = ["tokens", "logprobs", "terminals", "radix", "cow", "ended"]
+    if case == "cancel":        # when the cancel lands is the emitter's
+        same.remove("ended")
+    elif case in ("close", "takeover"):     # and when these do, the test's
+        same = ["terminals", "cow"]
+    for key in same:
+        assert lag0[key] == lag1[key], key
+    a = [11, 5, 6, 7, 8, 9]
+    chain = _AheadStub.chain
+    if case == "count":
+        # the end is a count: no step is dispatched past it
+        assert lag1["tokens"] == [chain(a, 9), chain([12, 3, 4], 5),
+                                  chain([13, 2, 2, 2, 2, 2, 2], 1)]
+        assert lag1["terminals"] == [None] * 3
+        assert lag0["applications"] == lag1["applications"] \
+            == {11: 9, 12: 5, 13: 1}
+    elif case in ("eos", "reuse"):
+        # the end is seen a step late: exactly one surplus step, its
+        # token nowhere
+        assert lag1["tokens"][0] == chain(a, 6)
+        assert lag1["ended"][11] == (a + chain(a, 6), len(a) + 6, True)
+        assert lag0["applications"][11] == 6
+        assert lag1["applications"][11] == 7
+        assert lag1["applications"][12] == lag0["applications"][12] == 6
+        if case == "reuse":     # one slot: each took the slot just freed
+            assert lag1["tokens"][2] == chain([13, 2, 2, 2, 2, 2, 2], 7)
+    elif case == "cancel":
+        assert lag1["tokens"][0] == chain(a, 3)
+        assert lag1["terminals"] == [errors.EINTERNAL, None]
+    elif case == "shared_tail":
+        assert lag1["cow"] == 1     # at the reservation, and not again
+        assert lag1["tokens"] == [chain(a, 7)]
+    elif case == "exhausted":
+        # the first request fills its page with its first token and
+        # cannot have a second one; its step-mate never notices
+        assert lag1["terminals"] == [errors.ELIMIT, None]
+        assert lag1["tokens"] == [chain([16] + [1] * 14, 1),
+                                  chain([12, 3, 4], 12)]
+    elif case in ("close", "takeover"):
+        assert lag0["in_flight_after"] == lag1["in_flight_after"] == 0
+        assert lag1["terminals"] == [errors.ELOGOFF]
+        for r in (lag0, lag1):
+            got, (booked, filled, _cache) = r["tokens"][0], r["ended"][11]
+            assert got == chain(a, len(got))
+            assert booked == a + chain(a, len(booked) - len(a))
+            assert len(got) <= len(booked) - len(a) and filled == len(booked)
+    elif case == "fetch_raises_unsupervised":
+        # the fourth fetch raised: three tokens, then a definite error
+        assert lag1["tokens"] == [chain(a, 3)]
+        assert lag1["terminals"] == [errors.EINTERNAL]
+    elif case == "fetch_raises_supervised":
+        assert lag0["booked"] == lag1["booked"] == a + chain(a, 3)
