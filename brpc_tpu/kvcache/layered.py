@@ -18,6 +18,18 @@ runner's donated programs update in place (no per-call restack):
              live sequence and one a SNAPSHOT; the last two rows are a
              scratch row (idle decode slots read and write it) and a
              row that stays zero (a cold sequence starts from it)
+  ``latent`` ``[latent layers, P, T, C]`` bfloat16 (ISSUE 34): ONE row
+             a token a layer of a latent-attention model, ``[c_kv;
+             k_rope]`` and nothing per head; ``C`` is the row's width
+             rounded up to whole 128-lane tiles (the chip's compiler
+             gives an array whose minor dimension is not whole tiles
+             another layout than the kernels read, and copies it whole
+             around every call), the lanes past the row stay zero
+
+A kind the model has no layer of is an array with no element and costs
+nothing: no state row is allocated, restored or snapshot where
+``n_linear`` is 0, and a hit is then whatever whole pages the radix tree
+matches.
 
 A snapshot is the recurrent state after exactly a whole number of
 pages.  The radix node that ends that prefix owns it
@@ -45,9 +57,21 @@ class LayeredSpec:
     n_lin_heads: int
     lin_head_dim: int
     state_rows: int          # live sequences + snapshots
+    n_latent: int = 0        # layers that keep one latent row a token
+    latent_dim: int = 0      # its width: kv_lora_rank + the rope key's
+
+    @property
+    def has_state(self) -> bool:
+        return self.n_linear > 0
+
+    @property
+    def latent_lanes(self) -> int:
+        """The latent array's minor dimension: whole 128-lane tiles."""
+        return -(-self.latent_dim // 128) * 128
 
     def kv_bytes_per_token(self) -> int:
-        return self.n_sparse * 2 * self.n_kv_heads * self.head_dim * 2
+        return (self.n_sparse * 2 * self.n_kv_heads * self.head_dim
+                + self.n_latent * self.latent_dim) * 2
 
     def state_row_bytes(self) -> int:
         return self.n_linear * self.n_lin_heads * self.lin_head_dim ** 2 * 4
@@ -66,10 +90,11 @@ def _copy_row():
 def _copy_page():
     import jax
 
-    def kvcache_page_copy(kv, kc, dst, src):
+    def kvcache_page_copy(kv, kc, latent, dst, src):
         return (kv.at[:, :, :, dst].set(kv[:, :, :, src]),
-                kc.at[:, dst].set(kc[:, src]))
-    return jax.jit(kvcache_page_copy, donate_argnums=(0, 1))
+                kc.at[:, dst].set(kc[:, src]),
+                latent.at[:, dst].set(latent[:, src]))
+    return jax.jit(kvcache_page_copy, donate_argnums=(0, 1, 2))
 
 
 class LayeredCache:
@@ -101,6 +126,8 @@ class LayeredCache:
                          self.page_tokens, s.head_dim), bf16)
         self.kc = zeros((s.n_sparse, self.pages, 4, s.n_kv_heads,
                          s.head_dim), bf16)
+        self.latent = zeros((s.n_latent, self.pages, self.page_tokens,
+                             s.latent_lanes), bf16)
         self.scratch_row = s.state_rows
         self.zero_row = s.state_rows + 1
         self.state = zeros((s.state_rows + 2, s.n_linear, s.n_lin_heads,
@@ -157,14 +184,16 @@ class LayeredCache:
         return snap
 
     def copy_page(self, dst_flat: int, src_flat: int) -> None:
-        """K/V and compressed keys of one page, device to device (the
-        copy half of copy-on-write)."""
+        """K/V, compressed keys and latent rows of one page, device to
+        device (the copy half of copy-on-write)."""
         with self.lock:
-            self.kv, self.kc = _copy_page()(
-                self.kv, self.kc, np.int32(dst_flat), np.int32(src_flat))
+            self.kv, self.kc, self.latent = _copy_page()(
+                self.kv, self.kc, self.latent, np.int32(dst_flat),
+                np.int32(src_flat))
 
     def nbytes(self) -> int:
-        return int(self.kv.nbytes + self.kc.nbytes + self.state.nbytes)
+        return int(self.kv.nbytes + self.kc.nbytes + self.state.nbytes
+                   + self.latent.nbytes)
 
     def stats(self) -> dict:
         return {"pages": self.pages, "state_rows": self.spec.state_rows,
@@ -180,4 +209,4 @@ class LayeredCache:
             v = find_exposed(n)
             if v is not None:
                 v.hide()
-        self.kv = self.kc = self.state = None
+        self.kv = self.kc = self.state = self.latent = None

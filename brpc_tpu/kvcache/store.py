@@ -432,7 +432,8 @@ class KVCacheStore:
     def fork(self, seq: KVSeq) -> KVSeq:
         """A second sequence sharing every page of `seq` (divergent
         continuations isolate via copy-on-write on extend)."""
-        self._no_state_copy("fork")
+        if self._has_state():
+            self._no_state_copy("fork")
         with self._mu:
             if seq.retired:
                 raise RuntimeError(f"fork on retired seq {seq.seq_id}")
@@ -440,6 +441,7 @@ class KVCacheStore:
             child.tokens = list(seq.tokens)
             child.prefill_from = len(seq.tokens)
             child.kv_filled = min(seq.kv_filled, len(seq.tokens))
+            child.state_row = seq.state_row     # the stateless scratch row
             for p in seq.pages:
                 self.pagepool.ref(p)
                 child.pages.append(p)
@@ -673,7 +675,7 @@ class KVCacheStore:
         recurrent state at its boundary, so deeper pages could never
         be served."""
         nfull = min(len(seq.tokens), seq.kv_filled) // self.page_tokens
-        if self.layers is not None:
+        if self._has_state():
             deepest = max([seq.prefill_from // self.page_tokens]
                           + [n for n, _ in seq.snaps])
             nfull = min(nfull, deepest)
@@ -689,7 +691,7 @@ class KVCacheStore:
         (their K/V is recomputed with the state), and a match that is
         cut short so is a miss that counts (an admission's; a probe
         counts nothing).  An admitting caller holds ``_mu``."""
-        if self.layers is None:
+        if not self._has_state():
             return self.radix.match(tokens, max_chunks=max_chunks), None
         pages, snaps = self.radix.match(tokens, max_chunks=max_chunks,
                                         snapshots=True)
@@ -702,15 +704,26 @@ class KVCacheStore:
     def _install_state(self, seq: KVSeq, snapshot) -> None:
         """Give an admitted sequence its state row: the hit's snapshot
         restored into it, or zeros.  The snapshot's node cannot be
-        evicted meanwhile: the sequence holds a ref on its page."""
+        evicted meanwhile: the sequence holds a ref on its page.  A
+        cache that keeps no recurrent state gives every sequence the
+        scratch row: it is live, and there is nothing to restore."""
+        if not self.layers.spec.has_state:
+            seq.state_row = self.layers.scratch_row
+            return
         seq.state_row = self.layers.alloc_row()
         if snapshot is not None:
             self.layers.restore(seq.state_row, snapshot)
         else:
             self.layers.reset_row(seq.state_row)
 
+    def _has_state(self) -> bool:
+        """Whether a hit needs a recurrent state's snapshot beside its
+        pages."""
+        return self.layers is not None and self.layers.spec.has_state
+
     def _free_state(self, seq: KVSeq) -> None:
-        if self.layers is None:
+        if not self._has_state():
+            seq.state_row = None
             return
         self.layers.free_row(seq.state_row)
         seq.state_row = None
@@ -734,7 +747,8 @@ class KVCacheStore:
         if self.layers is not None:
             raise NotImplementedError(
                 f"{what}: a layered store does not copy or ship "
-                f"recurrent state yet (ROADMAP R8)")
+                f"recurrent state or its own device arrays' pages yet "
+                f"(ROADMAP R8)")
 
     def _copy_page(self, dst: KVPage, src: KVPage) -> None:
         self.pagepool.copy_page(dst, src)
@@ -747,6 +761,8 @@ class KVCacheStore:
         sequence's prefill should snapshot its state: the longest
         prefix a re-admit of the same prompt could hit; 0 where that
         lies at or before what was restored."""
+        if not self._has_state():
+            return 0
         b = (len(seq.tokens) - 1) // self.page_tokens * self.page_tokens
         return b if b > seq.prefill_from else 0
 

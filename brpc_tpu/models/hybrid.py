@@ -13,16 +13,27 @@ Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
   ``lightning-attn``  linear attention: a float32 ``[H, D, D]`` state a
                       layer a sequence (``ops.lightning``), restored from
                       a snapshot on a radix hit
+  ``mla``             latent attention (GLM-4.7-Flash, ISSUE 34): the
+                      cache holds ``[c_kv; k_rope]`` a token, ONE row
+                      and nothing per head; both programs attend in the
+                      absorbed form (``W_UK`` folded into the query,
+                      ``W_UV`` applied to the attended latents) over the
+                      latent pages (``ops.latent_attention``)
 
-around them learned RMS norms, per-head q/k norms, rotary positions on
-the lightning layers, output gates, a silu gated MLP, the muP scalings
-and an untied head.  The equations are those of
-``benchmarks/harness/reference_sala.py`` (the plain reference; the
-tier-1 tests hold this runner to it).
+and ``ffn_types`` its feed-forward: ``dense`` (the silu gated MLP) or
+``moe`` (a float32 sigmoid router over all experts, the top ``k`` of
+score + correction bias, the chosen scores normalised and scaled, the
+held experts' gated MLPs as ragged grouped matmuls with no capacity,
+``ops.moe``, and a shared expert).  Around them learned RMS norms,
+per-head q/k norms, rotary positions, output gates, the muP scalings
+where the family has them and an untied head.  The equations are those
+of ``benchmarks/harness/reference_sala.py`` and ``reference_glm.py``
+(the plain references; the tier-1 tests hold this runner to them).
 
 The cache is the store's :class:`~brpc_tpu.kvcache.layered.LayeredCache`:
-three persistent device arrays that the two jitted programs here
-(``jit_runner_hybrid_step``, ``jit_runner_hybrid_prefill``) take
+persistent device arrays, one a kind of state (a kind the model has no
+layer of is an array with no element), that the two jitted programs
+here (``jit_runner_hybrid_step``, ``jit_runner_hybrid_prefill``) take
 DONATED and return updated, fixed shapes throughout.
 
 Position contract (the engine's): ``step(tok, pos)`` computes position
@@ -38,7 +49,9 @@ bfloat16.  ``param_dtype="float32"`` (the CPU tests) multiplies at
 control and nothing a deployment sets: everything the configuration
 states in float32 and the program accumulates (every matmul's sum, the
 residual stream, the lightning state) then holds bfloat16 values, and
-the K/V pages the values of an int8 cache (scale 1/16).
+the K/V and latent pages the values of an int8 cache (scale 1/16), the
+router bfloat16.  ``control="drop"`` leaves the last chosen expert's
+share out of every routed sum.
 """
 from __future__ import annotations
 
@@ -53,7 +66,13 @@ from brpc_tpu import fault
 from brpc_tpu.bvar import Adder
 from brpc_tpu.models.runner import ModelRunner, TransformerConfig
 
-SPARSE, LINEAR = "minicpm4", "lightning-attn"
+SPARSE, LINEAR, MLA = "minicpm4", "lightning-attn", "mla"
+DENSE, MOE = "dense", "moe"
+# what ``layer_shapes`` gives in place of a fan-in for what is neither a
+# norm weight nor a matrix in the parameters' type: the float32 router
+# (normal(0, 1/d_model)) and its correction bias (normal(0, 0.1))
+ROUTER, BIAS = "router", "bias"
+PREFILL_ROWS = 16     # positions a grid row of the latent prefill kernel
 # a slot's "live" column of the step program's operand: its token comes
 # from the host, or from the result of the step before on the device
 LIVE, FED = 1, 2
@@ -63,12 +82,23 @@ LIVE, FED = 1, 2
 # parameters
 # ---------------------------------------------------------------------------
 
-def layer_shapes(cfg: TransformerConfig, kind: str) -> dict:
+def layer_shapes(cfg: TransformerConfig, kind: str,
+                 ffn: str = DENSE) -> dict:
     """``{name: (shape, fan_in or None for a norm weight)}`` of one
     layer, in the order the seeded init draws them."""
     dm, ff = cfg.d_model, cfg.d_ff
     out = {"norm1": ((dm,), None), "norm2": ((dm,), None)}
-    if kind == SPARSE:
+    if kind == MLA:
+        h, r, ql = cfg.n_heads, cfg.kv_lora_rank, cfg.q_lora_rank
+        nope, rope, v = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        # kv_b is held as its two factors a head, head-major: the
+        # absorbed form contracts each over its own minor dimensions
+        out.update(wq_a=((dm, ql), dm), q_norm=((ql,), None),
+                   wq_b=((ql, h * (nope + rope)), ql),
+                   wkv_a=((dm, r + rope), dm), kv_norm=((r,), None),
+                   w_uk=((h, nope, r), r), w_uv=((h, r, v), r),
+                   wo=((h * v, dm), h * v))
+    elif kind == SPARSE:
         hd, kvd, d = (cfg.n_heads * cfg.head_dim,
                       cfg.n_kv_heads * cfg.head_dim, cfg.head_dim)
         out.update(wq=((dm, hd), dm), wk=((dm, kvd), dm),
@@ -81,9 +111,24 @@ def layer_shapes(cfg: TransformerConfig, kind: str) -> dict:
                    wv=((dm, hd), dm), wg=((dm, hd), dm),
                    wo=((hd, dm), hd), q_norm=((d,), None),
                    k_norm=((d,), None), o_norm=((d,), None))
-    out.update(w_gate=((dm, ff), dm), w_up=((dm, ff), dm),
-               w_down=((ff, dm), ff))
+    if ffn == MOE:
+        n, fe = cfg.experts_held[1], cfg.moe_d_ff
+        fs = fe * cfg.n_shared_experts
+        out.update(router=((dm, cfg.n_experts), ROUTER),
+                   router_bias=((cfg.n_experts,), BIAS),
+                   we_gate=((n, dm, fe), dm), we_up=((n, dm, fe), dm),
+                   we_down=((n, fe, dm), fe), ws_gate=((dm, fs), dm),
+                   ws_up=((dm, fs), dm), ws_down=((fs, dm), fs))
+    else:
+        out.update(w_gate=((dm, ff), dm), w_up=((dm, ff), dm),
+                   w_down=((ff, dm), ff))
     return out
+
+
+def layer_kinds(cfg: TransformerConfig) -> list:
+    """``[(mixer kind, feed-forward kind)]`` of the held layers."""
+    ffns = cfg.ffn_types or (DENSE,) * len(cfg.mixer_types)
+    return list(zip(cfg.mixer_types, ffns))
 
 
 def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
@@ -103,26 +148,31 @@ def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
         out = {}
         for k, (name, (shape, fan_in)) in zip(ks, shapes.items()):
             x = jax.random.normal(k, shape, jnp.float32)
-            out[name] = (1.0 + 0.1 * x) if fan_in is None \
-                else (x / math.sqrt(fan_in)).astype(dt)
+            if fan_in is None:
+                out[name] = 1.0 + 0.1 * x
+            elif fan_in == BIAS:
+                out[name] = 0.1 * x
+            elif fan_in == ROUTER:
+                out[name] = x / math.sqrt(shape[0])
+            else:
+                out[name] = (x / math.sqrt(fan_in)).astype(dt)
         return out
 
     ks = jax.random.split(key, cfg.n_layers + 1)
     head_gain = cfg.d_model / cfg.dim_model_base if cfg.dim_model_base \
         else 1.0
+    kinds = layer_kinds(cfg)
     # built once a model at start-up, one program a kind of layer
     # brpc-check: allow(jit-hot-path)
     init = {kind: jax.jit(functools.partial(draw, shapes=shapes))
-            for kind, shapes in (
+            for kind, shapes in [
         ("top", {"emb": ((cfg.vocab, cfg.d_model), cfg.d_model),
                  "head": ((cfg.vocab, cfg.d_model),
                           cfg.d_model / head_gain ** 2),
-                 "norm_f": ((cfg.d_model,), None)}),
-        (SPARSE, layer_shapes(cfg, SPARSE)),
-        (LINEAR, layer_shapes(cfg, LINEAR)))}
+                 "norm_f": ((cfg.d_model,), None)})]
+            + [(k, layer_shapes(cfg, *k)) for k in sorted(set(kinds))]}
     top = init["top"](ks[0])
-    top["layers"] = [init[kind](k)
-                     for kind, k in zip(cfg.mixer_types, ks[1:])]
+    top["layers"] = [init[kind](k) for kind, k in zip(kinds, ks[1:])]
     return top
 
 
@@ -145,6 +195,41 @@ def _mm(x, w):
                       precision=jax.lax.Precision.DEFAULT)
     else:
         out = jnp.dot(x, w, precision="highest")
+    return _acc(getattr(_TRACING, "control", ""), out)
+
+
+def _bmm(spec: str, x, w):
+    """A batched product a head (``jnp.einsum``) at ``_mm``'s
+    precision."""
+    import jax
+    import jax.numpy as jnp
+    if w.dtype == jnp.bfloat16 and jax.default_backend() == "cpu" \
+            and getattr(_TRACING, "backend", None) != "mosaic":
+        # XLA's CPU backend has no batched bfloat16 dot: the same
+        # products (exact in float32) of the same bfloat16 values
+        out = jnp.einsum(spec, _to_bf16(x).astype(jnp.float32),
+                         w.astype(jnp.float32), precision="highest")
+    elif w.dtype == jnp.bfloat16:
+        out = jnp.einsum(spec, _to_bf16(x), w,
+                         preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.DEFAULT)
+    else:
+        out = jnp.einsum(spec, x, w, precision="highest")
+    return _acc(getattr(_TRACING, "control", ""), out)
+
+
+def _rmm(x, w, sizes):
+    """The ragged product of ``ops.moe.expert_ffn`` at ``_mm``'s
+    precision: rows of ``x`` in groups of ``sizes`` against ``w [E, K,
+    N]``."""
+    import jax
+    import jax.numpy as jnp
+    if w.dtype == jnp.bfloat16:
+        out = jax.lax.ragged_dot(_to_bf16(x), w, sizes,
+                                 preferred_element_type=jnp.float32,
+                                 precision=jax.lax.Precision.DEFAULT)
+    else:
+        out = jax.lax.ragged_dot(x, w, sizes, precision="highest")
     return _acc(getattr(_TRACING, "control", ""), out)
 
 
@@ -191,8 +276,9 @@ def _cache_round(x, control):
     return _to_bf16(x)
 
 
-# the control a program is being TRACED under (``step`` / ``prefill`` set
-# it first thing; it is a static argument of both, so one value a trace)
+# the control and the backend a program is being TRACED under (``step`` /
+# ``prefill`` set them first thing; they are static arguments of both, so
+# one value a trace)
 _TRACING = threading.local()
 
 
@@ -211,6 +297,75 @@ def _mlp(p, h, cfg, rs):
     x = _rms(h, p["norm2"], cfg.rms_eps)
     return h + rs * _mm(jax.nn.silu(_mm(x, p["w_gate"]))
                         * _mm(x, p["w_up"]), p["w_down"])
+
+
+def moe_share(p, x, cfg, valid):
+    """One expert layer's feed-forward of the normed rows ``x [N, dm]``:
+    ``(the held experts' share of the routed sum, the shared expert's
+    output, group sizes [held] of this call)``.  The router scores ALL
+    ``cfg.n_experts``; the share is of ``cfg.experts_held``."""
+    import jax
+    import jax.numpy as jnp
+    from brpc_tpu.ops.moe import expert_ffn, route
+    control = getattr(_TRACING, "control", "")
+    if control == "low":
+        logit = _acc(control, jnp.dot(
+            _to_bf16(x), _to_bf16(p["router"]),
+            preferred_element_type=jnp.float32))
+    else:
+        logit = jnp.dot(x, p["router"], precision="highest")
+    idx, w = route(jax.nn.sigmoid(logit), p["router_bias"],
+                   cfg.experts_per_tok, norm=cfg.norm_topk,
+                   scale=cfg.routed_scale)
+    if control == "drop":
+        w = w.at[:, -1].set(0.0)
+    y, sizes = expert_ffn(x, idx, w, valid, p["we_gate"], p["we_up"],
+                          p["we_down"], held=cfg.experts_held, mm=_rmm)
+    shared = _mm(jax.nn.silu(_mm(x, p["ws_gate"])) * _mm(x, p["ws_up"]),
+                 p["ws_down"])
+    return y, shared, sizes
+
+
+def _moe(p, h, cfg, valid):
+    """``h + routed share + shared expert`` and the experts this call
+    hit."""
+    y, shared, sizes = moe_share(p, _rms(h, p["norm2"], cfg.rms_eps), cfg,
+                                 valid)
+    return h + y + shared, (sizes > 0).sum()
+
+
+def _mla_project(p, x, qpos, cfg, lanes: int, control):
+    """Latent attention's two projections of the normed rows ``x [N,
+    dm]`` at positions ``qpos``: ``(absorbed queries [N, H, lanes],
+    scaled; the row the cache holds [N, lanes], at the cache's
+    values)``; lanes past ``kv_lora_rank + rope`` are zero."""
+    import jax.numpy as jnp
+    n, h = x.shape[0], cfg.n_heads
+    nope, rope, r = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
+    cq = _rms(_mm(x, p["wq_a"]), p["q_norm"], cfg.rms_eps)
+    q = _mm(cq, p["wq_b"]).reshape(n, h, nope + rope)
+    kv = _mm(x, p["wkv_a"])
+    c_kv = _rms(kv[:, :r], p["kv_norm"], cfg.rms_eps)
+    k_rope = _rope(kv[:, None, r:], qpos, cfg.rope_theta)[:, 0]
+    pad = lanes - r - rope
+    row = _cache_round(jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros((n, pad), jnp.float32)], axis=-1), control)
+    qq = jnp.concatenate(
+        [_bmm("nhd,hdc->nhc", q[..., :nope], p["w_uk"]),
+         _rope(q[..., nope:], qpos, cfg.rope_theta),
+         jnp.zeros((n, h, pad), jnp.float32)], axis=-1)
+    qq = qq / math.sqrt(nope + rope)
+    if p["wo"].dtype == jnp.bfloat16:
+        qq = _to_bf16(qq)        # a matmul input, like any other
+    return qq, row
+
+
+def _mla_out(p, o_lat, cfg):
+    """The attended latents ``[N, H, lanes]`` through ``W_UV`` and
+    ``W_o``."""
+    n = o_lat.shape[0]
+    o = _bmm("nhc,hcd->nhd", o_lat[..., :cfg.kv_lora_rank], p["w_uv"])
+    return _mm(o.reshape(n, cfg.n_heads * cfg.v_head_dim), p["wo"])
 
 
 def _logits(params, h, cfg):
@@ -350,11 +505,13 @@ def _attend_positions(q4, kv, ls, tables, kcs, qpos, live, cfg, backend):
 @functools.cache
 def _programs():
     """The two jitted programs, built at the first runner.  Neither
-    reads or writes the K/V arena through XLA operations: on the chip
-    ``cache_write``, ``page_keys`` and the attention kernel are Pallas
-    calls (``ops.sparse_attention`` says why)."""
+    reads or writes the K/V or the latent arena through XLA operations:
+    on the chip ``cache_write``, ``page_keys``, ``latent_write`` and the
+    attention kernels are Pallas calls (``ops.sparse_attention`` says
+    why)."""
     import jax
     import jax.numpy as jnp
+    from brpc_tpu.ops.latent_attention import latent_attend, latent_write
     from brpc_tpu.ops.lightning import (lightning_chunk, lightning_decode,
                                         log_decays)
     from brpc_tpu.ops.sparse_attention import (KERNELS_PER_PAGE,
@@ -363,19 +520,21 @@ def _programs():
 
     # ---- one decode position a slot --------------------------------------
 
-    def step(params, kv, kc, state, packed, prev, *, cfg, backend, control,
-             logits_out):
+    def step(params, kv, kc, state, packed, prev, latent=None, *, cfg,
+             backend, control, logits_out):
         """``packed [S, 4 + MPs]`` int32: a slot's token, position, state
         row, whether it is live (0 no, ``LIVE``, or ``FED``: live, and
         its token is row 0 of ``prev``, the step before's result, not
         ``packed[:, 0]``), then its page table.  ONE operand made on the
         host and one result (``[3, S]`` float32: next token, its
-        log-probability, blocks selected) a step: every device array a
+        log-probability, blocks selected; a model with expert layers
+        adds a row whose first value is the experts its layers hit) a
+        step: every device array a
         step makes and drops costs the engine thread a hand-off of the
         interpreter lock under load (PERF.md section 7, entry 15).
         ``FED`` is data: a step dispatched before the one ahead of it
         was fetched is the same program as any other."""
-        _TRACING.control = control
+        _TRACING.control, _TRACING.backend = control, backend
         positions, rows = packed[:, 1], packed[:, 2]
         tokens = jnp.where(packed[:, 3] == FED,
                            prev[0].astype(jnp.int32), packed[:, 0])
@@ -402,11 +561,25 @@ def _programs():
         win_at = (jc % KERNELS_PER_PAGE) * stride
         round_state = "bfloat16" if control == "low" else None
         h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
-        ls = ll = 0
+        ls = ll = lm = 0
         n_sel = jnp.zeros((s_n,), jnp.float32)
-        for p, kind in zip(params["layers"], cfg.mixer_types):
+        n_hit = jnp.zeros((), jnp.int32)
+        for p, (kind, ffn) in zip(params["layers"], layer_kinds(cfg)):
             x = _rms(h, p["norm1"], cfg.rms_eps)
-            if kind == SPARSE:
+            if kind == MLA:
+                qq, row = _mla_project(p, x, qpos, cfg, latent.shape[-1],
+                                       control)
+                latent = latent_write(latent, lm, page, qpos % t_page,
+                                      row[:, None, :], backend=backend)
+                seen = jnp.where(active, qpos + 1, 0)
+                o = latent_attend(
+                    qq, jnp.broadcast_to(seen[:, None, None],
+                                         (s_n, cfg.n_heads, 1)),
+                    latent, lm, jnp.arange(s_n, dtype=jnp.int32), tables,
+                    backend=backend)
+                h = _acc(control, h + rs * _mla_out(p, o, cfg))
+                lm += 1
+            elif kind == SPARSE:
                 q, k, v = _qkv(p, x, cfg.n_heads, hkv, cfg.head_dim, cfg)
                 kv = cache_write(
                     kv, ls, page, qpos % t_page,
@@ -447,23 +620,31 @@ def _programs():
                 h = _acc(control, h + rs * _gate_out(
                     p, x, o.reshape(s_n, hl * dl), cfg.lin_output_gate))
                 ll += 1
-            h = _acc(control, _mlp(p, h, cfg, rs))
+            if ffn == MOE:
+                h, hit = _moe(p, h, cfg, active)
+                h, n_hit = _acc(control, h), n_hit + hit
+            else:
+                h = _acc(control, _mlp(p, h, cfg, rs))
         logits = _logits(params, h, cfg)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         logprob = jnp.take_along_axis(
             jax.nn.log_softmax(logits, axis=-1), nxt[:, None], axis=1)[:, 0]
-        out = jnp.stack([nxt.astype(jnp.float32), logprob,
-                         n_sel / max(1, cfg.n_sparse)])
-        return (out, logits if logits_out else None, kv, kc, state)
+        out = [nxt.astype(jnp.float32), logprob,
+               n_sel / max(1, cfg.n_sparse)]
+        if cfg.n_moe:
+            out.append(jnp.zeros((s_n,), jnp.float32).at[0].set(
+                n_hit.astype(jnp.float32)))
+        return (jnp.stack(out), logits if logits_out else None, kv, kc,
+                state, latent)
 
     # ---- one prefill chunk of one sequence -------------------------------
 
-    def prefill(params, kv, kc, state, packed, *, cfg, backend, control,
-                logits_out, max_pages):
+    def prefill(params, kv, kc, state, packed, latent=None, *, cfg, backend,
+                control, logits_out, max_pages):
         """``packed`` int32: the chunk's start, its valid length, the
         sequence's state row, its page table ``[MPs]``, then the
         bucket's tokens (``MPs`` is the store's, static)."""
-        _TRACING.control = control
+        _TRACING.control, _TRACING.backend = control, backend
         mps = max_pages
         start, n_valid, row = packed[0], packed[1], packed[2]
         table, tokens = packed[3:3 + mps], packed[3 + mps:]
@@ -500,11 +681,30 @@ def _programs():
         k_page = jnp.where(k_done & (k_page >= 0), k_page, n_arena)
         qs = min(c, 64)              # positions a call of the kernel
         h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
-        ls = ll = 0
+        ls = ll = lm = 0
         n_sel = jnp.zeros((c,), jnp.float32)
-        for p, kind in zip(params["layers"], cfg.mixer_types):
+        # the latent kernel's grid rows: blocks of positions, every head
+        # of a block a row of ONE matmul against the page
+        pr = PREFILL_ROWS if c % PREFILL_ROWS == 0 else 1
+        for p, (kind, ffn) in zip(params["layers"], layer_kinds(cfg)):
             x = _rms(h, p["norm1"], cfg.rms_eps)
-            if kind == SPARSE:
+            if kind == MLA:
+                nh = cfg.n_heads
+                qq, row = _mla_project(p, x, qpos, cfg, latent.shape[-1],
+                                       control)
+                latent = latent_write(
+                    latent, lm, page, jnp.zeros((n_pg,), jnp.int32),
+                    row.reshape(n_pg, t_page, -1), backend=backend)
+                seen = jnp.where(valid, qpos + 1, 0)
+                o = latent_attend(
+                    qq.reshape(c // pr, pr * nh, -1),
+                    jnp.repeat(seen, nh).reshape(c // pr, pr * nh, 1),
+                    latent, lm, jnp.zeros((c // pr,), jnp.int32),
+                    table[None], backend=backend)
+                h = _acc(control, h + rs * _mla_out(
+                    p, o.reshape(c, nh, -1), cfg))
+                lm += 1
+            elif kind == SPARSE:
                 q, k, v = _qkv(p, x, cfg.n_heads, hkv, d, cfg)
                 before = page_keys(kv, ls, page_before[None],
                                    backend=backend)[0]       # [Hkv,T,D]
@@ -557,10 +757,13 @@ def _programs():
                 h = _acc(control, h + rs * _gate_out(
                     p, x, o.reshape(c, hl * dl), cfg.lin_output_gate))
                 ll += 1
-            h = _acc(control, _mlp(p, h, cfg, rs))
+            if ffn == MOE:
+                h = _acc(control, _moe(p, h, cfg, valid)[0])
+            else:
+                h = _acc(control, _mlp(p, h, cfg, rs))
         return (n_sel.sum() / max(1, cfg.n_sparse),
                 _logits(params, h, cfg) if logits_out else None,
-                kv, kc, state)
+                kv, kc, state, latent)
 
     def named(fn, scope, *more_statics):
         @functools.wraps(fn)
@@ -571,7 +774,7 @@ def _programs():
         scoped.__name__ = scoped.__qualname__ = scope.replace(".", "_")
         return jax.jit(scoped, static_argnames=(
             "cfg", "backend", "control", "logits_out") + more_statics,
-            donate_argnames=("kv", "kc", "state"))
+            donate_argnames=("kv", "kc", "state", "latent"))
     return {"step": named(step, "runner.hybrid_step"),
             "prefill": named(prefill, "runner.hybrid_prefill", "max_pages")}
 
@@ -586,22 +789,31 @@ def layered_spec(cfg: TransformerConfig, state_rows: int):
         n_sparse=cfg.n_sparse, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, n_linear=cfg.n_linear,
         n_lin_heads=cfg.lin_heads, lin_head_dim=cfg.lin_head_dim,
-        state_rows=int(state_rows))
+        state_rows=int(state_rows) if cfg.n_linear else 0,
+        n_latent=cfg.n_latent, latent_dim=cfg.latent_dim)
 
 
 def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
-                       state_rows: int, device=None, name: str = "kv"):
+                       state_rows: int = 0, page_tokens: int = 0,
+                       device=None, name: str = "kv"):
     """A :class:`KVCacheStore` for a described architecture: page ids,
-    refcounts and the radix tree as ever, ``cache_pages`` pages of
-    ``cfg.sparse_block`` tokens whose K/V (attention layers only),
-    compressed keys and ``state_rows`` recurrent-state rows live in the
-    store's :class:`LayeredCache`.  The pool's own blocks hold the
-    4-byte token-id stand-in only."""
+    refcounts and the radix tree as ever, ``cache_pages`` pages whose
+    K/V (sparse-attention layers), compressed keys, latent rows
+    (latent-attention layers) and ``state_rows`` recurrent-state rows
+    (linear layers) live in the store's :class:`LayeredCache`.  A page
+    holds ``cfg.sparse_block`` tokens where the model selects blocks
+    (one selection block a page), else ``page_tokens``.  The pool's own
+    blocks hold the 4-byte token-id stand-in only."""
     from brpc_tpu.ici.block_pool import BlockPool
     from brpc_tpu.kvcache import KVCacheStore
-    pt = cfg.sparse_block
-    if cfg.sparse_kernel != 2 * cfg.sparse_stride \
-            or pt != 4 * cfg.sparse_stride:
+    pt = cfg.sparse_block if cfg.n_sparse else int(page_tokens)
+    if pt <= 0 or (page_tokens and pt != page_tokens):
+        raise ValueError(
+            f"page_tokens {page_tokens}: a model with sparse-attention "
+            f"layers pages by its selection block ({cfg.sparse_block}), "
+            f"any other states its page")
+    if cfg.n_sparse and (cfg.sparse_kernel != 2 * cfg.sparse_stride
+                         or pt != 4 * cfg.sparse_stride):
         raise ValueError(
             "the cache's compressed-key index needs kernel_size = 2 x "
             "kernel_stride and block_size = 4 x kernel_stride")
@@ -650,9 +862,18 @@ class HybridRunner(ModelRunner):
         self.sparse_positions = Adder(f"runner_{safe}_sparse_positions")
         self.dense_positions = Adder(f"runner_{safe}_dense_positions")
         self.lightning_tokens = Adder(f"runner_{safe}_lightning_tokens")
-        self._bvar_names = [f"runner_{safe}_{n}" for n in (
-            "sparse_selected_blocks", "sparse_positions", "dense_positions",
-            "lightning_tokens")]
+        names = ["sparse_selected_blocks", "sparse_positions",
+                 "dense_positions", "lightning_tokens"]
+        # expert layers: (token, expert) pairs routed, and the distinct
+        # experts a layer a decode step hit, summed; latent layers: rows
+        # a decode step attended to (a layer), and the distinct pages
+        # they lie in
+        new = ("moe_assignments", "moe_experts_hit") * bool(cfg.n_moe) \
+            + ("latent_tokens_read", "latent_pages_distinct") \
+            * bool(cfg.n_latent)
+        for n in new:
+            setattr(self, n, Adder(f"runner_{safe}_{n}"))
+        self._bvar_names = [f"runner_{safe}_{n}" for n in names + list(new)]
         if store is not None:
             self.bind(store)
 
@@ -669,7 +890,8 @@ class HybridRunner(ModelRunner):
                 return
             if self.store is not None:
                 raise ValueError("runner already bound to a store")
-            if store.page_tokens != self.cfg.sparse_block:
+            if self.cfg.n_sparse \
+                    and store.page_tokens != self.cfg.sparse_block:
                 raise ValueError(
                     f"store pages hold {store.page_tokens} tokens, a "
                     f"selection block {self.cfg.sparse_block}")
@@ -708,11 +930,17 @@ class HybridRunner(ModelRunner):
 
     def _count(self, qpos, n_sel) -> None:
         """Counters of the positions a program just computed."""
-        dense = int((qpos + 1 <= self.cfg.sparse_dense_len).sum())
-        self.dense_positions.add(dense)
-        self.sparse_positions.add(len(qpos) - dense)
-        self.sparse_selected.add(float(n_sel))
-        self.lightning_tokens.add(len(qpos))
+        cfg = self.cfg
+        if cfg.n_sparse:
+            dense = int((qpos + 1 <= cfg.sparse_dense_len).sum())
+            self.dense_positions.add(dense)
+            self.sparse_positions.add(len(qpos) - dense)
+            self.sparse_selected.add(float(n_sel))
+        if cfg.n_linear:
+            self.lightning_tokens.add(len(qpos))
+        if cfg.n_moe:
+            self.moe_assignments.add(
+                len(qpos) * cfg.experts_per_tok * cfg.n_moe)
 
     # ---- the ModelRunner surface ----
 
@@ -744,10 +972,12 @@ class HybridRunner(ModelRunner):
             np.asarray([start, n, seq.state_row], np.int32), table,
             np.asarray(tokens, np.int32)])
         with lay.lock:
-            n_sel, out, lay.kv, lay.kc, lay.state = self._fns["prefill"](
-                self.params, lay.kv, lay.kc, lay.state, jnp.asarray(packed),
-                logits_out=bool(logits), max_pages=len(table),
-                **self._statics())
+            n_sel, out, lay.kv, lay.kc, lay.state, lay.latent = \
+                self._fns["prefill"](
+                    self.params, lay.kv, lay.kc, lay.state,
+                    jnp.asarray(packed), lay.latent,
+                    logits_out=bool(logits), max_pages=len(table),
+                    **self._statics())
         self.store.mark_filled(seq, start + n)
         # counted when the next step dispatched completes: the chunk has
         # run by then, and ``float(n_sel)`` here would hold the engine
@@ -789,14 +1019,26 @@ class HybridRunner(ModelRunner):
                 # placed as a step's own result is (committed, beside the
                 # cache): the same program whether a step came before
                 before = self._no_prev[n] = jax.device_put(
-                    np.zeros((3, n), np.float32), lay.kv.sharding)
+                    np.zeros((3 + bool(self.cfg.n_moe), n), np.float32),
+                    lay.kv.sharding)
         with lay.lock:
-            out, lg, lay.kv, lay.kc, lay.state = self._fns["step"](
-                self.params, lay.kv, lay.kc, lay.state, jnp.asarray(packed),
-                before, logits_out=bool(logits), **self._statics())
+            out, lg, lay.kv, lay.kc, lay.state, lay.latent = \
+                self._fns["step"](
+                    self.params, lay.kv, lay.kc, lay.state,
+                    jnp.asarray(packed), before, lay.latent,
+                    logits_out=bool(logits), **self._statics())
         owed, self._uncounted = self._uncounted, []
-        return {"out": out, "logits": lg, "live": live, "seqs": seqs,
-                "positions": np.asarray(positions), "prefills": owed}
+        handle = {"out": out, "logits": lg, "live": live, "seqs": seqs,
+                  "positions": np.asarray(positions), "prefills": owed}
+        if self.cfg.n_latent:
+            # the pages the live slots' rows lie in, each once however
+            # many slots share it: what a step must read of the cache
+            t = self.store.page_tokens
+            used = [packed[i, 4:4 + -(-int(packed[i, 1]) // t)]
+                    for i in np.flatnonzero(live)]
+            handle["latent_pages"] = len(np.unique(np.concatenate(used))) \
+                if used else 0
+        return handle
 
     def complete_step(self, handle):
         """Fetch a dispatched step's result and book its positions
@@ -810,6 +1052,13 @@ class HybridRunner(ModelRunner):
         for qpos, n_sel in handle["prefills"]:
             self._count(qpos, n_sel)
         self._count(positions[live] - 1, out[2][live].sum())
+        if self.cfg.n_moe:
+            self.moe_experts_hit.add(int(out[3][0]))
+        if self.cfg.n_latent:
+            self.latent_tokens_read.add(
+                int(positions[live].sum()) * self.cfg.n_latent)
+            self.latent_pages_distinct.add(
+                handle["latent_pages"] * self.cfg.n_latent)
         return out[0].astype(np.int32), None, out[1]
 
     def step(self, tokens, positions, pages, seqs=None):

@@ -1,6 +1,12 @@
-"""Mixture-of-Experts block with expert parallelism over the device mesh.
+"""Mixture-of-Experts block with expert parallelism over the device mesh:
+the TRAINING-side layer (top-1 routing, a fixed capacity an expert, tokens
+over it dropped, the exchange across chips inside ``shard_map``).  No
+serving path calls it: the served models' routed experts (a sigmoid
+router with a correction bias, top-k, no capacity and no dropped token,
+ragged grouped matmuls inside the decode step) are ``brpc_tpu.ops.moe``,
+called from ``brpc_tpu.models.hybrid``.
 
-The second flagship model family: a Switch-style top-1 MoE layer whose
+A Switch-style top-1 MoE layer whose
 experts shard over an ``ep`` mesh axis and whose token dispatch rides
 ``lax.all_to_all`` inside ``shard_map`` — the canonical TPU MoE recipe
 (GShard/Switch): static-shape one-hot dispatch einsums (no dynamic

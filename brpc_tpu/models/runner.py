@@ -250,8 +250,10 @@ class TransformerConfig:
     :func:`from_hf_config`: ``mixer_types`` names each held layer's
     mixer (``"minicpm4"``: learned block-sparse attention over paged
     K/V; ``"lightning-attn"``: linear attention with a recurrent
-    state) and :class:`~brpc_tpu.models.hybrid.HybridRunner` serves
-    it."""
+    state; ``"mla"``: latent attention over pages of one compressed
+    row a token), ``ffn_types`` its feed-forward (``"dense"``: the
+    gated MLP; ``"moe"``: routed experts and a shared one), and
+    :class:`~brpc_tpu.models.hybrid.HybridRunner` serves it."""
     vocab: int = 128
     d_model: int = 32
     n_layers: int = 2
@@ -286,6 +288,21 @@ class TransformerConfig:
     sparse_init_blocks: int = 1
     sparse_window: int = 2048
     sparse_dense_len: int = 8192
+    # latent attention (ISSUE 34): the cache holds [c_kv; k_rope] a token
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # feed-forward kind a held layer; () is the gated MLP everywhere
+    ffn_types: tuple = ()
+    n_experts: int = 0              # routed experts the router scores
+    experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    moe_d_ff: int = 0               # width of one expert, shared alike
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    experts_held: tuple = ()        # (first, count) computed here
 
     @property
     def kv_bytes_per_token(self) -> int:
@@ -296,8 +313,8 @@ class TransformerConfig:
         (``kvcache.layered``): bf16 K/V for its attention layers only,
         and this is then that figure."""
         if self.mixer_types:
-            return (self.n_sparse * 2 * self.n_kv_heads
-                    * self.head_dim * 2)
+            return (self.n_sparse * 2 * self.n_kv_heads * self.head_dim
+                    + self.n_latent * self.latent_dim) * 2
         return self.n_layers * 2 * self.n_kv_heads * self.head_dim * 4
 
     @property
@@ -309,39 +326,79 @@ class TransformerConfig:
         return sum(1 for m in self.mixer_types if m == "lightning-attn")
 
     @property
+    def n_latent(self) -> int:
+        return sum(1 for m in self.mixer_types if m == "mla")
+
+    @property
+    def latent_dim(self) -> int:
+        """One cached row: the compressed K/V and the shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def n_moe(self) -> int:
+        return sum(1 for f in self.ffn_types if f == "moe")
+
+    @property
     def residual_scale(self) -> float:
-        if not self.depth_published:
+        """muP's depth scaling, where the family has it (it states
+        ``dim_model_base``)."""
+        if not (self.depth_published and self.dim_model_base):
             return 1.0
         return self.scale_depth / math.sqrt(self.depth_published)
 
     def layer_param_counts(self) -> dict:
         """Parameters of one MLP, one mixer of each kind (norm weights
-        left out) and the embedding."""
+        left out), one expert layer's feed-forward (every routed expert,
+        the shared ones and the router) and the embedding."""
         dm = self.d_model
         hd = self.n_heads * self.head_dim
         kvd = self.n_kv_heads * self.head_dim
         lin = self.lin_heads * self.lin_head_dim
+        h = self.n_heads
         return {"mlp": 3 * dm * self.d_ff,
                 "minicpm4": dm * hd * (2 + int(self.attn_output_gate))
                 + 2 * dm * kvd,
                 "lightning-attn": dm * lin * (4
                                               + int(self.lin_output_gate)),
+                "mla": dm * self.q_lora_rank + self.q_lora_rank * h
+                * (self.qk_nope_dim + self.qk_rope_dim)
+                + dm * self.latent_dim + self.kv_lora_rank * h
+                * (self.qk_nope_dim + self.v_head_dim)
+                + h * self.v_head_dim * dm,
+                "moe": 3 * dm * self.moe_d_ff
+                * (self.n_experts + self.n_shared_experts)
+                + dm * self.n_experts,
                 "embedding": self.vocab * dm}
 
 
-MIXER_KINDS = ("minicpm4", "lightning-attn")
+MIXER_KINDS = ("minicpm4", "lightning-attn", "mla")
 
 
 def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
                    sparse: Optional[dict] = None,
+                   experts: Optional[tuple] = None,
                    param_dtype: str = "bfloat16") -> TransformerConfig:
     """A :class:`TransformerConfig` from a published ``config.json``'s
-    keys, taken verbatim (MiniCPM-SALA's today).  ``layers`` =
+    keys, taken verbatim: a file that names its ``mixer_types``
+    (MiniCPM-SALA's), or ``model_type`` ``glm4_moe_lite`` (latent
+    attention in every layer, ``first_k_dense_replace`` gated MLPs and
+    then routed experts); any other raises.  ``layers`` =
     ``(first, count)`` holds a contiguous slice of the published
     layers (a pipeline stage); ``sparse`` gives what the published
     file does not carry (``kernel_size``, ``kernel_stride``,
     ``block_size``, ``topk``, ``init_blocks``, ``window_size``,
-    ``dense_len``)."""
+    ``dense_len``); ``experts`` = ``(first, count)`` are the routed
+    experts this chip computes (all of them where it is None)."""
+    if "mixer_types" not in hf:
+        if hf.get("model_type") != "glm4_moe_lite":
+            raise ValueError(
+                f"no description of model_type {hf.get('model_type')!r}: "
+                f"a config names its mixer_types or is glm4_moe_lite")
+        if sparse:
+            raise ValueError("glm4_moe_lite has no sparse settings")
+        return _from_glm4_moe_lite(hf, layers, experts, param_dtype)
+    if experts is not None:
+        raise ValueError("this family has no routed experts")
     mixers = tuple(hf["mixer_types"])
     unknown = sorted(set(mixers) - set(MIXER_KINDS))
     if unknown:
@@ -385,6 +442,56 @@ def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
         dim_model_base=int(hf["dim_model_base"]),
         tie_embeddings=bool(hf["tie_word_embeddings"]),
         param_dtype=param_dtype, **fields)
+
+
+def _from_glm4_moe_lite(hf: dict, layers, experts,
+                        param_dtype: str) -> TransformerConfig:
+    """GLM-4.7-Flash's keys.  What this runner does not compute raises:
+    grouped routing (``n_group`` / ``topk_group`` other than 1 are not
+    the identity), scaled rotary positions, biases, a partial rotary
+    factor."""
+    for key, want in (("hidden_act", "silu"), ("topk_method", "noaux_tc"),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("rope_scaling", None), ("attention_bias", False),
+                      ("partial_rotary_factor", 1)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"glm4_moe_lite with {key}={hf[key]!r} is "
+                             f"not described (only {want!r})")
+    if int(hf["num_key_value_heads"]) != int(hf["num_attention_heads"]):
+        raise ValueError("latent attention has one K/V head a query head")
+    depth = int(hf["num_hidden_layers"])
+    first, count = layers if layers is not None else (0, depth)
+    if first < 0 or first + count > depth:
+        raise ValueError(f"layers {first}+{count} exceed {depth}")
+    dense = int(hf["first_k_dense_replace"])
+    n_exp = int(hf["n_routed_experts"])
+    e_first, e_count = experts if experts is not None else (0, n_exp)
+    if e_first < 0 or e_count < 1 or e_first + e_count > n_exp:
+        raise ValueError(f"experts {e_first}+{e_count} exceed {n_exp}")
+    rope = int(hf["qk_rope_head_dim"])
+    return TransformerConfig(
+        vocab=int(hf["vocab_size"]), d_model=int(hf["hidden_size"]),
+        n_layers=count, n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["qk_nope_head_dim"]) + rope,
+        d_ff=int(hf["intermediate_size"]),
+        mixer_types=("mla",) * count,
+        ffn_types=tuple("dense" if first + i < dense else "moe"
+                        for i in range(count)),
+        depth_published=depth, layer_offset=first,
+        rope_theta=float(hf["rope_theta"]),
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        q_lora_rank=int(hf["q_lora_rank"]),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        qk_nope_dim=int(hf["qk_nope_head_dim"]), qk_rope_dim=rope,
+        v_head_dim=int(hf["v_head_dim"]), n_experts=n_exp,
+        experts_per_tok=int(hf["num_experts_per_tok"]),
+        n_shared_experts=int(hf["n_shared_experts"]),
+        moe_d_ff=int(hf["moe_intermediate_size"]),
+        routed_scale=float(hf["routed_scaling_factor"]),
+        norm_topk=bool(hf["norm_topk_prob"]),
+        experts_held=(e_first, e_count), param_dtype=param_dtype)
 
 
 def init_runner_params(cfg: TransformerConfig, key=None) -> dict:

@@ -302,12 +302,14 @@ def _write_kernel(page_ref, slot_ref, ok_ref, new_ref, kv_ref, out_ref, *,
                   width: int):
     from jax.experimental import pallas as pl
     i = pl.program_id(0)
-    old = kv_ref[...].astype(jnp.float32)                    # [2,Hkv,T,D]
-    new = new_ref[...].astype(jnp.float32)                   # [2,Hkv,W,D]
-    rows = jax.lax.broadcasted_iota(jnp.int32, old.shape, 2)
+    # a block is [..., T, D]: [2, Hkv, T, D] of the K/V arena, [T, C] of
+    # the latent one (``ops.latent_attention`` calls this kernel too)
+    old = kv_ref[...].astype(jnp.float32)
+    new = new_ref[...].astype(jnp.float32)                   # [..., W, D]
+    rows = jax.lax.broadcasted_iota(jnp.int32, old.shape, old.ndim - 2)
     hit = (rows >= slot_ref[i]) & (rows < slot_ref[i] + width) \
         & (ok_ref[i] > 0)
-    if width != old.shape[2]:
+    if width != old.shape[-2]:
         new = jnp.broadcast_to(new, old.shape)
     out_ref[...] = jnp.where(hit, new, old).astype(out_ref.dtype)
 

@@ -279,3 +279,102 @@ def test_sala_question_prefill_compiles_without_copying_the_arena(sds):
         max_pages=mp, **statics).compile()
     assert _has_kernel(compiled)
     assert _arena_copies(compiled, c["cache_pages"]) == []
+
+
+def _glm_programs(sds):
+    import json
+    import os
+    from brpc_tpu.kvcache.layered import LayeredSpec
+    from brpc_tpu.models import hybrid
+    from brpc_tpu.models.runner import from_hf_config
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "glm47_flash_l8_1chip.json")
+    with open(path) as f:
+        c = json.load(f)
+    cfg = from_hf_config(
+        dict(c, num_hidden_layers=c["published_num_hidden_layers"]),
+        layers=(c["first_published_layer"], c["num_hidden_layers"]),
+        param_dtype=c["param_dtype"])
+
+    def shaped(shapes):
+        return {n: sds(s, jnp.bfloat16 if isinstance(fan, (int, float))
+                       else jnp.float32) for n, (s, fan) in shapes.items()}
+    params = shaped({"emb": ((cfg.vocab, cfg.d_model), 1),
+                     "head": ((cfg.vocab, cfg.d_model), 1),
+                     "norm_f": ((cfg.d_model,), None)})
+    params["layers"] = [shaped(hybrid.layer_shapes(cfg, *kinds))
+                        for kinds in hybrid.layer_kinds(cfg)]
+    pages, t = c["cache_pages"], c["page_tokens"]
+    lanes = LayeredSpec(0, 0, 0, 0, 0, 0, 0, n_latent=cfg.n_latent,
+                        latent_dim=cfg.latent_dim).latent_lanes
+    # the kinds this model has no layer of: arrays with no element
+    caches = (sds((0, 2, 20, pages, t, 256), jnp.bfloat16),
+              sds((0, pages, 4, 20, 256), jnp.bfloat16),
+              sds((2, 0, 0, 0, 0), jnp.float32))
+    latent = sds((cfg.n_latent, pages, t, lanes), jnp.bfloat16)
+    statics = dict(cfg=cfg, backend="mosaic", control="")
+    return c, hybrid._programs(), params, caches, latent, statics
+
+
+def _latent_copies(compiled, latent) -> list:
+    """Copies of the whole latent arena in a compiled program (a row of
+    576 lanes, not whole tiles, got the arena another layout and 1 GB
+    copied each way around every kernel call: found before the first
+    chip run of PR 34)."""
+    shape = "bf16[" + ",".join(str(d) for d in latent.shape) + "]"
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line and f"= {shape}" in line]
+
+
+def test_glm_decode_step_compiles_at_published_widths_and_fits(sds):
+    """``glm47_flash_l8_1chip``'s decode step for a described v5e: the
+    latent write and attention of 8 layers and the three ragged products
+    of 7 expert layers are custom calls, the latent arena is copied
+    nowhere, its rows are whole tiles, and weights + cache + temporaries
+    fit the chip."""
+    c, fns, params, caches, latent, statics = _glm_programs(sds)
+    assert latent.shape == (8, 1536, 64, 640)
+    s, mp = c["num_slots"], c["max_pages_per_slot"]
+    compiled = fns["step"].lower(
+        params, *caches, sds((s, 4 + mp), jnp.int32),
+        sds((4, s), jnp.float32), latent, logits_out=False,
+        **statics).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 8 * 2 + 7 * 3
+    assert _latent_copies(compiled, latent) == []
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 11e9 < need <= 0.9 * 16 * 2**30, \
+        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+    assert mem.temp_size_in_bytes < 0.2e9
+
+
+def test_glm_prefill_compiles_without_copying_the_arena(sds):
+    """Both prefill buckets of ``glm_agent_turns`` (every request of the
+    window runs one of them through the experts)."""
+    c, fns, params, caches, latent, statics = _glm_programs(sds)
+    mp = c["max_pages_per_slot"]
+    for bucket in c["prefill_buckets"]:
+        compiled = fns["prefill"].lower(
+            params, *caches, sds((3 + mp + bucket,), jnp.int32), latent,
+            logits_out=False, max_pages=mp, **statics).compile()
+        assert compiled.as_text().count("tpu_custom_call") >= 8 * 2 + 7 * 3
+        assert _latent_copies(compiled, latent) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_glm_latent_rows_of_the_published_width_would_be_copied(sds):
+    """Why the arena's rows are 640 lanes and not the 576 the model
+    caches: at 576 the chip's compiler gives the array another layout
+    than the kernel reads and copies it around the call."""
+    from brpc_tpu.ops.latent_attention import latent_write
+    for lanes, copied in ((576, True), (640, False)):
+        latent = sds((8, 1536, 64, lanes), jnp.bfloat16)
+        compiled = jax.jit(
+            lambda lat, pg, sl, rows: latent_write(lat, 3, pg, sl, rows,
+                                                   backend="mosaic"),
+            donate_argnums=0).lower(
+            latent, sds((16,), jnp.int32), sds((16,), jnp.int32),
+            sds((16, 1, lanes), jnp.float32)).compile()
+        assert _has_kernel(compiled)
+        assert bool(_latent_copies(compiled, latent)) is copied
