@@ -18,7 +18,9 @@ Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
                       and nothing per head; both programs attend in the
                       absorbed form (``W_UK`` folded into the query,
                       ``W_UV`` applied to the attended latents) over the
-                      latent pages (``ops.latent_attention``)
+                      latent pages (``ops.latent_attention``); the decode
+                      step reads the run of pages its slots hold in
+                      common once for all of them (ISSUE 35)
 
 and ``ffn_types`` its feed-forward: ``dense`` (the silu gated MLP) or
 ``moe`` (a float32 sigmoid router over all experts, the top ``k`` of
@@ -511,7 +513,9 @@ def _programs():
     why)."""
     import jax
     import jax.numpy as jnp
-    from brpc_tpu.ops.latent_attention import latent_attend, latent_write
+    from brpc_tpu.ops.latent_attention import (latent_attend,
+                                               latent_attend_slots,
+                                               latent_write)
     from brpc_tpu.ops.lightning import (lightning_chunk, lightning_decode,
                                         log_decays)
     from brpc_tpu.ops.sparse_attention import (KERNELS_PER_PAGE,
@@ -525,7 +529,10 @@ def _programs():
         """``packed [S, 4 + MPs]`` int32: a slot's token, position, state
         row, whether it is live (0 no, ``LIVE``, or ``FED``: live, and
         its token is row 0 of ``prev``, the step before's result, not
-        ``packed[:, 0]``), then its page table.  ONE operand made on the
+        ``packed[:, 0]``), then its page table; a model with latent
+        layers has two columns more ahead of the table, the keys at the
+        head of it that the slot shares with the leader row and that
+        row (``ops.latent_attention.shared_run``).  ONE operand made on the
         host and one result (``[3, S]`` float32: next token, its
         log-probability, blocks selected; a model with expert layers
         adds a row whose first value is the experts its layers hit) a
@@ -538,7 +545,8 @@ def _programs():
         positions, rows = packed[:, 1], packed[:, 2]
         tokens = jnp.where(packed[:, 3] == FED,
                            prev[0].astype(jnp.int32), packed[:, 0])
-        active, tables = packed[:, 3] > 0, packed[:, 4:]
+        active = packed[:, 3] > 0
+        tables = packed[:, 4 + 2 * bool(cfg.n_latent):]
         t_page = kv.shape[4]
         n_arena = kv.shape[3]
         stride = t_page // KERNELS_PER_PAGE
@@ -571,12 +579,9 @@ def _programs():
                                        control)
                 latent = latent_write(latent, lm, page, qpos % t_page,
                                       row[:, None, :], backend=backend)
-                seen = jnp.where(active, qpos + 1, 0)
-                o = latent_attend(
-                    qq, jnp.broadcast_to(seen[:, None, None],
-                                         (s_n, cfg.n_heads, 1)),
-                    latent, lm, jnp.arange(s_n, dtype=jnp.int32), tables,
-                    backend=backend)
+                o = latent_attend_slots(
+                    qq, jnp.where(active, qpos + 1, 0), packed[:, 4],
+                    packed[:1, 5], latent, lm, tables, backend=backend)
                 h = _acc(control, h + rs * _mla_out(p, o, cfg))
                 lm += 1
             elif kind == SPARSE:
@@ -853,6 +858,8 @@ class HybridRunner(ModelRunner):
         self._mu = threading.Lock()
         self._fns = _programs()
         self._table_cache: dict = {}  # seq id -> (table's key, arena indices)
+        self._rows: list = []         # the last step's rows of it, a slot
+        self._run = None              # (leader, keys shared a slot) of them
         self._no_prev: dict = {}      # slots -> zeros [3, S] on the device
         # prefill chunks dispatched and not counted yet: the engine
         # thread does not wait for a prefill to run (see prefill)
@@ -867,9 +874,12 @@ class HybridRunner(ModelRunner):
         # expert layers: (token, expert) pairs routed, and the distinct
         # experts a layer a decode step hit, summed; latent layers: rows
         # a decode step attended to (a layer), and the distinct pages
-        # they lie in
+        # they lie in, beside the pages the kernel fetched for them (a
+        # layer, whole key blocks, by either pass) and how many of a
+        # slot-by-slot pass's fetches the shared pass stood in for
         new = ("moe_assignments", "moe_experts_hit") * bool(cfg.n_moe) \
-            + ("latent_tokens_read", "latent_pages_distinct") \
+            + ("latent_tokens_read", "latent_pages_distinct",
+               "latent_page_visits", "latent_page_visits_shared") \
             * bool(cfg.n_latent)
         for n in new:
             setattr(self, n, Adder(f"runner_{safe}_{n}"))
@@ -925,6 +935,13 @@ class HybridRunner(ModelRunner):
                 hit = (key, self._flat_tables(pages[i]))
             live[s.seq_id] = hit
             out[i] = hit[1]
+        # the run the slots share is read off these rows (dispatch_step):
+        # it stands while every slot holds the row it held
+        rows = [live[s.seq_id][1] if s is not None else None
+                for s in seqs or ()]
+        if len(rows) != len(self._rows) or any(
+                a is not b for a, b in zip(rows, self._rows)):
+            self._rows, self._run = rows, None
         self._table_cache = live
         return out
 
@@ -1001,7 +1018,8 @@ class HybridRunner(ModelRunner):
             raise RuntimeError("injected model step-compute failure")
         lay = self.store.layers
         n = len(tokens)
-        packed = np.empty((n, 4 + np.shape(pages)[1]), np.int32)
+        head = 4 + 2 * bool(self.cfg.n_latent)
+        packed = np.empty((n, head + np.shape(pages)[1]), np.int32)
         packed[:, 0], packed[:, 1] = tokens, positions
         packed[:, 2], packed[:, 3] = lay.scratch_row, 0
         for i, s in enumerate(seqs or ()):
@@ -1010,7 +1028,16 @@ class HybridRunner(ModelRunner):
         live = packed[:, 3] > 0
         if fed is not None:
             packed[live & np.asarray(fed, bool), 3] = FED
-        packed[:, 4:] = self._slot_tables(pages, seqs)
+        tables = packed[:, head:] = self._slot_tables(pages, seqs)
+        if self.cfg.n_latent:
+            from brpc_tpu.ops.latent_attention import page_visits, shared_run
+            t = self.store.page_tokens
+            seen = np.where(live, np.maximum(packed[:, 1], 1), 0)
+            # found again only when a table row changed: between those a
+            # slot's ``seen`` only grows, so what it shares stays shared
+            if self._run is None:
+                self._run = shared_run(tables, seen, t)
+            packed[:, 5], packed[:, 4] = self._run
         if prev is not None:
             before = prev["out"]
         else:
@@ -1033,11 +1060,11 @@ class HybridRunner(ModelRunner):
         if self.cfg.n_latent:
             # the pages the live slots' rows lie in, each once however
             # many slots share it: what a step must read of the cache
-            t = self.store.page_tokens
-            used = [packed[i, 4:4 + -(-int(packed[i, 1]) // t)]
+            used = [tables[i, :-(-int(seen[i]) // t)]
                     for i in np.flatnonzero(live)]
             handle["latent_pages"] = len(np.unique(np.concatenate(used))) \
                 if used else 0
+            handle["latent_visits"] = page_visits(seen, packed[:, 4], t)
         return handle
 
     def complete_step(self, handle):
@@ -1059,6 +1086,9 @@ class HybridRunner(ModelRunner):
                 int(positions[live].sum()) * self.cfg.n_latent)
             self.latent_pages_distinct.add(
                 handle["latent_pages"] * self.cfg.n_latent)
+            visits, stood_in = handle["latent_visits"]
+            self.latent_page_visits.add(visits * self.cfg.n_latent)
+            self.latent_page_visits_shared.add(stood_in * self.cfg.n_latent)
         return out[0].astype(np.int32), None, out[1]
 
     def step(self, tokens, positions, pages, seqs=None):

@@ -333,6 +333,73 @@ def test_a_warm_request_equals_a_cold_one_logit_for_logit(model):
     rig.close()
 
 
+@pytest.mark.parametrize("backend", [None, "pallas"])
+def test_slots_on_one_prompt_decode_as_on_private_copies(model, backend):
+    """Four slots admitted through the radix tree on one prompt: the
+    step reads the nine pages they share once, all their heads in one
+    pass, then each slot's own tail (``ops.latent_attention``), and gives
+    the logits and tokens of the same four sequences on private copies
+    of every page; the counters read what the tables say."""
+    from brpc_tpu.ops.latent_attention import PAGES_PER_STEP, page_visits
+    cfg, _, params = model
+    doc = tokens_of(150, seed=11)               # 9 whole pages and 6 over
+    asks = [tokens_of(n, seed=12 + i) for i, n in enumerate((5, 23, 2, 40))]
+
+    def four_slots(rig):
+        seqs = [rig.store.admit(doc + a) for a in asks]
+        for seq, a in zip(seqs, asks):
+            rig.prefill(seq, doc + a)
+        pos = np.asarray([len(doc + a) for a in asks], np.int32)
+        tabs = np.stack([rig.table(seq) for seq in seqs])
+        r = rig.runner
+        before = (r.latent_page_visits.get_value(),
+                  r.latent_page_visits_shared.get_value())
+        logits = np.asarray(r.step_logits(
+            np.asarray([a[-1] for a in asks], np.int32), pos, tabs,
+            seqs=seqs))
+        after = (r.latent_page_visits.get_value(),
+                 r.latent_page_visits_shared.get_value())
+        # and the position after it, no table row changed: the run the
+        # runner found for the first step stands
+        found = r._run
+        nxt = logits.argmax(axis=-1).astype(np.int32)
+        for seq, tok in zip(seqs, nxt):
+            rig.store.extend(seq, int(tok))
+        assert np.array_equal(
+            np.stack([rig.table(seq) for seq in seqs]), tabs)
+        again = np.asarray(r.step_logits(nxt, pos + 1, tabs, seqs=seqs))
+        assert r._run is found
+        return (seqs, pos, np.stack([logits, again]),
+                (after[0] - before[0], after[1] - before[1]))
+
+    shared = Rig(cfg, params, f"g_one_prompt_{backend}", backend=backend)
+    first = shared.store.admit(doc + [3, 4])
+    shared.prefill(first, doc + [3, 4])
+    shared.store.retire(first)
+    seqs, pos, got, counted = four_slots(shared)
+    assert [seq.prefill_from for seq in seqs] == [9 * T] * 4
+    assert len({seq.pages[8].pid for seq in seqs}) == 1
+    private = Rig(cfg, params, f"g_private_{backend}", backend=backend)
+    seqs_p, _, want, counted_p = four_slots(private)
+    assert [seq.prefill_from for seq in seqs_p] == [0] * 4
+    assert np.abs(got - want).max() < (TOL if backend is None else 6e-2)
+    assert got.argmax(axis=-1).tolist() == want.argmax(axis=-1).tolist()
+    assert got.shape == (2, 4, 256)
+    # a key block is 8 pages of 16: one block of the nine shared pages
+    # is read once for all four slots, the ninth page with each tail
+    block = PAGES_PER_STEP * T
+    own = sum(-(-int(n) // block) for n in pos)
+    assert counted == (((own - 4) + 1) * PAGES_PER_STEP * HELD,
+                       4 * PAGES_PER_STEP * HELD)
+    assert counted == tuple(HELD * v for v in page_visits(
+        pos, np.full((4,), block, np.int32), T))
+    assert counted_p == ((own + 1) * PAGES_PER_STEP * HELD, 0)
+    for rig, held in ((shared, seqs), (private, seqs_p)):
+        for seq in held:
+            rig.store.retire(seq, cache=False)
+        rig.close()
+
+
 def test_a_shared_tail_page_is_copied_before_it_is_written(model):
     """Copy-on-write over the latent array: a fork shares a half-full
     tail page; each side's next position copies it first, and both then
