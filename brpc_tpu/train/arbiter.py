@@ -341,6 +341,7 @@ class MixedWorkloadHarness:
             arbiter=self.arbiter, seed=self.seed,
             name=f"{self.name}_trainer")
         self.trainer.seed_dense(self._dense0)
+        self._delay_seen: dict = {}   # batcher -> (count, sum_us) last read
         self._closed = False
 
     # ---- fleet construction / teardown ----
@@ -444,9 +445,18 @@ class MixedWorkloadHarness:
                 st = b.stats()
                 out["queue_depth"] = max(out["queue_depth"],
                                          float(st["queued"]))
-                out["queue_delay_us"] = max(
-                    out.get("queue_delay_us", 0.0),
-                    float(b.queue_delay_rec.latency_percentile(0.99)))
+                # the mean delay of what waited SINCE THE LAST LOOK.  The
+                # recorder's percentile stands over every sample it ever
+                # took, and a run gives it a handful: one slow batch (or a
+                # killed server's last) held the trainer shed to its
+                # deadline
+                n, us, _ = b.queue_delay_rec.snapshot()
+                n0, us0 = self._delay_seen.get(id(b), (0, 0))
+                self._delay_seen[id(b)] = (n, us)
+                if n > n0:
+                    out["queue_delay_us"] = max(
+                        out.get("queue_delay_us", 0.0),
+                        (us - us0) / (n - n0))
             except Exception:
                 pass
         for store, _eng, _srv, _addr in self.replicas:

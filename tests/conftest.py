@@ -4,6 +4,11 @@ Tests run on a virtual 8-device CPU mesh (the TPU-build analog of the
 reference's 127.0.0.1 loopback servers, SURVEY.md §4): multi-chip sharding
 logic is validated with ``xla_force_host_platform_device_count=8`` so no real
 pod is needed.  What runs on the chip is chip_smoke.py, not these.
+
+The driver cuts a whole run at 1,470 s and counts what it reached, so a
+run stays under 1,000 s here (ROADMAP D12): a file compiles each program
+once (a module-scoped fixture or module-level ``jax.jit``; cases differ
+in their data) at the smallest shape that has every branch it names.
 """
 import os
 import sys
@@ -52,8 +57,10 @@ def pytest_collection_modifyitems(config, items):
     # tests/test_chip_compile.py loads the TPU's compiler library into
     # this process (dozens of threads, gigabytes of it).  It runs after
     # everything else, so that no timing- or signal-sensitive test (the
-    # SIGPROF profiler's, the overhead gates) shares a process with it.
-    # The order stays deterministic: every worker collects the same list.
+    # SIGPROF profiler's, the overhead gates) shares a process with it;
+    # so its compiles of the served models are also what a run that
+    # outgrows the driver's limit loses first.  The order stays
+    # deterministic: every worker collects the same list.
     last = [i for i in items if i.path.name == "test_chip_compile.py"]
     items[:] = [i for i in items if i not in last] + last
 
@@ -76,16 +83,23 @@ def _restore_process_globals():
     that turns it on for all its tests keeps its spans between them);
     the health checker's broken endpoints with their probe threads (one
     a dead port, waking every second for the rest of the run) and the
-    circuit breaker's windows over them (ports come round again)."""
+    circuit breaker's windows over them (ports come round again);
+    ``tracemalloc``, which the console's heap pages start and leave on:
+    under it a tight loop of every later test ran 25 times slower, and a
+    heap page a thousand tests later outlasted its caller's timeout."""
+    import tracemalloc
     from brpc_tpu import rpcz
     from brpc_tpu.policy import circuit_breaker, health_check
     was_on, rate = rpcz.enabled(), rpcz.sample_rate()
+    was_tracing = tracemalloc.is_tracing()
     if not was_on:
         rpcz.flush()
         with rpcz._collect_lock:
             rpcz._collected.clear()
     yield
     rpcz.set_enabled(was_on, rate)
+    if not was_tracing:
+        tracemalloc.stop()
     health_check.reset_all()
     with circuit_breaker._breaker_mu:
         circuit_breaker._breaker = None

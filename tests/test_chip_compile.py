@@ -3,15 +3,20 @@ and not attached (guide on-chip-measurement, section 2, rehearsal 3).
 
 Interpret mode hides what the chip's compiler refuses: the paged kernel
 passed every interpret-mode test for eleven PRs and was refused by
-Mosaic at every shape.  These compiles cost seconds and no chip time,
-and they guard every later PR.  A compile that passes is not a chip
-run: results and times come from ``chip_smoke.py`` on the chip.
+Mosaic at every shape.  These compiles cost 75 s alone (the five whole
+programs 8 to 22 s each, the ten kernel-only tests 8 s together)
+and no chip time, and they guard every later PR.  The file runs LAST
+(``conftest.pytest_collection_modifyitems``): it is what a run that
+outgrows the driver's limit loses first.  A compile that passes is not
+a chip run: results and times come from ``chip_smoke.py`` on the chip.
 
 The topology is described inside a fixture, after a test of this file
 has started — never at import, in a ``skipif`` or in a ``parametrize``
 argument — because only one process may load the TPU's library.  All
 of these tests live in this one file for the same reason.
 """
+import contextlib
+import dataclasses
 import functools
 
 import jax
@@ -139,24 +144,38 @@ def test_rail_programs_compile_at_a_4mb_block(sds):
 def test_full_width_decode_step_compiles_and_fits(sds, monkeypatch):
     """The runner's ``step`` at the smoke's shapes, kernel included.  Its
     memory analysis is what ``chip_smoke.CACHE_BLOCKS`` was sized from
-    (the temporaries are ~5x the cache, ROADMAP S1)."""
+    (the temporaries are ~5x the cache, ROADMAP S1).
+
+    Three quarters of the 16-layer compile are the six bfloat16 passes
+    of each float32 product, arithmetic and no buffer (temporaries 2.690
+    GB at one pass against 2.692, PR 36; arguments and outputs are the
+    same).  So the 16 layers are sized at one pass, and the kernel as the
+    chip traces it (``highest`` reaches its dots) compiles at two."""
     # the dispatcher asks jax which backend is live; steer it to the
     # branch the chip takes
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = chip_smoke.full_width_config()
     slots = chip_smoke.SERVING_SIZES["num_slots"]
     max_pages = chip_smoke.SERVING_SIZES["max_pages_per_slot"]
-    page_bytes = PAGE_TOKENS * cfg.kv_bytes_per_token
-    params = {k: sds(v.shape, v.dtype) for k, v in jax.eval_shape(
-        lambda: init_runner_params(cfg)).items()}
-    compiled = _jits()["step"].lower(
-        params, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-        sds((slots, max_pages), jnp.int32),
-        sds((chip_smoke.CACHE_BLOCKS, page_bytes), jnp.uint8),
-        cfg=cfg, page_tokens=PAGE_TOKENS, backend=None, mesh=None).compile()
+
+    def step(cfg):
+        page_bytes = PAGE_TOKENS * cfg.kv_bytes_per_token
+        params = {k: sds(v.shape, v.dtype) for k, v in jax.eval_shape(
+            lambda: init_runner_params(cfg)).items()}
+        return _jits()["step"].lower(
+            params, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots, max_pages), jnp.int32),
+            sds((chip_smoke.CACHE_BLOCKS, page_bytes), jnp.uint8),
+            cfg=cfg, page_tokens=PAGE_TOKENS, backend=None,
+            mesh=None).compile(), chip_smoke.CACHE_BLOCKS * page_bytes
+
+    cfg = chip_smoke.full_width_config()
+    compiled, _ = step(dataclasses.replace(cfg, n_layers=2))
     assert _has_kernel(compiled)
+    with monkeypatch.context() as one_pass:
+        one_pass.setattr(jax, "default_matmul_precision",
+                         lambda _: contextlib.nullcontext())
+        compiled, cache = step(cfg)
     mem = compiled.memory_analysis()
-    cache = chip_smoke.CACHE_BLOCKS * page_bytes
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         + mem.output_size_in_bytes + cache
     assert need <= (1.0 - chip_smoke.MEMORY_HEADROOM) * 16 * 2**30, \
@@ -207,94 +226,21 @@ def test_cache_write_and_page_keys_lower(sds):
     assert _has_kernel(compiled)
 
 
-def _sala_programs(sds):
+def _served(name, sds):
+    """A benchmark configuration: its file, the two programs, the shapes
+    of its weights (a matrix bfloat16, what the seeded init keeps float32
+    float32) and the programs' static arguments on the chip."""
     import json
     import os
     from brpc_tpu.models import hybrid
     from brpc_tpu.models.runner import from_hf_config
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks", "configs",
-        "minicpm_sala_l16_1chip.json")
-    with open(path) as f:
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs", name)) as f:
         c = json.load(f)
     cfg = from_hf_config(
         dict(c, num_hidden_layers=c["published_num_hidden_layers"]),
         layers=(c["first_published_layer"], c["num_hidden_layers"]),
-        sparse=c["assumed"]["sparse_config"]["value"],
-        param_dtype=c["param_dtype"])
-
-    def shaped(shapes):
-        return {n: sds(s, jnp.float32 if fan is None else jnp.bfloat16)
-                for n, (s, fan) in shapes.items()}
-    params = shaped({"emb": ((cfg.vocab, cfg.d_model), 1),
-                     "head": ((cfg.vocab, cfg.d_model), 1),
-                     "norm_f": ((cfg.d_model,), None)})
-    params["layers"] = [shaped(hybrid.layer_shapes(cfg, k))
-                        for k in cfg.mixer_types]
-    pages, rows = c["cache_pages"], c["state_rows"] + 2
-    caches = (sds((4, 2, 2, pages, 64, 128), jnp.bfloat16),
-              sds((4, pages, 4, 2, 128), jnp.bfloat16),
-              sds((rows, 12, 32, 128, 128), jnp.float32))
-    statics = dict(cfg=cfg, backend="mosaic", control="")
-    return c, hybrid._programs(), params, caches, statics
-
-
-def _arena_copies(compiled, pages: int) -> list:
-    """Copies of the whole K/V arena in a compiled program: what an XLA
-    gather, scatter or dynamic-update-slice over it costs on the chip
-    (0.3 GB each way; found in PR 32's first compile)."""
-    shape = f"bf16[4,2,2,{pages},64,128]"
-    return [line.strip()[:120] for line in compiled.as_text().splitlines()
-            if " copy(" in line and f"= {shape}" in line]
-
-
-def test_sala_decode_step_compiles_at_published_widths_and_fits(sds):
-    """``minicpm_sala_l16_1chip``'s decode step for a described v5e:
-    every kernel a custom call (12 state updates, the attention over
-    selected pages in both branches, the cache's writes and reads), no
-    copy of the arena, and weights + cache + temporaries inside the
-    chip."""
-    c, fns, params, caches, statics = _sala_programs(sds)
-    s, mp = c["num_slots"], c["max_pages_per_slot"]
-    i32 = jnp.int32
-    compiled = fns["step"].lower(
-        params, *caches, sds((s, 4 + mp), i32), sds((3, s), jnp.float32),
-        logits_out=False, **statics).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 12 + 4 * 4
-    assert _arena_copies(compiled, c["cache_pages"]) == []
-    mem = compiled.memory_analysis()
-    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert 10e9 < need <= 0.9 * 16 * 2**30, \
-        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
-
-
-def test_sala_question_prefill_compiles_without_copying_the_arena(sds):
-    """The 64-token bucket: the one prefill shape inside the measured
-    window of ``sala_doc_turns``."""
-    c, fns, params, caches, statics = _sala_programs(sds)
-    i32 = jnp.int32
-    mp = c["max_pages_per_slot"]
-    compiled = fns["prefill"].lower(
-        params, *caches, sds((3 + mp + 64,), i32), logits_out=False,
-        max_pages=mp, **statics).compile()
-    assert _has_kernel(compiled)
-    assert _arena_copies(compiled, c["cache_pages"]) == []
-
-
-def _glm_programs(sds):
-    import json
-    import os
-    from brpc_tpu.kvcache.layered import LayeredSpec
-    from brpc_tpu.models import hybrid
-    from brpc_tpu.models.runner import from_hf_config
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks", "configs",
-        "glm47_flash_l8_1chip.json")
-    with open(path) as f:
-        c = json.load(f)
-    cfg = from_hf_config(
-        dict(c, num_hidden_layers=c["published_num_hidden_layers"]),
-        layers=(c["first_published_layer"], c["num_hidden_layers"]),
+        sparse=c["assumed"].get("sparse_config", {}).get("value"),
         param_dtype=c["param_dtype"])
 
     def shaped(shapes):
@@ -305,6 +251,70 @@ def _glm_programs(sds):
                      "norm_f": ((cfg.d_model,), None)})
     params["layers"] = [shaped(hybrid.layer_shapes(cfg, *kinds))
                         for kinds in hybrid.layer_kinds(cfg)]
+    return (c, hybrid._programs(), params,
+            dict(cfg=cfg, backend="mosaic", control=""))
+
+
+@pytest.fixture(scope="module")
+def sala(sds):
+    c, fns, params, statics = _served("minicpm_sala_l16_1chip.json", sds)
+    pages, rows = c["cache_pages"], c["state_rows"] + 2
+    caches = (sds((4, 2, 2, pages, 64, 128), jnp.bfloat16),
+              sds((4, pages, 4, 2, 128), jnp.bfloat16),
+              sds((rows, 12, 32, 128, 128), jnp.float32))
+    return c, fns, params, caches, statics
+
+
+def _copies(compiled, arena) -> list:
+    """Copies of a whole cache array in a compiled program.  The K/V
+    arena: what an XLA gather, scatter or dynamic-update-slice over it
+    costs on the chip (0.3 GB each way; found in PR 32's first compile).
+    The latent arena: a row of 576 lanes, not whole tiles, got it another
+    layout and 1 GB copied each way around every kernel call (found
+    before the first chip run of PR 34)."""
+    shape = "bf16[" + ",".join(str(d) for d in arena.shape) + "]"
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line and f"= {shape}" in line]
+
+
+def test_sala_decode_step_compiles_at_published_widths_and_fits(sds, sala):
+    """``minicpm_sala_l16_1chip``'s decode step for a described v5e:
+    every kernel a custom call (12 state updates, the attention over
+    selected pages in both branches, the cache's writes and reads), no
+    copy of the arena, and weights + cache + temporaries inside the
+    chip."""
+    c, fns, params, caches, statics = sala
+    s, mp = c["num_slots"], c["max_pages_per_slot"]
+    i32 = jnp.int32
+    compiled = fns["step"].lower(
+        params, *caches, sds((s, 4 + mp), i32), sds((3, s), jnp.float32),
+        logits_out=False, **statics).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 12 + 4 * 4
+    assert _copies(compiled, caches[0]) == []
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 10e9 < need <= 0.9 * 16 * 2**30, \
+        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+
+
+def test_sala_question_prefill_compiles_without_copying_the_arena(sds, sala):
+    """The 64-token bucket: the one prefill shape inside the measured
+    window of ``sala_doc_turns``."""
+    c, fns, params, caches, statics = sala
+    i32 = jnp.int32
+    mp = c["max_pages_per_slot"]
+    compiled = fns["prefill"].lower(
+        params, *caches, sds((3 + mp + 64,), i32), logits_out=False,
+        max_pages=mp, **statics).compile()
+    assert _has_kernel(compiled)
+    assert _copies(compiled, caches[0]) == []
+
+
+@pytest.fixture(scope="module")
+def glm(sds):
+    from brpc_tpu.kvcache.layered import LayeredSpec
+    c, fns, params, statics = _served("glm47_flash_l8_1chip.json", sds)
+    cfg = statics["cfg"]
     pages, t = c["cache_pages"], c["page_tokens"]
     lanes = LayeredSpec(0, 0, 0, 0, 0, 0, 0, n_latent=cfg.n_latent,
                         latent_dim=cfg.latent_dim).latent_lanes
@@ -313,21 +323,10 @@ def _glm_programs(sds):
               sds((0, pages, 4, 20, 256), jnp.bfloat16),
               sds((2, 0, 0, 0, 0), jnp.float32))
     latent = sds((cfg.n_latent, pages, t, lanes), jnp.bfloat16)
-    statics = dict(cfg=cfg, backend="mosaic", control="")
-    return c, hybrid._programs(), params, caches, latent, statics
+    return c, fns, params, caches, latent, statics
 
 
-def _latent_copies(compiled, latent) -> list:
-    """Copies of the whole latent arena in a compiled program (a row of
-    576 lanes, not whole tiles, got the arena another layout and 1 GB
-    copied each way around every kernel call: found before the first
-    chip run of PR 34)."""
-    shape = "bf16[" + ",".join(str(d) for d in latent.shape) + "]"
-    return [line.strip()[:120] for line in compiled.as_text().splitlines()
-            if " copy(" in line and f"= {shape}" in line]
-
-
-def test_glm_decode_step_compiles_at_published_widths_and_fits(sds):
+def test_glm_decode_step_compiles_at_published_widths_and_fits(sds, glm):
     """``glm47_flash_l8_1chip``'s decode step for a described v5e: the
     latent write and the two passes of attention (the shared run, each
     slot's own tail: ISSUE 35) of 8 layers and the three ragged products
@@ -335,7 +334,7 @@ def test_glm_decode_step_compiles_at_published_widths_and_fits(sds):
     nowhere, its rows are whole tiles, and weights + cache + temporaries
     (the stacked ``[320, 640]`` queries and three float32 partials a
     layer among them) fit the chip."""
-    c, fns, params, caches, latent, statics = _glm_programs(sds)
+    c, fns, params, caches, latent, statics = glm
     assert latent.shape == (8, 1536, 64, 640)
     s, mp = c["num_slots"], c["max_pages_per_slot"]
     compiled = fns["step"].lower(
@@ -343,7 +342,7 @@ def test_glm_decode_step_compiles_at_published_widths_and_fits(sds):
         sds((4, s), jnp.float32), latent, logits_out=False,
         **statics).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 8 * 3 + 7 * 3
-    assert _latent_copies(compiled, latent) == []
+    assert _copies(compiled, latent) == []
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 11e9 < need <= 0.9 * 16 * 2**30, \
@@ -351,17 +350,17 @@ def test_glm_decode_step_compiles_at_published_widths_and_fits(sds):
     assert mem.temp_size_in_bytes < 0.2e9
 
 
-def test_glm_prefill_compiles_without_copying_the_arena(sds):
+def test_glm_prefill_compiles_without_copying_the_arena(sds, glm):
     """Both prefill buckets of ``glm_agent_turns`` (every request of the
     window runs one of them through the experts)."""
-    c, fns, params, caches, latent, statics = _glm_programs(sds)
+    c, fns, params, caches, latent, statics = glm
     mp = c["max_pages_per_slot"]
     for bucket in c["prefill_buckets"]:
         compiled = fns["prefill"].lower(
             params, *caches, sds((3 + mp + bucket,), jnp.int32), latent,
             logits_out=False, max_pages=mp, **statics).compile()
         assert compiled.as_text().count("tpu_custom_call") >= 8 * 2 + 7 * 3
-        assert _latent_copies(compiled, latent) == []
+        assert _copies(compiled, latent) == []
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
@@ -379,4 +378,4 @@ def test_glm_latent_rows_of_the_published_width_would_be_copied(sds):
             latent, sds((16,), jnp.int32), sds((16,), jnp.int32),
             sds((16, 1, lanes), jnp.float32)).compile()
         assert _has_kernel(compiled)
-        assert bool(_latent_copies(compiled, latent)) is copied
+        assert bool(_copies(compiled, latent)) is copied

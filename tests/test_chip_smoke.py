@@ -16,6 +16,8 @@ import chip_smoke
 from brpc_tpu import fault
 from brpc_tpu.models.runner import TransformerConfig, make_tp_mesh
 
+# 18 tokens: two shared pages of 4, a run of 8 repeated for the drafts, 2
+# over; a bucket each for the warm, the shared and the cold suffix
 TOY_SERVING = dict(cfg=TransformerConfig(), seed=0, page_tokens=4,
                    num_slots=2, max_pages_per_slot=24, cache_blocks=16,
                    prompt_len=18, new_tokens=6,

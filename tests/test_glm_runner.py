@@ -14,11 +14,11 @@ import pytest
 
 from benchmarks.harness import reference_glm as ref
 from brpc_tpu.models import hybrid
-from brpc_tpu.models.hybrid import (HybridRunner, init_hybrid_params,
-                                    make_layered_store)
+from brpc_tpu.models.hybrid import init_hybrid_params
 from brpc_tpu.models.runner import from_hf_config
 from brpc_tpu.ops.moe import route
-from brpc_tpu.serving import DecodeEngine
+from hybrid_rig import (Gated, InARow, Rig, T, as_drawn, serve,
+                        tokens_of)
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # the published keys (the catalog row's, verbatim)
@@ -43,84 +43,27 @@ HF = dict(PUBLISHED, hidden_size=64, intermediate_size=128,
           num_key_value_heads=4, q_lora_rank=24, kv_lora_rank=32,
           qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16,
           vocab_size=256)
-HELD = 3                     # the dense layer and two expert layers
-T = 16
-MAX_PAGES = 16
+# the dense layer and one expert layer, every kind once; two of a kind are
+# the toy cell's three layers (benchmarks/tests/test_glm_cell.py, tier-1)
+HELD = 2
 # float32 weights over a bfloat16 cache: a latent value that the two
 # sides round to neighbouring bfloat16s moves a logit by about 1e-4
 TOL = 5e-4
 
 
-def toy(seed=5, experts=None, dtype="float32"):
-    cfg = from_hf_config(HF, layers=(0, HELD), experts=experts,
-                         param_dtype=dtype)
+def configs(dtype="float32"):
+    cfg = from_hf_config(HF, layers=(0, HELD), param_dtype=dtype)
     ref_cfg = dict(HF, num_hidden_layers=HELD,
                    published_num_hidden_layers=47, first_published_layer=0,
                    param_dtype=dtype)
-    return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(seed))
-
-
-class Rig:
-    """A runner over its store, driven by hand as the engine drives
-    it."""
-
-    def __init__(self, cfg, params, name, pages=64, backend=None):
-        self.store = make_layered_store(cfg, cache_pages=pages,
-                                        page_tokens=T, name=name)
-        self.runner = HybridRunner(params, cfg, store=self.store, name=name,
-                                   backend=backend)
-
-    def table(self, seq):
-        out = np.full((MAX_PAGES,), -1, np.int32)
-        ids = seq.page_ids()
-        out[:len(ids)] = ids
-        return out
-
-    def prefill(self, seq, tokens, chunk=32):
-        """Positions prefill_from .. len - 2; returns their logits."""
-        out = []
-        at, end = seq.prefill_from, len(tokens) - 1
-        while at < end:
-            k = min(chunk, end - at)
-            pad = np.zeros((chunk,), np.int32)
-            pad[:k] = tokens[at:at + k]
-            lg = self.runner.prefill(pad, at + np.arange(chunk),
-                                     self.table(seq), seq=seq, n_valid=k,
-                                     logits=True)
-            out.append(np.asarray(lg)[:k])
-            at += k
-        return np.concatenate(out) if out else np.zeros((0, 256))
-
-    def decode(self, seq, tokens, upto, slot=1):
-        """Teacher-forced steps for positions len(seq) - 1 .. upto - 1;
-        returns their logits."""
-        out = []
-        for pos in range(len(seq.tokens), upto + 1):
-            tok = np.zeros((4,), np.int32)
-            p = np.zeros((4,), np.int32)
-            tok[slot], p[slot] = tokens[pos - 1], pos
-            tabs = np.full((4, MAX_PAGES), -1, np.int32)
-            tabs[slot] = self.table(seq)
-            seqs = [None] * 4
-            seqs[slot] = seq
-            lg = self.runner.step_logits(tok, p, tabs, seqs=seqs)
-            out.append(np.asarray(lg)[slot])
-            if pos < upto:
-                self.store.extend(seq, tokens[pos])
-        return np.stack(out)
-
-    def close(self):
-        self.runner.close()
-        self.store.close()
+    return cfg, ref_cfg
 
 
 @pytest.fixture(scope="module")
 def model():
-    return toy()
-
-
-def tokens_of(n, seed=0):
-    return np.random.default_rng(seed).integers(1, 256, n).tolist()
+    # the file's one seeded draw: every call compiles its programs anew
+    cfg, ref_cfg = configs()
+    return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(5))
 
 
 def test_from_hf_config_gives_the_published_parameter_counts():
@@ -179,11 +122,11 @@ def test_prefill_then_decode_equals_the_full_forward_pass(model, backend):
     tol = TOL if backend is None else 6e-2
     assert np.abs(got - want[:90]).max() < tol
     r = rig.runner
-    assert r.moe_assignments.get_value() == 90 * 4 * 2
-    assert 31 * 2 * 1 <= r.moe_experts_hit.get_value() <= 31 * 2 * 4
-    assert r.latent_tokens_read.get_value() == sum(range(60, 91)) * 3
+    assert r.moe_assignments.get_value() == 90 * 4 * cfg.n_moe
+    assert 31 * 1 <= r.moe_experts_hit.get_value() / cfg.n_moe <= 31 * 4
+    assert r.latent_tokens_read.get_value() == sum(range(60, 91)) * HELD
     assert r.latent_pages_distinct.get_value() \
-        == sum(-(-n // T) for n in range(60, 91)) * 3
+        == sum(-(-n // T) for n in range(60, 91)) * HELD
     rig.store.retire(seq, cache=False)
     rig.close()
 
@@ -199,24 +142,28 @@ def test_absorbed_attention_equals_expanded_attention(model):
     n = 40
     x = jnp.asarray(rng.normal(size=(n, 64)), jnp.float32)
     pos = jnp.arange(n, dtype=jnp.int32)
-    want, rows = ref.attention(m, p, x, jnp.zeros((48, 40), jnp.float32),
-                               pos, n)
+    want, rows = jax.jit(lambda p, x: ref.attention(
+        m, p, x, jnp.zeros((48, 40), jnp.float32), pos, n))(p, x)
     lanes = 128
-    with jax.default_matmul_precision("highest"):
-        qq, row = hybrid._mla_project(p, x, pos, cfg, lanes, "")
-        assert np.array_equal(np.asarray(row[:, :40], np.float32),
-                              np.asarray(rows[:n]))
-        assert not np.asarray(row[:, 40:]).any()
-        from brpc_tpu.ops.latent_attention import latent_attend, latent_write
-        lat = jnp.zeros((1, 4, T, lanes), jnp.bfloat16)
-        lat = latent_write(lat, 0, jnp.asarray([2, 0, 3]),
-                           jnp.zeros((3,), jnp.int32),
-                           jnp.pad(row, ((0, 8), (0, 0))).reshape(3, T, lanes))
-        o = latent_attend(qq, jnp.broadcast_to((pos + 1)[:, None, None],
-                                               (n, 4, 1)), lat, 0,
-                          jnp.zeros((n,), jnp.int32),
-                          jnp.asarray([[2, 0, 3, -1]], jnp.int32))
-        got = hybrid._mla_out(p, o, cfg)
+    from brpc_tpu.ops.latent_attention import latent_attend, latent_write
+
+    @jax.jit            # one program, not one an operation
+    def absorbed(p, x):
+        with jax.default_matmul_precision("highest"):
+            qq, row = hybrid._mla_project(p, x, pos, cfg, lanes, "")
+            lat = latent_write(
+                jnp.zeros((1, 4, T, lanes), jnp.bfloat16), 0,
+                jnp.asarray([2, 0, 3]), jnp.zeros((3,), jnp.int32),
+                jnp.pad(row, ((0, 8), (0, 0))).reshape(3, T, lanes))
+            o = latent_attend(
+                qq, jnp.broadcast_to((pos + 1)[:, None, None], (n, 4, 1)),
+                lat, 0, jnp.zeros((n,), jnp.int32),
+                jnp.asarray([[2, 0, 3, -1]], jnp.int32))
+            return row, hybrid._mla_out(p, o, cfg)
+    row, got = absorbed(p, x)
+    assert np.array_equal(np.asarray(row[:, :40], np.float32),
+                          np.asarray(rows[:n]))
+    assert not np.asarray(row[:, 40:]).any()
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
 
 
@@ -259,7 +206,7 @@ def test_eight_shares_of_eight_experts_add_up_to_the_uncut_layer(model):
     expert, counted ONCE, are the uncut reference's layer."""
     cfg, ref_cfg, params = model
     m = ref.model_cfg(ref_cfg)
-    p = params["layers"][2]
+    p = params["layers"][1]
     x = jnp.asarray(np.random.default_rng(3).normal(size=(24, 64)),
                     jnp.float32)
     valid = jnp.ones((24,), bool)
@@ -425,12 +372,15 @@ def test_a_shared_tail_page_is_copied_before_it_is_written(model):
     rig.close()
 
 
-def test_bfloat16_weights_agree_with_the_reference_at_the_stated_precision():
-    """``param_dtype="bfloat16"`` (what the chip serves): the reference
-    takes every weight's input at bfloat16 values too, so the two differ
-    by where the absorbed form rounds (the query after ``W_UK``, not
-    before) and by an expert choice where that moves a near tie."""
-    cfg, ref_cfg, params = toy(dtype="bfloat16")
+def test_bfloat16_weights_agree_with_the_reference_at_the_stated_precision(
+        model):
+    """``param_dtype="bfloat16"`` (what the chip serves; the toy cell is
+    float32): the reference takes every weight's input at bfloat16
+    values too, so the two differ by where the absorbed form rounds (the
+    query after ``W_UK``, not before) and by an expert choice where that
+    moves a near tie."""
+    cfg, ref_cfg = configs("bfloat16")
+    params = as_drawn(model[2], cfg)
     assert params["layers"][1]["we_gate"].dtype == jnp.bfloat16
     assert params["layers"][1]["router"].dtype == jnp.float32
     toks = tokens_of(80)
@@ -448,59 +398,14 @@ def test_bfloat16_weights_agree_with_the_reference_at_the_stated_precision():
     rig.close()
 
 
-class _InARow(HybridRunner):
-    feeds_tokens = False      # the engine completes each step it dispatches
-
-
-class _Take:
-    def __init__(self):
-        import threading
-        self.tokens, self.logprobs, self.err = [], [], "UNSET"
-        self.done = threading.Event()
-
-    def emit(self, tok, lp):
-        self.tokens.append(tok)
-        self.logprobs.append(lp)
-
-    def on_done(self, err):
-        self.err = err
-        self.done.set()
-
-
-def _serve(model, cls, name):
-    cfg, _ref_cfg, params = model
-    store = make_layered_store(cfg, cache_pages=64, page_tokens=T, name=name)
-    runner = cls(params, cfg, store=store, name=name)
-    engine = DecodeEngine(runner=runner, num_slots=4, store=store,
-                          max_pages_per_slot=MAX_PAGES,
-                          prefill_buckets=(16, 32), name=name)
-    try:
-        takes = []
-        for k, (n, new) in enumerate(zip((40, 44, 47), (14, 9, 12))):
-            takes.append(_Take())
-            engine.submit(tokens_of(n, seed=70 + k), new, takes[-1].emit,
-                          takes[-1].on_done, logprobs=True)
-        for t in takes:
-            assert t.done.wait(120) and t.err is None
-        assert engine.join_idle(20)
-        stats = engine.stats()
-        return {"tokens": [t.tokens for t in takes],
-                "logprobs": [t.logprobs for t in takes],
-                "steps": stats["steps"], "ahead": stats["steps_ahead"]}
-    finally:
-        engine.close()
-        runner.close()
-        store.close()
-
-
 def test_a_step_in_flight_serves_what_steps_in_a_row_serve(model):
     """Through the ``DecodeEngine`` (the path ``Serving.Generate``
     takes): with a step in flight (the runner feeds tokens on the
     device) and with every step completed before the next, the same
     tokens with log-probabilities the reference gives."""
     cfg, ref_cfg, params = model
-    row = _serve(model, _InARow, "g_inrow")
-    fly = _serve(model, HybridRunner, "g_inflight")
+    row = serve(model, InARow, "g_inrow", [])
+    fly = serve(model, Gated, "g_inflight", [])
     assert row["ahead"] == 0 and fly["ahead"] > 0
     assert fly["tokens"] == row["tokens"]
     assert [len(t) for t in fly["tokens"]] == [14, 9, 12]

@@ -7,6 +7,7 @@ import json
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -15,7 +16,10 @@ from benchmarks.harness import reference_sala as ref
 from brpc_tpu.models.hybrid import (HybridRunner, init_hybrid_params,
                                     make_layered_store)
 from brpc_tpu.models.runner import from_hf_config
+from brpc_tpu.ops.sparse_attention import select_blocks
 from brpc_tpu.serving import DecodeEngine, register_serving
+from hybrid_rig import (MAX_PAGES, Gated, InARow, Rig, T, as_drawn,
+                        serve, tokens_of)
 
 MIXERS = ["minicpm4"] + ["lightning-attn"] * 8 + ["minicpm4"] \
     + ["lightning-attn"] * 6 + ["minicpm4", "minicpm4"] \
@@ -32,86 +36,30 @@ HF = {"attention_bias": False, "attn_use_rope": False, "head_dim": 16,
       "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 16,
       "tie_word_embeddings": False, "use_output_gate": True,
       "use_output_norm": True, "attn_use_output_gate": True}
-SPARSE = dict(block_size=16, kernel_size=8, kernel_stride=4, topk=6,
+SPARSE = dict(block_size=T, kernel_size=8, kernel_stride=4, topk=6,
               init_blocks=1, window_size=24, dense_len=48)
-FIRST, HELD = 15, 4          # lightning, minicpm4, minicpm4, lightning
-T = SPARSE["block_size"]
-MAX_PAGES = 16
+# lightning, minicpm4: every kind once.  Two of a kind (a second layer's
+# index into the K/V and the state) is the toy cell's, at four layers
+# (benchmarks/tests/test_sala_cell.py, run from tier-1)
+FIRST, HELD = 15, 2
 
 
-def toy(sparse=None, seed=5):
-    sparse = dict(SPARSE, **(sparse or {}))
+def configs(dtype="float32", **sparse):
+    sparse = dict(SPARSE, **sparse)
     cfg = from_hf_config(HF, layers=(FIRST, HELD), sparse=sparse,
-                         param_dtype="float32")
+                         param_dtype=dtype)
     ref_cfg = dict(HF, num_hidden_layers=HELD,
                    published_num_hidden_layers=32,
-                   first_published_layer=FIRST, param_dtype="float32",
+                   first_published_layer=FIRST, param_dtype=dtype,
                    assumed={"sparse_config": {"value": sparse}})
-    return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(seed))
-
-
-class Rig:
-    """A runner over its store, driven by hand as the engine drives
-    it."""
-
-    def __init__(self, cfg, params, name, pages=64, rows=6):
-        self.store = make_layered_store(cfg, cache_pages=pages,
-                                        state_rows=rows, name=name)
-        self.runner = HybridRunner(params, cfg, store=self.store, name=name)
-
-    def table(self, seq):
-        out = np.full((MAX_PAGES,), -1, np.int32)
-        ids = seq.page_ids()
-        out[:len(ids)] = ids
-        return out
-
-    def prefill(self, seq, tokens, chunk=32):
-        """Positions prefill_from .. len - 2, as the engine cuts them;
-        returns the logits of those positions."""
-        out = []
-        at, end = seq.prefill_from, len(tokens) - 1
-        cuts = [c for c in self.runner.prefill_cuts(seq) if at < c < end]
-        for cut in cuts + [end]:
-            while at < cut:
-                k = min(chunk, cut - at)
-                pad = np.zeros((chunk,), np.int32)
-                pad[:k] = tokens[at:at + k]
-                lg = self.runner.prefill(pad, at + np.arange(chunk),
-                                         self.table(seq), seq=seq,
-                                         n_valid=k, logits=True)
-                out.append(np.asarray(lg)[:k])
-                at += k
-        return np.concatenate(out) if out else np.zeros((0, 256))
-
-    def decode(self, seq, tokens, upto):
-        """Teacher-forced steps for positions len(seq) - 1 .. upto - 1;
-        returns their logits."""
-        out = []
-        for pos in range(len(seq.tokens), upto + 1):
-            tok = np.zeros((4,), np.int32)
-            p = np.zeros((4,), np.int32)
-            tok[1], p[1] = tokens[pos - 1], pos
-            tabs = np.full((4, MAX_PAGES), -1, np.int32)
-            tabs[1] = self.table(seq)
-            lg = self.runner.step_logits(tok, p, tabs,
-                                         seqs=[None, seq, None, None])
-            out.append(np.asarray(lg)[1])
-            if pos < upto:
-                self.store.extend(seq, tokens[pos])
-        return np.stack(out)
-
-    def close(self):
-        self.runner.close()
-        self.store.close()
+    return cfg, ref_cfg
 
 
 @pytest.fixture(scope="module")
 def model():
-    return toy()
-
-
-def tokens_of(n, seed=0):
-    return np.random.default_rng(seed).integers(1, 256, n).tolist()
+    # the file's one seeded draw: every call compiles its programs anew
+    cfg, ref_cfg = configs()
+    return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(5))
 
 
 def test_from_hf_config_gives_the_published_parameter_counts():
@@ -169,21 +117,16 @@ def test_prefill_then_decode_equals_the_full_forward_pass(model):
     rig.close()
 
 
-def test_bfloat16_weights_agree_with_the_reference_at_the_stated_precision():
-    """``param_dtype="bfloat16"`` (what the chip serves): weights and
-    matmul inputs bfloat16, everything else float32.  The reference
-    takes every weight's input at bfloat16 values too (its departure
-    2), so the two differ by float32 summation order only, and by a
-    block selection where that moves a near tie."""
-    sparse = dict(SPARSE)
-    cfg = from_hf_config(HF, layers=(FIRST, HELD), sparse=sparse,
-                         param_dtype="bfloat16")
-    ref_cfg = dict(HF, num_hidden_layers=HELD,
-                   published_num_hidden_layers=32,
-                   first_published_layer=FIRST, param_dtype="bfloat16",
-                   assumed={"sparse_config": {"value": sparse}})
-    params = init_hybrid_params(cfg, jax.random.PRNGKey(5))
-    assert params["layers"][0]["wq"].dtype == jax.numpy.bfloat16
+def test_bfloat16_weights_agree_with_the_reference_at_the_stated_precision(
+        model):
+    """``param_dtype="bfloat16"`` (what the chip serves; the toy cell is
+    float32): weights and matmul inputs bfloat16, everything else
+    float32.  The reference takes every weight's input at bfloat16
+    values too (its departure 2), so the two differ by float32 summation
+    order only, and by a block selection where that moves a near tie."""
+    cfg, ref_cfg = configs("bfloat16")
+    params = as_drawn(model[2], cfg)
+    assert params["layers"][0]["wq"].dtype == jnp.bfloat16
     toks = tokens_of(120)
     want, _, _ = ref.full_logits(params, ref_cfg, toks, block=16, s_max=128)
     rig = Rig(cfg, params, "t_bf16")
@@ -229,10 +172,12 @@ def test_a_warm_request_equals_a_cold_one_logit_for_logit(model):
 
 
 def test_chunked_prefill_equals_one_chunk(model):
+    """32 positions: the file's chunk whole (a page boundary inside it)
+    against two of a page."""
     cfg, _, params = model
-    toks = tokens_of(64, seed=6)
+    toks = tokens_of(33, seed=6)
     one, many = Rig(cfg, params, "t_one"), Rig(cfg, params, "t_many")
-    a = one.prefill(one.store.admit(toks), toks, chunk=64)
+    a = one.prefill(one.store.admit(toks), toks)
     seq = many.store.admit(toks)
     b = many.prefill(seq, toks, chunk=16)
     assert np.abs(a - b).max() < 2e-5
@@ -242,47 +187,56 @@ def test_chunked_prefill_equals_one_chunk(model):
     many.close()
 
 
-def test_sparse_branch_with_topk_over_all_blocks_equals_dense():
+def test_sparse_branch_with_topk_over_all_blocks_equals_dense(model):
     """With ``topk`` >= every block the selection is everything: the
-    sparse branch must read what the dense branch reads."""
+    sparse branch must read what the dense branch reads.  90 tokens are
+    six blocks, the file's ``topk``: its configuration from position 48
+    on against one that never leaves the dense branch."""
+    cfg_s, _, params = model
+    assert -(-90 // T) <= SPARSE["topk"]
     toks = tokens_of(90, seed=7)
-    cfg_s, _, params = toy(sparse={"topk": 16, "dense_len": 16})
-    cfg_d, _, _ = toy(sparse={"topk": 16, "dense_len": 4096})
-    out = []
-    for cfg, name in ((cfg_s, "t_sp"), (cfg_d, "t_de")):
+    out, sparse = [], []
+    for cfg, name in ((cfg_s, "t_sp"),
+                      (configs(dense_len=4096)[0], "t_de")):
         rig = Rig(cfg, params, name)
         seq = rig.store.admit(toks[:60])
         out.append(np.concatenate([rig.prefill(seq, toks[:60]),
                                    rig.decode(seq, toks, 90)]))
+        sparse.append(rig.runner.sparse_positions.get_value())
         rig.close()
+    assert sparse == [90 - 48, 0]
     assert np.abs(out[0] - out[1]).max() < 2e-5
+
+
+M = ref.model_cfg(configs()[1])      # the toy as the reference reads it
+
+
+@jax.jit                    # one program for both seeds, not one an operation
+def selections(q, k_all, pos):
+    """The reference's selection over the whole context's keys, and the
+    system's over the compressed keys the cache would hold of them."""
+    want = ref.selected_blocks(M, q, k_all, pos)
+    k16 = k_all.reshape(-1, M["stride"], M["hkv"], M["d"]).mean(axis=1)
+    kc = (0.5 * (k16[:-1] + k16[1:])).astype(jnp.bfloat16)
+    kc = jnp.concatenate([kc, jnp.zeros_like(kc[:1])])       # J = 4 pages
+    return want, select_blocks(
+        q.reshape(4, M["hkv"], -1, M["d"]),
+        jnp.broadcast_to(kc[None], (4,) + kc.shape), pos, page_tokens=T,
+        topk=M["topk"], init_blocks=M["init_blocks"], window=M["window"])
 
 
 @pytest.mark.parametrize("seed", [11, 12])
 def test_selected_blocks_equal_the_references(seed):
     """The page tables the system attends to are the reference's
     selection (seeds with no near tie of block scores)."""
-    import jax.numpy as jnp
-    from brpc_tpu.ops.sparse_attention import select_blocks
-    cfg, ref_cfg, params = toy(seed=seed)
-    m = ref.model_cfg(ref_cfg)
     rng = np.random.default_rng(seed)
-    s = 128
-    k_all = jnp.asarray(rng.normal(size=(s, m["hkv"], m["d"])), jnp.float32)
+    k_all = jnp.asarray(rng.normal(size=(128, M["hkv"], M["d"])), jnp.float32)
     k_all = k_all.astype(jnp.bfloat16).astype(jnp.float32)
-    pos = jnp.asarray([55, 77, 100, 127])
-    q = jnp.asarray(rng.normal(size=(4, m["h"], m["d"])), jnp.float32)
-    want = np.asarray(ref.selected_blocks(m, q, k_all, pos))
-    st = m["stride"]
-    k16 = k_all.reshape(s // st, st, m["hkv"], m["d"]).mean(axis=1)
-    kc = (0.5 * (k16[:-1] + k16[1:])).astype(jnp.bfloat16)
-    kc = jnp.concatenate([kc, jnp.zeros_like(kc[:1])])       # J = 4 pages
-    got = np.asarray(select_blocks(
-        q.reshape(4, m["hkv"], -1, m["d"]),
-        jnp.broadcast_to(kc[None], (4,) + kc.shape), pos, page_tokens=T,
-        topk=m["topk"], init_blocks=m["init_blocks"], window=m["window"]))
+    q = jnp.asarray(rng.normal(size=(4, M["h"], M["d"])), jnp.float32)
+    want, got = map(np.asarray, selections(
+        q, k_all, jnp.asarray([55, 77, 100, 127])))
     for i in range(4):
-        for g in range(m["hkv"]):
+        for g in range(M["hkv"]):
             assert set(got[i, g][got[i, g] >= 0].tolist()) \
                 == set(np.flatnonzero(want[i, g]).tolist())
 
@@ -405,82 +359,6 @@ def test_generate_with_and_without_logprobs(model):
         store.close()
 
 
-class _Gated(HybridRunner):
-    """Holds the engine inside the first admission until the test has
-    queued every request: who rides which step is then the same in every
-    run, and so is the order in which pages are taken."""
-
-    def __init__(self, *a, **kw):
-        super().__init__(*a, **kw)
-        self.gate, self.parked = threading.Event(), threading.Event()
-
-    def prefill(self, *a, **kw):
-        self.parked.set()
-        assert self.gate.wait(60)
-        return super().prefill(*a, **kw)
-
-
-class _InARow(_Gated):
-    feeds_tokens = False      # the engine completes each step it dispatches
-
-
-class _Take:
-    def __init__(self):
-        self.tokens, self.logprobs, self.err = [], [], "UNSET"
-        self.done = threading.Event()
-
-    def emit(self, tok, lp):
-        self.tokens.append(tok)
-        self.logprobs.append(lp)
-
-    def on_done(self, err):
-        self.err = err
-        self.done.set()
-
-
-def _serve(model, cls, name, compiles):
-    """Three requests through a ``DecodeEngine``: prompts of 40, 44 and 47
-    tokens that decode across position 48, a page boundary (16-token
-    pages) and the switch from the dense to the sparse branch
-    (``dense_len`` 48), ending by count on different steps."""
-    cfg, _ref_cfg, params = model
-    store = make_layered_store(cfg, cache_pages=64, state_rows=8, name=name)
-    runner = cls(params, cfg, store=store, name=name)
-    engine = DecodeEngine(runner=runner, num_slots=4, store=store,
-                          max_pages_per_slot=MAX_PAGES,
-                          prefill_buckets=(16, 32), name=name)
-    try:
-        takes = [_Take() for _ in range(3)]
-        for k, (n, new, t) in enumerate(zip((40, 44, 47), (14, 9, 12),
-                                            takes)):
-            engine.submit(tokens_of(n, seed=70 + k), new, t.emit, t.on_done,
-                          logprobs=True)
-            if k == 0:      # the engine stays inside this admission
-                assert runner.parked.wait(20)
-        # what compiles from here on compiles inside the steps
-        seen = len(compiles)
-        runner.gate.set()
-        for t in takes:
-            assert t.done.wait(120) and t.err is None
-        assert engine.join_idle(20)
-        stats = engine.stats()
-        lay = store.layers
-        with lay.lock:
-            arrays = [np.asarray(x) for x in (lay.kv, lay.kc, lay.state)]
-        return {"tokens": [t.tokens for t in takes],
-                "logprobs": [t.logprobs for t in takes],
-                "arrays": arrays, "steps": stats["steps"],
-                "ahead": stats["steps_ahead"],
-                "compiled": compiles[seen:],
-                "counters": (runner.sparse_positions.get_value(),
-                             runner.dense_positions.get_value(),
-                             runner.sparse_selected.get_value())}
-    finally:
-        engine.close()
-        runner.close()
-        store.close()
-
-
 def test_a_step_in_flight_serves_what_steps_in_a_row_serve(model):
     """The engine over a ``HybridRunner`` keeps one step in flight (the
     runner feeds tokens on the device); over the same runner saying it
@@ -496,8 +374,8 @@ def test_a_step_in_flight_serves_what_steps_in_a_row_serve(model):
         if on[0] and event == "/jax/core/compile/backend_compile_duration"
         else None)
     try:
-        row = _serve(model, _InARow, "t_inrow", compiles)
-        fly = _serve(model, _Gated, "t_inflight", compiles)
+        row = serve(model, InARow, "t_inrow", compiles)
+        fly = serve(model, Gated, "t_inflight", compiles)
     finally:
         on[0] = False
     assert row["ahead"] == 0 and fly["ahead"] >= fly["steps"] - 2 > 0

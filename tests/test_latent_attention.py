@@ -4,6 +4,8 @@ shared pass, the own pass and the join, as ``models/hybrid.step`` calls
 them through ``latent_attend_slots``) against one float32 pass of
 ``latent_attend_gather`` over each slot's whole table.  What Mosaic makes
 of the kernel is ``tests/test_chip_compile.py``'s."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,11 +13,22 @@ import pytest
 
 from brpc_tpu.ops import latent_attention as la
 
-T, C, MP = 4, 128, 48               # a key block is 8 pages = 32 keys
+T, C = 4, 128                       # a key block is 8 pages = 32 keys
+MP = 32             # whole blocks over the longest table (30 pages, qlo's)
 BLOCK = la.PAGES_PER_STEP * T
-ARENA = 160
 PREFIX = np.arange(100, 100 + MP)   # the pages of one long prompt
 OTHER = np.arange(20, 20 + MP)      # and of another
+ARENA = 132                         # PREFIX's last page and no more
+# x 4 heads: the shared pass stacks whole (16, 128) tiles, the own pass
+# pads a slot's 4; cases of fewer slots are padded with idle ones
+SLOTS = 8
+
+
+# one compile a shape (4 or 5 heads), not one a case as when called bare
+@functools.partial(jax.jit, static_argnames="backend")
+def attend_slots(q, seen, shared, leader, arena, tables, backend):
+    return la.latent_attend_slots(q, seen, shared, leader, arena, 1, tables,
+                                  backend=backend)
 
 
 def table(prefix, n_shared, own):
@@ -113,8 +126,7 @@ def arena():
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_shared_then_own_equals_one_pass(arena, name):
     heads, slots, want_shared = CASES[name]
-    # idle slots up to five: one compile of the interpreted kernel a shape
-    idle = [(np.full((MP,), -1, np.int32), 0)] * (5 - len(slots))
+    idle = [(np.full((MP,), -1, np.int32), 0)] * (SLOTS - len(slots))
     slots, want_shared = slots + idle, want_shared + [0] * len(idle)
     tables = np.stack([row for row, _ in slots])
     seen = np.asarray([n for _, n in slots], np.int32)
@@ -125,10 +137,9 @@ def test_shared_then_own_equals_one_pass(arena, name):
     # queries at bfloat16 values, as the kernel multiplies them
     q = jnp.asarray(rng.normal(size=(len(slots), heads, C)) * 0.4,
                     jnp.bfloat16).astype(jnp.float32)
-    got = la.latent_attend_slots(
-        q, jnp.asarray(seen), jnp.asarray(shared),
-        jnp.asarray([leader], jnp.int32), arena, 1, jnp.asarray(tables),
-        backend="pallas")
+    args = (q, jnp.asarray(seen), jnp.asarray(shared),
+            jnp.asarray([leader], jnp.int32), arena, jnp.asarray(tables))
+    got = attend_slots(*args, backend="pallas")
     qlen = jnp.broadcast_to(jnp.asarray(seen)[:, None, None],
                             (len(slots), heads, 1))
     want = la.latent_attend_gather(
@@ -140,10 +151,7 @@ def test_shared_then_own_equals_one_pass(arena, name):
     for i, n in enumerate(seen):
         assert n or not got[i].any()            # an idle slot gives 0
     # the same two passes without a kernel: float32 noise
-    plain = la.latent_attend_slots(
-        q, jnp.asarray(seen), jnp.asarray(shared),
-        jnp.asarray([leader], jnp.int32), arena, 1, jnp.asarray(tables),
-        backend="gather")
+    plain = attend_slots(*args, backend="gather")
     assert np.abs(np.asarray(plain) - want).max() < 2e-6
     # and what the counters will say
     visits, stood_in = la.page_visits(seen, shared, T)

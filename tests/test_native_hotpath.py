@@ -28,7 +28,7 @@ import pytest
 from brpc_tpu import errors, flags, native_path
 from brpc_tpu.serving import DecodeEngine
 
-from testutil import wait_until
+from testutil import wait_until, wedged_consumer_is_cut
 
 pytestmark = pytest.mark.skipif(
     native_path._core_lib() is None,
@@ -161,34 +161,10 @@ def test_native_ring_wedged_consumer_cut_fast_reader_streams(native_flag):
     flush, while a fast reader beside it streams at full speed — and
     the cut request's ring is freed (no leak)."""
     base = _live()
-    eng = DecodeEngine((lambda t, p: t + 1), num_slots=2, emit_buffer=8,
-                       kv_bytes_per_slot=1024, name="t_native_wedge")
-    try:
-        slow, fast = _Sink(), _Sink()
 
-        def slow_emit(tok):
-            time.sleep(0.25)              # a wedged stream consumer
-            slow.tokens.append(tok)
-
-        eng.submit([0], 10_000, slow_emit, slow.on_done)
-        # the wedged request rides a native ring (the thing under
-        # test): the live-ring counter moved above the baseline
+    def rides_a_native_ring():      # the live-ring counter moved
         assert wait_until(lambda: native_path.tokring_live() > base, 10)
-        assert wait_until(lambda: len(slow.tokens) >= 1, 20)
-        t0 = time.monotonic()
-        eng.submit([500], 200, fast.emit, fast.on_done)
-        assert fast.done.wait(20) and fast.err is None
-        fast_elapsed = time.monotonic() - t0
-        assert fast.tokens == list(range(501, 701))
-        assert fast_elapsed < 5.0, \
-            f"fast reader stalled {fast_elapsed:.1f}s behind wedged one"
-        assert slow.done.wait(30)
-        assert slow.err is not None and \
-            slow.err.code == errors.EOVERCROWDED
-        assert eng.stats()["emit_cut"] == 1
-        assert eng.join_idle(10)
-    finally:
-        eng.close()
+    wedged_consumer_is_cut("t_native_wedge", _Sink, rides_a_native_ring)
     assert wait_until(lambda: _live() == base, 10), \
         f"leaked {_live() - base} native emit rings after the cut"
 

@@ -26,7 +26,8 @@ from brpc_tpu.models.runner import ModelRunner
 from brpc_tpu.serving import (DecodeEngine, DynamicBatcher, ServingService,
                               register_serving)
 
-from testutil import batcher_slot_held, wait_until
+from testutil import (batcher_slot_held, wait_until,
+                      wedged_consumer_is_cut)
 
 
 def _sum_fn():
@@ -534,36 +535,7 @@ def test_engine_slow_consumer_cut_without_stalling_fast():
     its BOUNDED per-request emit buffer and is cut with EOVERCROWDED —
     the shared step loop never blocks on it, so a fast reader admitted
     alongside keeps streaming at full speed."""
-    eng = DecodeEngine((lambda t, p: t + 1), num_slots=2, emit_buffer=8,
-                       kv_bytes_per_slot=1024, name="t_emitbuf")
-    try:
-        slow, fast = _Sink(), _Sink()
-
-        def slow_emit(tok):
-            time.sleep(0.25)              # a wedged stream consumer
-            slow.tokens.append(tok)
-
-        eng.submit([0], 10_000, slow_emit, slow.on_done)
-        assert wait_until(lambda: len(slow.tokens) >= 1, 20)
-        t0 = time.monotonic()
-        eng.submit([500], 200, fast.emit, fast.on_done)
-        assert fast.done.wait(20) and fast.err is None
-        fast_elapsed = time.monotonic() - t0
-        # 200 tokens under the old engine would serialize behind the
-        # slow consumer's writes (>= tens of seconds); with per-request
-        # buffering the fast stream finishes at step-loop speed
-        assert fast.tokens == list(range(501, 701))
-        assert fast_elapsed < 5.0, \
-            f"fast reader stalled {fast_elapsed:.1f}s behind slow one"
-        # the slow consumer is CUT once its buffer overflows, with a
-        # definite error after its buffered tokens flush
-        assert slow.done.wait(30)
-        assert slow.err is not None and \
-            slow.err.code == errors.EOVERCROWDED
-        assert eng.stats()["emit_cut"] == 1
-        assert eng.join_idle(10)
-    finally:
-        eng.close()
+    wedged_consumer_is_cut("t_emitbuf", _Sink)
 
 
 def test_engine_close_completes_inflight_with_elogoff():

@@ -498,7 +498,9 @@ def _drive_until(cli, router, engine, mults, *, want_state,
     reaches ``want_state``; every stream is checked bit-exact."""
     deadline = time.monotonic() + timeout_s
     i = 0
-    while engine.state != want_state:
+    # under the engine's lock: ``tick`` sets the state and THEN pushes the
+    # weights, holding it; the bare attribute read PROMOTED between the two
+    while engine.snapshot()["state"] != want_state:
         assert time.monotonic() < deadline, \
             f"engine stuck in {engine.state}: {engine.snapshot()}"
         prompt = [100 + (i % 7) + j for j in range(6)]

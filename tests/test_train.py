@@ -398,6 +398,26 @@ def test_mixed_harness_all_shapes_one_fleet():
     assert rep["train"]["loss_final"] < rep["train"]["loss_first"]
 
 
+def test_harness_pressure_is_the_delay_since_the_last_look():
+    """One slow batch reads over the shed rung ONCE; a recorder's standing
+    p99 over a handful of samples read over it for good, and the trainer's
+    waves were shed to their 30 s deadline (ROADMAP D21)."""
+    from types import SimpleNamespace as NS
+    from brpc_tpu.bvar.recorder import LatencyRecorder
+    rec = LatencyRecorder()
+    b = NS(stats=lambda: {"queued": 0}, queue_delay_rec=rec)
+    h = NS(ps_svcs=[NS(_lookup_b=b)], replicas=[], _delay_seen={})
+
+    def look():
+        return MixedWorkloadHarness._pressures(h).get("queue_delay_us", 0.0)
+    rec << 80_000
+    assert look() > 50_000          # over the shed rung, once
+    assert look() == 0.0            # nothing waited since
+    rec << 1_000
+    rec << 3_000
+    assert look() == 2_000          # the new samples alone
+
+
 # ---------------------------------------------------------------------------
 # Score adopter (ISSUE 17 satellite a)
 # ---------------------------------------------------------------------------
