@@ -480,8 +480,8 @@ def _attend_positions(q4, kv, ls, tables, kcs, qpos, live, cfg, backend):
     width = max(cfg.sparse_topk, dense_pages)
     pages_s, blocks_s = _select_tables(q4, kcs, qpos, tables, cfg, t_page)
     # a row that is not live (an idle slot, a bucket's padding) names no
-    # page: the kernel then fetches none (an unchanged block index is
-    # not fetched again) where it would fetch 64 pages to mask them all
+    # page: the kernel's work list then holds one step for it (its zero
+    # output) where it would step through 64 pages to mask them all
     pages_s = jnp.where(live[:, None, None], pages_s, -1)
     tables = jnp.where(live[:, None], tables, -1)
     lengths = jnp.where(live, qpos + 1, 0)
@@ -868,9 +868,12 @@ class HybridRunner(ModelRunner):
         self.sparse_selected = Adder(f"runner_{safe}_sparse_selected_blocks")
         self.sparse_positions = Adder(f"runner_{safe}_sparse_positions")
         self.dense_positions = Adder(f"runner_{safe}_dense_positions")
+        # grid steps of ``sparse_attend`` (PAGES_PER_STEP pages each): x 8
+        # over the blocks selected, the pages stepped a page that is read
+        self.sparse_steps = Adder(f"runner_{safe}_sparse_steps")
         self.lightning_tokens = Adder(f"runner_{safe}_lightning_tokens")
         names = ["sparse_selected_blocks", "sparse_positions",
-                 "dense_positions", "lightning_tokens"]
+                 "dense_positions", "sparse_steps", "lightning_tokens"]
         # expert layers: (token, expert) pairs routed, and the distinct
         # experts a layer a decode step hit, summed; latent layers: rows
         # a decode step attended to (a layer), and the distinct pages
@@ -945,14 +948,24 @@ class HybridRunner(ModelRunner):
         self._table_cache = live
         return out
 
-    def _count(self, qpos, n_sel) -> None:
-        """Counters of the positions a program just computed."""
+    def _count(self, qpos, n_sel, dead: int) -> None:
+        """Counters of the positions a program just computed; ``dead``
+        the rows beside them that were not live (idle slots, a bucket's
+        padding)."""
         cfg = self.cfg
         if cfg.n_sparse:
-            dense = int((qpos + 1 <= cfg.sparse_dense_len).sum())
-            self.dense_positions.add(dense)
-            self.sparse_positions.add(len(qpos) - dense)
+            from brpc_tpu.ops.sparse_attention import steps_visited
+            dense = qpos + 1 <= cfg.sparse_dense_len
+            n_dense = int(dense.sum())
+            self.dense_positions.add(n_dense)
+            self.sparse_positions.add(len(qpos) - n_dense)
             self.sparse_selected.add(float(n_sel))
+            # a position's rows see its pages under ``dense_len`` and the
+            # blocks it selects past it; a dead row is visited once
+            pages = qpos // self.store.page_tokens + 1
+            seen = np.where(dense, pages, np.minimum(pages, cfg.sparse_topk))
+            self.sparse_steps.add((steps_visited(seen) + dead)
+                                  * cfg.n_kv_heads * cfg.n_sparse)
         if cfg.n_linear:
             self.lightning_tokens.add(len(qpos))
         if cfg.n_moe:
@@ -999,7 +1012,7 @@ class HybridRunner(ModelRunner):
         # counted when the next step dispatched completes: the chunk has
         # run by then, and ``float(n_sel)`` here would hold the engine
         # thread until the device has caught up with everything queued
-        self._uncounted.append((start + np.arange(n), n_sel))
+        self._uncounted.append((start + np.arange(n), n_sel, len(tokens) - n))
         if start + n == self.store.snapshot_boundary(seq):
             self.store.take_snapshot(seq, start + n)
         return out
@@ -1076,9 +1089,10 @@ class HybridRunner(ModelRunner):
         for i, s in enumerate(handle["seqs"] or ()):
             if live[i] and not s.retired:
                 self.store.mark_filled(s, int(positions[i]))
-        for qpos, n_sel in handle["prefills"]:
-            self._count(qpos, n_sel)
-        self._count(positions[live] - 1, out[2][live].sum())
+        for chunk in handle["prefills"]:
+            self._count(*chunk)
+        self._count(positions[live] - 1, out[2][live].sum(),
+                    len(live) - int(live.sum()))
         if self.cfg.n_moe:
             self.moe_experts_hit.add(int(out[3][0]))
         if self.cfg.n_latent:

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from brpc_tpu.ops.paged_attention import default_backend
-from brpc_tpu.ops.sparse_attention import _write_kernel
+from brpc_tpu.ops.sparse_attention import (_write_kernel, first_of_row,
+                                           flat_work_list)
 
 __all__ = ["latent_write", "latent_attend", "latent_attend_slots",
            "latent_join", "shared_run", "page_visits", "default_backend"]
@@ -153,8 +154,7 @@ def _attend_kernel(tix_ref, tab_ref, wrow_ref, wblk_ref, q_ref, qlo_ref,
     w = pl.program_id(0)
     base = wblk_ref[w] * (pps * page_tokens)
 
-    # the first block of a grid row's run of the work list
-    @pl.when((w == 0) | (wrow_ref[jnp.maximum(w - 1, 0)] != wrow_ref[w]))
+    @pl.when(first_of_row(wrow_ref, w))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -185,24 +185,18 @@ def _attend_kernel(tix_ref, tab_ref, wrow_ref, wblk_ref, q_ref, qlo_ref,
 
 def work_list(qlo, qlen, n_blocks: int, block_keys: int):
     """The key blocks each grid row must visit, flat: ``(rows [W], blocks
-    [W], n)``, ``W = R n_blocks`` static, the first ``n`` entries live
-    (a device scalar, the kernel's grid).  Row ``r`` visits the blocks
+    [W], n)``, ``W = R n_blocks + 1`` static, the first ``n`` entries
+    live (a device scalar, the kernel's grid).  Row ``r`` visits the blocks
     that hold a key some query row of it can see, ``min qlo <= k < max
     qlen`` over the rows with any; a row with none visits one block all
     the same (it gives the parts of no key: its output is written)."""
     i32 = jnp.int32
-    r = qlen.shape[0]
     some = qlen > qlo
     lo = jnp.where(some, qlo, jnp.iinfo(i32).max).min(axis=(1, 2))
     hi = jnp.where(some, qlen, 0).max(axis=(1, 2))
     first = jnp.minimum(lo // block_keys, n_blocks - 1)
     count = jnp.maximum(-(-hi // block_keys) - first, 1)
-    end = jnp.cumsum(count)
-    w = jnp.arange(r * n_blocks, dtype=i32)
-    rows = jnp.minimum((w[:, None] >= end[None, :]).sum(axis=1), r - 1)
-    blocks = first[rows] + w - (end - count)[rows]
-    return (rows.astype(i32),
-            jnp.clip(blocks, 0, n_blocks - 1).astype(i32), end[-1])
+    return flat_work_list(first, count, n_blocks)
 
 
 def latent_attend_pallas(q, qlen, latent, layer: int, tix, tables, qlo=None,

@@ -14,10 +14,15 @@ not a contiguous sequence and the causal mask needs each key's position.
   :func:`sparse_attend`   attention of one query a row over its selected
                           pages; on a TPU the ``sparse_attend`` Pallas
                           kernel (page table, block indices and lengths
-                          ride scalar prefetch, each page is DMA'd from
-                          the arena row the table names, ``q k^T`` and
-                          ``p v`` run on the MXU for the whole query
-                          group of a K/V head), elsewhere a gather.
+                          ride scalar prefetch, each page is DMA'd by
+                          hand from the arena row the table names, the
+                          next step's while this one computes; a grid
+                          step takes ``PAGES_PER_STEP`` pages as ONE key
+                          tile: one ``q k^T``, one softmax update and
+                          one ``p v`` on the MXU for the whole query
+                          group of a K/V head; the grid is the flat list
+                          of the steps that hold a page a row sees,
+                          :func:`flat_work_list`), elsewhere a gather.
 
 Geometry (fixed ratios, checked): a page holds ``4 * stride`` tokens,
 a kernel spans ``2 * stride`` tokens and starts every ``stride``: four
@@ -37,15 +42,18 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from brpc_tpu.ops.paged_attention import default_backend
 
 __all__ = ["compress_keys", "select_blocks", "sparse_attend",
-           "sparse_attend_gather", "sparse_attend_pallas",
-           "cache_write", "page_keys",
-           "default_backend", "KERNELS_PER_PAGE"]
+           "sparse_attend_gather", "sparse_attend_pallas", "steps_visited",
+           "live_pages", "flat_work_list", "first_of_row", "cache_write",
+           "page_keys", "default_backend", "KERNELS_PER_PAGE",
+           "PAGES_PER_STEP"]
 
 KERNELS_PER_PAGE = 4
+PAGES_PER_STEP = 8    # pages of one grid step of sparse_attend: one key tile
 NEG = -1.0            # score of a block no complete kernel overlaps
 
 
@@ -154,12 +162,13 @@ def sparse_attend_gather(q, kv, layer: int, heads, tables, block_ids,
 
 
 def _dot_split(a, b, dims):
-    """A float32 ``a`` against a ``b`` the cache holds: where ``b`` is
-    bfloat16, ``a`` is split into two bfloat16 terms (``a = hi + lo`` to
-    2^-17) and each multiplies ``b`` in ONE pass of the MXU with a
-    float32 sum: the product of float32 ``highest`` (six passes, which
-    made this kernel compute-bound: 0.4 us a page where its two 16 KB
-    fetches take 0.04) at a third of the passes and no cast of the
+    """A float32 ``a [M, K]`` against a ``b`` the cache holds: where
+    ``b`` is bfloat16, ``a`` is split into two bfloat16 terms (``a = hi
+    + lo`` to 2^-17), the two STACKED into one ``[2 M, K]`` operand that
+    multiplies ``b`` in ONE pass of the MXU (``b``'s tiles are loaded as
+    weights once for both), and the halves of the float32 result added:
+    the product of float32 ``highest`` (six passes, which made this
+    kernel compute-bound) at a third of the passes and no cast of the
     page.  Any other ``b`` multiplies as it is."""
     f32 = jnp.float32
     if b.dtype != jnp.bfloat16:
@@ -167,98 +176,189 @@ def _dot_split(a, b, dims):
                                    preferred_element_type=f32)
     hi = a.astype(jnp.bfloat16)
     lo = (a - hi.astype(f32)).astype(jnp.bfloat16)
-    one = jax.lax.Precision.DEFAULT
-    return jax.lax.dot_general(hi, b, dims, preferred_element_type=f32,
-                               precision=one) \
-        + jax.lax.dot_general(lo, b, dims, preferred_element_type=f32,
-                              precision=one)
+    # one pass, named so: the caller may trace under
+    # default_matmul_precision("highest")
+    both = jax.lax.dot_general(
+        jnp.concatenate([hi, lo], axis=0), b, dims,
+        preferred_element_type=f32, precision=jax.lax.Precision.DEFAULT)
+    return both[:a.shape[0]] + both[a.shape[0]:]
 
 
-def _sparse_kernel(tab_ref, blk_ref, len_ref, q_ref, *refs, pps: int,
-                   page_tokens: int, scale: float):
+def flat_work_list(first, count, n_blocks: int):
+    """The grid of a kernel over ragged rows, flat: row ``r`` visits the
+    ``count[r] >= 1`` key blocks from ``first[r]`` on.  Returns ``(rows
+    [W], blocks [W], n)`` int32, ``W = R n_blocks + 1`` static, the
+    first ``n`` entries live (``n`` a device scalar: the kernel's dynamic
+    grid bound) and every other a valid (row, block) too: the chip's
+    pipeline evaluates the index maps of step ``n`` while it runs step
+    ``n - 1``, so a list of exactly ``n`` entries is read one past its
+    end where every row visits every block (the core halted there, my
+    chip run, PR 37).  Rows in order, so a row's first step is the one
+    whose predecessor names another row (:func:`first_of_row`).  What no row
+    visits is neither fetched nor stepped through (a step ``pl.when``
+    skips costs 0.5 us on a v5e, PERF.md section 6, PR 35).
+    ``sparse_attend`` and ``ops.latent_attention`` both grid so."""
+    i32 = jnp.int32
+    r = count.shape[0]
+    end = jnp.cumsum(count)
+    w = jnp.arange(r * n_blocks + 1, dtype=i32)
+    rows = jnp.minimum((w[:, None] >= end[None, :]).sum(axis=1), r - 1)
+    blocks = first[rows] + w - (end - count)[rows]
+    return (rows.astype(i32),
+            jnp.clip(blocks, 0, n_blocks - 1).astype(i32),
+            end[-1].astype(i32))
+
+
+def first_of_row(wrow_ref, w):
+    """Whether step ``w`` of a :func:`flat_work_list` grid opens its row's
+    run (the kernel resets the row's running softmax there)."""
+    return (w == 0) | (wrow_ref[jnp.maximum(w - 1, 0)] != wrow_ref[w])
+
+
+def live_pages(tables, block_ids, lengths, page_tokens: int):
+    """``[N]`` int32: how far into its table each row must be read, 1 +
+    the index of its last entry that names a page with a key under the
+    row's length (0: the row sees nothing).  Selected tables are
+    best-first with -1 at the tail and dense tables in block order, so
+    this is the count of such entries."""
+    seen = (tables >= 0) & (block_ids * page_tokens < lengths[:, None])
+    return (seen * jnp.arange(1, tables.shape[1] + 1,
+                              dtype=jnp.int32)).max(axis=1)
+
+
+def steps_visited(pages):
+    """Grid steps ``sparse_attend`` takes for rows that see ``pages``
+    pages each (numpy, on the host, as :func:`flat_work_list` lays them
+    out): a row with none is visited once (its zero output is written)."""
+    return int(np.maximum(-(-np.asarray(pages) // PAGES_PER_STEP), 1).sum())
+
+
+def _sparse_kernel(tab_ref, blk_ref, len_ref, hd_ref, wrow_ref, wblk_ref,
+                   n_ref, q_ref, kv_ref, o_ref, m_ref, l_ref, k_buf, v_buf,
+                   sem, *, layer: int, scale: float):
+    """One entry of the work list: ``PAGES_PER_STEP`` pages of row
+    ``wrow[w]`` as ONE key tile and one value tile.  ``kv_ref`` is the
+    whole arena where it lies (HBM); the step's pages are copied by hand,
+    one DMA a page and tensor, straight into ``k_buf`` / ``v_buf [2, pps,
+    T, D]`` (two slots: step ``w + 1``'s copies start before step ``w``
+    computes).  As block operands of the grid the same 16 fetches cost
+    0.93 us a step in the pipeline's own bookkeeping, twice what the
+    step computes (PERF.md section 6, PR 37)."""
     from jax.experimental import pallas as pl
-    k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
-    o_ref, m_ref, l_ref = refs[2 * pps:]
-    r = pl.program_id(0)
-    mi = pl.program_id(1)
+    from jax.experimental.pallas import tpu as pltpu
+    _, pps, t, d = k_buf.shape
+    n_pages = kv_ref.shape[3]
+    w = pl.program_id(0)
+    slot = jax.lax.rem(w, 2)
 
-    @pl.when(mi == 0)
+    def copies(step, slot):
+        r = wrow_ref[step]
+        e0, head = wblk_ref[step] * pps, hd_ref[r]
+        for i in range(pps):
+            # an entry that names no page fetches page 0 and is masked
+            at = jnp.clip(tab_ref[r, e0 + i], 0, n_pages - 1)
+            for which, buf in ((0, k_buf), (1, v_buf)):
+                yield pltpu.make_async_copy(
+                    kv_ref.at[layer, which, head, at], buf.at[slot, i],
+                    sem.at[slot])
+
+    @pl.when(w == 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    @pl.when(w + 1 < n_ref[0])
+    def _next():
+        for c in copies(w + 1, 1 - slot):
+            c.start()
+
+    @pl.when(first_of_row(wrow_ref, w))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...] * scale                                   # [G, D]
+    # each key's position from its page's logical block (lane ``j`` of
+    # page ``i`` is key ``block_i T + j - i T``); an entry that names no
+    # page lies behind every length
+    r = wrow_ref[w]
+    e0 = wblk_ref[w] * pps
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, pps * t), 1)
+    off = jnp.zeros_like(lane)
     for i in range(pps):
-        e = mi * pps + i
-        k, v = k_refs[i][...], v_refs[i][...]                # [T, D] bf16
-        s = _dot_split(q, k, (((1,), (1,)), ((), ())))
-        kpos = blk_ref[r, e] * page_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        valid = (kpos < len_ref[r]) & (tab_ref[r, e] >= 0)
-        s = jnp.where(valid, s, -jnp.inf)                    # [G, T]
-        m_prev = m_ref[...]                                  # [G, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        alpha = jnp.where(jnp.isneginf(m_prev), 0.0,
-                          jnp.exp(m_prev - m_safe))
-        p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-        o_ref[...] = o_ref[...] * alpha + _dot_split(
-            p, v, (((1,), (0,)), ((), ())))
+        at = jnp.where(tab_ref[r, e0 + i] >= 0, blk_ref[r, e0 + i] * t,
+                       jnp.iinfo(jnp.int32).max // 2)
+        off = jnp.where(lane >= i * t, at - i * t, off)
+    valid = off + lane < len_ref[r]                          # [1, pps T]
+    for c in copies(w, slot):
+        c.wait()
+    k = k_buf[slot].reshape(pps * t, d)
+    v = v_buf[slot].reshape(pps * t, d)
+    s = _dot_split(q_ref[...] * scale, k, (((1,), (1,)), ((), ())))
+    s = s + jnp.where(valid, 0.0, -jnp.inf)                  # [G, pps T]
+    m_prev = m_ref[...]                                      # [G, 1]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+    alpha = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
+    p = jnp.exp(s - m_safe)                                  # masked: 0
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    o_ref[...] = o_ref[...] * alpha + _dot_split(
+        p, v, (((1,), (0,)), ((), ())))
 
 
 def sparse_attend_pallas(q, kv, layer: int, heads, tables, block_ids,
                          lengths, extra_k=None, extra_v=None, *,
-                         pages_per_step: int = 4,
                          interpret: Optional[bool] = None):
+    """The kernel: a grid step takes ``PAGES_PER_STEP`` entries of a
+    row's table as one key tile, and the grid is the flat list of the
+    steps that hold a page some key of which the row sees
+    (:func:`flat_work_list`): a row whose last such entry is its ``n``-th
+    takes ``max(1, ceil(n / PAGES_PER_STEP))`` steps."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     n, g, d = q.shape
-    _, _, hkv, p, t, _ = kv.shape
-    mp = tables.shape[1]
-    pps = pages_per_step if mp % pages_per_step == 0 else 1
+    t = kv.shape[4]
+    pps = PAGES_PER_STEP
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     scale = 1.0 / math.sqrt(d)
-    f32 = jnp.float32
-    # the row's K/V head rides scalar prefetch inside the table: fold it
-    # into a fourth prefetch operand
-    heads = heads.astype(jnp.int32)
+    f32, i32 = jnp.float32, jnp.int32
+    qf = q.astype(f32)
+    tables, block_ids = tables.astype(i32), block_ids.astype(i32)
+    lengths = lengths.astype(i32)
+    if tables.shape[1] % pps:         # the table in whole steps of pages
+        pad = ((0, 0), (0, -tables.shape[1] % pps))
+        tables = jnp.pad(tables, pad, constant_values=-1)
+        block_ids = jnp.pad(block_ids, pad, constant_values=-1)
+    live = live_pages(tables, block_ids, lengths, t)
+    rows, blocks, n_steps = flat_work_list(
+        jnp.zeros((n,), i32), jnp.maximum(-(-live // pps), 1),
+        tables.shape[1] // pps)
 
-    def row3(r, m, tab, blk, ln, hd):
-        return (r, 0, 0)
-
-    def page(which, i):
-        def index(r, m, tab, blk, ln, hd):
-            return (layer, which, hd[r],
-                    jnp.clip(tab[r, m * pps + i], 0, p - 1), 0, 0)
-        return pl.BlockSpec((None, None, None, None, t, d), index)
-
-    def kernel(tab, blk, ln, hd, *refs):
-        _sparse_kernel(tab, blk, ln, *refs, pps=pps, page_tokens=t,
-                       scale=scale)
+    def row3(w, tab, blk, ln, hd, wrow, wblk, n_):
+        return (wrow[w], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(n, mp // pps),
-        in_specs=[pl.BlockSpec((None, g, d), row3)]
-        + [page(0, i) for i in range(pps)]
-        + [page(1, i) for i in range(pps)],
+        num_scalar_prefetch=7, grid=(n_steps,),
+        in_specs=[pl.BlockSpec((None, g, d), row3),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[pl.BlockSpec((None, g, d), row3),
                    pl.BlockSpec((None, g, 1), row3),
-                   pl.BlockSpec((None, g, 1), row3)])
+                   pl.BlockSpec((None, g, 1), row3)],
+        scratch_shapes=[pltpu.VMEM((2, pps, t, d), kv.dtype),
+                        pltpu.VMEM((2, pps, t, d), kv.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
     o, m, l = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        functools.partial(_sparse_kernel, layer=layer, scale=scale),
+        grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n, g, d), f32),
                    jax.ShapeDtypeStruct((n, g, 1), f32),
                    jax.ShapeDtypeStruct((n, g, 1), f32)],
         interpret=interpret, name="sparse_attend",
-    )(tables.astype(jnp.int32), block_ids.astype(jnp.int32),
-      lengths.astype(jnp.int32), heads, q.astype(f32),
-      *([kv] * (2 * pps)))
-    return _finish(o, m[..., 0], l[..., 0], q.astype(f32), extra_k,
-                   extra_v, scale)
+    )(tables, block_ids, lengths, heads.astype(i32), rows, blocks,
+      n_steps.reshape(1), qf, kv)
+    return _finish(o, m[..., 0], l[..., 0], qf, extra_k, extra_v, scale)
 
 
 def sparse_attend(q, kv, layer: int, heads, tables, block_ids, lengths,

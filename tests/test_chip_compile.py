@@ -186,7 +186,10 @@ def test_full_width_decode_step_compiles_and_fits(sds, monkeypatch):
 
 def test_sparse_attend_and_state_kernels_lower_at_published_widths(sds):
     """16 query heads a K/V head over 64 selected pages of 64 x 128
-    bf16 (decode: 16 rows; a prefill call: 128), and the lightning state
+    bf16, the two shapes the programs call it at (decode: 16 rows; a
+    prefill call: 128), eight pages a grid step over a work list whose
+    length is the grid's DYNAMIC bound (ISSUE 37): one Mosaic kernel a
+    call, the list built by plain XLA beside it; and the lightning state
     of 8 slots updated in place."""
     from brpc_tpu.ops.lightning import lightning_decode_pallas
     from brpc_tpu.ops.sparse_attention import sparse_attend_pallas
@@ -198,7 +201,9 @@ def test_sparse_attend_and_state_kernels_lower_at_published_widths(sds):
                 q, kv, 2, hd, tab, bid, ln, interpret=False)).lower(
             sds((n, 16, 128), f32), kv, sds((n,), i32), sds((n, 64), i32),
             sds((n, 64), i32), sds((n,), i32)).compile()
-        assert _has_kernel(compiled)
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert "sparse_attend" in text
     qkv = sds((8, 32, 128), f32)
     compiled = jax.jit(
         lambda st, rows, q, k, v, ld: lightning_decode_pallas(

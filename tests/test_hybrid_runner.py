@@ -208,6 +208,45 @@ def test_sparse_branch_with_topk_over_all_blocks_equals_dense(model):
     assert np.abs(out[0] - out[1]).max() < 2e-5
 
 
+def test_sparse_steps_counts_the_grid_steps_the_kernel_takes(model,
+                                                            monkeypatch):
+    """``runner_*_sparse_steps`` is derived on the host from positions
+    alone; held here to the length of every work list the device built
+    (the kernel interpreted, its grid bound read out through a callback)
+    over a prefill with a padded bucket and decode steps with idle
+    slots: ``sum max(1, ceil(live pages / 8))`` over the live rows and
+    one step a dead row.  ``topk`` 12, so a full row is two steps."""
+    from brpc_tpu.ops import sparse_attention as sa
+    cfg = configs(topk=12)[0]
+    built, real = [], sa.flat_work_list
+
+    def spy(first, count, n_blocks):
+        rows, blocks, n = real(first, count, n_blocks)
+        jax.debug.callback(lambda v: built.append(int(v)), n)
+        return rows, blocks, n
+    monkeypatch.setattr(sa, "flat_work_list", spy)
+    toks = tokens_of(190, seed=3)
+    rig = Rig(cfg, model[2], "t_steps", backend="pallas")
+    seq = rig.store.admit(toks[:170])
+    rig.prefill(seq, toks[:170])
+    rig.decode(seq, toks, 190)
+    jax.effects_barrier()
+    got = rig.runner.sparse_steps.get_value()
+    selected = rig.runner.sparse_selected.get_value()
+    rig.close()
+    hkv = cfg.n_kv_heads * cfg.n_sparse
+    # 169 prefilled positions in six buckets of 32 (23 of padding), then
+    # positions 169-189 a step each beside three idle slots
+    live = [min(p // T + 1, 12) if p >= 48 else p // T + 1
+            for p in range(190)]
+    want = hkv * (sum(max(1, -(-n // sa.PAGES_PER_STEP)) for n in live)
+                  + 23 + 3 * 21)
+    assert got == sum(built) == want
+    # pages stepped a page read: whole steps of 8, and the dead rows'
+    assert 1.0 < got * sa.PAGES_PER_STEP / (hkv * sum(live)) < 3.0
+    assert selected == sum(n for p, n in enumerate(live) if p >= 48)
+
+
 M = ref.model_cfg(configs()[1])      # the toy as the reference reads it
 
 

@@ -179,7 +179,7 @@ def test_the_work_list_visits_every_block_with_a_visible_key_once(lower):
     want = [(0, 0), (0, 1), (0, 2), (1, 7), (2, 0)] \
         + [(3, b) for b in range(3 if lower else 0, 7)]
     assert live == want
-    assert rows.shape == blocks.shape == (4 * 8,)
+    assert rows.shape == blocks.shape == (4 * 8 + 1,)
     assert int(np.asarray(blocks).max()) <= 7
 
 
