@@ -7,17 +7,29 @@ describes its cache.  :class:`LayeredCache` holds what the
 radix tree MANAGE, each kind in ONE persistent device array that the
 runner's donated programs update in place (no per-call restack):
 
-  ``kv``     ``[sparse layers, 2, Hkv, P, T, D]`` bfloat16: K and V of
-             the attention layers only, page ``p`` = flat arena index
-             ``p`` of the store's :class:`PagePool` (the pool's block
-             buffers shrink to a token-id stand-in of 4 bytes a token)
-  ``kc``     ``[sparse layers, P, 4, Hkv, D]`` bfloat16: the compressed
+  ``kv``     ``[K/V layers, 2, Hkv, P, T, D]`` bfloat16: K and V of
+             the attention layers only (learned-sparse and full
+             attention alike), page ``p`` = flat arena index ``p`` of
+             the store's :class:`PagePool` (the pool's block buffers
+             shrink to a token-id stand-in of 4 bytes a token)
+  ``kc``     ``[K/V layers, P, 4, Hkv, D]`` bfloat16: the compressed
              keys, an index beside the pages (a kernel is written when
-             its last key is, into that key's page)
-  ``state``  ``[rows + 2, linear layers, H, D, D]`` float32: one row a
-             live sequence and one a SNAPSHOT; the last two rows are a
-             scratch row (idle decode slots read and write it) and a
-             row that stays zero (a cold sequence starts from it)
+             its last key is, into that key's page); no element where
+             no layer selects blocks (``compressed`` false)
+  ``state``  ``[rows + 2, recurrent layers, ...]`` float32: one row a
+             live sequence and one a SNAPSHOT, holding whatever the
+             model's recurrent layers keep (``state_layer_shape``): a
+             lightning layer ``[H, D, D]``; a Mamba layer (ISSUE 38)
+             ``[ssm_rows, channels]``, channels minor in whole 128-lane
+             tiles: rows ``0 .. N-1`` the scan state, rows ``N .. N+2``
+             the causal convolution's tail (its last three inputs,
+             oldest first), the rest of the last 8-row tile zero
+             (``ops.mamba``): BOTH kinds of recurrent state a sequence
+             in the one row, so that restore, snapshot, the free list
+             and a radix node's ownership are one mechanism.  The last
+             two rows are a scratch row (idle decode slots read and
+             write it) and a row that stays zero (a cold sequence
+             starts from it)
   ``latent`` ``[latent layers, P, T, C]`` bfloat16 (ISSUE 34): ONE row
              a token a layer of a latent-attention model, ``[c_kv;
              k_rope]`` and nothing per head; ``C`` is the row's width
@@ -29,7 +41,10 @@ runner's donated programs update in place (no per-call restack):
 A kind the model has no layer of is an array with no element and costs
 nothing: no state row is allocated, restored or snapshot where
 ``n_linear`` is 0, and a hit is then whatever whole pages the radix tree
-matches.
+matches.  A live sequence's row comes before any snapshot's: an
+admission that finds no free row has the store evict cached prefixes
+until one is (``KVCacheStore._install_state``), and a snapshot that
+finds none is not taken (``state_snapshot_no_row`` counts them).
 
 A snapshot is the recurrent state after exactly a whole number of
 pages.  The radix node that ends that prefix owns it
@@ -54,15 +69,25 @@ class LayeredSpec:
     n_kv_heads: int
     head_dim: int
     n_linear: int            # layers that keep a recurrent state
-    n_lin_heads: int
+    n_lin_heads: int         # a lightning layer's [H, D, D] ...
     lin_head_dim: int
     state_rows: int          # live sequences + snapshots
     n_latent: int = 0        # layers that keep one latent row a token
     latent_dim: int = 0      # its width: kv_lora_rank + the rope key's
+    ssm_rows: int = 0        # ... or a Mamba layer's [rows, channels]
+    ssm_channels: int = 0
+    compressed: bool = True  # the K/V layers select blocks (keep ``kc``)
 
     @property
     def has_state(self) -> bool:
         return self.n_linear > 0
+
+    @property
+    def state_layer_shape(self) -> tuple:
+        """What ONE recurrent layer keeps of a sequence."""
+        if self.ssm_rows:
+            return (self.ssm_rows, self.ssm_channels)
+        return (self.n_lin_heads, self.lin_head_dim, self.lin_head_dim)
 
     @property
     def latent_lanes(self) -> int:
@@ -74,7 +99,7 @@ class LayeredSpec:
                 + self.n_latent * self.latent_dim) * 2
 
     def state_row_bytes(self) -> int:
-        return self.n_linear * self.n_lin_heads * self.lin_head_dim ** 2 * 4
+        return self.n_linear * int(np.prod(self.state_layer_shape)) * 4
 
 
 @functools.cache
@@ -124,20 +149,23 @@ class LayeredCache:
         bf16 = jnp.bfloat16
         self.kv = zeros((s.n_sparse, 2, s.n_kv_heads, self.pages,
                          self.page_tokens, s.head_dim), bf16)
-        self.kc = zeros((s.n_sparse, self.pages, 4, s.n_kv_heads,
-                         s.head_dim), bf16)
+        self.kc = zeros((s.n_sparse if s.compressed else 0, self.pages, 4,
+                         s.n_kv_heads, s.head_dim), bf16)
         self.latent = zeros((s.n_latent, self.pages, self.page_tokens,
                              s.latent_lanes), bf16)
         self.scratch_row = s.state_rows
         self.zero_row = s.state_rows + 1
-        self.state = zeros((s.state_rows + 2, s.n_linear, s.n_lin_heads,
-                            s.lin_head_dim, s.lin_head_dim), np.float32)
+        self.state = zeros((s.state_rows + 2, s.n_linear)
+                           + s.state_layer_shape, np.float32)
         self._free_rows = list(range(s.state_rows))[::-1]
         safe = "".join(c if c.isalnum() else "_" for c in name)
         self.bvar_names = [f"kvcache_{safe}_state_{what}" for what in
-                           ("snapshots", "restores", "restore_misses")]
-        self.snapshots, self.restores, self.restore_misses = (
-            Adder(n) for n in self.bvar_names)
+                           ("snapshots", "restores", "restore_misses",
+                            "snapshot_no_row")]
+        # snapshot_no_row: snapshots refused for want of a free row (the
+        # prefix is then not cached)
+        (self.snapshots, self.restores, self.restore_misses,
+         self.snapshot_no_row) = (Adder(n) for n in self.bvar_names)
 
     # ---- state rows ----
 
@@ -201,7 +229,8 @@ class LayeredCache:
                 "bytes": self.nbytes(),
                 "snapshots": self.snapshots.get_value(),
                 "restores": self.restores.get_value(),
-                "restore_misses": self.restore_misses.get_value()}
+                "restore_misses": self.restore_misses.get_value(),
+                "snapshot_no_row": self.snapshot_no_row.get_value()}
 
     def close(self) -> None:
         from brpc_tpu.bvar.variable import find_exposed

@@ -710,11 +710,28 @@ class KVCacheStore:
         if not self.layers.spec.has_state:
             seq.state_row = self.layers.scratch_row
             return
-        seq.state_row = self.layers.alloc_row()
+        seq.state_row = self._alloc_state_row()
         if snapshot is not None:
             self.layers.restore(seq.state_row, snapshot)
         else:
             self.layers.reset_row(seq.state_row)
+
+    def _alloc_state_row(self) -> int:
+        """A live sequence's row, with pressure-driven eviction as
+        :meth:`_alloc_page` has it: where snapshots hold every row,
+        LRU cached prefixes go (a radix node that is evicted frees its
+        snapshot's row) until one is free or the tree is dry.  The
+        hit's own snapshot is safe: the sequence holds a ref on its
+        page."""
+        while True:
+            try:
+                return self.layers.alloc_row()
+            except MemoryError:
+                with self._mu:
+                    freed = self.radix.evict(self.pagepool.pages_per_block)
+                self.evictions.add(freed)
+                if freed == 0:
+                    raise
 
     def _has_state(self) -> bool:
         """Whether a hit needs a recurrent state's snapshot beside its
@@ -769,10 +786,12 @@ class KVCacheStore:
     def take_snapshot(self, seq: KVSeq, n_tokens: int) -> bool:
         """Snapshot `seq`'s state row as the state after ``n_tokens``
         (the runner calls this when its prefill stands exactly there).
-        False where no row is free: the prefix is then not cached."""
+        False where no row is free: the prefix is then not cached
+        (``kvcache_*_state_snapshot_no_row`` counts them)."""
         try:
             row = self.layers.snapshot(seq.state_row)
         except MemoryError:
+            self.layers.snapshot_no_row.add(1)
             return False
         seq.snaps.append((n_tokens // self.page_tokens, row))
         return True

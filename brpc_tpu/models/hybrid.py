@@ -1,7 +1,8 @@
 """HybridRunner — a model described by its configuration (ISSUE 32).
 
 Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
-``mixer_types`` names each layer's mixer (MiniCPM-SALA today):
+``mixer_types`` names each layer's mixer (MiniCPM-SALA's, GLM-4.7-Flash's
+and Jamba's kinds today):
 
   ``minicpm4``        learned block-sparse attention (InfLLM-V2): K/V of
                       these layers only live in bf16 pages, one selection
@@ -21,6 +22,18 @@ Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
                       latent pages (``ops.latent_attention``); the decode
                       step reads the run of pages its slots hold in
                       common once for all of them (ISSUE 35)
+  ``mamba``           the Mamba-1 state-space mixer (Jamba, ISSUE 38): a
+                      causal depthwise convolution and a selective scan
+                      (``ops.mamba``), the step size, ``B`` and ``C``
+                      each through a learned RMS norm; a sequence keeps
+                      a float32 scan state ``[N, channels]`` and the
+                      convolution's last inputs a layer, both in its
+                      state row, restored from a snapshot on a radix hit
+  ``attention``       full softmax attention over the layered cache's
+                      K/V pages: ``minicpm4``'s kernel under a table of
+                      ALL the sequence's pages, no compressed keys, no
+                      selection, no rotary, q/k norm or gate unless the
+                      family states them
 
 and ``ffn_types`` its feed-forward: ``dense`` (the silu gated MLP) or
 ``moe`` (a float32 sigmoid router over all experts, the top ``k`` of
@@ -28,9 +41,11 @@ score + correction bias, the chosen scores normalised and scaled, the
 held experts' gated MLPs as ragged grouped matmuls with no capacity,
 ``ops.moe``, and a shared expert).  Around them learned RMS norms,
 per-head q/k norms, rotary positions, output gates, the muP scalings
-where the family has them and an untied head.  The equations are those
-of ``benchmarks/harness/reference_sala.py`` and ``reference_glm.py``
-(the plain references; the tier-1 tests hold this runner to them).
+where the family has them and an untied head, or the embedding as the
+head where the family ties them.  The equations are those of
+``benchmarks/harness/reference_sala.py``, ``reference_glm.py`` and
+``reference_jamba.py`` (the plain references; the tier-1 tests hold
+this runner to them).
 
 The cache is the store's :class:`~brpc_tpu.kvcache.layered.LayeredCache`:
 persistent device arrays, one a kind of state (a kind the model has no
@@ -50,7 +65,8 @@ bfloat16.  ``param_dtype="float32"`` (the CPU tests) multiplies at
 ``highest``.  ``control="low"`` is the benchmark's low-precision
 control and nothing a deployment sets: everything the configuration
 states in float32 and the program accumulates (every matmul's sum, the
-residual stream, the lightning state) then holds bfloat16 values, and
+residual stream, the lightning state, the scan state and the
+convolution's tail) then holds bfloat16 values, and
 the K/V and latent pages the values of an int8 cache (scale 1/16), the
 router bfloat16.  ``control="drop"`` leaves the last chosen expert's
 share out of every routed sum.
@@ -69,11 +85,18 @@ from brpc_tpu.bvar import Adder
 from brpc_tpu.models.runner import ModelRunner, TransformerConfig
 
 SPARSE, LINEAR, MLA = "minicpm4", "lightning-attn", "mla"
+MAMBA, ATTN = "mamba", "attention"
 DENSE, MOE = "dense", "moe"
 # what ``layer_shapes`` gives in place of a fan-in for what is neither a
-# norm weight nor a matrix in the parameters' type: the float32 router
-# (normal(0, 1/d_model)) and its correction bias (normal(0, 0.1))
+# norm weight nor a matrix in the parameters' type, all float32: a
+# matrix normal(0, 1/rows) (the router; the convolution's taps), a bias
+# normal(0, 0.1) (the router's correction; the convolution's), and
+# Mamba's own init of the state-space mixer: ``A_log = log(1..N)`` a
+# channel, ``D`` ones, ``dt_proj``'s bias the inverse softplus of a
+# step size log-uniform in DT_RANGE
 ROUTER, BIAS = "router", "bias"
+A_LOG, ONES, DT_BIAS = "a_log", "ones", "dt_bias"
+DT_RANGE = (1e-3, 1e-1)
 PREFILL_ROWS = 16     # positions a grid row of the latent prefill kernel
 # a slot's "live" column of the step program's operand: its token comes
 # from the host, or from the result of the step before on the device
@@ -107,6 +130,21 @@ def layer_shapes(cfg: TransformerConfig, kind: str,
                    wv=((dm, kvd), dm), wg=((dm, hd), dm),
                    wo=((hd, dm), hd), q_norm=((d,), None),
                    k_norm=((d,), None))
+    elif kind == ATTN:
+        hd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+        out.update(wq=((dm, hd), dm), wk=((dm, kvd), dm),
+                   wv=((dm, kvd), dm), wo=((hd, dm), hd))
+    elif kind == MAMBA:
+        di, n, r, k = (cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank,
+                       cfg.ssm_conv)
+        # what is per (state value, channel) or per (tap, channel) lies
+        # channels minor, as the state row does
+        out.update(w_in=((dm, 2 * di), dm), conv_w=((k, di), ROUTER),
+                   conv_b=((di,), BIAS), w_x=((di, r + 2 * n), di),
+                   dt_norm=((r,), None), b_norm=((n,), None),
+                   c_norm=((n,), None), w_dt=((r, di), r),
+                   b_dt=((di,), DT_BIAS), a_log=((n, di), A_LOG),
+                   d=((di,), ONES), w_out=((di, dm), di))
     else:
         hd, d = cfg.lin_heads * cfg.lin_head_dim, cfg.lin_head_dim
         out.update(wq=((dm, hd), dm), wk=((dm, hd), dm),
@@ -133,6 +171,17 @@ def layer_kinds(cfg: TransformerConfig) -> list:
     return list(zip(cfg.mixer_types, ffns))
 
 
+def top_shapes(cfg: TransformerConfig, head_gain: float = 1.0) -> dict:
+    """The embedding, the final norm and, where the family does not tie
+    it to the embedding, the head; in the order the init draws them."""
+    out = {"emb": ((cfg.vocab, cfg.d_model), cfg.d_model)}
+    if not cfg.tie_embeddings:
+        out["head"] = ((cfg.vocab, cfg.d_model),
+                       cfg.d_model / head_gain ** 2)
+    out["norm_f"] = ((cfg.d_model,), None)
+    return out
+
+
 def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
     """Seeded parameters: matrices normal(0, 1/fan_in) in
     ``cfg.param_dtype``, norm weights ``1 + 0.1 normal`` in float32,
@@ -152,6 +201,16 @@ def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
             x = jax.random.normal(k, shape, jnp.float32)
             if fan_in is None:
                 out[name] = 1.0 + 0.1 * x
+            elif fan_in == A_LOG:
+                out[name] = jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, shape[0] + 1, dtype=jnp.float32))[:, None], shape)
+            elif fan_in == ONES:
+                out[name] = jnp.ones(shape, jnp.float32)
+            elif fan_in == DT_BIAS:
+                lo, hi = (math.log(v) for v in DT_RANGE)
+                step = jnp.exp(jax.random.uniform(k, shape, jnp.float32,
+                                                  lo, hi))
+                out[name] = step + jnp.log(-jnp.expm1(-step))
             elif fan_in == BIAS:
                 out[name] = 0.1 * x
             elif fan_in == ROUTER:
@@ -168,10 +227,7 @@ def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
     # brpc-check: allow(jit-hot-path)
     init = {kind: jax.jit(functools.partial(draw, shapes=shapes))
             for kind, shapes in [
-        ("top", {"emb": ((cfg.vocab, cfg.d_model), cfg.d_model),
-                 "head": ((cfg.vocab, cfg.d_model),
-                          cfg.d_model / head_gain ** 2),
-                 "norm_f": ((cfg.d_model,), None)})]
+        ("top", top_shapes(cfg, head_gain))]
             + [(k, layer_shapes(cfg, *k)) for k in sorted(set(kinds))]}
     top = init["top"](ks[0])
     top["layers"] = [init[kind](k) for kind, k in zip(kinds, ks[1:])]
@@ -379,7 +435,7 @@ def _logits(params, h, cfg):
     # which is the layout the chip's compiler wants (a [d_model, vocab]
     # head is copied transposed, 0.6 GB, every step)
     import jax
-    w = params["head"]
+    w = params["emb"] if cfg.tie_embeddings else params["head"]
     if w.dtype == x.dtype:
         return jax.lax.dot_general(x, w, (((1,), (1,)), ((), ())),
                                    precision="highest")
@@ -405,6 +461,21 @@ def _gate_out(p, x, o, gated):
     if gated:
         o = o * jax.nn.sigmoid(_mm(x, p["wg"]))
     return _mm(o, p["wo"])
+
+
+def _ssm_inputs(p, xc, cfg):
+    """The state-space mixer's per-token inputs from the convolved rows
+    ``xc [N, channels]``: ``(delta [N, channels], B [N, n], C [N, n])``,
+    the low-rank step size, ``B`` and ``C`` each through its learned
+    RMS norm (the family's addition to Mamba-1), ``delta = softplus(W_dt
+    dt + b_dt)``."""
+    import jax
+    r, n = cfg.ssm_dt_rank, cfg.ssm_state
+    dbc = _mm(xc, p["w_x"])
+    dt = _rms(dbc[:, :r], p["dt_norm"], cfg.rms_eps)
+    bm = _rms(dbc[:, r:r + n], p["b_norm"], cfg.rms_eps)
+    cm = _rms(dbc[:, r + n:], p["c_norm"], cfg.rms_eps)
+    return jax.nn.softplus(_mm(dt, p["w_dt"]) + p["b_dt"]), bm, cm
 
 
 def _select_tables(q, kcs, qpos, table, cfg, page_tokens):
@@ -459,6 +530,23 @@ def _attend_rows(q, kv, ls, pages, blocks, lengths, backend):
         pages.reshape(n * hkv, mp), blocks.reshape(n * hkv, mp),
         jnp.repeat(lengths, hkv), backend=backend)
     return o.reshape(n, hkv * g * d)
+
+
+def _attend_all(q4, kv, ls, tables, qpos, live, backend):
+    """The ``attention`` mixer's attention for N positions: every page
+    of the sequence's table ``tables [N, MPs]`` IS the position's page
+    table, keys at positions ``<= qpos`` take part.  Keys are read from
+    the arena only: write before you attend.  A row that is not live
+    names no page (see :func:`_attend_positions`)."""
+    import jax.numpy as jnp
+    n, hkv = q4.shape[0], q4.shape[1]
+    mps = tables.shape[1]
+    pages, blocks = _dense_tables(jnp.where(live[:, None], tables, -1),
+                                  mps, mps)
+    return _attend_rows(
+        q4, kv, ls, jnp.broadcast_to(pages[:, None, :], (n, hkv, mps)),
+        jnp.broadcast_to(blocks[:, None, :], (n, hkv, mps)),
+        jnp.where(live, qpos + 1, 0), backend)
 
 
 def _attend_positions(q4, kv, ls, tables, kcs, qpos, live, cfg, backend):
@@ -518,6 +606,8 @@ def _programs():
                                                latent_write)
     from brpc_tpu.ops.lightning import (lightning_chunk, lightning_decode,
                                         log_decays)
+    from brpc_tpu.ops.mamba import (conv_chunk, conv_step, mamba_scan,
+                                    mamba_step)
     from brpc_tpu.ops.sparse_attention import (KERNELS_PER_PAGE,
                                                cache_write, compress_keys,
                                                page_keys)
@@ -610,6 +700,30 @@ def _programs():
                 h = _acc(control, h + rs * _gate_out(
                     p, x, o, cfg.attn_output_gate))
                 ls += 1
+            elif kind == ATTN:
+                q, k, v = _qkv(p, x, cfg.n_heads, hkv, cfg.head_dim, cfg)
+                kv = cache_write(
+                    kv, ls, page, qpos % t_page,
+                    _cache_round(k, control)[:, :, None],
+                    _cache_round(v, control)[:, :, None], backend=backend)
+                o = _attend_all(q.reshape(s_n, hkv, g, cfg.head_dim), kv,
+                                ls, tables, qpos, active, backend)
+                h = _acc(control, h + rs * _mm(o, p["wo"]))
+                ls += 1
+            elif kind == MAMBA:
+                di = cfg.ssm_inner
+                xz = _mm(x, p["w_in"])
+                xc, state = conv_step(
+                    state, rows, ll, xz[:, :di], p["conv_w"], p["conv_b"],
+                    d_state=cfg.ssm_state, round_state=round_state,
+                    backend=backend)
+                delta, bm, cm = _ssm_inputs(p, xc, cfg)
+                y, state = mamba_step(
+                    state, rows, ll, xc, delta, xz[:, di:], bm, cm,
+                    p["a_log"], p["d"], round_state=round_state,
+                    backend=backend)
+                h = _acc(control, h + rs * _mm(y, p["w_out"]))
+                ll += 1
             else:
                 hl, dl = cfg.lin_heads, cfg.lin_head_dim
                 q, k, v = _qkv(p, x, hl, hl, dl, cfg)
@@ -747,6 +861,45 @@ def _programs():
                 h = _acc(control, h + rs * _gate_out(
                     p, x, o.reshape(c, hkv * g * d), cfg.attn_output_gate))
                 ls += 1
+            elif kind == ATTN:
+                q, k, v = _qkv(p, x, cfg.n_heads, hkv, d, cfg)
+                kv = cache_write(
+                    kv, ls, page, jnp.zeros((n_pg,), jnp.int32),
+                    _cache_round(k, control).reshape(
+                        n_pg, t_page, hkv, d).transpose(0, 2, 1, 3),
+                    _cache_round(v, control).reshape(
+                        n_pg, t_page, hkv, d).transpose(0, 2, 1, 3),
+                    backend=backend)
+
+                def block(args, ls=ls, kv=kv):
+                    qq, pp, vv = args
+                    return _attend_all(
+                        qq, kv, ls, jnp.broadcast_to(table[None], (qs, mps)),
+                        pp, vv, backend)
+                o = jax.lax.map(block, (
+                    q.reshape(c // qs, qs, hkv, g, d),
+                    qpos.reshape(c // qs, qs), valid.reshape(c // qs, qs)))
+                h = _acc(control, h + rs * _mm(
+                    o.reshape(c, hkv * g * d), p["wo"]))
+                ls += 1
+            elif kind == MAMBA:
+                di, n_s, taps = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv
+                xz = _mm(x, p["w_in"])
+                # this layer's block of the sequence's state row: the
+                # scan state, the convolution's tail, the tile's padding
+                mine = state[row, ll]
+                xc, tail = conv_chunk(
+                    xz[:, :di], mine[n_s:n_s + taps - 1], p["conv_w"],
+                    p["conv_b"], n_valid, round_state=round_state)
+                delta, bm, cm = _ssm_inputs(p, xc, cfg)
+                y, s_end = mamba_scan(
+                    xc, delta, xz[:, di:], bm, cm, mine[:n_s], p["a_log"],
+                    p["d"], n_valid, round_state=round_state,
+                    backend=backend)
+                state = state.at[row, ll].set(jnp.concatenate(
+                    [s_end, tail, mine[n_s + taps - 1:]], axis=0))
+                h = _acc(control, h + rs * _mm(y, p["w_out"]))
+                ll += 1
             else:
                 hl, dl = cfg.lin_heads, cfg.lin_head_dim
                 q, k, v = _qkv(p, x, hl, hl, dl, cfg)
@@ -790,12 +943,21 @@ def _programs():
 
 def layered_spec(cfg: TransformerConfig, state_rows: int):
     from brpc_tpu.kvcache.layered import LayeredSpec
+    from brpc_tpu.ops.mamba import state_block_rows
+    if cfg.n_linear and cfg.n_mamba:
+        raise ValueError("a state row holds one kind of recurrent layer: "
+                         "lightning or Mamba, not both")
+    recurrent = cfg.n_linear + cfg.n_mamba
     return LayeredSpec(
-        n_sparse=cfg.n_sparse, n_kv_heads=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, n_linear=cfg.n_linear,
+        n_sparse=cfg.n_kv_layers, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, n_linear=recurrent,
         n_lin_heads=cfg.lin_heads, lin_head_dim=cfg.lin_head_dim,
-        state_rows=int(state_rows) if cfg.n_linear else 0,
-        n_latent=cfg.n_latent, latent_dim=cfg.latent_dim)
+        state_rows=int(state_rows) if recurrent else 0,
+        n_latent=cfg.n_latent, latent_dim=cfg.latent_dim,
+        ssm_rows=state_block_rows(cfg.ssm_state, cfg.ssm_conv)
+        if cfg.n_mamba else 0,
+        ssm_channels=cfg.ssm_inner if cfg.n_mamba else 0,
+        compressed=bool(cfg.n_sparse))
 
 
 def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
@@ -803,9 +965,10 @@ def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
                        device=None, name: str = "kv"):
     """A :class:`KVCacheStore` for a described architecture: page ids,
     refcounts and the radix tree as ever, ``cache_pages`` pages whose
-    K/V (sparse-attention layers), compressed keys, latent rows
-    (latent-attention layers) and ``state_rows`` recurrent-state rows
-    (linear layers) live in the store's :class:`LayeredCache`.  A page
+    K/V (sparse- and full-attention layers), compressed keys, latent
+    rows (latent-attention layers) and ``state_rows`` recurrent-state
+    rows (lightning or Mamba layers) live in the store's
+    :class:`LayeredCache`.  A page
     holds ``cfg.sparse_block`` tokens where the model selects blocks
     (one selection block a page), else ``page_tokens``.  The pool's own
     blocks hold the 4-byte token-id stand-in only."""
@@ -872,8 +1035,13 @@ class HybridRunner(ModelRunner):
         # over the blocks selected, the pages stepped a page that is read
         self.sparse_steps = Adder(f"runner_{safe}_sparse_steps")
         self.lightning_tokens = Adder(f"runner_{safe}_lightning_tokens")
+        # state-space layers: valid positions through ``mamba_scan`` (a
+        # prefill chunk's) and slot-steps through ``mamba_step``
+        self.mamba_tokens = Adder(f"runner_{safe}_mamba_tokens")
+        self.mamba_steps = Adder(f"runner_{safe}_mamba_steps")
         names = ["sparse_selected_blocks", "sparse_positions",
-                 "dense_positions", "sparse_steps", "lightning_tokens"]
+                 "dense_positions", "sparse_steps", "lightning_tokens",
+                 "mamba_tokens", "mamba_steps"]
         # expert layers: (token, expert) pairs routed, and the distinct
         # experts a layer a decode step hit, summed; latent layers: rows
         # a decode step attended to (a layer), and the distinct pages
@@ -948,11 +1116,14 @@ class HybridRunner(ModelRunner):
         self._table_cache = live
         return out
 
-    def _count(self, qpos, n_sel, dead: int) -> None:
-        """Counters of the positions a program just computed; ``dead``
-        the rows beside them that were not live (idle slots, a bucket's
+    def _count(self, qpos, n_sel, dead: int, step: bool = False) -> None:
+        """Counters of the positions a program just computed (``step``:
+        the decode step, else a prefill chunk); ``dead`` the rows
+        beside them that were not live (idle slots, a bucket's
         padding)."""
         cfg = self.cfg
+        if cfg.n_mamba:
+            (self.mamba_steps if step else self.mamba_tokens).add(len(qpos))
         if cfg.n_sparse:
             from brpc_tpu.ops.sparse_attention import steps_visited
             dense = qpos + 1 <= cfg.sparse_dense_len
@@ -1092,7 +1263,7 @@ class HybridRunner(ModelRunner):
         for chunk in handle["prefills"]:
             self._count(*chunk)
         self._count(positions[live] - 1, out[2][live].sum(),
-                    len(live) - int(live.sum()))
+                    len(live) - int(live.sum()), step=True)
         if self.cfg.n_moe:
             self.moe_experts_hit.add(int(out[3][0]))
         if self.cfg.n_latent:

@@ -251,8 +251,11 @@ class TransformerConfig:
     mixer (``"minicpm4"``: learned block-sparse attention over paged
     K/V; ``"lightning-attn"``: linear attention with a recurrent
     state; ``"mla"``: latent attention over pages of one compressed
-    row a token), ``ffn_types`` its feed-forward (``"dense"``: the
-    gated MLP; ``"moe"``: routed experts and a shared one), and
+    row a token; ``"mamba"``: the Mamba-1 state-space mixer, a scan
+    state and a convolution tail a sequence; ``"attention"``: full
+    softmax attention over paged K/V, no selection), ``ffn_types`` its
+    feed-forward (``"dense"``: the gated MLP; ``"moe"``: routed experts
+    and a shared one), and
     :class:`~brpc_tpu.models.hybrid.HybridRunner` serves it."""
     vocab: int = 128
     d_model: int = 32
@@ -294,6 +297,14 @@ class TransformerConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # the state-space mixer (ISSUE 38, Mamba-1): ``ssm_expand x
+    # d_model`` channels, ``ssm_state`` state values a channel, a causal
+    # depthwise convolution of ``ssm_conv`` taps, a step size projected
+    # up from ``ssm_dt_rank``
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_dt_rank: int = 0
+    ssm_expand: int = 0
     # feed-forward kind a held layer; () is the gated MLP everywhere
     ffn_types: tuple = ()
     n_experts: int = 0              # routed experts the router scores
@@ -313,13 +324,31 @@ class TransformerConfig:
         (``kvcache.layered``): bf16 K/V for its attention layers only,
         and this is then that figure."""
         if self.mixer_types:
-            return (self.n_sparse * 2 * self.n_kv_heads * self.head_dim
+            return (self.n_kv_layers * 2 * self.n_kv_heads * self.head_dim
                     + self.n_latent * self.latent_dim) * 2
         return self.n_layers * 2 * self.n_kv_heads * self.head_dim * 4
 
     @property
     def n_sparse(self) -> int:
         return sum(1 for m in self.mixer_types if m == "minicpm4")
+
+    @property
+    def n_attention(self) -> int:
+        return sum(1 for m in self.mixer_types if m == "attention")
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep K/V pages: both attention kinds."""
+        return self.n_sparse + self.n_attention
+
+    @property
+    def n_mamba(self) -> int:
+        return sum(1 for m in self.mixer_types if m == "mamba")
+
+    @property
+    def ssm_inner(self) -> int:
+        """The state-space mixer's channels."""
+        return self.ssm_expand * self.d_model
 
     @property
     def n_linear(self) -> int:
@@ -348,9 +377,13 @@ class TransformerConfig:
 
     def layer_param_counts(self) -> dict:
         """Parameters of one MLP, one mixer of each kind (norm weights
-        left out), one expert layer's feed-forward (every routed expert,
-        the shared ones and the router) and the embedding."""
+        left out; of the state-space mixer everything its published
+        count has: the convolution and its bias, ``dt_proj``'s bias,
+        ``A_log``, ``D`` and the dt/B/C norms), one expert layer's
+        feed-forward (every routed expert, the shared ones and the
+        router) and the embedding."""
         dm = self.d_model
+        di, r, n = self.ssm_inner, self.ssm_dt_rank, self.ssm_state
         hd = self.n_heads * self.head_dim
         kvd = self.n_kv_heads * self.head_dim
         lin = self.lin_heads * self.lin_head_dim
@@ -365,13 +398,17 @@ class TransformerConfig:
                 + dm * self.latent_dim + self.kv_lora_rank * h
                 * (self.qk_nope_dim + self.v_head_dim)
                 + h * self.v_head_dim * dm,
+                "mamba": dm * 2 * di + di * (self.ssm_conv + 1)
+                + di * (r + 2 * n) + r * di + di + di * n + di
+                + r + 2 * n + di * dm,
+                "attention": 2 * dm * hd + 2 * dm * kvd,
                 "moe": 3 * dm * self.moe_d_ff
                 * (self.n_experts + self.n_shared_experts)
                 + dm * self.n_experts,
                 "embedding": self.vocab * dm}
 
 
-MIXER_KINDS = ("minicpm4", "lightning-attn", "mla")
+MIXER_KINDS = ("minicpm4", "lightning-attn", "mla", "mamba", "attention")
 
 
 def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
@@ -380,9 +417,11 @@ def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
                    param_dtype: str = "bfloat16") -> TransformerConfig:
     """A :class:`TransformerConfig` from a published ``config.json``'s
     keys, taken verbatim: a file that names its ``mixer_types``
-    (MiniCPM-SALA's), or ``model_type`` ``glm4_moe_lite`` (latent
+    (MiniCPM-SALA's), ``model_type`` ``glm4_moe_lite`` (latent
     attention in every layer, ``first_k_dense_replace`` gated MLPs and
-    then routed experts); any other raises.  ``layers`` =
+    then routed experts), or ``model_type`` ``jamba`` (state-space
+    mixers with full attention where ``attn_layer_period`` /
+    ``attn_layer_offset`` put it); any other raises.  ``layers`` =
     ``(first, count)`` holds a contiguous slice of the published
     layers (a pipeline stage); ``sparse`` gives what the published
     file does not carry (``kernel_size``, ``kernel_stride``,
@@ -390,12 +429,17 @@ def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
     ``dense_len``); ``experts`` = ``(first, count)`` are the routed
     experts this chip computes (all of them where it is None)."""
     if "mixer_types" not in hf:
-        if hf.get("model_type") != "glm4_moe_lite":
+        family = hf.get("model_type")
+        if family not in ("glm4_moe_lite", "jamba"):
             raise ValueError(
-                f"no description of model_type {hf.get('model_type')!r}: "
-                f"a config names its mixer_types or is glm4_moe_lite")
+                f"no description of model_type {family!r}: a config names "
+                f"its mixer_types or is glm4_moe_lite or jamba")
         if sparse:
-            raise ValueError("glm4_moe_lite has no sparse settings")
+            raise ValueError(f"{family} has no sparse settings")
+        if family == "jamba":
+            if experts is not None:
+                raise ValueError("this family's experts are not described")
+            return _from_jamba(hf, layers, param_dtype)
         return _from_glm4_moe_lite(hf, layers, experts, param_dtype)
     if experts is not None:
         raise ValueError("this family has no routed experts")
@@ -492,6 +536,44 @@ def _from_glm4_moe_lite(hf: dict, layers, experts,
         routed_scale=float(hf["routed_scaling_factor"]),
         norm_topk=bool(hf["norm_topk_prob"]),
         experts_held=(e_first, e_count), param_dtype=param_dtype)
+
+
+def _from_jamba(hf: dict, layers, param_dtype: str) -> TransformerConfig:
+    """Jamba's keys: layer ``i`` mixes by attention where ``i %
+    attn_layer_period == attn_layer_offset`` and by Mamba-1 elsewhere
+    (the family's convention; the published file does not list the
+    order).  What this runner does not compute raises: routed experts
+    (``num_experts`` over 1), a sliding window, another activation than
+    silu, biases on the state-space mixer's projections, a convolution
+    without its bias.  The family has no rotary positions, no q/k norms
+    and no output gate; heads are ``hidden_size / num_attention_heads``
+    wide."""
+    for key, want in (("hidden_act", "silu"), ("num_experts", 1),
+                      ("sliding_window", None), ("mamba_proj_bias", False),
+                      ("mamba_conv_bias", True)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"jamba with {key}={hf[key]!r} is not "
+                             f"described (only {want!r})")
+    depth = int(hf["num_hidden_layers"])
+    first, count = layers if layers is not None else (0, depth)
+    if first < 0 or first + count > depth:
+        raise ValueError(f"layers {first}+{count} exceed {depth}")
+    period, offset = int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
+    dm, heads = int(hf["hidden_size"]), int(hf["num_attention_heads"])
+    if dm % heads:
+        raise ValueError(f"hidden_size {dm} is not whole heads of {heads}")
+    return TransformerConfig(
+        vocab=int(hf["vocab_size"]), d_model=dm, n_layers=count,
+        n_heads=heads, n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=dm // heads, d_ff=int(hf["intermediate_size"]),
+        mixer_types=tuple("attention" if (first + i) % period == offset
+                          else "mamba" for i in range(count)),
+        depth_published=depth, layer_offset=first,
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        ssm_state=int(hf["mamba_d_state"]), ssm_conv=int(hf["mamba_d_conv"]),
+        ssm_dt_rank=int(hf["mamba_dt_rank"]),
+        ssm_expand=int(hf["mamba_expand"]), param_dtype=param_dtype)
 
 
 def init_runner_params(cfg: TransformerConfig, key=None) -> dict:

@@ -242,18 +242,17 @@ def _served(name, sds):
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmarks", "configs", name)) as f:
         c = json.load(f)
+    held = c["num_hidden_layers"]     # a whole model names no slice
     cfg = from_hf_config(
-        dict(c, num_hidden_layers=c["published_num_hidden_layers"]),
-        layers=(c["first_published_layer"], c["num_hidden_layers"]),
+        dict(c, num_hidden_layers=c.get("published_num_hidden_layers", held)),
+        layers=(c.get("first_published_layer", 0), held),
         sparse=c["assumed"].get("sparse_config", {}).get("value"),
         param_dtype=c["param_dtype"])
 
     def shaped(shapes):
         return {n: sds(s, jnp.bfloat16 if isinstance(fan, (int, float))
                        else jnp.float32) for n, (s, fan) in shapes.items()}
-    params = shaped({"emb": ((cfg.vocab, cfg.d_model), 1),
-                     "head": ((cfg.vocab, cfg.d_model), 1),
-                     "norm_f": ((cfg.d_model,), None)})
+    params = shaped(hybrid.top_shapes(cfg))
     params["layers"] = [shaped(hybrid.layer_shapes(cfg, *kinds))
                         for kinds in hybrid.layer_kinds(cfg)]
     return (c, hybrid._programs(), params,
@@ -277,7 +276,8 @@ def _copies(compiled, arena) -> list:
     The latent arena: a row of 576 lanes, not whole tiles, got it another
     layout and 1 GB copied each way around every kernel call (found
     before the first chip run of PR 34)."""
-    shape = "bf16[" + ",".join(str(d) for d in arena.shape) + "]"
+    kind = "bf16" if arena.dtype == jnp.bfloat16 else "f32"
+    shape = kind + "[" + ",".join(str(d) for d in arena.shape) + "]"
     return [line.strip()[:120] for line in compiled.as_text().splitlines()
             if " copy(" in line and f"= {shape}" in line]
 
@@ -384,3 +384,100 @@ def test_glm_latent_rows_of_the_published_width_would_be_copied(sds):
             sds((16, 1, lanes), jnp.float32)).compile()
         assert _has_kernel(compiled)
         assert bool(_copies(compiled, latent)) is copied
+
+
+# ---- Jamba (ISSUE 38): the state-space kernels and both programs --------
+
+def test_jamba_scan_and_step_kernels_lower_at_published_widths(sds):
+    """5,120 channels, 16 state values: the chunk scan at the smallest
+    and the largest prefill bucket (the state of ten channel blocks in
+    VMEM across the chunk), and the convolution's and the scan's slot
+    updates of 32 slots on a 98-row, 26-layer state array, in place:
+    one Mosaic kernel each and no copy of the array."""
+    from brpc_tpu.ops import mamba
+    f32, i32 = jnp.float32, jnp.int32
+    ch, n = 5120, 16
+    for c in (64, 512):
+        wide, thin = sds((c, ch), f32), sds((c, n), f32)
+        compiled = jax.jit(
+            lambda xc, dl, z, b, cm, h0, a, d, nv: mamba.mamba_scan(
+                xc, dl, z, b, cm, h0, a, d, nv, backend="mosaic")).lower(
+            wide, wide, wide, thin, thin, sds((n, ch), f32),
+            sds((n, ch), f32), sds((ch,), f32), sds((), i32)).compile()
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert "mamba_scan" in text
+    state = sds((98, 26, 24, ch), f32)
+    wide, thin = sds((32, ch), f32), sds((32, n), f32)
+    conv = jax.jit(
+        lambda st, rows, xs, w, b: mamba.conv_step(
+            st, rows, 3, xs, w, b, d_state=n, backend="mosaic"),
+        donate_argnums=0).lower(
+        state, sds((32,), i32), wide, sds((4, ch), f32),
+        sds((ch,), f32)).compile()
+    step = jax.jit(
+        lambda st, rows, xc, dl, z, b, c, a, d: mamba.mamba_step(
+            st, rows, 3, xc, dl, z, b, c, a, d, backend="mosaic"),
+        donate_argnums=0).lower(
+        state, sds((32,), i32), wide, wide, wide, thin, thin,
+        sds((n, ch), f32), sds((ch,), f32)).compile()
+    for compiled, name in ((conv, "mamba_conv"), (step, "mamba_step")):
+        text = compiled.as_text()
+        assert _has_kernel(compiled) and name in text
+        assert "f32[98,26,24,5120]" in text
+        assert _copies(compiled, state) == []
+
+
+@pytest.fixture(scope="module")
+def jamba(sds):
+    from brpc_tpu.models.hybrid import layered_spec
+    c, fns, params, statics = _served("jamba2_3b_1chip.json", sds)
+    spec = layered_spec(statics["cfg"], c["state_rows"])
+    pages, t = c["cache_pages"], c["page_tokens"]
+    caches = (sds((2, 2, 1, pages, t, 128), jnp.bfloat16),
+              sds((0, pages, 4, 1, 128), jnp.bfloat16),
+              sds((c["state_rows"] + 2, 26) + spec.state_layer_shape,
+                  jnp.float32))
+    return c, fns, params, caches, statics
+
+
+def test_jamba_decode_step_compiles_at_published_widths_and_fits(sds, jamba):
+    """``jamba2_3b_1chip``'s decode step for a described v5e, the whole
+    model: two kernels a Mamba layer (the convolution's and the scan's
+    slot updates, 52), the cache's write and the attention over every
+    page of the 2 attention layers, neither the K/V arena nor the 1.25
+    GB state array copied, weights + cache + temporaries inside the
+    chip."""
+    c, fns, params, caches, statics = jamba
+    assert caches[2].shape == (98, 26, 24, 5120)
+    s, mp = c["num_slots"], c["max_pages_per_slot"]
+    compiled = fns["step"].lower(
+        params, *caches, sds((s, 4 + mp), jnp.int32),
+        sds((3, s), jnp.float32), logits_out=False, **statics).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 26 * 2 + 2 * 2
+    assert _copies(compiled, caches[0]) == []
+    assert _copies(compiled, caches[2]) == []
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 7e9 < need <= 0.9 * 16 * 2**30, \
+        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+    assert mem.temp_size_in_bytes < 0.2e9
+
+
+def test_jamba_prefill_compiles_without_copying_the_caches(sds, jamba):
+    """The largest prefill bucket of ``jamba_chat_churn`` (every request
+    of the window runs a chunk through the scan; the kernel alone at the
+    smallest is above): 26 ``mamba_scan`` kernels, the attention layers'
+    writes and reads, no copy of the arena or of the state array."""
+    c, fns, params, caches, statics = jamba
+    mp = c["max_pages_per_slot"]
+    for bucket in c["prefill_buckets"][-1:]:
+        compiled = fns["prefill"].lower(
+            params, *caches, sds((3 + mp + bucket,), jnp.int32),
+            logits_out=False, max_pages=mp, **statics).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 26 + 2 * 2
+        assert "mamba_scan" in text
+        assert _copies(compiled, caches[0]) == []
+        assert _copies(compiled, caches[2]) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
