@@ -364,6 +364,13 @@ _sigs = {
                                ctypes.c_int, ctypes.c_int64,
                                ctypes.POINTER(ctypes.c_int),
                                ctypes.POINTER(ctypes.c_int32)]),
+    "brpc_tokring_pop_each": (ctypes.c_int,
+                              [ctypes.POINTER(ctypes.c_void_p),
+                               ctypes.c_int,
+                               ctypes.POINTER(ctypes.c_int32),
+                               ctypes.c_int,
+                               ctypes.POINTER(ctypes.c_int32),
+                               ctypes.POINTER(ctypes.c_uint8)]),
     "brpc_tokring_size": (ctypes.c_int64, [ctypes.c_void_p]),
     # native flight recorder (ISSUE 15; src/cc/butil/flight.h):
     # always-on per-thread event rings in the C++ core — merged dump,

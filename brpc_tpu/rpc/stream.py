@@ -482,6 +482,56 @@ class Stream:
         self._on_closed_internal()
 
 
+def write_runs(runs: list) -> tuple:
+    """Many small host messages to many streams without waiting for any
+    of them: each entry of ``runs`` is ``(stream, messages)``, and the
+    longest prefix of ``messages`` that fits the stream's window NOW is
+    taken (credit, then a sequence number a message, in order, under the
+    stream's lock: what ``write`` does for one).  The frames of every
+    stream bound to one connection leave as ONE ``Transport.write_frames``
+    call, in the order of ``runs``.  Returns ``(taken, writes)``:
+    ``taken[i]`` is how many of entry i's messages went out (what is left
+    stays the caller's, to offer again once feedback has come), or -1
+    where the stream is closed or its connection refused the write
+    (every stream of that run is then closed, as after a failed
+    ``write``); ``writes`` counts the socket writes made.  For a sender
+    that serves many streams from one thread (the decode engine's emit
+    drainer); ``write`` stays the call for one that may wait."""
+    taken = [0] * len(runs)
+    by_sid: dict = {}        # sid -> (frames, entries that ride it)
+    for i, (s, messages) in enumerate(runs):
+        with s._mu:
+            if s._closed or s._close_sent:
+                taken[i] = -1
+                continue
+            if s._sid is None or s.remote_id is None:
+                continue        # not bound yet: nothing fits
+            n = 0
+            frames, members = by_sid.setdefault(s._sid, ([], []))
+            for m in messages:
+                if s._produced + len(m) - s._remote_consumed \
+                        > s.max_buf_size:
+                    break
+                s._produced += len(m)
+                frames.append((M.RpcMeta.encode_stream_data(
+                    s.remote_id, s._send_seq), m))
+                s._send_seq += 1
+                n += 1
+            taken[i] = n
+            if n:
+                members.append(i)
+    writes = 0
+    for sid, (frames, members) in by_sid.items():
+        if not frames:
+            continue
+        writes += 1
+        if Transport.instance().write_frames(sid, frames) != 0:
+            for i in members:
+                taken[i] = -1
+                runs[i][0]._on_closed_internal()
+    return taken, writes
+
+
 def _tensor_send_loop(wref, q) -> None:
     """Per-stream tensor sender (module-level: holds NO strong reference
     to the Stream between batches).  Exits on the close sentinel, when

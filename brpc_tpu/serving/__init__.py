@@ -195,7 +195,7 @@ def generations_snapshot(limit: int = 50) -> dict:
 
 
 from brpc_tpu.serving.batcher import DynamicBatcher  # noqa: E402,F401
-from brpc_tpu.serving.engine import DecodeEngine  # noqa: E402,F401
+from brpc_tpu.serving.engine import DecodeEngine, MessageSink  # noqa: E402,F401
 from brpc_tpu.serving.service import (  # noqa: E402,F401
     ScoreClient, ServingService, http_generate_handler, register_serving,
 )

@@ -62,13 +62,24 @@ the slot object it was dispatched for; and a slot is handed on
 flight and as booked, so that whoever rebuilds a sequence from it
 applies no step twice.
 
-Emission: each admitted request gets a BOUNDED emit buffer drained by
-its own emitter thread — the shared step loop never blocks in
-``emit``.  A consumer that stops draining (stream credit exhausted,
-dead HTTP peer) fills its buffer and is CUT with EOVERCROWDED at the
-next step boundary while every other slot keeps streaming; a raising
-``emit`` retires just that request.  ``on_done(err)`` fires exactly
-once per request, success or failure, after its buffered tokens flush.
+Emission: each admitted request gets a BOUNDED emit buffer (a native
+token ring) that the step loop pushes into without ever blocking, one
+``push_many`` a step across every slot.  WHO drains it is read from what
+the request's sink is.  A :class:`MessageSink` (one encoded message a
+token on an rpc stream: what ``Serving.Generate`` hands in) rides the
+engine's ONE emit drainer: a thread that the engine thread wakes once a
+booked step, that takes what every ring holds in one native call,
+encodes the step's messages and hands each connection its run of frames
+as one socket write (``rpc/stream.write_runs``: credit is taken from
+each stream's window without waiting).  A stream whose window is full
+keeps its tokens in its own ring, and nobody else waits for it.  Any
+other callable may block for as long as it likes (a chunked HTTP reply,
+a supervisor's relay), so it gets an emitter thread of its own.  Either
+way a consumer that stops draining fills its buffer and is CUT with
+EOVERCROWDED at the next step boundary while every other slot keeps
+streaming; a failing sink retires just that request.  ``on_done(err)``
+fires exactly once per request, success or failure, after its buffered
+tokens flush.
 
 Supervision (serving/supervisor.py): the step loop publishes a
 step-progress HEARTBEAT every iteration (suppressible by the
@@ -97,6 +108,7 @@ from brpc_tpu import errors, fault, native_path, rpcz
 from brpc_tpu.butil import hostcpu, stagetag
 from brpc_tpu.butil.lockprof import InstrumentedLock
 from brpc_tpu.bvar import Adder, IntRecorder, LatencyRecorder, PassiveStatus
+from brpc_tpu.rpc import stream as stream_mod
 
 _req_ids = itertools.count(1)
 
@@ -127,12 +139,15 @@ PassiveStatus(_spec_accept_rate).expose("serving_spec_accept_rate")
 
 
 class _EmitBuf:
-    """Bounded token buffer between the shared step loop and one
-    request's emitter thread.  ``push`` never blocks (the step loop
-    must not stall on a slow consumer); the terminal marker is always
-    accepted so a cut/finished request can flush and notify."""
+    """Bounded token buffer between the shared step loop and whoever
+    drains one request (its emitter thread, or the engine's emit
+    drainer).  ``push`` never blocks (the step loop must not stall on a
+    slow consumer); the terminal marker is always accepted so a
+    cut/finished request can flush and notify.  ``wake``, where set, is
+    called after a terminal lands (the drainer's signal; the step loop
+    signals a step's tokens itself, once)."""
 
-    __slots__ = ("cap", "q", "cv", "terminal", "has_terminal")
+    __slots__ = ("cap", "q", "cv", "terminal", "has_terminal", "wake")
 
     def __init__(self, cap: int):
         self.cap = cap
@@ -144,6 +159,7 @@ class _EmitBuf:
         self.cv = threading.Condition(InstrumentedLock("serving.emit_buf"))
         self.terminal = None
         self.has_terminal = False
+        self.wake = None
 
     def push(self, tok: int) -> bool:
         with self.cv:
@@ -159,63 +175,69 @@ class _EmitBuf:
                 self.has_terminal = True
                 self.terminal = err
             self.cv.notify()
+        if self.wake is not None:
+            self.wake()
 
-    def pop(self, timeout_s: float):
-        """Next item: ``("tok", t)``, ``("done", err)`` once drained,
-        or None on timeout."""
+    def pop_batch(self, timeout_s: float, limit: int = 512):
+        """``(tokens, terminal_seen, err)``: up to ``limit`` buffered
+        tokens, after waiting up to ``timeout_s`` for the first; the
+        terminal shows only once no token is left behind it."""
         with self.cv:
-            if not self.q and not self.has_terminal:
+            if not self.q and not self.has_terminal and timeout_s > 0:
                 self.cv.wait(timeout_s)
-            if self.q:
-                return ("tok", self.q.popleft())
-            if self.has_terminal:
-                return ("done", self.terminal)
-            return None
+            toks = [self.q.popleft()
+                    for _ in range(min(limit, len(self.q)))]
+            term = self.has_terminal and not self.q
+            return toks, term, self.terminal if term else None
 
 
 class _NativeEmitBuf:
-    """Native bounded emit ring (ISSUE 9) with the _EmitBuf protocol
-    plus batch pop.  The step loop pushes through ONE GIL-released
+    """Native bounded emit ring (ISSUE 9) with the _EmitBuf protocol.
+    The step loop pushes through ONE GIL-released
     ``brpc_tokring_push_many`` call per step across all slots (the
-    engine batches; ``push`` here is the single-slot/fallback entry),
-    and the emitter drains MANY tokens per wakeup via ``pop_batch``
-    instead of a Python lock round-trip per token.  Semantics are
-    identical to _EmitBuf: push never blocks, a full ring means the
-    consumer is cut with EOVERCROWDED, the terminal is always accepted
-    and only surfaces after every buffered token."""
+    engine batches; ``push`` here is the single-slot/fallback entry);
+    an emitter thread drains MANY tokens per wakeup via ``pop_batch``
+    instead of a Python lock round-trip per token, and the emit drainer
+    takes every ring's tokens in one ``brpc_tokring_pop_each``.
+    Semantics are identical to _EmitBuf: push never blocks, a full ring
+    means the consumer is cut with EOVERCROWDED, the terminal is always
+    accepted and only surfaces after every buffered token."""
 
-    __slots__ = ("ring", "cap", "popbuf")
+    __slots__ = ("ring", "cap", "popbuf", "wake")
 
     def __init__(self, ring, cap: int):
         self.ring = ring
         self.cap = cap
-        # the emitter thread owns this scratch array (single consumer)
-        self.popbuf = (ctypes.c_int32 * min(int(cap), 512))()
+        # whoever drains this ring owns the scratch array (single
+        # consumer); made at the first pop_batch (the drainer's rings
+        # never need one)
+        self.popbuf = None
+        self.wake = None
 
     @property
     def handle(self):
         return self.ring.handle
+
+    @property
+    def terminal(self):
+        return self.ring._terminal_obj
 
     def push(self, tok: int) -> bool:
         return self.ring.push(int(tok))
 
     def push_terminal(self, err) -> None:
         self.ring.push_terminal(err)
+        if self.wake is not None:
+            self.wake()
 
     def pop_batch(self, timeout_s: float):
-        """(count, terminal_seen, err) — tokens land in ``popbuf``."""
-        return self.ring.pop_many(self.popbuf, timeout_s)
-
-    def pop(self, timeout_s: float):
-        """Single-item _EmitBuf-protocol pop (compat path for callers
-        that drain one token at a time)."""
-        one = (ctypes.c_int32 * 1)()
-        n, term, err = self.ring.pop_many(one, timeout_s)
-        if n:
-            return ("tok", int(one[0]))
-        if term:
-            return ("done", err)
-        return None
+        """``(tokens, terminal_seen, err)`` as _EmitBuf's; the wait
+        parks in native code, off the GIL."""
+        out = self.popbuf
+        if out is None:
+            out = self.popbuf = (ctypes.c_int32 * min(int(self.cap), 512))()
+        n, term, err = self.ring.pop_many(out, timeout_s)
+        return out[:n], term, err
 
 
 def _make_emit_buf(cap: int):
@@ -223,6 +245,66 @@ def _make_emit_buf(cap: int):
     if ring is not None:
         return _NativeEmitBuf(ring, cap)
     return _EmitBuf(cap)
+
+
+class MessageSink:
+    """A request's sink that needs no thread of its own: one encoded
+    message a token, and one for the terminal, on an rpc ``Stream``.  A
+    subclass says what the messages are (``token_message``,
+    ``done_message``).  Handed to :meth:`DecodeEngine.submit` as
+    ``emit`` (with ``on_done`` its ``on_done``) it rides the engine's
+    emit drainer, which writes every such sink's messages of a step in
+    one pass and never waits for a window; called like any ``emit`` (a
+    supervisor's relay, another engine-shaped submitter) it is the
+    blocking write it always was, bounded by ``STALL_S``."""
+
+    # how long a stream's window may take nothing before its request
+    # is given up (a dead-but-open peer must not hold a slot for ever)
+    STALL_S = 2.0
+
+    __slots__ = ("stream",)
+
+    def __init__(self, stream):
+        self.stream = stream
+
+    def token_message(self, tok: int, logprob) -> bytes:
+        raise NotImplementedError
+
+    def done_message(self, err) -> bytes:
+        raise NotImplementedError
+
+    def __call__(self, tok: int, logprob=None) -> None:
+        self.stream.write(self.token_message(tok, logprob),
+                          timeout_s=self.STALL_S)
+
+    def on_done(self, err) -> None:
+        """The terminal: its message, then the stream's close.  After
+        the drainer, which has sent both, there is nothing left to do."""
+        if self.stream.closed:
+            return
+        try:
+            self.stream.write(self.done_message(err),
+                              timeout_s=self.STALL_S)
+        except errors.RpcError:
+            pass   # peer already gone; nothing to tell it
+        self.stream.close()
+
+
+class _Lane:
+    """One request on the emit drainer: the messages its stream's
+    window has not taken yet (while there are any its ring is left
+    alone, so the ring still bounds what waits), since when none of
+    them went out, and whether the terminal's message is the last of
+    them."""
+
+    __slots__ = ("req", "sink", "carry", "stalled_at", "term")
+
+    def __init__(self, req: "_Request"):
+        self.req = req
+        self.sink: MessageSink = req.emit
+        self.carry: list = []
+        self.stalled_at = 0.0
+        self.term = False
 
 
 class _Request:
@@ -445,6 +527,9 @@ class DecodeEngine:
         self.retired = Adder(f"serving_{safe}_retired")
         self.admit_errors = Adder(f"serving_{safe}_admit_errors")
         self.emit_cut = Adder(f"serving_{safe}_emit_cut")
+        # the drainer's socket writes, and the tokens that left in them
+        self.emit_runs = Adder(f"serving_{safe}_emit_runs")
+        self.emit_run_tokens = Adder(f"serving_{safe}_emit_run_tokens")
         self.occupancy_rec = IntRecorder(f"serving_{safe}_occupancy")
         PassiveStatus(self.active_count).expose(
             f"serving_{safe}_active_slots")
@@ -463,6 +548,16 @@ class DecodeEngine:
         self._prefill_fn_cpu_s = 0.0   # model-fn CPU of the last admit
         self._beat_steps = 0
         self._beat_t = time.monotonic()
+
+        # the emit drainer (one an engine, started with its first
+        # MessageSink request): its lanes, the step loop's signal, and
+        # how many tokens of one ring a pass takes
+        self._safe = safe
+        self._emit_mu = InstrumentedLock("engine.emit_lanes")
+        self._emit_wake = threading.Event()
+        self._lanes: list[_Lane] = []
+        self._emit_thread: Optional[threading.Thread] = None
+        self._pop_cap = min(self.emit_buffer, 32)
 
         # scratch for the per-step batched native emit push (ISSUE 9):
         # sized once at the slot count — times the per-slot burst in
@@ -516,7 +611,10 @@ class DecodeEngine:
         ``logprobs=True`` asks for each served token's log-probability
         (float32 log-softmax, at the runner's stated precision):
         ``emit`` is then called ``emit(token, logprob)``; a runner that
-        computes none gives None."""
+        computes none gives None.  ``emit`` is any callable (it may
+        block: it gets an emitter thread of its own) or a
+        :class:`MessageSink` (with ``on_done`` its ``on_done``), whose
+        messages the engine's one emit drainer writes."""
         limit = self.max_new_tokens_cap
         brownout = self.degraded_clamp
         if clamp and brownout is not None:
@@ -648,43 +746,50 @@ class DecodeEngine:
             else "engine closed"))
         return None
 
-    # ---- emitter threads (one per admitted request) ----
+    # ---- emission: the drainer's lanes, or a thread a callable ----
 
     def _start_emitter(self, slot: _Slot) -> None:
-        t = threading.Thread(target=self._emit_pump, args=(slot.req,),
-                             daemon=True,
-                             name=f"serving-emit-{slot.req.req_id}")
-        t.start()
+        """Who drains ``slot``'s request is read from what its sink IS:
+        a :class:`MessageSink` joins the engine's one emit drainer, any
+        other callable (it may block for seconds) gets a thread of its
+        own."""
+        req = slot.req
+        if isinstance(req.emit, MessageSink):
+            req.buf.wake = self._emit_wake.set
+            with self._emit_mu:
+                self._lanes.append(_Lane(req))
+                if self._emit_thread is None:
+                    self._emit_thread = threading.Thread(
+                        target=self._emit_drain, daemon=True,
+                        name=f"serving-emit-drain-{self._safe}")
+                    self._emit_thread.start()
+            return
+        threading.Thread(target=self._emit_pump, args=(req,), daemon=True,
+                         name=f"serving-emit-{req.req_id}").start()
 
     def _emit_pump(self, req: _Request) -> None:
-        """Drain one request's emit buffer.  Only THIS request stalls
-        when its consumer blocks; emit failures retire just this
-        request; the terminal marker flushes after the tokens and fires
-        on_done exactly once."""
-        if isinstance(req.buf, _NativeEmitBuf):
-            return self._emit_pump_native(req)
+        """Drain one request's emit buffer into its callable, a BATCH of
+        tokens a wakeup (a native ring's wait parks off the GIL).  Only
+        THIS request stalls when its consumer blocks; emit failures
+        retire just this request; the terminal marker flushes after the
+        tokens and fires on_done exactly once."""
         while True:
-            item = req.buf.pop(0.25)
-            if item is None:
+            toks, term, err = req.buf.pop_batch(0.25)
+            if not toks and not term:
                 if req.done_fired:
                     return        # finished elsewhere (close timeout path)
                 continue
             # emit fan-out host-CPU accounting (ISSUE 6): the pop wait
             # burns no thread_time, so measuring from here captures
-            # exactly the per-token delivery work
+            # exactly the delivery work
             t_cpu0 = time.thread_time()
-            kind, val = item
-            if kind == "done":
-                hostcpu.add("emit_fanout",
-                            (time.thread_time() - t_cpu0) * 1e6)
-                req.finish(val)
-                return
             try:
-                with rpcz.stage("serve.emit"):
-                    if req.logprobs is None:
-                        req.emit(val)
-                    else:
-                        req.emit(val, req.logprobs.popleft())
+                with rpcz.stage("serve.emit", tokens=len(toks)):
+                    for tok in toks:
+                        if req.logprobs is None:
+                            req.emit(tok)
+                        else:
+                            req.emit(tok, req.logprobs.popleft())
             except Exception as e:
                 self._cancel(req, errors.RpcError(
                     errors.EINTERNAL,
@@ -693,42 +798,134 @@ class DecodeEngine:
             finally:
                 hostcpu.add("emit_fanout",
                             (time.thread_time() - t_cpu0) * 1e6)
-
-    def _emit_pump_native(self, req: _Request) -> None:
-        """Native-ring emitter: each wakeup drains a BATCH of tokens in
-        one GIL-released call (the pop wait parks in native code, off
-        the GIL), then delivers them through the request's emit
-        callback.  Terminal semantics are byte-for-byte the _EmitBuf
-        pump's: every buffered token flushes before on_done fires
-        exactly once."""
-        buf: _NativeEmitBuf = req.buf
-        out = buf.popbuf
-        while True:
-            n, term, err = buf.pop_batch(0.25)
-            if n == 0 and not term:
-                if req.done_fired:
-                    return        # finished elsewhere (close timeout path)
-                continue
-            t_cpu0 = time.thread_time()
-            try:
-                with rpcz.stage("serve.emit", tokens=n):
-                    for k in range(n):
-                        if req.logprobs is None:
-                            req.emit(int(out[k]))
-                        else:
-                            req.emit(int(out[k]), req.logprobs.popleft())
-            except Exception as e:
-                hostcpu.add("emit_fanout",
-                            (time.thread_time() - t_cpu0) * 1e6)
-                self._cancel(req, errors.RpcError(
-                    errors.EINTERNAL,
-                    f"emit failed: {type(e).__name__}: {e}"))
-                return
-            hostcpu.add("emit_fanout",
-                        (time.thread_time() - t_cpu0) * 1e6)
             if term:
                 req.finish(err)
                 return
+
+    def _emit_drain(self) -> None:
+        """The engine's ONE emit drainer: every :class:`MessageSink`
+        request's tokens leave through this thread.  It sleeps until the
+        step loop has pushed a step's tokens (one signal a step, not one
+        a ring) or a terminal lands, and ends once the engine has
+        stopped and its last lane has flushed."""
+        while True:
+            self._emit_wake.wait(0.25)
+            self._emit_wake.clear()
+            with self._emit_mu:
+                lanes = list(self._lanes)
+                if not lanes and not self._running:
+                    self._emit_thread = None
+                    return
+            while lanes and self._drain_lanes(lanes):
+                # a ring held more than one pass takes: again at once
+                with self._emit_mu:
+                    lanes = list(self._lanes)
+
+    def _drain_lanes(self, lanes: list) -> bool:
+        """One pass over the drainer's lanes: take what every ring
+        holds (a lane with unsent messages keeps its tokens in its
+        ring), encode, and hand each connection its run of frames in
+        one write.  True when some ring was left with more."""
+        t_cpu0 = time.thread_time()
+        now = time.monotonic()
+        tokens = 0
+        with rpcz.stage("serve.emit") as stg:
+            more = self._pop_into_lanes(lanes)
+            busy = [ln for ln in lanes if ln.carry]
+            taken, writes = stream_mod.write_runs(
+                [(ln.sink.stream, ln.carry) for ln in busy]) \
+                if busy else ((), 0)
+            for ln, n in zip(busy, taken):
+                if n < 0:
+                    self._drop_lane(ln, "the stream is closed")
+                    continue
+                if n:
+                    del ln.carry[:n]
+                    ln.stalled_at = 0.0
+                    tokens += n
+                if not ln.carry:
+                    if ln.term:
+                        tokens -= 1     # the last was the terminal's
+                        ln.sink.stream.close()
+                        self._detach_lane(ln)
+                        ln.req.finish(ln.req.buf.terminal)
+                elif not ln.stalled_at:
+                    ln.stalled_at = now
+                elif now - ln.stalled_at > ln.sink.STALL_S:
+                    self._drop_lane(
+                        ln, f"stream window full for {ln.sink.STALL_S} s")
+            if stg is not rpcz.NOOP_STAGE:
+                stg.set(tokens=tokens, streams=len(busy))
+        if writes:
+            self.emit_runs.add(writes)
+            self.emit_run_tokens.add(tokens)
+        hostcpu.add("emit_fanout", (time.thread_time() - t_cpu0) * 1e6)
+        return more
+
+    def _pop_into_lanes(self, lanes: list) -> bool:
+        """Move the tokens (and, behind them, the terminal) of every
+        lane that has nothing unsent from its ring into its carry,
+        encoded: ONE native call over all the native rings.  True when
+        a ring gave as many as a pass takes (it may hold more)."""
+        ready = [ln for ln in lanes if not ln.carry and not ln.term]
+        got = []                # (lane, tokens, terminal seen)
+        native = [ln for ln in ready
+                  if isinstance(ln.req.buf, _NativeEmitBuf)]
+        cap = self._pop_cap
+        if native:
+            n = len(native)
+            handles = (ctypes.c_void_p * n)(
+                *[ln.req.buf.handle for ln in native])
+            out = (ctypes.c_int32 * (n * cap))()
+            counts = (ctypes.c_int32 * n)()
+            terms = (ctypes.c_uint8 * n)()
+            native_path._core_lib().core.brpc_tokring_pop_each(
+                handles, n, out, cap, counts, terms)
+            for k, ln in enumerate(native):
+                c = counts[k]
+                if c or terms[k]:
+                    got.append((ln, out[k * cap:k * cap + c],
+                                bool(terms[k])))
+        for ln in ready:
+            if not isinstance(ln.req.buf, _NativeEmitBuf):
+                toks, term, _ = ln.req.buf.pop_batch(0, cap)
+                if toks or term:
+                    got.append((ln, toks, term))
+        more = False
+        for ln, toks, term in got:
+            req, sink = ln.req, ln.sink
+            more = more or len(toks) == cap
+            try:
+                lps = req.logprobs
+                for tok in toks:
+                    ln.carry.append(sink.token_message(
+                        tok, None if lps is None else lps.popleft()))
+                if term:
+                    ln.carry.append(sink.done_message(req.buf.terminal))
+                    ln.term = True
+            except Exception as e:
+                self._drop_lane(ln, f"{type(e).__name__}: {e}")
+        for ln in ready:
+            if not ln.carry and ln.req.done_fired:
+                self._detach_lane(ln)   # finished elsewhere
+        return more
+
+    def _detach_lane(self, ln: _Lane) -> None:
+        with self._emit_mu:
+            if ln in self._lanes:
+                self._lanes.remove(ln)
+
+    def _drop_lane(self, ln: _Lane, why: str) -> None:
+        """A lane whose sink failed (dead peer, a window that stays
+        full): retire just that request, as an emitter thread's failed
+        ``emit`` does."""
+        ln.carry.clear()
+        self._detach_lane(ln)
+        ln.sink.stream.close()
+        # a request the engine has ended already (cut for its full
+        # ring, say) ends with that error, not with the sink's
+        self._cancel(ln.req, ln.req.buf.terminal or errors.RpcError(
+            errors.EINTERNAL, f"emit failed: {why}"))
 
     def _cancel(self, req: _Request, err) -> None:
         """Retire `req`'s slot from OFF the engine thread (emitter saw
@@ -1130,7 +1327,7 @@ class DecodeEngine:
                 self._retire(i, errors.RpcError(
                     errors.EINTERNAL,
                     f"KV write failed: {type(e).__name__}: {e}"))
-        deliver: list = []   # (slot index, slot, token) surviving
+        deliver: list = []   # (slot index, slot, (token,)) surviving
         for i, s in members:
             s.inflight -= 1
             if i in wrote_bad or self._slots[i] is not s:
@@ -1163,33 +1360,8 @@ class DecodeEngine:
             # errors, the loop and its peers go on
             if s.seq is not None and not self._grow_kv(i, s, nxt):
                 continue
-            deliver.append((i, s, nxt))
-        # emit fan-out: ONE GIL-released native push across every
-        # surviving slot's ring (ISSUE 9) — the per-token Python
-        # lock acquire/notify this replaces was the step loop's
-        # biggest fixed cost.  Python _EmitBuf requests (flag off /
-        # no native lib / flipped mid-flight) push individually.
-        # One-token runs of the speculative path's batched push — one
-        # emit fan-out implementation for both loops.
-        pushed = self._push_token_runs(
-            [(i, s, (nxt,)) for i, s, nxt in deliver])
-        for (i, s, nxt), ok in zip(deliver, pushed):
-            if not ok:
-                # consumer stopped draining: cut it HERE, without
-                # the step loop ever blocking in a write
-                self.emit_cut.add(1)
-                if s.span is not rpcz.NULL_SPAN:
-                    s.span.annotate(
-                        f"emit-buffer stall: {self.emit_buffer} "
-                        f"buffered tokens undrained, consumer cut")
-                self._retire(i, errors.RpcError(
-                    errors.EOVERCROWDED,
-                    "slow stream consumer: emit buffer overflow"))
-                continue
-            if s.generated >= s.req.max_new_tokens or \
-                    (self.eos_token is not None
-                     and nxt == self.eos_token):
-                self._retire(i, None)
+            deliver.append((i, s, (nxt,)))
+        self._deliver(deliver)
 
     # ---- speculative decoding (ISSUE 11) ----
 
@@ -1524,8 +1696,24 @@ class DecodeEngine:
                     s.span.annotate(f"first token: ttft_us={ttft_us}")
             s.last_tok_t = t_tok
             deliver.append((i, s, raw))
+        self._deliver(deliver)
+        hostcpu.add("decode_step",
+                    (time.thread_time() - t_cpu0 - fn_cpu_s) * 1e6)
+        hostcpu.add("model_compute", fn_cpu_s * 1e6)
+        return True
+
+    def _deliver(self, deliver: list) -> None:
+        """A booked step's tokens to their consumers, and the end of
+        what ends with them: each entry is ``(i, slot, [tokens])``, one
+        token a slot from the plain step, a verify burst from the
+        speculative one.  A consumer that stopped draining is cut HERE,
+        without the step loop ever blocking in a write.  The emit
+        drainer is woken once the tokens are in and the first request
+        that ends with them has its terminal in (a terminal wakes it
+        itself), so a request's last token and its ``{"done"}`` leave in
+        one run."""
         pushed = self._push_token_runs(deliver)
-        for (i, s, raw), ok in zip(deliver, pushed):
+        for (i, s, toks), ok in zip(deliver, pushed):
             if not ok:
                 self.emit_cut.add(1)
                 if s.span is not rpcz.NULL_SPAN:
@@ -1535,15 +1723,12 @@ class DecodeEngine:
                 self._retire(i, errors.RpcError(
                     errors.EOVERCROWDED,
                     "slow stream consumer: emit buffer overflow"))
-                continue
-            if s.generated >= s.req.max_new_tokens or \
+            elif s.generated >= s.req.max_new_tokens or \
                     (self.eos_token is not None
-                     and raw[-1] == self.eos_token):
+                     and toks[-1] == self.eos_token):
                 self._retire(i, None)
-        hostcpu.add("decode_step",
-                    (time.thread_time() - t_cpu0 - fn_cpu_s) * 1e6)
-        hostcpu.add("model_compute", fn_cpu_s * 1e6)
-        return True
+        if self._lanes:
+            self._emit_wake.set()
 
     def _push_token_runs(self, deliver: list) -> list:
         """THE emit fan-out (ISSUE 9/11): each entry is ``(i, slot,
@@ -1788,6 +1973,10 @@ class DecodeEngine:
             "admit_errors": self.admit_errors.get_value(),
             "emit_buffer": self.emit_buffer,
             "emit_cut": self.emit_cut.get_value(),
+            "emit_runs": self.emit_runs.get_value(),
+            "emit_tokens_per_run": round(
+                self.emit_run_tokens.get_value()
+                / max(1, self.emit_runs.get_value()), 2),
             "avg_step_occupancy": round(self.occupancy_rec.get_value(), 2),
             "heartbeat_steps": self._beat_steps,
             "heartbeat_age_s": round(time.monotonic() - self._beat_t, 3),

@@ -12,7 +12,8 @@ MethodStatus accounting all apply):
     "max_new_tokens": N}`` with a client stream attached
     (``stream_create``); each generated token arrives as one stream
     message ``{"token": t}``, terminated by ``{"done": true}`` and
-    stream close.
+    stream close (the decode engine's emit drainer writes a step's
+    messages of every such stream as one run of frames a connection).
 
 HTTP clients get the same decode stream without a TRPC stack:
 ``/serving/generate?prompt=1,2,3&max_new_tokens=8`` answers chunked
@@ -27,6 +28,7 @@ import numpy as np
 
 from brpc_tpu import errors
 from brpc_tpu.rpc.service import Service, method
+from brpc_tpu.serving.engine import MessageSink
 
 
 class ServingService(Service):
@@ -132,41 +134,7 @@ class ServingService(Service):
 
         want_lp = bool(req.get("logprobs"))
 
-        def emit(tok: int, logprob=None) -> None:
-            # emit runs on THIS request's emitter thread (the engine's
-            # per-request bounded emit buffer), so a consumer that
-            # stops draining its credit window stalls only itself: the
-            # shared step loop keeps decoding every other slot, and
-            # once this request's buffer overflows the engine cuts it
-            # with EOVERCROWDED.  The bounded write keeps the emitter
-            # itself from wedging forever on a dead-but-open peer.
-            msg = {"token": tok}
-            if want_lp:
-                # the served token's log-probability (float32
-                # log-softmax at the runner's stated precision): what
-                # chat front ends ask for, and what a check against a
-                # reference can hold on every seed where a bare greedy
-                # token cannot
-                msg["logprob"] = logprob
-            stream.write(json.dumps(msg).encode(), timeout_s=2.0)
-
-        def on_done(err) -> None:
-            if err is None and model_key is not None \
-                    and self.deployments is not None:
-                # warm-up proof: a completed generation flips this
-                # deployment loading -> warm on the published plane
-                self.deployments.note_generation(model_key)
-            msg = {"done": True}
-            if err is not None:
-                msg["error"] = err.code
-                msg["error_text"] = err.text
-            try:
-                # same bound as emit (also on the per-request emitter
-                # thread, after the buffered tokens flush)
-                stream.write(json.dumps(msg).encode(), timeout_s=2.0)
-            except errors.RpcError:
-                pass   # peer already gone; nothing to tell it
-            stream.close()
+        sink = _GenerateSink(stream, want_lp, self.deployments, model_key)
 
         # advisory prefix probe BEFORE submit: how many prompt tokens
         # the local KV cache can serve without re-decoding.  The
@@ -215,11 +183,58 @@ class ServingService(Service):
             kw["speculative"] = bool(req["speculative"])
         if want_lp:
             kw["logprobs"] = True
-        rid = engine.submit(prompt, max_new, emit, on_done, **kw)
+        rid = engine.submit(prompt, max_new, sink, sink.on_done, **kw)
         resp = {"accepted": True, "req_id": rid, "prefix_hit": hit}
         if model_key is not None:
             resp["model"] = model_key
         return resp
+
+
+class _GenerateSink(MessageSink):
+    """``Serving.Generate``'s sink: one JSON message a token on the
+    call's stream, ``{"done": true}`` and the stream's close after the
+    last.  A ``DecodeEngine`` writes it from its emit drainer, so a
+    consumer that stops draining its credit window stalls only itself:
+    its tokens wait in its own bounded emit buffer while the shared
+    step loop keeps decoding every other slot, and once that overflows
+    the engine cuts the request with EOVERCROWDED.  Behind any other
+    engine-shaped submitter (a supervisor) it is a callable whose
+    bounded write keeps an emitter thread from wedging forever on a
+    dead-but-open peer."""
+
+    __slots__ = ("_want_lp", "_deployments", "_model_key")
+
+    def __init__(self, stream, want_lp: bool, deployments=None,
+                 model_key=None):
+        super().__init__(stream)
+        self._want_lp = want_lp
+        self._deployments = deployments
+        self._model_key = model_key
+
+    def token_message(self, tok: int, logprob) -> bytes:
+        msg = {"token": tok}
+        if self._want_lp:
+            # the served token's log-probability (float32 log-softmax
+            # at the runner's stated precision): what chat front ends
+            # ask for, and what a check against a reference can hold on
+            # every seed where a bare greedy token cannot
+            msg["logprob"] = logprob
+        return json.dumps(msg).encode()
+
+    def done_message(self, err) -> bytes:
+        msg = {"done": True}
+        if err is not None:
+            msg["error"] = err.code
+            msg["error_text"] = err.text
+        return json.dumps(msg).encode()
+
+    def on_done(self, err) -> None:
+        if err is None and self._model_key is not None \
+                and self._deployments is not None:
+            # warm-up proof: a completed generation flips this
+            # deployment loading -> warm on the published plane
+            self._deployments.note_generation(self._model_key)
+        super().on_done(err)
 
 
 class ScoreClient:

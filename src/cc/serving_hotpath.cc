@@ -7,7 +7,8 @@
 //     per step across every active slot (brpc_tokring_push_many: ctypes
 //     releases the GIL for the call's duration), and the emitter drains
 //     MANY tokens per wakeup (brpc_tokring_pop_many) instead of paying a
-//     Python lock round-trip per token.  The PR 3 contract is preserved
+//     Python lock round-trip per token; the engine's one emit drainer
+//     takes what EVERY ring holds in one call (brpc_tokring_pop_each).  The PR 3 contract is preserved
 //     natively: push never blocks (a full ring returns 0 and the engine
 //     cuts the consumer with EOVERCROWDED), the terminal marker is
 //     always accepted and only surfaces after every buffered token, and
@@ -190,6 +191,45 @@ int brpc_tokring_pop_many(void* h, int32_t* out, int cap,
     }
   }
   return n;
+}
+
+// One call a drain (the engine's emit drainer): pop up to `per_cap`
+// tokens of EACH of `n` rings, ring i's into out[i * per_cap ...].
+// counts_out[i] = tokens popped; term_out[i] = 1 only once ring i is
+// EMPTY and carries its terminal marker (pop_many's rule: tokens
+// always flush before the terminal).  A null handle is skipped (count
+// 0, no terminal).  Never blocks.  Returns the tokens popped in all.
+// The drainer holds a Python reference to every ring's wrapper while
+// this runs, so the raw handles cannot be freed under us.
+int brpc_tokring_pop_each(void** rings, int n, int32_t* out, int per_cap,
+                          int32_t* counts_out, uint8_t* term_out) {
+  int total = 0, terms = 0;
+  for (int i = 0; i < n; ++i) {
+    counts_out[i] = 0;
+    term_out[i] = 0;
+    auto* r = (TokenRing*)rings[i];
+    if (r == nullptr) continue;
+    int32_t* dst = out + (size_t)i * per_cap;
+    int k = 0;
+    {
+      std::lock_guard<std::mutex> g(r->mu);
+      while (k < per_cap && r->count > 0) {
+        dst[k++] = r->buf[r->head];
+        r->head = (r->head + 1) % r->cap;
+        --r->count;
+      }
+      if (r->count == 0 && r->terminal) term_out[i] = 1;
+    }
+    counts_out[i] = k;
+    total += k;
+    terms += term_out[i];
+  }
+  // one event per DRAIN, not per ring (push_many's granularity)
+  if (total > 0 || terms > 0) {
+    butil::flight::record(butil::flight::EV_RING_POP,
+                          n > 0 ? (uint64_t)(uintptr_t)rings[0] : 0, total);
+  }
+  return total;
 }
 
 int64_t brpc_tokring_size(void* h) {

@@ -46,6 +46,8 @@ int brpc_tokring_push_terminal(void* h, int32_t err_code);
 int brpc_tokring_pop_many(void* h, int32_t* out, int cap,
                           int64_t timeout_us, int* terminal_out,
                           int32_t* err_out);
+int brpc_tokring_pop_each(void** rings, int n, int32_t* out, int per_cap,
+                          int32_t* counts_out, uint8_t* term_out);
 int64_t brpc_tokring_size(void* h);
 }
 
@@ -164,6 +166,68 @@ void tokring_stress() {
   std::printf("tokring stress: %d rings x %d steps ok (checksums "
               "balanced, terminals exactly-once, live back to "
               "baseline)\n", kRings, kSteps);
+}
+
+// ---- TokenRing: step-loop fan-out vs ONE drainer over every ring ----------
+
+void tokring_drain_stress() {
+  const int kRings = 8;
+  const int kSteps = 4000;
+  const int kCap = 64;
+  const int kPer = 16;
+  const int64_t base_live = brpc_tokring_live();
+  std::vector<void*> rings(kRings);
+  for (auto& r : rings) r = brpc_tokring_new(kCap);
+  std::vector<int64_t> popped_sum(kRings, 0), popped_n(kRings, 0);
+  std::vector<int> terminals(kRings, 0);
+
+  // the emit drainer: one pop_each over all rings a pass, until every
+  // ring has shown its terminal (only ever after its last token)
+  std::thread drainer([&] {
+    std::vector<int32_t> out(kRings * kPer), counts(kRings);
+    std::vector<uint8_t> terms(kRings);
+    int seen = 0;
+    while (seen < kRings) {
+      std::vector<void*> hs(rings);
+      for (int i = 0; i < kRings; ++i) if (terminals[i]) hs[i] = nullptr;
+      int n = brpc_tokring_pop_each(hs.data(), kRings, out.data(), kPer,
+                                    counts.data(), terms.data());
+      for (int i = 0; i < kRings; ++i) {
+        for (int k = 0; k < counts[i]; ++k) popped_sum[i] += out[i * kPer + k];
+        popped_n[i] += counts[i];
+        if (terms[i]) { terminals[i]++; ++seen; }
+      }
+      if (n == 0) std::this_thread::yield();
+    }
+  });
+
+  std::vector<int64_t> pushed_sum(kRings, 0), pushed_n(kRings, 0);
+  std::vector<int32_t> toks(kRings);
+  std::vector<uint8_t> ok(kRings);
+  for (int step = 0; step < kSteps; ++step) {
+    for (int i = 0; i < kRings; ++i) toks[i] = step ^ (i << 16);
+    brpc_tokring_push_many(rings.data(), toks.data(), kRings, ok.data());
+    for (int i = 0; i < kRings; ++i) {
+      if (ok[i]) { pushed_sum[i] += toks[i]; pushed_n[i] += 1; }
+    }
+  }
+  for (int i = 0; i < kRings; ++i) brpc_tokring_push_terminal(rings[i], 7);
+  drainer.join();
+  for (int i = 0; i < kRings; ++i) {
+    CHECK(terminals[i] == 1, "ring %d: drainer saw %d terminals", i,
+          terminals[i]);
+    CHECK(popped_n[i] == pushed_n[i],
+          "ring %d: drained %lld != pushed %lld tokens", i,
+          (long long)popped_n[i], (long long)pushed_n[i]);
+    CHECK(popped_sum[i] == pushed_sum[i],
+          "ring %d: drained checksum %lld != pushed %lld", i,
+          (long long)popped_sum[i], (long long)pushed_sum[i]);
+    brpc_tokring_free(rings[i]);
+  }
+  CHECK(brpc_tokring_live() == base_live, "live rings %lld != baseline %lld",
+        (long long)brpc_tokring_live(), (long long)base_live);
+  std::printf("tokring drain stress: one pop_each drainer over %d rings x "
+              "%d steps ok\n", kRings, kSteps);
 }
 
 // ---- spanq: MPSC Treiber producers vs exchange+reverse drainer ------------
@@ -324,6 +388,7 @@ void flight_stress() {
 
 int main() {
   tokring_stress();
+  tokring_drain_stress();
   spanq_stress();
   flight_stress();
   std::printf("ring stress: all invariants held\n");

@@ -33,6 +33,7 @@ def test_stagetag_thread_name_map():
     from brpc_tpu.butil import stagetag
     assert stagetag.stage_of(0, "serving-batcher-x") == "batch_formation"
     assert stagetag.stage_of(0, "serving-emit-42") == "emit_fanout"
+    assert stagetag.stage_of(0, "serving-emit-drain-engine") == "emit_fanout"
     assert stagetag.stage_of(0, "bvar-collector") == "span_submit"
     assert stagetag.stage_of(0, "Dummy-3") == "frame_pump"
     assert stagetag.stage_of(0, "nonsense") == "other"

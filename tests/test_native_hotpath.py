@@ -95,6 +95,33 @@ def test_tokring_tokens_flush_before_terminal(native_flag):
     assert (n, term) == (0, True)
 
 
+def test_tokring_pop_each_takes_every_ring_in_one_call(native_flag):
+    """The emit drainer's entry: up to ``per_cap`` tokens of each ring,
+    ring i's at ``out[i * per_cap]``; a terminal shows only once its
+    ring is empty; a null handle is skipped; nothing blocks."""
+    from brpc_tpu._core.lib import core
+    a, b, c = (native_path.token_ring(8) for _ in range(3))
+    for t in (1, 2, 3):
+        a.push(t)
+    b.push(9)
+    b.push_terminal(None)
+    handles = (ctypes.c_void_p * 4)(a.handle, None, b.handle, c.handle)
+    out = (ctypes.c_int32 * 8)()
+    counts = (ctypes.c_int32 * 4)()
+    terms = (ctypes.c_uint8 * 4)()
+
+    def pop():
+        n = core.brpc_tokring_pop_each(  # brpc-check: allow(wedge-hygiene) — never blocks: a mutex section a ring
+            handles, 4, out, 2, counts, terms)
+        return n, list(counts), list(terms)
+    assert pop() == (3, [2, 0, 1, 0], [0, 0, 1, 0])
+    assert out[0:2] == [1, 2] and out[4] == 9
+    assert pop() == (1, [1, 0, 0, 0], [0, 0, 1, 0]) and out[0] == 3
+    c.push_terminal(errors.RpcError(errors.ELOGOFF, "close"))
+    assert pop() == (0, [0, 0, 0, 0], [0, 0, 1, 1])
+    assert len(a) == len(b) == len(c) == 0
+
+
 def test_tokring_terminal_exactly_once_first_wins(native_flag):
     ring = native_path.token_ring(8)
     first = errors.RpcError(errors.EOVERCROWDED, "cut")
