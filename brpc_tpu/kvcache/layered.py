@@ -24,9 +24,18 @@ runner's donated programs update in place (no per-call restack):
              tiles: rows ``0 .. N-1`` the scan state, rows ``N .. N+2``
              the causal convolution's tail (its last three inputs,
              oldest first), the rest of the last 8-row tile zero
-             (``ops.mamba``): BOTH kinds of recurrent state a sequence
-             in the one row, so that restore, snapshot, the free list
-             and a radix node's ownership are one mechanism.  The last
+             (``ops.mamba``); a Mamba-2 layer (ISSUE 40) the same two
+             numbers with another meaning, ``[8448, 128]`` at the
+             published widths, ONE lane tile wide: rows ``0 .. 8191``
+             the matrix state of 128 heads packed two heads a tile of
+             ``N`` rows (row ``n``, lane ``(h % 2) 64 + p`` of tile
+             ``h // 2`` is ``S^h[p, n]``), then the tail's three inputs
+             of 10,240 channels as 80 rows each, padded to a block of
+             256 rows that divides the state's (``ops.ssd`` owns the
+             layout).  Whatever the kind, BOTH kinds of recurrent state
+             a sequence lie in the one row, so that restore, snapshot,
+             scratch and zero row, the free list and a radix node's
+             ownership are one mechanism.  The last
              two rows are a scratch row (idle decode slots read and
              write it) and a row that stays zero (a cold sequence
              starts from it)
@@ -75,7 +84,7 @@ class LayeredSpec:
     n_latent: int = 0        # layers that keep one latent row a token
     latent_dim: int = 0      # its width: kv_lora_rank + the rope key's
     ssm_rows: int = 0        # ... or a Mamba layer's [rows, channels]
-    ssm_channels: int = 0
+    ssm_channels: int = 0    # (Mamba-2: ops.ssd's packed rows, 128 wide)
     compressed: bool = True  # the K/V layers select blocks (keep ``kc``)
 
     @property

@@ -1,8 +1,8 @@
 """HybridRunner — a model described by its configuration (ISSUE 32).
 
 Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
-``mixer_types`` names each layer's mixer (MiniCPM-SALA's, GLM-4.7-Flash's
-and Jamba's kinds today):
+``mixer_types`` names each layer's mixer (MiniCPM-SALA's, GLM-4.7-Flash's,
+Jamba's and Nemotron 3's kinds today):
 
   ``minicpm4``        learned block-sparse attention (InfLLM-V2): K/V of
                       these layers only live in bf16 pages, one selection
@@ -29,23 +29,40 @@ and Jamba's kinds today):
                       a float32 scan state ``[N, channels]`` and the
                       convolution's last inputs a layer, both in its
                       state row, restored from a snapshot on a radix hit
+  ``mamba2``          the Mamba-2 (SSD) mixer (Nemotron 3, ISSUE 40): ONE
+                      projection to the gate, the convolved channels
+                      (the heads' values, ``B`` and ``C``) and a step
+                      size a head; a scalar decay a head over a ``[64,
+                      128]`` matrix state, ``B`` and ``C`` shared by a
+                      group of heads (``ops.ssd``), the gate BEFORE a
+                      group RMS norm; a sequence keeps the packed scan
+                      state and the convolution's last inputs a layer in
+                      its state row (``ops.ssd``'s layout, 128 lanes
+                      wide)
   ``attention``       full softmax attention over the layered cache's
                       K/V pages: ``minicpm4``'s kernel under a table of
                       ALL the sequence's pages, no compressed keys, no
                       selection, no rotary, q/k norm or gate unless the
                       family states them
+  ``none``            no mixer: the block is its feed-forward alone
 
-and ``ffn_types`` its feed-forward: ``dense`` (the silu gated MLP) or
+and ``ffn_types`` its feed-forward: ``dense`` (the silu gated MLP),
 ``moe`` (a float32 sigmoid router over all experts, the top ``k`` of
 score + correction bias, the chosen scores normalised and scaled, the
 held experts' gated MLPs as ragged grouped matmuls with no capacity,
-``ops.moe``, and a shared expert).  Around them learned RMS norms,
+``ops.moe``, and a shared expert), ``latent_moe`` (the same router;
+the held experts two matrices around ``relu(.)^2`` in a LATENT width,
+between one projection down and one up; the shared expert of the same
+body in the model's width) or ``none`` (the block is its mixer alone).
+A block with a ``none`` is ONE sublayer and has one norm.  Around them
+learned RMS norms,
 per-head q/k norms, rotary positions, output gates, the muP scalings
 where the family has them and an untied head, or the embedding as the
-head where the family ties them.  The equations are those of
-``benchmarks/harness/reference_sala.py``, ``reference_glm.py`` and
-``reference_jamba.py`` (the plain references; the tier-1 tests hold
-this runner to them).
+head where the family ties them; the vocabulary may be the chip's slice
+of it.  The equations are those of
+``benchmarks/harness/reference_sala.py``, ``reference_glm.py``,
+``reference_jamba.py`` and ``reference_nemotron.py`` (the plain
+references; the tier-1 tests hold this runner to them).
 
 The cache is the store's :class:`~brpc_tpu.kvcache.layered.LayeredCache`:
 persistent device arrays, one a kind of state (a kind the model has no
@@ -65,7 +82,7 @@ bfloat16.  ``param_dtype="float32"`` (the CPU tests) multiplies at
 ``highest``.  ``control="low"`` is the benchmark's low-precision
 control and nothing a deployment sets: everything the configuration
 states in float32 and the program accumulates (every matmul's sum, the
-residual stream, the lightning state, the scan state and the
+residual stream, the lightning state, either scan state and the
 convolution's tail) then holds bfloat16 values, and
 the K/V and latent pages the values of an int8 cache (scale 1/16), the
 router bfloat16.  ``control="drop"`` leaves the last chosen expert's
@@ -85,17 +102,22 @@ from brpc_tpu.bvar import Adder
 from brpc_tpu.models.runner import ModelRunner, TransformerConfig
 
 SPARSE, LINEAR, MLA = "minicpm4", "lightning-attn", "mla"
-MAMBA, ATTN = "mamba", "attention"
-DENSE, MOE = "dense", "moe"
+MAMBA, MAMBA2, ATTN, NONE = "mamba", "mamba2", "attention", "none"
+DENSE, MOE, LATENT_MOE = "dense", "moe", "latent_moe"
 # what ``layer_shapes`` gives in place of a fan-in for what is neither a
 # norm weight nor a matrix in the parameters' type, all float32: a
 # matrix normal(0, 1/rows) (the router; the convolution's taps), a bias
-# normal(0, 0.1) (the router's correction; the convolution's), and
+# normal(0, 0.1) (the router's correction; the convolution's; over 512
+# experts top-22 the correction is normal(0, 0.01): the 22nd and 23rd
+# scores lie 0.003 apart, and 0.1 would choose half of every token's
+# experts by the bias alone and leave half the experts idle), and
 # Mamba's own init of the state-space mixer: ``A_log = log(1..N)`` a
-# channel, ``D`` ones, ``dt_proj``'s bias the inverse softplus of a
-# step size log-uniform in DT_RANGE
-ROUTER, BIAS = "router", "bias"
-A_LOG, ONES, DT_BIAS = "a_log", "ones", "dt_bias"
+# channel (Mamba-2: ``log(uniform 1..16)`` a head), ``D`` ones,
+# ``dt_proj``'s bias the inverse softplus of a step size log-uniform in
+# DT_RANGE (Mamba-2's ``time_step_min`` .. ``time_step_max``; its floor
+# of 1e-4 lies under the range)
+ROUTER, BIAS, SMALL_BIAS = "router", "bias", "small_bias"
+A_LOG, A_LOG_U, ONES, DT_BIAS = "a_log", "a_log_uniform", "ones", "dt_bias"
 DT_RANGE = (1e-3, 1e-1)
 PREFILL_ROWS = 16     # positions a grid row of the latent prefill kernel
 # a slot's "live" column of the step program's operand: its token comes
@@ -112,7 +134,9 @@ def layer_shapes(cfg: TransformerConfig, kind: str,
     """``{name: (shape, fan_in or None for a norm weight)}`` of one
     layer, in the order the seeded init draws them."""
     dm, ff = cfg.d_model, cfg.d_ff
-    out = {"norm1": ((dm,), None), "norm2": ((dm,), None)}
+    # a norm a sublayer the block has
+    out = {name: ((dm,), None) for name, has in
+           (("norm1", kind != NONE), ("norm2", ffn != NONE)) if has}
     if kind == MLA:
         h, r, ql = cfg.n_heads, cfg.kv_lora_rank, cfg.q_lora_rank
         nope, rope, v = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -145,7 +169,16 @@ def layer_shapes(cfg: TransformerConfig, kind: str,
                    c_norm=((n,), None), w_dt=((r, di), r),
                    b_dt=((di,), DT_BIAS), a_log=((n, di), A_LOG),
                    d=((di,), ONES), w_out=((di, dm), di))
-    else:
+    elif kind == MAMBA2:
+        h, di, ch = cfg.ssm_heads, cfg.ssd_inner, cfg.ssd_channels
+        # one projection: the gate, the convolved channels, a step size
+        # a head
+        out.update(w_in=((dm, di + ch + h), dm),
+                   conv_w=((cfg.ssm_conv, ch), ROUTER),
+                   conv_b=((ch,), BIAS), b_dt=((h,), DT_BIAS),
+                   a_log=((h,), A_LOG_U), d=((h,), ONES),
+                   g_norm=((di,), None), w_out=((di, dm), di))
+    elif kind == LINEAR:
         hd, d = cfg.lin_heads * cfg.lin_head_dim, cfg.lin_head_dim
         out.update(wq=((dm, hd), dm), wk=((dm, hd), dm),
                    wv=((dm, hd), dm), wg=((dm, hd), dm),
@@ -159,7 +192,15 @@ def layer_shapes(cfg: TransformerConfig, kind: str,
                    we_gate=((n, dm, fe), dm), we_up=((n, dm, fe), dm),
                    we_down=((n, fe, dm), fe), ws_gate=((dm, fs), dm),
                    ws_up=((dm, fs), dm), ws_down=((fs, dm), fs))
-    else:
+    elif ffn == LATENT_MOE:
+        n, fe, lat, fs = (cfg.experts_held[1], cfg.moe_d_ff, cfg.moe_latent,
+                          cfg.shared_d_ff)
+        out.update(router=((dm, cfg.n_experts), ROUTER),
+                   router_bias=((cfg.n_experts,), SMALL_BIAS),
+                   w_lat_in=((dm, lat), dm), we_up=((n, lat, fe), lat),
+                   we_down=((n, fe, lat), fe), w_lat_out=((lat, dm), lat),
+                   ws_up=((dm, fs), dm), ws_down=((fs, dm), fs))
+    elif ffn == DENSE:
         out.update(w_gate=((dm, ff), dm), w_up=((dm, ff), dm),
                    w_down=((ff, dm), ff))
     return out
@@ -204,6 +245,9 @@ def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
             elif fan_in == A_LOG:
                 out[name] = jnp.broadcast_to(jnp.log(jnp.arange(
                     1, shape[0] + 1, dtype=jnp.float32))[:, None], shape)
+            elif fan_in == A_LOG_U:
+                out[name] = jnp.log(jax.random.uniform(
+                    k, shape, jnp.float32, 1.0, 16.0))
             elif fan_in == ONES:
                 out[name] = jnp.ones(shape, jnp.float32)
             elif fan_in == DT_BIAS:
@@ -213,6 +257,8 @@ def init_hybrid_params(cfg: TransformerConfig, key=None) -> dict:
                 out[name] = step + jnp.log(-jnp.expm1(-step))
             elif fan_in == BIAS:
                 out[name] = 0.1 * x
+            elif fan_in == SMALL_BIAS:
+                out[name] = 0.01 * x
             elif fan_in == ROUTER:
                 out[name] = x / math.sqrt(shape[0])
             else:
@@ -357,11 +403,14 @@ def _mlp(p, h, cfg, rs):
                         * _mm(x, p["w_up"]), p["w_down"])
 
 
-def moe_share(p, x, cfg, valid):
-    """One expert layer's feed-forward of the normed rows ``x [N, dm]``:
-    ``(the held experts' share of the routed sum, the shared expert's
-    output, group sizes [held] of this call)``.  The router scores ALL
-    ``cfg.n_experts``; the share is of ``cfg.experts_held``."""
+def moe_share(p, x, cfg, valid, ffn: str = MOE):
+    """One expert layer's feed-forward (of kind ``ffn``) of the normed
+    rows ``x [N, dm]``: ``(the held experts' share of the routed sum,
+    the shared expert's output, group sizes [held] of this call)``.
+    The router scores ALL ``cfg.n_experts``; the share is of
+    ``cfg.experts_held``.  ``latent_moe``'s share is the held experts'
+    sum in the latent width through the projection up: the shares of
+    every holder add up to the whole layer's routed output."""
     import jax
     import jax.numpy as jnp
     from brpc_tpu.ops.moe import expert_ffn, route
@@ -377,6 +426,14 @@ def moe_share(p, x, cfg, valid):
                    scale=cfg.routed_scale)
     if control == "drop":
         w = w.at[:, -1].set(0.0)
+    if ffn == LATENT_MOE:
+        r, sizes = expert_ffn(_mm(x, p["w_lat_in"]), idx, w, valid, None,
+                              p["we_up"], p["we_down"],
+                              held=cfg.experts_held, mm=_rmm)
+        y = _mm(r, p["w_lat_out"])
+        shared = _mm(jnp.square(jax.nn.relu(_mm(x, p["ws_up"]))),
+                     p["ws_down"])
+        return y, shared, sizes
     y, sizes = expert_ffn(x, idx, w, valid, p["we_gate"], p["we_up"],
                           p["we_down"], held=cfg.experts_held, mm=_rmm)
     shared = _mm(jax.nn.silu(_mm(x, p["ws_gate"])) * _mm(x, p["ws_up"]),
@@ -384,12 +441,13 @@ def moe_share(p, x, cfg, valid):
     return y, shared, sizes
 
 
-def _moe(p, h, cfg, valid):
-    """``h + routed share + shared expert`` and the experts this call
-    hit."""
+def _moe(p, h, cfg, valid, ffn):
+    """``h + routed share + shared expert``, and ``[the held experts
+    this call hit, the assignments that fell to them]``."""
+    import jax.numpy as jnp
     y, shared, sizes = moe_share(p, _rms(h, p["norm2"], cfg.rms_eps), cfg,
-                                 valid)
-    return h + y + shared, (sizes > 0).sum()
+                                 valid, ffn)
+    return h + y + shared, jnp.stack([(sizes > 0).sum(), sizes.sum()])
 
 
 def _mla_project(p, x, qpos, cfg, lanes: int, control):
@@ -476,6 +534,35 @@ def _ssm_inputs(p, xc, cfg):
     bm = _rms(dbc[:, r:r + n], p["b_norm"], cfg.rms_eps)
     cm = _rms(dbc[:, r + n:], p["c_norm"], cfg.rms_eps)
     return jax.nn.softplus(_mm(dt, p["w_dt"]) + p["b_dt"]), bm, cm
+
+
+def _ssd_in(p, x, cfg):
+    """The Mamba-2 mixer's ONE projection of the normed rows ``x``:
+    ``(gate z [N, H P], the convolution's input [N, H P + 2 G N], the
+    step size before its bias [N, H])``."""
+    di, ch = cfg.ssd_inner, cfg.ssd_channels
+    zxd = _mm(x, p["w_in"])
+    return zxd[:, :di], zxd[:, di:di + ch], zxd[:, di + ch:]
+
+
+def _ssd_split(c, cfg):
+    """The convolved rows -> ``(xs [N, H P], B [N, G N], C [N, G N])``."""
+    di, gn = cfg.ssd_inner, cfg.ssm_groups * cfg.ssm_state
+    return c[:, :di], c[:, di:di + gn], c[:, di + gn:]
+
+
+def _ssd_out(p, y, xs, z, cfg):
+    """``y + D xs`` gated by ``silu(z)``, THEN the RMS norm over each
+    group's channels apart, and the projection out."""
+    import jax
+    import jax.numpy as jnp
+    n = y.shape[0]
+    g = (y + jnp.repeat(p["d"], cfg.ssm_head_dim)[None, :] * xs) \
+        * jax.nn.silu(z)
+    gg = g.reshape(n, cfg.ssm_groups, -1)
+    gg = gg * jax.lax.rsqrt(jnp.mean(gg * gg, axis=-1, keepdims=True)
+                            + cfg.rms_eps)
+    return _mm(gg.reshape(n, -1) * p["g_norm"], p["w_out"])
 
 
 def _select_tables(q, kcs, qpos, table, cfg, page_tokens):
@@ -611,6 +698,8 @@ def _programs():
     from brpc_tpu.ops.sparse_attention import (KERNELS_PER_PAGE,
                                                cache_write, compress_keys,
                                                page_keys)
+    from brpc_tpu.ops.ssd import ssd_scan, ssd_step, state_rows, tail_rows
+    from brpc_tpu.ops.ssd import conv_step as ssd_conv_step
 
     # ---- one decode position a slot --------------------------------------
 
@@ -625,7 +714,8 @@ def _programs():
         row (``ops.latent_attention.shared_run``).  ONE operand made on the
         host and one result (``[3, S]`` float32: next token, its
         log-probability, blocks selected; a model with expert layers
-        adds a row whose first value is the experts its layers hit) a
+        adds two rows whose first value is the held experts its layers
+        hit, and the assignments that fell to them) a
         step: every device array a
         step makes and drops costs the engine thread a hand-off of the
         interpreter lock under load (PERF.md section 7, entry 15).
@@ -661,9 +751,10 @@ def _programs():
         h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
         ls = ll = lm = 0
         n_sel = jnp.zeros((s_n,), jnp.float32)
-        n_hit = jnp.zeros((), jnp.int32)
+        n_moe = jnp.zeros((2,), jnp.int32)
         for p, (kind, ffn) in zip(params["layers"], layer_kinds(cfg)):
-            x = _rms(h, p["norm1"], cfg.rms_eps)
+            # a block of kind "none" takes no arm below
+            x = _rms(h, p["norm1"], cfg.rms_eps) if kind != NONE else None
             if kind == MLA:
                 qq, row = _mla_project(p, x, qpos, cfg, latent.shape[-1],
                                        control)
@@ -724,7 +815,21 @@ def _programs():
                     backend=backend)
                 h = _acc(control, h + rs * _mm(y, p["w_out"]))
                 ll += 1
-            else:
+            elif kind == MAMBA2:
+                z, xbc, dt = _ssd_in(p, x, cfg)
+                c, state = ssd_conv_step(
+                    state, rows, ll, xbc, p["conv_w"], p["conv_b"],
+                    n_state=state_rows(cfg.ssm_heads, cfg.ssm_head_dim,
+                                       cfg.ssm_state),
+                    round_state=round_state, backend=backend)
+                xs, bm, cm = _ssd_split(c, cfg)
+                y, state = ssd_step(
+                    state, rows, ll, xs, jax.nn.softplus(dt + p["b_dt"]),
+                    bm, cm, p["a_log"], groups=cfg.ssm_groups,
+                    round_state=round_state, backend=backend)
+                h = _acc(control, h + rs * _ssd_out(p, y, xs, z, cfg))
+                ll += 1
+            elif kind == LINEAR:
                 hl, dl = cfg.lin_heads, cfg.lin_head_dim
                 q, k, v = _qkv(p, x, hl, hl, dl, cfg)
                 if cfg.lin_rope:
@@ -739,10 +844,10 @@ def _programs():
                 h = _acc(control, h + rs * _gate_out(
                     p, x, o.reshape(s_n, hl * dl), cfg.lin_output_gate))
                 ll += 1
-            if ffn == MOE:
-                h, hit = _moe(p, h, cfg, active)
-                h, n_hit = _acc(control, h), n_hit + hit
-            else:
+            if ffn in (MOE, LATENT_MOE):
+                h, hit = _moe(p, h, cfg, active, ffn)
+                h, n_moe = _acc(control, h), n_moe + hit
+            elif ffn == DENSE:
                 h = _acc(control, _mlp(p, h, cfg, rs))
         logits = _logits(params, h, cfg)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -751,8 +856,8 @@ def _programs():
         out = [nxt.astype(jnp.float32), logprob,
                n_sel / max(1, cfg.n_sparse)]
         if cfg.n_moe:
-            out.append(jnp.zeros((s_n,), jnp.float32).at[0].set(
-                n_hit.astype(jnp.float32)))
+            out += [jnp.zeros((s_n,), jnp.float32).at[0].set(v)
+                    for v in n_moe.astype(jnp.float32)]
         return (jnp.stack(out), logits if logits_out else None, kv, kc,
                 state, latent)
 
@@ -802,11 +907,13 @@ def _programs():
         h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
         ls = ll = lm = 0
         n_sel = jnp.zeros((c,), jnp.float32)
+        n_held = jnp.zeros((), jnp.int32)
         # the latent kernel's grid rows: blocks of positions, every head
         # of a block a row of ONE matmul against the page
         pr = PREFILL_ROWS if c % PREFILL_ROWS == 0 else 1
         for p, (kind, ffn) in zip(params["layers"], layer_kinds(cfg)):
-            x = _rms(h, p["norm1"], cfg.rms_eps)
+            # a block of kind "none" takes no arm below
+            x = _rms(h, p["norm1"], cfg.rms_eps) if kind != NONE else None
             if kind == MLA:
                 nh = cfg.n_heads
                 qq, row = _mla_project(p, x, qpos, cfg, latent.shape[-1],
@@ -900,7 +1007,30 @@ def _programs():
                     [s_end, tail, mine[n_s + taps - 1:]], axis=0))
                 h = _acc(control, h + rs * _mm(y, p["w_out"]))
                 ll += 1
-            else:
+            elif kind == MAMBA2:
+                n_s = state_rows(cfg.ssm_heads, cfg.ssm_head_dim,
+                                 cfg.ssm_state)
+                n_t = tail_rows(cfg.ssd_channels, cfg.ssm_conv)
+                z, xbc, dt = _ssd_in(p, x, cfg)
+                # this layer's block of the sequence's state row: the
+                # packed scan state, the convolution's tail, padding
+                mine = state[row, ll]
+                cc, tail = conv_chunk(
+                    xbc, mine[n_s:n_s + n_t].reshape(cfg.ssm_conv - 1, -1),
+                    p["conv_w"], p["conv_b"], n_valid,
+                    round_state=round_state)
+                xs, bm, cm = _ssd_split(cc, cfg)
+                y, s_end = ssd_scan(
+                    xs, jax.nn.softplus(dt + p["b_dt"]), bm, cm, mine[:n_s],
+                    p["a_log"], n_valid, groups=cfg.ssm_groups,
+                    chunk=cfg.ssm_chunk, round_state=round_state,
+                    backend=backend)
+                state = state.at[row, ll].set(jnp.concatenate(
+                    [s_end, tail.reshape(n_t, -1), mine[n_s + n_t:]],
+                    axis=0))
+                h = _acc(control, h + rs * _ssd_out(p, y, xs, z, cfg))
+                ll += 1
+            elif kind == LINEAR:
                 hl, dl = cfg.lin_heads, cfg.lin_head_dim
                 q, k, v = _qkv(p, x, hl, hl, dl, cfg)
                 if cfg.lin_rope:
@@ -915,12 +1045,17 @@ def _programs():
                 h = _acc(control, h + rs * _gate_out(
                     p, x, o.reshape(c, hl * dl), cfg.lin_output_gate))
                 ll += 1
-            if ffn == MOE:
-                h = _acc(control, _moe(p, h, cfg, valid)[0])
-            else:
+            if ffn in (MOE, LATENT_MOE):
+                h, hit = _moe(p, h, cfg, valid, ffn)
+                h, n_held = _acc(control, h), n_held + hit[1]
+            elif ffn == DENSE:
                 h = _acc(control, _mlp(p, h, cfg, rs))
-        return (n_sel.sum() / max(1, cfg.n_sparse),
-                _logits(params, h, cfg) if logits_out else None,
+        # blocks selected a sparse layer; a model with expert layers
+        # adds the assignments that fell to the held experts
+        counts = n_sel.sum() / max(1, cfg.n_sparse)
+        if cfg.n_moe:
+            counts = jnp.stack([counts, n_held.astype(jnp.float32)])
+        return (counts, _logits(params, h, cfg) if logits_out else None,
                 kv, kc, state, latent)
 
     def named(fn, scope, *more_statics):
@@ -943,20 +1078,28 @@ def _programs():
 
 def layered_spec(cfg: TransformerConfig, state_rows: int):
     from brpc_tpu.kvcache.layered import LayeredSpec
+    from brpc_tpu.ops import ssd
     from brpc_tpu.ops.mamba import state_block_rows
-    if cfg.n_linear and cfg.n_mamba:
+    recurrent = cfg.n_linear + cfg.n_mamba + cfg.n_mamba2
+    if recurrent > max(cfg.n_linear, cfg.n_mamba, cfg.n_mamba2):
         raise ValueError("a state row holds one kind of recurrent layer: "
-                         "lightning or Mamba, not both")
-    recurrent = cfg.n_linear + cfg.n_mamba
+                         "lightning, Mamba or Mamba-2, no two of them")
+    if cfg.n_mamba2:
+        # a Mamba-2 layer's block is ops.ssd's: one lane tile wide
+        ssm = (ssd.state_block_rows(cfg.ssm_heads, cfg.ssm_head_dim,
+                                    cfg.ssm_groups, cfg.ssm_state,
+                                    cfg.ssm_conv), ssd.LANES)
+    elif cfg.n_mamba:
+        ssm = (state_block_rows(cfg.ssm_state, cfg.ssm_conv), cfg.ssm_inner)
+    else:
+        ssm = (0, 0)
     return LayeredSpec(
         n_sparse=cfg.n_kv_layers, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, n_linear=recurrent,
         n_lin_heads=cfg.lin_heads, lin_head_dim=cfg.lin_head_dim,
         state_rows=int(state_rows) if recurrent else 0,
         n_latent=cfg.n_latent, latent_dim=cfg.latent_dim,
-        ssm_rows=state_block_rows(cfg.ssm_state, cfg.ssm_conv)
-        if cfg.n_mamba else 0,
-        ssm_channels=cfg.ssm_inner if cfg.n_mamba else 0,
+        ssm_rows=ssm[0], ssm_channels=ssm[1],
         compressed=bool(cfg.n_sparse))
 
 
@@ -967,7 +1110,7 @@ def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
     refcounts and the radix tree as ever, ``cache_pages`` pages whose
     K/V (sparse- and full-attention layers), compressed keys, latent
     rows (latent-attention layers) and ``state_rows`` recurrent-state
-    rows (lightning or Mamba layers) live in the store's
+    rows (lightning, Mamba or Mamba-2 layers) live in the store's
     :class:`LayeredCache`.  A page
     holds ``cfg.sparse_block`` tokens where the model selects blocks
     (one selection block a page), else ``page_tokens``.  The pool's own
@@ -1048,7 +1191,13 @@ class HybridRunner(ModelRunner):
         # they lie in, beside the pages the kernel fetched for them (a
         # layer, whole key blocks, by either pass) and how many of a
         # slot-by-slot pass's fetches the shared pass stood in for
-        new = ("moe_assignments", "moe_experts_hit") * bool(cfg.n_moe) \
+        # Mamba-2 layers: valid positions through ``ssd_scan`` and
+        # slot-steps through ``ssd_step``; ``moe_assignments_held``: the
+        # pairs that fell to the experts held here (over
+        # ``moe_assignments``: this chip's share of the routing)
+        new = ("ssd_tokens", "ssd_steps") * bool(cfg.n_mamba2) \
+            + ("moe_assignments", "moe_assignments_held",
+               "moe_experts_hit") * bool(cfg.n_moe) \
             + ("latent_tokens_read", "latent_pages_distinct",
                "latent_page_visits", "latent_page_visits_shared") \
             * bool(cfg.n_latent)
@@ -1116,14 +1265,20 @@ class HybridRunner(ModelRunner):
         self._table_cache = live
         return out
 
-    def _count(self, qpos, n_sel, dead: int, step: bool = False) -> None:
+    def _count(self, qpos, counts, dead: int, step: bool = False) -> None:
         """Counters of the positions a program just computed (``step``:
-        the decode step, else a prefill chunk); ``dead`` the rows
-        beside them that were not live (idle slots, a bucket's
-        padding)."""
+        the decode step, else a prefill chunk); ``counts`` what the
+        program counted itself (blocks selected, then, of a model with
+        expert layers, the assignments that fell to the held experts);
+        ``dead`` the rows beside the positions that were not live (idle
+        slots, a bucket's padding)."""
         cfg = self.cfg
+        counts = np.atleast_1d(np.asarray(counts))
+        n_sel = counts[0]
         if cfg.n_mamba:
             (self.mamba_steps if step else self.mamba_tokens).add(len(qpos))
+        if cfg.n_mamba2:
+            (self.ssd_steps if step else self.ssd_tokens).add(len(qpos))
         if cfg.n_sparse:
             from brpc_tpu.ops.sparse_attention import steps_visited
             dense = qpos + 1 <= cfg.sparse_dense_len
@@ -1142,6 +1297,7 @@ class HybridRunner(ModelRunner):
         if cfg.n_moe:
             self.moe_assignments.add(
                 len(qpos) * cfg.experts_per_tok * cfg.n_moe)
+            self.moe_assignments_held.add(int(counts[1]))
 
     # ---- the ModelRunner surface ----
 
@@ -1230,7 +1386,7 @@ class HybridRunner(ModelRunner):
                 # placed as a step's own result is (committed, beside the
                 # cache): the same program whether a step came before
                 before = self._no_prev[n] = jax.device_put(
-                    np.zeros((3 + bool(self.cfg.n_moe), n), np.float32),
+                    np.zeros((3 + 2 * bool(self.cfg.n_moe), n), np.float32),
                     lay.kv.sharding)
         with lay.lock:
             out, lg, lay.kv, lay.kc, lay.state, lay.latent = \
@@ -1262,10 +1418,12 @@ class HybridRunner(ModelRunner):
                 self.store.mark_filled(s, int(positions[i]))
         for chunk in handle["prefills"]:
             self._count(*chunk)
-        self._count(positions[live] - 1, out[2][live].sum(),
-                    len(live) - int(live.sum()), step=True)
+        counts = [out[2][live].sum()]
         if self.cfg.n_moe:
             self.moe_experts_hit.add(int(out[3][0]))
+            counts.append(out[4][0])
+        self._count(positions[live] - 1, counts,
+                    len(live) - int(live.sum()), step=True)
         if self.cfg.n_latent:
             self.latent_tokens_read.add(
                 int(positions[live].sum()) * self.cfg.n_latent)

@@ -252,10 +252,17 @@ class TransformerConfig:
     K/V; ``"lightning-attn"``: linear attention with a recurrent
     state; ``"mla"``: latent attention over pages of one compressed
     row a token; ``"mamba"``: the Mamba-1 state-space mixer, a scan
-    state and a convolution tail a sequence; ``"attention"``: full
-    softmax attention over paged K/V, no selection), ``ffn_types`` its
+    state and a convolution tail a sequence; ``"mamba2"``: the Mamba-2
+    (SSD) mixer, a matrix state a head and a convolution tail;
+    ``"attention"``: full softmax attention over paged K/V, no
+    selection; ``"none"``: the block has no mixer), ``ffn_types`` its
     feed-forward (``"dense"``: the gated MLP; ``"moe"``: routed experts
-    and a shared one), and
+    and a shared one, silu gated MLPs in the model's width;
+    ``"latent_moe"``: routed experts of two matrices and ``relu(.)^2``
+    in a latent width between a projection down and one up, and a
+    shared expert in the model's width; ``"none"``: the block has no
+    feed-forward.  A block with one of the two ``"none"`` is ONE
+    sublayer with one norm), and
     :class:`~brpc_tpu.models.hybrid.HybridRunner` serves it."""
     vocab: int = 128
     d_model: int = 32
@@ -305,12 +312,23 @@ class TransformerConfig:
     ssm_conv: int = 0
     ssm_dt_rank: int = 0
     ssm_expand: int = 0
+    # the Mamba-2 mixer (ISSUE 40): ``ssm_heads`` heads of
+    # ``ssm_head_dim``, a scalar decay a head over a ``[ssm_head_dim,
+    # ssm_state]`` state, ``B`` and ``C`` shared by ``ssm_groups`` groups
+    # of heads (the group norm's groups too), ``ssm_conv`` taps over the
+    # heads' channels + B + C, the dual form in blocks of ``ssm_chunk``
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_chunk: int = 0
     # feed-forward kind a held layer; () is the gated MLP everywhere
     ffn_types: tuple = ()
     n_experts: int = 0              # routed experts the router scores
     experts_per_tok: int = 0
     n_shared_experts: int = 0
     moe_d_ff: int = 0               # width of one expert, shared alike
+    moe_latent: int = 0             # "latent_moe": the experts' own width
+    shared_d_ff: int = 0            # "latent_moe": the shared expert's
     routed_scale: float = 1.0
     norm_topk: bool = True
     experts_held: tuple = ()        # (first, count) computed here
@@ -351,6 +369,20 @@ class TransformerConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def n_mamba2(self) -> int:
+        return sum(1 for m in self.mixer_types if m == "mamba2")
+
+    @property
+    def ssd_inner(self) -> int:
+        """The Mamba-2 mixer's channels: its heads' values."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssd_channels(self) -> int:
+        """What its convolution runs over: the heads' values, B and C."""
+        return self.ssd_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def n_linear(self) -> int:
         return sum(1 for m in self.mixer_types if m == "lightning-attn")
 
@@ -365,7 +397,8 @@ class TransformerConfig:
 
     @property
     def n_moe(self) -> int:
-        return sum(1 for f in self.ffn_types if f == "moe")
+        """Blocks with routed experts, of either kind."""
+        return sum(1 for f in self.ffn_types if f in ("moe", "latent_moe"))
 
     @property
     def residual_scale(self) -> float:
@@ -381,7 +414,11 @@ class TransformerConfig:
         count has: the convolution and its bias, ``dt_proj``'s bias,
         ``A_log``, ``D`` and the dt/B/C norms), one expert layer's
         feed-forward (every routed expert, the shared ones and the
-        router) and the embedding."""
+        router), the Mamba-2 mixer (its convolution and bias, ``A_log``,
+        ``D``, ``dt_bias`` and the gated norm with it), a latent expert
+        layer (every routed expert in the latent width, the shared one,
+        the two latent projections, the router and its correction bias)
+        and the embedding."""
         dm = self.d_model
         di, r, n = self.ssm_inner, self.ssm_dt_rank, self.ssm_state
         hd = self.n_heads * self.head_dim
@@ -401,48 +438,69 @@ class TransformerConfig:
                 "mamba": dm * 2 * di + di * (self.ssm_conv + 1)
                 + di * (r + 2 * n) + r * di + di + di * n + di
                 + r + 2 * n + di * dm,
+                "mamba2": dm * (self.ssd_inner + self.ssd_channels
+                                + self.ssm_heads)
+                + self.ssd_channels * (self.ssm_conv + 1)
+                + 3 * self.ssm_heads + self.ssd_inner + self.ssd_inner * dm,
                 "attention": 2 * dm * hd + 2 * dm * kvd,
                 "moe": 3 * dm * self.moe_d_ff
                 * (self.n_experts + self.n_shared_experts)
                 + dm * self.n_experts,
+                "latent_moe": self.n_experts
+                * (2 * self.moe_latent * self.moe_d_ff + dm + 1)
+                + 2 * dm * self.shared_d_ff + 2 * dm * self.moe_latent,
                 "embedding": self.vocab * dm}
 
 
-MIXER_KINDS = ("minicpm4", "lightning-attn", "mla", "mamba", "attention")
+MIXER_KINDS = ("minicpm4", "lightning-attn", "mla", "mamba", "mamba2",
+               "attention", "none")
 
 
 def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
                    sparse: Optional[dict] = None,
                    experts: Optional[tuple] = None,
+                   vocab: Optional[tuple] = None,
                    param_dtype: str = "bfloat16") -> TransformerConfig:
     """A :class:`TransformerConfig` from a published ``config.json``'s
     keys, taken verbatim: a file that names its ``mixer_types``
     (MiniCPM-SALA's), ``model_type`` ``glm4_moe_lite`` (latent
     attention in every layer, ``first_k_dense_replace`` gated MLPs and
-    then routed experts), or ``model_type`` ``jamba`` (state-space
+    then routed experts), ``model_type`` ``jamba`` (state-space
     mixers with full attention where ``attn_layer_period`` /
-    ``attn_layer_offset`` put it); any other raises.  ``layers`` =
+    ``attn_layer_offset`` put it), or ``model_type`` ``nemotron_h``
+    (blocks of ONE sublayer by ``hybrid_override_pattern``: ``M``
+    Mamba-2, ``*`` attention, ``E`` latent experts); any other raises.
+    ``layers`` =
     ``(first, count)`` holds a contiguous slice of the published
     layers (a pipeline stage); ``sparse`` gives what the published
     file does not carry (``kernel_size``, ``kernel_stride``,
     ``block_size``, ``topk``, ``init_blocks``, ``window_size``,
     ``dense_len``); ``experts`` = ``(first, count)`` are the routed
-    experts this chip computes (all of them where it is None)."""
+    experts this chip computes (all of them where it is None), and
+    ``vocab`` = ``(first, count)`` the rows of the vocabulary it holds
+    (a sliced vocabulary is a smaller vocabulary: embedding, head,
+    logits and sampling are over the slice)."""
     if "mixer_types" not in hf:
         family = hf.get("model_type")
-        if family not in ("glm4_moe_lite", "jamba"):
+        if family not in ("glm4_moe_lite", "jamba", "nemotron_h"):
             raise ValueError(
                 f"no description of model_type {family!r}: a config names "
-                f"its mixer_types or is glm4_moe_lite or jamba")
+                f"its mixer_types or is glm4_moe_lite, jamba or nemotron_h")
         if sparse:
             raise ValueError(f"{family} has no sparse settings")
+        if family == "nemotron_h":
+            return _from_nemotron_h(hf, layers, experts, vocab, param_dtype)
+        if vocab is not None:
+            raise ValueError("a slice of the vocabulary is described for "
+                             "nemotron_h only")
         if family == "jamba":
             if experts is not None:
                 raise ValueError("this family's experts are not described")
             return _from_jamba(hf, layers, param_dtype)
         return _from_glm4_moe_lite(hf, layers, experts, param_dtype)
-    if experts is not None:
-        raise ValueError("this family has no routed experts")
+    if experts is not None or vocab is not None:
+        raise ValueError("this family has no routed experts and holds "
+                         "its whole vocabulary")
     mixers = tuple(hf["mixer_types"])
     unknown = sorted(set(mixers) - set(MIXER_KINDS))
     if unknown:
@@ -488,6 +546,14 @@ def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
         param_dtype=param_dtype, **fields)
 
 
+def _held(what: str, held, whole: int) -> tuple:
+    """``(first, count)`` of ``whole``, all of it where None."""
+    first, count = held if held is not None else (0, whole)
+    if first < 0 or count < 1 or first + count > whole:
+        raise ValueError(f"{what} {first}+{count} exceed {whole}")
+    return int(first), int(count)
+
+
 def _from_glm4_moe_lite(hf: dict, layers, experts,
                         param_dtype: str) -> TransformerConfig:
     """GLM-4.7-Flash's keys.  What this runner does not compute raises:
@@ -504,14 +570,10 @@ def _from_glm4_moe_lite(hf: dict, layers, experts,
     if int(hf["num_key_value_heads"]) != int(hf["num_attention_heads"]):
         raise ValueError("latent attention has one K/V head a query head")
     depth = int(hf["num_hidden_layers"])
-    first, count = layers if layers is not None else (0, depth)
-    if first < 0 or first + count > depth:
-        raise ValueError(f"layers {first}+{count} exceed {depth}")
+    first, count = _held("layers", layers, depth)
     dense = int(hf["first_k_dense_replace"])
     n_exp = int(hf["n_routed_experts"])
-    e_first, e_count = experts if experts is not None else (0, n_exp)
-    if e_first < 0 or e_count < 1 or e_first + e_count > n_exp:
-        raise ValueError(f"experts {e_first}+{e_count} exceed {n_exp}")
+    e_first, e_count = _held("experts", experts, n_exp)
     rope = int(hf["qk_rope_head_dim"])
     return TransformerConfig(
         vocab=int(hf["vocab_size"]), d_model=int(hf["hidden_size"]),
@@ -555,9 +617,7 @@ def _from_jamba(hf: dict, layers, param_dtype: str) -> TransformerConfig:
             raise ValueError(f"jamba with {key}={hf[key]!r} is not "
                              f"described (only {want!r})")
     depth = int(hf["num_hidden_layers"])
-    first, count = layers if layers is not None else (0, depth)
-    if first < 0 or first + count > depth:
-        raise ValueError(f"layers {first}+{count} exceed {depth}")
+    first, count = _held("layers", layers, depth)
     period, offset = int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
     dm, heads = int(hf["hidden_size"]), int(hf["num_attention_heads"])
     if dm % heads:
@@ -574,6 +634,74 @@ def _from_jamba(hf: dict, layers, param_dtype: str) -> TransformerConfig:
         ssm_state=int(hf["mamba_d_state"]), ssm_conv=int(hf["mamba_d_conv"]),
         ssm_dt_rank=int(hf["mamba_dt_rank"]),
         ssm_expand=int(hf["mamba_expand"]), param_dtype=param_dtype)
+
+
+def _from_nemotron_h(hf: dict, layers, experts, vocab,
+                     param_dtype: str) -> TransformerConfig:
+    """Nemotron-H's keys as Nemotron 3 publishes them: block ``i`` is
+    ONE sublayer, ``hybrid_override_pattern[i]``: ``M`` the Mamba-2
+    mixer, ``*`` attention (no rotary or other position signal, no q/k
+    norm), ``E`` the latent mixture of ``relu(.)^2`` experts with its
+    shared expert.  What this runner does not compute raises: a dense
+    feed-forward block (``-``), a sliding window, grouped routing
+    (``n_group`` / ``topk_group`` other than 1), the shared expert
+    overlapped, a bias on any projection, a convolution without its
+    bias, another activation, Mamba-2 channels that are not ``expand x
+    hidden_size``.  The multi-token-prediction module
+    (``num_nextn_predict_layers``) adds nothing to the next token's
+    logits and is not built."""
+    for key, want in (("mlp_hidden_act", "relu2"),
+                      ("mamba_hidden_act", "silu"), ("sliding_window", None),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("moe_shared_expert_overlap", False),
+                      ("n_shared_experts", 1), ("use_bias", False),
+                      ("mlp_bias", False), ("mamba_proj_bias", False),
+                      ("attention_bias", False), ("use_conv_bias", True)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"nemotron_h with {key}={hf[key]!r} is not "
+                             f"described (only {want!r})")
+    pattern = str(hf["hybrid_override_pattern"])
+    depth = int(hf["num_hidden_layers"])
+    if len(pattern) != depth:
+        raise ValueError(f"hybrid_override_pattern names {len(pattern)} "
+                         f"blocks of {depth}")
+    first, count = _held("layers", layers, depth)
+    kinds = {"M": ("mamba2", "none"), "*": ("attention", "none"),
+             "E": ("none", "latent_moe")}
+    unknown = sorted(set(pattern[first:first + count]) - set(kinds))
+    if unknown:
+        raise ValueError(
+            f"nemotron_h blocks {unknown} are not described (only M, * and "
+            f"E; '-' is a dense feed-forward block nothing here computes)")
+    held = [kinds[c] for c in pattern[first:first + count]]
+    dm = int(hf["hidden_size"])
+    heads, hd = int(hf["mamba_num_heads"]), int(hf["mamba_head_dim"])
+    if heads * hd != int(hf["expand"]) * dm:
+        raise ValueError(f"{heads} Mamba-2 heads of {hd} are not expand "
+                         f"{hf['expand']} x hidden_size {dm}")
+    n_exp = int(hf["n_routed_experts"])
+    return TransformerConfig(
+        vocab=_held("vocabulary rows", vocab, int(hf["vocab_size"]))[1],
+        d_model=dm, n_layers=count, n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]), d_ff=int(hf["intermediate_size"]),
+        mixer_types=tuple(m for m, _ in held),
+        ffn_types=tuple(f for _, f in held),
+        depth_published=depth, layer_offset=first,
+        rms_eps=float(hf["layer_norm_epsilon"]),
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        ssm_state=int(hf["ssm_state_size"]), ssm_conv=int(hf["conv_kernel"]),
+        ssm_heads=heads, ssm_head_dim=hd, ssm_groups=int(hf["n_groups"]),
+        ssm_chunk=int(hf["chunk_size"]), n_experts=n_exp,
+        experts_per_tok=int(hf["num_experts_per_tok"]),
+        n_shared_experts=int(hf["n_shared_experts"]),
+        moe_d_ff=int(hf["moe_intermediate_size"]),
+        moe_latent=int(hf["moe_latent_size"]),
+        shared_d_ff=int(hf["moe_shared_expert_intermediate_size"]),
+        routed_scale=float(hf["routed_scaling_factor"]),
+        norm_topk=bool(hf["norm_topk_prob"]),
+        experts_held=_held("experts", experts, n_exp),
+        param_dtype=param_dtype)
 
 
 def init_runner_params(cfg: TransformerConfig, key=None) -> dict:

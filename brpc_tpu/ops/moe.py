@@ -1,7 +1,10 @@
 """Routed experts on the serving path (ISSUE 34): the router of
-``noaux_tc`` as GLM-4.7-Flash publishes it, and the experts' gated MLPs
-as ONE ragged grouped matmul an operand, with no capacity and no dropped
-token.
+``noaux_tc`` as GLM-4.7-Flash and Nemotron 3 publish it, and the
+experts' bodies as ONE ragged grouped matmul an operand, with no
+capacity and no dropped token.  An expert's body is one of two: the
+silu gated MLP of three matrices, or (ISSUE 40) two matrices around
+``relu(.)^2``; either in whatever width it is handed (the model's, or
+a latent width the caller projects down to and up from).
 
   :func:`route`       ``s = sigmoid(x W_g)`` in float32; the top ``k`` of
                       ``s + b`` are CHOSEN (``b``: the per-expert
@@ -13,13 +16,15 @@ token.
                       (``held = (first, count)`` of the router's
                       ``n_experts``: this chip's share; what the other
                       experts add is theirs to compute).  Assignments
-                      are sorted by expert and the three matrices of
-                      every expert multiplied by ``lax.ragged_dot`` over
-                      the stacked weights ``[E, K, N]``: on a TPU one
+                      are sorted by expert and the matrices of every
+                      expert multiplied by ``lax.ragged_dot`` over the
+                      stacked weights ``[E, K, N]``: on a TPU one
                       Mosaic kernel an operand that visits the groups in
                       turn and reads only the experts that were hit; 64
                       rows in a decode step and 4,096 in a prefill chunk
-                      alike.
+                      alike.  Of 64 slots x 22 choices over 512 experts
+                      with 128 held, a quarter of the sorted rows are
+                      groups and the rest sort behind the last.
 
 The training-side layer with a capacity and an exchange across chips is
 ``brpc_tpu.models.moe`` (top-1, ``shard_map``); nothing on the serving
@@ -54,7 +59,9 @@ def expert_ffn(x, experts, weights, valid, w_gate, w_up, w_down, *,
     ``valid``    ``[N]`` bool: a row that is no token (an idle slot, a
                  bucket's padding) is routed nowhere
     ``w_gate``, ``w_up`` ``[count, dm, ff]``, ``w_down`` ``[count, ff,
-                 dm]``: the held experts' matrices
+                 dm]``: the held experts' matrices, ``dm`` the width of
+                 ``x``; ``w_gate`` None is the second body,
+                 ``w_down relu(w_up x)^2``
     ``held``     ``(first, count)``
     ``mm``       ``mm(lhs [M, K], rhs [count, K, N], group_sizes)``: the
                  ragged product at the caller's precision
@@ -68,7 +75,10 @@ def expert_ffn(x, experts, weights, valid, w_gate, w_up, w_down, *,
         order = jnp.argsort(key, stable=True)
         sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
         xs = x[order // k]                                   # [N * k, dm]
-        hidden = jax.nn.silu(mm(xs, w_gate, sizes)) * mm(xs, w_up, sizes)
+        if w_gate is None:
+            hidden = jnp.square(jax.nn.relu(mm(xs, w_up, sizes)))
+        else:
+            hidden = jax.nn.silu(mm(xs, w_gate, sizes)) * mm(xs, w_up, sizes)
         ys = mm(hidden, w_down, sizes)
         # rows past the last group are no product of anything: masked,
         # not multiplied (they may hold anything)

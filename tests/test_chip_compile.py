@@ -243,11 +243,17 @@ def _served(name, sds):
             os.path.abspath(__file__))), "benchmarks", "configs", name)) as f:
         c = json.load(f)
     held = c["num_hidden_layers"]     # a whole model names no slice
+    cuts = {}
+    if "published_n_routed_experts" in c:   # a chip's share of a layer
+        cuts = {"experts": (c["first_expert_held"], c["n_routed_experts"]),
+                "vocab": (c["first_vocab_row"], c["vocab_size"])}
+        c = dict(c, n_routed_experts=c["published_n_routed_experts"],
+                 vocab_size=c["published_vocab_size"])
     cfg = from_hf_config(
         dict(c, num_hidden_layers=c.get("published_num_hidden_layers", held)),
         layers=(c.get("first_published_layer", 0), held),
         sparse=c["assumed"].get("sparse_config", {}).get("value"),
-        param_dtype=c["param_dtype"])
+        param_dtype=c["param_dtype"], **cuts)
 
     def shaped(shapes):
         return {n: sds(s, jnp.bfloat16 if isinstance(fan, (int, float))
@@ -344,7 +350,7 @@ def test_glm_decode_step_compiles_at_published_widths_and_fits(sds, glm):
     s, mp = c["num_slots"], c["max_pages_per_slot"]
     compiled = fns["step"].lower(
         params, *caches, sds((s, 6 + mp), jnp.int32),
-        sds((4, s), jnp.float32), latent, logits_out=False,
+        sds((5, s), jnp.float32), latent, logits_out=False,
         **statics).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 8 * 3 + 7 * 3
     assert _copies(compiled, latent) == []
@@ -481,3 +487,115 @@ def test_jamba_prefill_compiles_without_copying_the_caches(sds, jamba):
         assert _copies(compiled, caches[0]) == []
         assert _copies(compiled, caches[2]) == []
         assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# ---- Nemotron 3 Super (ISSUE 40): the Mamba-2 kernels and both programs ---
+
+def test_nemotron_ssd_kernels_lower_at_published_widths(sds):
+    """128 heads of 64, state 128, 8 groups, 10,240 convolved channels:
+    the chunk scan at the smallest and the largest prefill bucket (a
+    group's state in VMEM across the chunk's blocks of 128 positions,
+    float32 products), and the convolution's and the recurrence's slot
+    updates of 64 slots on a 146-row, 5-layer state array, in place: one
+    Mosaic kernel each and no copy of the array."""
+    from brpc_tpu.ops import ssd
+    f32, i32 = jnp.float32, jnp.int32
+    h, p, g, n, k = 128, 64, 8, 128, 4
+    di, ch = h * p, h * p + 2 * g * n
+    rows = ssd.state_block_rows(h, p, g, n, k)
+    n_state = ssd.state_rows(h, p, n)
+    for c in (64, 512):
+        compiled = jax.jit(
+            lambda xs, dl, b, cm, h0, a, nv: ssd.ssd_scan(
+                xs, dl, b, cm, h0, a, nv, groups=g, chunk=128,
+                backend="mosaic")).lower(
+            sds((c, di), f32), sds((c, h), f32), sds((c, g * n), f32),
+            sds((c, g * n), f32), sds((n_state, 128), f32), sds((h,), f32),
+            sds((), i32)).compile()
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert "ssd_scan" in text
+    state = sds((146, 5, rows, 128), f32)
+    conv = jax.jit(
+        lambda st, r, xs, w, b: ssd.conv_step(
+            st, r, 3, xs, w, b, n_state=n_state, backend="mosaic"),
+        donate_argnums=0).lower(
+        state, sds((64,), i32), sds((64, ch), f32), sds((k, ch), f32),
+        sds((ch,), f32)).compile()
+    step = jax.jit(
+        lambda st, r, xs, dl, b, c, a: ssd.ssd_step(
+            st, r, 3, xs, dl, b, c, a, groups=g, backend="mosaic"),
+        donate_argnums=0).lower(
+        state, sds((64,), i32), sds((64, di), f32), sds((64, h), f32),
+        sds((64, g * n), f32), sds((64, g * n), f32),
+        sds((h,), f32)).compile()
+    for compiled, name in ((conv, "ssd_conv"), (step, "ssd_step")):
+        text = compiled.as_text()
+        assert _has_kernel(compiled) and name in text
+        assert "f32[146,5,8448,128]" in text
+        assert _copies(compiled, state) == []
+
+
+@pytest.fixture(scope="module")
+def nemotron(sds):
+    from brpc_tpu.models.hybrid import layered_spec
+    c, fns, params, statics = _served("nemotron3_super_l11_ep4_1chip.json",
+                                      sds)
+    spec = layered_spec(statics["cfg"], c["state_rows"])
+    pages, t = c["cache_pages"], c["page_tokens"]
+    caches = (sds((1, 2, 2, pages, t, 128), jnp.bfloat16),
+              sds((0, pages, 4, 2, 128), jnp.bfloat16),
+              sds((c["state_rows"] + 2, 5) + spec.state_layer_shape,
+                  jnp.float32))
+    return c, fns, params, caches, statics
+
+
+def test_nemotron_decode_step_compiles_at_published_widths_and_fits(
+        sds, nemotron):
+    """``nemotron3_super_l11_ep4_1chip``'s decode step for a described
+    v5e: two kernels a Mamba-2 block (the convolution's and the
+    recurrence's slot updates, 10), the cache's write and the attention
+    over every page of the attention block, the held experts' ragged
+    products, neither the K/V arena nor the 3.16 GB state array copied,
+    weights + cache + temporaries inside the chip."""
+    c, fns, params, caches, statics = nemotron
+    assert caches[2].shape == (146, 5, 8448, 128)
+    assert params["head"].shape == (32768, 4096)
+    assert params["layers"][1]["we_up"].shape == (128, 1024, 2688)
+    assert params["layers"][1]["router"].shape == (4096, 512)
+    s, mp = c["num_slots"], c["max_pages_per_slot"]
+    compiled = fns["step"].lower(
+        params, *caches, sds((s, 4 + mp), jnp.int32),
+        sds((5, s), jnp.float32), logits_out=False, **statics).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5 * 2 + 2
+    assert "ssd_step" in text and "ssd_conv" in text
+    assert _copies(compiled, caches[0]) == []
+    assert _copies(compiled, caches[2]) == []
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 12e9 < need <= 0.9 * 16 * 2**30, \
+        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_nemotron_prefill_compiles_without_copying_the_caches(sds, nemotron):
+    """The largest prefill bucket of ``nemotron_reason_decode``: 5
+    ``ssd_scan`` kernels, the attention block's write and read, the held
+    experts' products over 512 x 22 assignments, no copy of the arena or
+    of the state array."""
+    c, fns, params, caches, statics = nemotron
+    mp = c["max_pages_per_slot"]
+    for bucket in c["prefill_buckets"][-1:]:
+        compiled = fns["prefill"].lower(
+            params, *caches, sds((3 + mp + bucket,), jnp.int32),
+            logits_out=False, max_pages=mp, **statics).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 5 + 2
+        assert "ssd_scan" in text
+        assert _copies(compiled, caches[0]) == []
+        assert _copies(compiled, caches[2]) == []
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 1.5e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            <= 0.9 * 16 * 2**30
