@@ -52,8 +52,9 @@ score + correction bias, the chosen scores normalised and scaled, the
 held experts' gated MLPs as ragged grouped matmuls with no capacity,
 ``ops.moe``, and a shared expert), ``latent_moe`` (the same router;
 the held experts two matrices around ``relu(.)^2`` in a LATENT width,
-between one projection down and one up; the shared expert of the same
-body in the model's width) or ``none`` (the block is its mixer alone).
+between one projection down and one up, on a TPU the ``expert_ffn``
+kernel of ``ops.moe``; the shared expert of the same body in the
+model's width) or ``none`` (the block is its mixer alone).
 A block with a ``none`` is ONE sublayer and has one norm.  Around them
 learned RMS norms,
 per-head q/k norms, rotary positions, output gates, the muP scalings
@@ -427,9 +428,11 @@ def moe_share(p, x, cfg, valid, ffn: str = MOE):
     if control == "drop":
         w = w.at[:, -1].set(0.0)
     if ffn == LATENT_MOE:
-        r, sizes = expert_ffn(_mm(x, p["w_lat_in"]), idx, w, valid, None,
-                              p["we_up"], p["we_down"],
-                              held=cfg.experts_held, mm=_rmm)
+        r, sizes = expert_ffn(
+            _mm(x, p["w_lat_in"]), idx, w, valid, None, p["we_up"],
+            p["we_down"], held=cfg.experts_held, mm=_rmm,
+            backend=getattr(_TRACING, "backend", None),
+            round_acc="bfloat16" if control == "low" else None)
         y = _mm(r, p["w_lat_out"])
         shared = _mm(jnp.square(jax.nn.relu(_mm(x, p["ws_up"]))),
                      p["ws_down"])
@@ -441,13 +444,25 @@ def moe_share(p, x, cfg, valid, ffn: str = MOE):
     return y, shared, sizes
 
 
+def ffn_kernel_blocks(cfg, backend) -> int:
+    """The expert blocks of ``cfg`` that go through the ``expert_ffn``
+    kernel under ``backend`` (``ops.moe``: the two-matrix body, on a
+    TPU or interpreted)."""
+    from brpc_tpu.ops.moe import default_backend
+    if (backend or default_backend()) == "gather":
+        return 0
+    return sum(1 for f in cfg.ffn_types if f == LATENT_MOE)
+
+
 def _moe(p, h, cfg, valid, ffn):
-    """``h + routed share + shared expert``, and ``[the held experts
-    this call hit, the assignments that fell to them]``."""
+    """``h + routed share + shared expert``, ``[the held experts this
+    call hit, the assignments that fell to them]`` and the group sizes
+    they are counted from."""
     import jax.numpy as jnp
     y, shared, sizes = moe_share(p, _rms(h, p["norm2"], cfg.rms_eps), cfg,
                                  valid, ffn)
-    return h + y + shared, jnp.stack([(sizes > 0).sum(), sizes.sum()])
+    return (h + y + shared, jnp.stack([(sizes > 0).sum(), sizes.sum()]),
+            sizes)
 
 
 def _mla_project(p, x, qpos, cfg, lanes: int, control):
@@ -698,6 +713,7 @@ def _programs():
     from brpc_tpu.ops.sparse_attention import (KERNELS_PER_PAGE,
                                                cache_write, compress_keys,
                                                page_keys)
+    from brpc_tpu.ops.moe import ROW_TILE, tile_visits
     from brpc_tpu.ops.ssd import ssd_scan, ssd_step, state_rows, tail_rows
     from brpc_tpu.ops.ssd import conv_step as ssd_conv_step
 
@@ -751,7 +767,10 @@ def _programs():
         h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
         ls = ll = lm = 0
         n_sel = jnp.zeros((s_n,), jnp.float32)
-        n_moe = jnp.zeros((2,), jnp.int32)
+        # where the expert blocks take the ``expert_ffn`` kernel, the
+        # row tiles it visits for them
+        kernel = bool(ffn_kernel_blocks(cfg, backend))
+        n_moe, n_tile = jnp.zeros((2,), jnp.int32), 0
         for p, (kind, ffn) in zip(params["layers"], layer_kinds(cfg)):
             # a block of kind "none" takes no arm below
             x = _rms(h, p["norm1"], cfg.rms_eps) if kind != NONE else None
@@ -845,8 +864,10 @@ def _programs():
                     p, x, o.reshape(s_n, hl * dl), cfg.lin_output_gate))
                 ll += 1
             if ffn in (MOE, LATENT_MOE):
-                h, hit = _moe(p, h, cfg, active, ffn)
+                h, hit, sizes = _moe(p, h, cfg, active, ffn)
                 h, n_moe = _acc(control, h), n_moe + hit
+                if kernel:
+                    n_tile = n_tile + tile_visits(sizes).sum()
             elif ffn == DENSE:
                 h = _acc(control, _mlp(p, h, cfg, rs))
         logits = _logits(params, h, cfg)
@@ -858,6 +879,10 @@ def _programs():
         if cfg.n_moe:
             out += [jnp.zeros((s_n,), jnp.float32).at[0].set(v)
                     for v in n_moe.astype(jnp.float32)]
+        if kernel and s_n > 1:
+            # the kernel's rows ride a spare column of the experts' row
+            out[3] = out[3].at[1].set(
+                (n_tile * ROW_TILE).astype(jnp.float32))
         return (jnp.stack(out), logits if logits_out else None, kv, kc,
                 state, latent)
 
@@ -907,7 +932,8 @@ def _programs():
         h = cfg.scale_emb * params["emb"][tokens].astype(jnp.float32)
         ls = ll = lm = 0
         n_sel = jnp.zeros((c,), jnp.float32)
-        n_held = jnp.zeros((), jnp.int32)
+        kernel = bool(ffn_kernel_blocks(cfg, backend))
+        n_held, n_tile = jnp.zeros((), jnp.int32), 0
         # the latent kernel's grid rows: blocks of positions, every head
         # of a block a row of ONE matmul against the page
         pr = PREFILL_ROWS if c % PREFILL_ROWS == 0 else 1
@@ -1046,15 +1072,21 @@ def _programs():
                     p, x, o.reshape(c, hl * dl), cfg.lin_output_gate))
                 ll += 1
             if ffn in (MOE, LATENT_MOE):
-                h, hit = _moe(p, h, cfg, valid, ffn)
+                h, hit, sizes = _moe(p, h, cfg, valid, ffn)
                 h, n_held = _acc(control, h), n_held + hit[1]
+                if kernel:
+                    n_tile = n_tile + tile_visits(sizes).sum()
             elif ffn == DENSE:
                 h = _acc(control, _mlp(p, h, cfg, rs))
         # blocks selected a sparse layer; a model with expert layers
-        # adds the assignments that fell to the held experts
+        # adds the assignments that fell to the held experts (and, where
+        # they take the ``expert_ffn`` kernel, the rows it multiplied)
         counts = n_sel.sum() / max(1, cfg.n_sparse)
         if cfg.n_moe:
-            counts = jnp.stack([counts, n_held.astype(jnp.float32)])
+            counts = [counts, n_held.astype(jnp.float32)]
+            if kernel:
+                counts.append((n_tile * ROW_TILE).astype(jnp.float32))
+            counts = jnp.stack(counts)
         return (counts, _logits(params, h, cfg) if logits_out else None,
                 kv, kc, state, latent)
 
@@ -1163,6 +1195,7 @@ class HybridRunner(ModelRunner):
         self._control = control
         self._mu = threading.Lock()
         self._fns = _programs()
+        self._kernel_blocks = ffn_kernel_blocks(cfg, backend)
         self._table_cache: dict = {}  # seq id -> (table's key, arena indices)
         self._rows: list = []         # the last step's rows of it, a slot
         self._run = None              # (leader, keys shared a slot) of them
@@ -1194,10 +1227,16 @@ class HybridRunner(ModelRunner):
         # Mamba-2 layers: valid positions through ``ssd_scan`` and
         # slot-steps through ``ssd_step``; ``moe_assignments_held``: the
         # pairs that fell to the experts held here (over
-        # ``moe_assignments``: this chip's share of the routing)
+        # ``moe_assignments``: this chip's share of the routing);
+        # ``moe_kernel_calls``: expert-block calls (a block a step, a
+        # block a chunk) that went through the ``expert_ffn`` kernel, and
+        # ``moe_tile_rows`` the rows it multiplied for them (visits x
+        # ``ops.moe.ROW_TILE``; over the held assignments, 1 / the
+        # tiles' fill)
         new = ("ssd_tokens", "ssd_steps") * bool(cfg.n_mamba2) \
             + ("moe_assignments", "moe_assignments_held",
-               "moe_experts_hit") * bool(cfg.n_moe) \
+               "moe_experts_hit", "moe_kernel_calls",
+               "moe_tile_rows") * bool(cfg.n_moe) \
             + ("latent_tokens_read", "latent_pages_distinct",
                "latent_page_visits", "latent_page_visits_shared") \
             * bool(cfg.n_latent)
@@ -1298,6 +1337,9 @@ class HybridRunner(ModelRunner):
             self.moe_assignments.add(
                 len(qpos) * cfg.experts_per_tok * cfg.n_moe)
             self.moe_assignments_held.add(int(counts[1]))
+            self.moe_kernel_calls.add(self._kernel_blocks)
+            if len(counts) > 2:
+                self.moe_tile_rows.add(int(counts[2]))
 
     # ---- the ModelRunner surface ----
 
@@ -1422,6 +1464,8 @@ class HybridRunner(ModelRunner):
         if self.cfg.n_moe:
             self.moe_experts_hit.add(int(out[3][0]))
             counts.append(out[4][0])
+            if self._kernel_blocks and out.shape[1] > 1:
+                counts.append(out[3][1])
         self._count(positions[live] - 1, counts,
                     len(live) - int(live.sum()), step=True)
         if self.cfg.n_latent:

@@ -70,6 +70,15 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _calls(text: str, kernel: str) -> int:
+    """Mosaic calls of the kernel named ``kernel`` in a compiled
+    program's text."""
+    import re
+    return len(re.findall(
+        rf"%{kernel}[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+
+
 @pytest.mark.parametrize("n_kv_heads", [16, 4])
 def test_paged_kernel_lowers_at_serving_widths(sds, n_kv_heads):
     """(H 16, Hkv 16, D 128) is what the smoke serves; (16, 4, 128) is
@@ -536,6 +545,29 @@ def test_nemotron_ssd_kernels_lower_at_published_widths(sds):
         assert _copies(compiled, state) == []
 
 
+@pytest.mark.parametrize("rows", [1408, 11264])
+def test_nemotron_expert_ffn_kernel_lowers_at_published_widths(sds, rows):
+    """The held experts' two products as one kernel (ISSUE 41): 128
+    experts of 1,024 x 2,688 and 2,688 x 1,024 bfloat16 where they lie,
+    two experts' pairs resident in VMEM (22 MB: over a kernel's default
+    16 MiB, so the call states its limit), at a decode step's 64 x 22
+    sorted rows and the largest prefill bucket's 512 x 22; under the
+    low-precision control too.  The work list's few XLA operations are
+    all that stands beside the one Mosaic call: no ragged product."""
+    from brpc_tpu.ops import moe
+    bf16 = jnp.bfloat16
+    for round_acc in (None, "bfloat16"):
+        compiled = jax.jit(functools.partial(
+            moe.expert_ffn_pallas, round_acc=round_acc,
+            interpret=False)).lower(
+            sds((rows, 1024), jnp.float32), sds((128, 1024, 2688), bf16),
+            sds((128, 2688, 1024), bf16), sds((128,), jnp.int32)).compile()
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert "expert_ffn" in text and "ragged" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
 @pytest.fixture(scope="module")
 def nemotron(sds):
     from brpc_tpu.models.hybrid import layered_spec
@@ -555,8 +587,9 @@ def test_nemotron_decode_step_compiles_at_published_widths_and_fits(
     """``nemotron3_super_l11_ep4_1chip``'s decode step for a described
     v5e: two kernels a Mamba-2 block (the convolution's and the
     recurrence's slot updates, 10), the cache's write and the attention
-    over every page of the attention block, the held experts' ragged
-    products, neither the K/V arena nor the 3.16 GB state array copied,
+    over every page of the attention block, the held experts' two
+    products as one ``expert_ffn`` kernel a block (5; no ragged product
+    is left), neither the K/V arena nor the 3.16 GB state array copied,
     weights + cache + temporaries inside the chip."""
     c, fns, params, caches, statics = nemotron
     assert caches[2].shape == (146, 5, 8448, 128)
@@ -568,8 +601,9 @@ def test_nemotron_decode_step_compiles_at_published_widths_and_fits(
         params, *caches, sds((s, 4 + mp), jnp.int32),
         sds((5, s), jnp.float32), logits_out=False, **statics).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 5 * 2 + 2
+    assert text.count("tpu_custom_call") >= 5 * 2 + 2 + 5
     assert "ssd_step" in text and "ssd_conv" in text
+    assert _calls(text, "expert_ffn") == 5 and "ragged-dot" not in text
     assert _copies(compiled, caches[0]) == []
     assert _copies(compiled, caches[2]) == []
     mem = compiled.memory_analysis()
@@ -582,8 +616,8 @@ def test_nemotron_decode_step_compiles_at_published_widths_and_fits(
 def test_nemotron_prefill_compiles_without_copying_the_caches(sds, nemotron):
     """The largest prefill bucket of ``nemotron_reason_decode``: 5
     ``ssd_scan`` kernels, the attention block's write and read, the held
-    experts' products over 512 x 22 assignments, no copy of the arena or
-    of the state array."""
+    experts' products over 512 x 22 assignments (``expert_ffn``
+    kernels), no copy of the arena or of the state array."""
     c, fns, params, caches, statics = nemotron
     mp = c["max_pages_per_slot"]
     for bucket in c["prefill_buckets"][-1:]:
@@ -591,8 +625,11 @@ def test_nemotron_prefill_compiles_without_copying_the_caches(sds, nemotron):
             params, *caches, sds((3 + mp + bucket,), jnp.int32),
             logits_out=False, max_pages=mp, **statics).compile()
         text = compiled.as_text()
-        assert text.count("tpu_custom_call") >= 5 + 2
+        assert text.count("tpu_custom_call") >= 5 + 2 + 4
         assert "ssd_scan" in text
+        # the last block's rows feed nothing where no logits are asked
+        # for: its kernel is dead code, its group sizes are still counted
+        assert _calls(text, "expert_ffn") == 4 and "ragged-dot" not in text
         assert _copies(compiled, caches[0]) == []
         assert _copies(compiled, caches[2]) == []
         mem = compiled.memory_analysis()

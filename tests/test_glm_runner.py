@@ -131,6 +131,27 @@ def test_prefill_then_decode_equals_the_full_forward_pass(model, backend):
     rig.close()
 
 
+def test_the_gated_experts_stay_off_the_expert_ffn_kernel(model):
+    """ISSUE 41: the ``expert_ffn`` Pallas kernel is the two-matrix
+    body's; the gated experts of this family go through
+    ``lax.ragged_dot`` whatever the backend, and the kernel's counters
+    say so."""
+    from brpc_tpu.models.hybrid import ffn_kernel_blocks
+    cfg, _, params = model
+    assert cfg.n_moe and ffn_kernel_blocks(cfg, "pallas") == 0
+    toks = tokens_of(50, seed=8)
+    rig = Rig(cfg, params, "g_calls", backend="pallas")
+    seq = rig.store.admit(toks[:40])
+    rig.prefill(seq, toks[:40])
+    rig.decode(seq, toks, 42)
+    r = rig.runner
+    assert r.moe_assignments.get_value() == 42 * 4 * cfg.n_moe
+    assert r.moe_kernel_calls.get_value() == 0
+    assert r.moe_tile_rows.get_value() == 0
+    rig.store.retire(seq, cache=False)
+    rig.close()
+
+
 def test_absorbed_attention_equals_expanded_attention(model):
     """One layer's attention alone: queries absorbed into the latent
     space over the latent pages against the reference's per-head keys

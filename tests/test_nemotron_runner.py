@@ -188,6 +188,40 @@ def test_prefill_then_decode_equals_the_full_forward_pass(model, backend):
     rig.close()
 
 
+@pytest.mark.parametrize("backend", [None, "pallas"])
+def test_kernel_calls_counts_a_block_a_step_and_a_block_a_chunk(model,
+                                                                backend):
+    """``runner_*_moe_kernel_calls``: the expert-block calls that went
+    through the ``expert_ffn`` kernel, two blocks here a decode step and
+    a prefill chunk alike where the kernels run (interpreted), none over
+    ``lax.ragged_dot``; ``_moe_tile_rows`` the rows it multiplied for
+    them: whole row tiles, at least the assignments that were held."""
+    from brpc_tpu.ops.moe import ROW_TILE
+    cfg, params = model
+    toks = tokens_of(50, seed=8)
+    rig = Rig(cfg, params, f"n_calls_{backend}", backend=backend)
+    r = rig.runner
+    chunks, run = [], r.prefill
+    r.prefill = lambda *a, **kw: chunks.append(1) or run(*a, **kw)
+    seq = rig.store.admit(toks[:40])
+    rig.prefill(seq, toks[:40])             # 0-31, the cut at 32, 32-38
+    rig.decode(seq, toks, 45)               # positions 39 .. 44
+    assert len(chunks) == 2
+    calls, rows = r.moe_kernel_calls.get_value(), r.moe_tile_rows.get_value()
+    held = r.moe_assignments_held.get_value()
+    assert 0 < held < 45 * 4 * 2
+    if backend is None:
+        assert (calls, rows) == (0, 0)
+    else:
+        assert calls == cfg.n_moe * (6 + 2) == 16
+        assert rows % ROW_TILE == 0 and held <= rows
+        # a group of a toy chunk never fills two tiles: at most two
+        # visits an expert held (4) a block a program
+        assert rows <= calls * 4 * 2 * ROW_TILE
+    rig.store.retire(seq, cache=False)
+    rig.close()
+
+
 def test_a_hit_on_a_snapshot_equals_a_cold_prefill(model):
     """A radix hit restores pages AND the row (packed scan state and
     tail of both Mamba-2 blocks): the second request on a shared prompt
