@@ -78,13 +78,13 @@ class LayeredSpec:
     n_kv_heads: int
     head_dim: int
     n_linear: int            # layers that keep a recurrent state
-    n_lin_heads: int         # a lightning layer's [H, D, D] ...
-    lin_head_dim: int
     state_rows: int          # live sequences + snapshots
+    # what ONE recurrent layer keeps of a sequence (its kind's record in
+    # ``models.layers``: a lightning layer's [H, D, D], a Mamba layer's
+    # [rows, channels], Mamba-2's ops.ssd's packed rows, 128 wide)
+    state_layer_shape: tuple = (0, 0, 0)
     n_latent: int = 0        # layers that keep one latent row a token
     latent_dim: int = 0      # its width: kv_lora_rank + the rope key's
-    ssm_rows: int = 0        # ... or a Mamba layer's [rows, channels]
-    ssm_channels: int = 0    # (Mamba-2: ops.ssd's packed rows, 128 wide)
     compressed: bool = True  # the K/V layers select blocks (keep ``kc``)
 
     @property
@@ -92,20 +92,9 @@ class LayeredSpec:
         return self.n_linear > 0
 
     @property
-    def state_layer_shape(self) -> tuple:
-        """What ONE recurrent layer keeps of a sequence."""
-        if self.ssm_rows:
-            return (self.ssm_rows, self.ssm_channels)
-        return (self.n_lin_heads, self.lin_head_dim, self.lin_head_dim)
-
-    @property
     def latent_lanes(self) -> int:
         """The latent array's minor dimension: whole 128-lane tiles."""
         return -(-self.latent_dim // 128) * 128
-
-    def kv_bytes_per_token(self) -> int:
-        return (self.n_sparse * 2 * self.n_kv_heads * self.head_dim
-                + self.n_latent * self.latent_dim) * 2
 
     def state_row_bytes(self) -> int:
         return self.n_linear * int(np.prod(self.state_layer_shape)) * 4

@@ -66,6 +66,8 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from brpc_tpu import fault
+from brpc_tpu.models.layers import (ATTN, LINEAR, MAMBA, MAMBA2, MIXERS, MLA,
+                                    ROUTED, SPARSE, held, keeping)
 
 DEFAULT_PREFILL_BUCKETS = (16, 64, 256, 1024, 4096)
 
@@ -241,6 +243,11 @@ def as_runner(step_fn=None, prefill_fn=None, *, runner=None, store=None,
 # the real transformer
 # ---------------------------------------------------------------------------
 
+def _layers_of(*kinds, of: str = "mixer_types") -> property:
+    """A configuration's count of its held layers of ``kinds``."""
+    return property(lambda cfg: held(getattr(cfg, of), *kinds))
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     """What a served model is made of.  The defaults are the dense
@@ -248,21 +255,8 @@ class TransformerConfig:
     every layer, gelu MLP, weightless norms, sinusoidal positions, tied
     head, float32).  A published architecture fills the rest through
     :func:`from_hf_config`: ``mixer_types`` names each held layer's
-    mixer (``"minicpm4"``: learned block-sparse attention over paged
-    K/V; ``"lightning-attn"``: linear attention with a recurrent
-    state; ``"mla"``: latent attention over pages of one compressed
-    row a token; ``"mamba"``: the Mamba-1 state-space mixer, a scan
-    state and a convolution tail a sequence; ``"mamba2"``: the Mamba-2
-    (SSD) mixer, a matrix state a head and a convolution tail;
-    ``"attention"``: full softmax attention over paged K/V, no
-    selection; ``"none"``: the block has no mixer), ``ffn_types`` its
-    feed-forward (``"dense"``: the gated MLP; ``"moe"``: routed experts
-    and a shared one, silu gated MLPs in the model's width;
-    ``"latent_moe"``: routed experts of two matrices and ``relu(.)^2``
-    in a latent width between a projection down and one up, and a
-    shared expert in the model's width; ``"none"``: the block has no
-    feed-forward.  A block with one of the two ``"none"`` is ONE
-    sublayer with one norm), and
+    mixer and ``ffn_types`` its feed-forward (the kinds and what each
+    is: :mod:`brpc_tpu.models.layers`), and
     :class:`~brpc_tpu.models.hybrid.HybridRunner` serves it."""
     vocab: int = 128
     d_model: int = 32
@@ -347,30 +341,9 @@ class TransformerConfig:
         return self.n_layers * 2 * self.n_kv_heads * self.head_dim * 4
 
     @property
-    def n_sparse(self) -> int:
-        return sum(1 for m in self.mixer_types if m == "minicpm4")
-
-    @property
-    def n_attention(self) -> int:
-        return sum(1 for m in self.mixer_types if m == "attention")
-
-    @property
-    def n_kv_layers(self) -> int:
-        """Layers that keep K/V pages: both attention kinds."""
-        return self.n_sparse + self.n_attention
-
-    @property
-    def n_mamba(self) -> int:
-        return sum(1 for m in self.mixer_types if m == "mamba")
-
-    @property
     def ssm_inner(self) -> int:
         """The state-space mixer's channels."""
         return self.ssm_expand * self.d_model
-
-    @property
-    def n_mamba2(self) -> int:
-        return sum(1 for m in self.mixer_types if m == "mamba2")
 
     @property
     def ssd_inner(self) -> int:
@@ -383,22 +356,20 @@ class TransformerConfig:
         return self.ssd_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
-    def n_linear(self) -> int:
-        return sum(1 for m in self.mixer_types if m == "lightning-attn")
-
-    @property
-    def n_latent(self) -> int:
-        return sum(1 for m in self.mixer_types if m == "mla")
-
-    @property
     def latent_dim(self) -> int:
         """One cached row: the compressed K/V and the shared rope key."""
         return self.kv_lora_rank + self.qk_rope_dim
 
-    @property
-    def n_moe(self) -> int:
-        """Blocks with routed experts, of either kind."""
-        return sum(1 for f in self.ffn_types if f in ("moe", "latent_moe"))
+    # held layers of a kind, by the records: K/V pages are kept by both
+    # attention kinds, routed experts by either expert kind
+    n_sparse = _layers_of(SPARSE)
+    n_attention = _layers_of(ATTN)
+    n_kv_layers = _layers_of(*keeping("kv"))
+    n_linear = _layers_of(LINEAR)
+    n_latent = _layers_of(MLA)
+    n_mamba = _layers_of(MAMBA)
+    n_mamba2 = _layers_of(MAMBA2)
+    n_moe = _layers_of(*ROUTED, of="ffn_types")
 
     @property
     def residual_scale(self) -> float:
@@ -452,8 +423,7 @@ class TransformerConfig:
                 "embedding": self.vocab * dm}
 
 
-MIXER_KINDS = ("minicpm4", "lightning-attn", "mla", "mamba", "mamba2",
-               "attention", "none")
+MIXER_KINDS = tuple(MIXERS)
 
 
 def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,

@@ -4,10 +4,11 @@ seeded tokens, and three requests through a ``DecodeEngine``."""
 import threading
 
 import jax
+import jax.monitoring
 import numpy as np
 
-from brpc_tpu.models.hybrid import (HybridRunner, init_hybrid_params,
-                                    make_layered_store)
+from brpc_tpu.models.hybrid import (HybridRunner, _programs,
+                                    init_hybrid_params, make_layered_store)
 from brpc_tpu.serving import DecodeEngine
 
 T = 16               # tokens a page (MiniCPM-SALA's toy selection block)
@@ -24,6 +25,28 @@ def as_drawn(params, cfg):
     return jax.tree_util.tree_map(
         lambda x, t: x.astype(t.dtype), params,
         jax.eval_shape(lambda: init_hybrid_params(cfg)))
+
+
+def draw_compiles(cfg) -> list:
+    """The backend compiles of a fresh seeded draw of ``cfg``."""
+    names = []
+
+    def on(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(kw.get("fun_name", "?"))
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        jax.block_until_ready(init_hybrid_params(cfg, jax.random.PRNGKey(5)))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    return names
+
+
+def program_entries(before=None) -> dict:
+    """Compiled entries of the two programs (every model's), or what was
+    added to them since ``before``."""
+    now = {k: f._cache_size() for k, f in _programs().items()}
+    return {k: n - (before or {}).get(k, 0) for k, n in now.items()}
 
 
 class Rig:

@@ -336,7 +336,7 @@ def glm(sds):
     c, fns, params, statics = _served("glm47_flash_l8_1chip.json", sds)
     cfg = statics["cfg"]
     pages, t = c["cache_pages"], c["page_tokens"]
-    lanes = LayeredSpec(0, 0, 0, 0, 0, 0, 0, n_latent=cfg.n_latent,
+    lanes = LayeredSpec(0, 0, 0, 0, 0, n_latent=cfg.n_latent,
                         latent_dim=cfg.latent_dim).latent_lanes
     # the kinds this model has no layer of: arrays with no element
     caches = (sds((0, 2, 20, pages, t, 256), jnp.bfloat16),

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from brpc_tpu.models.hybrid import _rmm
+from brpc_tpu.models.layers import _rmm
 from brpc_tpu.ops import moe
 
 N, K, E, HELD = 64, 4, 64, (16, 16)
@@ -87,7 +87,7 @@ def test_the_kernel_gives_what_the_ragged_products_give(case, dtype):
             jnp.asarray(valid), None, up, down)
     if round_acc:
         # the oracle's ``mm`` under the control: each product's sums
-        # hold bfloat16 values (``models/hybrid._acc``)
+        # hold bfloat16 values (``models/layers._acc``)
         low = lambda a, w, s: moe._round(_rmm(a, w, s), "bfloat16")
         want, sizes_w = jax.jit(functools.partial(
             moe.expert_ffn, held=HELD, mm=low, backend="gather"))(*args)

@@ -13,12 +13,12 @@ import numpy as np
 import pytest
 
 from benchmarks.harness import reference_glm as ref
-from brpc_tpu.models import hybrid
+from brpc_tpu.models import hybrid, layers
 from brpc_tpu.models.hybrid import init_hybrid_params
 from brpc_tpu.models.runner import from_hf_config
 from brpc_tpu.ops.moe import route
-from hybrid_rig import (Gated, InARow, Rig, T, as_drawn, serve,
-                        tokens_of)
+from hybrid_rig import (Gated, InARow, Rig, T, as_drawn, draw_compiles,
+                        program_entries, serve, tokens_of)
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # the published keys (the catalog row's, verbatim)
@@ -64,6 +64,12 @@ def model():
     # the file's one seeded draw: every call compiles its programs anew
     cfg, ref_cfg = configs()
     return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def entries_before():
+    # the programs' compiled entries before this file's first run
+    return program_entries()
 
 
 def test_from_hf_config_gives_the_published_parameter_counts():
@@ -171,7 +177,7 @@ def test_absorbed_attention_equals_expanded_attention(model):
     @jax.jit            # one program, not one an operation
     def absorbed(p, x):
         with jax.default_matmul_precision("highest"):
-            qq, row = hybrid._mla_project(p, x, pos, cfg, lanes, "")
+            qq, row = layers._mla_project(p, x, pos, cfg, lanes, "")
             lat = latent_write(
                 jnp.zeros((1, 4, T, lanes), jnp.bfloat16), 0,
                 jnp.asarray([2, 0, 3]), jnp.zeros((3,), jnp.int32),
@@ -180,7 +186,7 @@ def test_absorbed_attention_equals_expanded_attention(model):
                 qq, jnp.broadcast_to((pos + 1)[:, None, None], (n, 4, 1)),
                 lat, 0, jnp.zeros((n,), jnp.int32),
                 jnp.asarray([[2, 0, 3, -1]], jnp.int32))
-            return row, hybrid._mla_out(p, o, cfg)
+            return row, layers._mla_out(p, o, cfg)
     row, got = absorbed(p, x)
     assert np.array_equal(np.asarray(row[:, :40], np.float32),
                           np.asarray(rows[:n]))
@@ -439,3 +445,18 @@ def test_a_step_in_flight_serves_what_steps_in_a_row_serve(model):
         for j, tok in enumerate(fly["tokens"][k]):
             assert int(np.argmax(want[n - 1 + j])) == tok
             assert abs(fly["logprobs"][k][j] - lp[n - 1 + j, tok]) < TOL
+
+
+def test_set_up_compiles_a_draw_a_kind_of_block_and_a_program_a_shape(
+        model, entries_before):
+    """What set-up compiles: ONE seeded draw a distinct (mixer,
+    feed-forward) pair and one of the embedding and head, whatever the
+    depth; and each of the two programs once a shape and static value
+    this file ran.  A program a layer, or a static argument that hashes
+    anew each call, shows here first.  The file's LAST test: it counts
+    what the tests before it compiled."""
+    cfg = model[0]
+    names = draw_compiles(cfg)
+    assert len(names) == 3 == len(set(hybrid.layer_kinds(cfg))) + 1, \
+        names
+    assert program_entries(entries_before) == {"step": 4, "prefill": 5}

@@ -13,13 +13,14 @@ import pytest
 
 import brpc_tpu as brpc
 from benchmarks.harness import reference_sala as ref
+from brpc_tpu.models import hybrid
 from brpc_tpu.models.hybrid import (HybridRunner, init_hybrid_params,
                                     make_layered_store)
 from brpc_tpu.models.runner import from_hf_config
 from brpc_tpu.ops.sparse_attention import select_blocks
 from brpc_tpu.serving import DecodeEngine, register_serving
 from hybrid_rig import (MAX_PAGES, Gated, InARow, Rig, T, as_drawn,
-                        serve, tokens_of)
+                        draw_compiles, program_entries, serve, tokens_of)
 
 MIXERS = ["minicpm4"] + ["lightning-attn"] * 8 + ["minicpm4"] \
     + ["lightning-attn"] * 6 + ["minicpm4", "minicpm4"] \
@@ -60,6 +61,12 @@ def model():
     # the file's one seeded draw: every call compiles its programs anew
     cfg, ref_cfg = configs()
     return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def entries_before():
+    # the programs' compiled entries before this file's first run
+    return program_entries()
 
 
 def test_from_hf_config_gives_the_published_parameter_counts():
@@ -427,3 +434,18 @@ def test_a_step_in_flight_serves_what_steps_in_a_row_serve(model):
     assert fly["counters"] == row["counters"]
     assert fly["counters"][0] > 0 and fly["counters"][1] > 0
     assert fly["compiled"] == [], fly["compiled"]
+
+
+def test_set_up_compiles_a_draw_a_kind_of_block_and_a_program_a_shape(
+        model, entries_before):
+    """What set-up compiles: ONE seeded draw a distinct (mixer,
+    feed-forward) pair and one of the embedding and head, whatever the
+    depth; and each of the two programs once a shape and static value
+    this file ran.  A program a layer, or a static argument that hashes
+    anew each call, shows here first.  The file's LAST test: it counts
+    what the tests before it compiled."""
+    cfg = model[0]
+    names = draw_compiles(cfg)
+    assert len(names) == 3 == len(set(hybrid.layer_kinds(cfg))) + 1, \
+        names
+    assert program_entries(entries_before) == {"step": 5, "prefill": 9}

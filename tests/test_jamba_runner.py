@@ -16,7 +16,7 @@ from benchmarks.harness import reference_jamba as ref
 from brpc_tpu.models import hybrid
 from brpc_tpu.models.hybrid import init_hybrid_params
 from brpc_tpu.models.runner import from_hf_config
-from hybrid_rig import Rig, T, tokens_of
+from hybrid_rig import Rig, T, draw_compiles, program_entries, tokens_of
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # the published keys (the catalog row's, verbatim)
@@ -50,6 +50,12 @@ def model():
     cfg = from_hf_config(HF, param_dtype="float32")
     ref_cfg = dict(HF, param_dtype="float32")
     return cfg, ref_cfg, init_hybrid_params(cfg, jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def entries_before():
+    # the programs' compiled entries before this file's first run
+    return program_entries()
 
 
 def test_from_hf_config_gives_the_published_kinds_and_counts():
@@ -223,3 +229,18 @@ def test_verify_keeps_raising(model):
         rig.runner.verify(None, None, None, None, None)
     assert T == 16
     rig.close()
+
+
+def test_set_up_compiles_a_draw_a_kind_of_block_and_a_program_a_shape(
+        model, entries_before):
+    """What set-up compiles: ONE seeded draw a distinct (mixer,
+    feed-forward) pair and one of the embedding and head, whatever the
+    depth; and each of the two programs once a shape and static value
+    this file ran.  A program a layer, or a static argument that hashes
+    anew each call, shows here first.  The file's LAST test: it counts
+    what the tests before it compiled."""
+    cfg = model[0]
+    names = draw_compiles(cfg)
+    assert len(names) == 3 == len(set(hybrid.layer_kinds(cfg))) + 1, \
+        names
+    assert program_entries(entries_before) == {"step": 3, "prefill": 4}

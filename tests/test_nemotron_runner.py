@@ -18,7 +18,7 @@ from benchmarks.harness import reference_nemotron as ref
 from brpc_tpu.models import hybrid
 from brpc_tpu.models.hybrid import init_hybrid_params
 from brpc_tpu.models.runner import from_hf_config
-from hybrid_rig import Rig, tokens_of
+from hybrid_rig import Rig, draw_compiles, program_entries, tokens_of
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # the published keys (the catalog row's, verbatim)
@@ -75,6 +75,12 @@ def model():
     # the file's one seeded draw: every call compiles its programs anew
     cfg = from_hf_config(HF, param_dtype="float32", **CUT)
     return cfg, init_hybrid_params(cfg, jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def entries_before():
+    # the programs' compiled entries before this file's first run
+    return program_entries()
 
 
 def test_from_hf_config_gives_the_published_kinds_and_counts():
@@ -310,3 +316,18 @@ def test_four_shares_of_an_expert_block_add_up_to_the_uncut_block():
     assert np.abs(np.asarray(total + shared) - np.asarray(want)).max() < 2e-5
     # a share alone is not the block
     assert np.abs(np.asarray(y + shared) - np.asarray(want)).max() > 1e-3
+
+
+def test_set_up_compiles_a_draw_a_kind_of_block_and_a_program_a_shape(
+        model, entries_before):
+    """What set-up compiles: ONE seeded draw a distinct (mixer,
+    feed-forward) pair and one of the embedding and head, whatever the
+    depth; and each of the two programs once a shape and static value
+    this file ran.  A program a layer, or a static argument that hashes
+    anew each call, shows here first.  The file's LAST test: it counts
+    what the tests before it compiled."""
+    cfg = model[0]
+    names = draw_compiles(cfg)
+    assert len(names) == 4 == len(set(hybrid.layer_kinds(cfg))) + 1, \
+        names
+    assert program_entries(entries_before) == {"step": 3, "prefill": 3}
