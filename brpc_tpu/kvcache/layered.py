@@ -33,9 +33,12 @@ runner's donated programs update in place (no per-call restack):
              of 10,240 channels as 80 rows each, padded to a block of
              256 rows that divides the state's (``ops.ssd`` owns the
              layout).  Whatever the kind, BOTH kinds of recurrent state
-             a sequence lie in the one row, so that restore, snapshot,
-             scratch and zero row, the free list and a radix node's
-             ownership are one mechanism.  The last
+             a sequence lie in the one row, and so do its window
+             layers' ring pages: what a ROW owns is its block of
+             ``state`` and its ``R`` pages of ``ring``, so that restore,
+             snapshot, scratch and zero row, the free list and a radix
+             node's ownership are one mechanism over every array a row
+             owns.  The last
              two rows are a scratch row (idle decode slots read and
              write it) and a row that stays zero (a cold sequence
              starts from it)
@@ -46,17 +49,31 @@ runner's donated programs update in place (no per-call restack):
              gives an array whose minor dimension is not whole tiles
              another layout than the kernels read, and copies it whole
              around every call), the lanes past the row stay zero
+  ``ring``   ``[window layers, 2, Hkv, (rows + 2) R, T, D]`` bfloat16:
+             K and V of the sliding-window layers, a RING of ``R`` pages
+             a state row (``ring_pages``): logical block ``b`` of the
+             sequence in row ``r`` lies at ring page ``r R + b mod R``.
+             ``R`` is the fewest pages that hold a window (the pages a
+             decode query's window can span, less one) beside every
+             page a prefill chunk writes before its queries read, so a
+             window layer keeps a fixed few pages a sequence however
+             long it grows, and its keys never take a page of ``kv``
+             (whose first dimension is the full layers only, as
+             ``page_bytes`` counts them).  The ring's layout is the K/V
+             arena's, so ``sparse_attend`` and ``cache_write`` read and
+             write it as they do ``kv``
 
 A kind the model has no layer of is an array with no element and costs
 nothing: no state row is allocated, restored or snapshot where
-``n_linear`` is 0, and a hit is then whatever whole pages the radix tree
-matches.  A live sequence's row comes before any snapshot's: an
+``n_linear`` and ``n_window`` are 0, and a hit is then whatever whole
+pages the radix tree matches.  A live sequence's row comes before any snapshot's: an
 admission that finds no free row has the store evict cached prefixes
 until one is (``KVCacheStore._install_state``), and a snapshot that
 finds none is not taken (``state_snapshot_no_row`` counts them).
 
-A snapshot is the recurrent state after exactly a whole number of
-pages.  The radix node that ends that prefix owns it
+A snapshot is the recurrent state (and the window layers' ring) after
+exactly a whole number of pages: a restored ring is the one a cold
+prefill of the same prefix leaves.  The radix node that ends that prefix owns it
 (``_Node.snapshot``); a hit restores it into the sequence's row instead
 of re-reading the prefix, and evicting the node frees the row.
 """
@@ -86,10 +103,14 @@ class LayeredSpec:
     n_latent: int = 0        # layers that keep one latent row a token
     latent_dim: int = 0      # its width: kv_lora_rank + the rope key's
     compressed: bool = True  # the K/V layers select blocks (keep ``kc``)
+    n_window: int = 0        # sliding-window layers (their K/V in ``ring``)
+    ring_pages: int = 0      # R: ring pages a state row holds of each
 
     @property
     def has_state(self) -> bool:
-        return self.n_linear > 0
+        """Whether a sequence owns a state row (recurrent state, or the
+        window layers' ring)."""
+        return self.n_linear > 0 or self.n_window > 0
 
     @property
     def latent_lanes(self) -> int:
@@ -107,6 +128,18 @@ def _copy_row():
     def kvcache_state_copy(state, dst, src):
         return state.at[dst].set(state[src])
     return jax.jit(kvcache_state_copy, donate_argnums=0)
+
+
+@functools.cache
+def _copy_ring():
+    import jax
+
+    def kvcache_ring_copy(ring, dst, src, *, pages):
+        return jax.lax.dynamic_update_slice_in_dim(
+            ring, jax.lax.dynamic_slice_in_dim(ring, src * pages, pages, 3),
+            dst * pages, 3)
+    return jax.jit(kvcache_ring_copy, donate_argnums=0,
+                   static_argnames="pages")
 
 
 @functools.cache
@@ -155,6 +188,14 @@ class LayeredCache:
         self.zero_row = s.state_rows + 1
         self.state = zeros((s.state_rows + 2, s.n_linear)
                            + s.state_layer_shape, np.float32)
+        self.ring = zeros((s.n_window, 2, s.n_kv_heads,
+                           (s.state_rows + 2) * s.ring_pages,
+                           self.page_tokens, s.head_dim), bf16)
+        # what a row owns: the layers and the bytes a restore or a
+        # snapshot copies
+        self._row_layers = s.n_linear + s.n_window
+        self.row_bytes = s.state_row_bytes() + (
+            self.ring.nbytes // (s.state_rows + 2) if s.n_window else 0)
         self._free_rows = list(range(s.state_rows))[::-1]
         safe = "".join(c if c.isalnum() else "_" for c in name)
         self.bvar_names = [f"kvcache_{safe}_state_{what}" for what in
@@ -185,17 +226,22 @@ class LayeredCache:
             return len(self._free_rows)
 
     def _copy(self, dst: int, src: int) -> None:
+        """Row ``src`` -> row ``dst`` in every array a row owns."""
         with self.lock:
             self.state = _copy_row()(self.state, np.int32(dst),
                                      np.int32(src))
+            if self.spec.n_window:
+                self.ring = _copy_ring()(self.ring, np.int32(dst),
+                                         np.int32(src),
+                                         pages=self.spec.ring_pages)
 
     def reset_row(self, row: int) -> None:
         self._copy(row, self.zero_row)
 
     def restore(self, row: int, snapshot: int) -> None:
         """Snapshot -> a sequence's row: the radix hit's other half."""
-        with rpcz.stage("kvcache.state.restore", layers=self.spec.n_linear,
-                        bytes=self.spec.state_row_bytes()):
+        with rpcz.stage("kvcache.state.restore", layers=self._row_layers,
+                        bytes=self.row_bytes):
             self._copy(row, snapshot)
         self.restores.add(1)
 
@@ -203,8 +249,8 @@ class LayeredCache:
         """A sequence's row -> a fresh snapshot row; MemoryError where
         none is free."""
         snap = self.alloc_row()
-        with rpcz.stage("kvcache.state.snapshot", layers=self.spec.n_linear,
-                        bytes=self.spec.state_row_bytes()):
+        with rpcz.stage("kvcache.state.snapshot", layers=self._row_layers,
+                        bytes=self.row_bytes):
             self._copy(snap, row)
         self.snapshots.add(1)
         return snap
@@ -219,7 +265,7 @@ class LayeredCache:
 
     def nbytes(self) -> int:
         return int(self.kv.nbytes + self.kc.nbytes + self.state.nbytes
-                   + self.latent.nbytes)
+                   + self.latent.nbytes + self.ring.nbytes)
 
     def stats(self) -> dict:
         return {"pages": self.pages, "state_rows": self.spec.state_rows,
@@ -236,4 +282,4 @@ class LayeredCache:
             v = find_exposed(n)
             if v is not None:
                 v.hide()
-        self.kv = self.kc = self.state = self.latent = None
+        self.kv = self.kc = self.state = self.latent = self.ring = None

@@ -2,22 +2,24 @@
 
 Serves a :class:`~brpc_tpu.models.runner.TransformerConfig` whose
 ``mixer_types`` and ``ffn_types`` name each layer's mixer and
-feed-forward (MiniCPM-SALA's, GLM-4.7-Flash's, Jamba's and Nemotron 3's
-kinds today); what a kind draws, computes, keeps and counts is its
-record in :mod:`brpc_tpu.models.layers`.  Around them learned RMS norms,
-per-head q/k norms, rotary positions, output gates, the muP scalings
-where the family has them and an untied head, or the embedding as the
-head where the family ties them; the vocabulary may be the chip's slice
-of it.  The equations are those of
-``benchmarks/harness/reference_sala.py``, ``reference_glm.py``,
-``reference_jamba.py`` and ``reference_nemotron.py`` (the plain
-references; the tier-1 tests hold this runner to them).
+feed-forward (MiniCPM-SALA's, GLM-4.7-Flash's, Jamba's, Nemotron 3's
+and Mellum 2's kinds today); what a kind draws, computes, keeps and
+counts is its record in :mod:`brpc_tpu.models.layers`.  Around them
+learned RMS norms, per-head q/k norms, rotary positions (plain or
+YaRN-scaled, a base a kind), output gates, the muP scalings where the
+family has them and an untied head, or the embedding as the head where
+the family ties them; the vocabulary may be the chip's slice of it.  The
+equations are those of ``benchmarks/harness/reference_sala.py``,
+``reference_glm.py``, ``reference_jamba.py``, ``reference_nemotron.py``
+and ``reference_mellum.py`` (the plain references; the tier-1 tests hold
+this runner to them).
 
 The cache is the store's :class:`~brpc_tpu.kvcache.layered.LayeredCache`:
 persistent device arrays, one a kind of state (a kind the model has no
-layer of is an array with no element), that the two jitted programs
-here (``jit_runner_hybrid_step``, ``jit_runner_hybrid_prefill``) take
-DONATED and return updated, fixed shapes throughout.
+layer of is an array with no element; the window layers' ring is left
+out of the programs of a model that has none), that the two jitted
+programs here (``jit_runner_hybrid_step``, ``jit_runner_hybrid_prefill``)
+take DONATED and return updated, fixed shapes throughout.
 
 Position contract (the engine's): ``step(tok, pos)`` computes position
 ``pos - 1`` (the token newest in the sequence) and WRITES its K/V and
@@ -35,7 +37,10 @@ residual stream, the lightning state, either scan state and the
 convolution's tail) then holds bfloat16 values, and
 the K/V and latent pages the values of an int8 cache (scale 1/16), the
 router bfloat16.  ``control="drop"`` leaves the last chosen expert's
-share out of every routed sum.
+share out of every routed sum; ``control="unbounded"`` lets a window
+query read every page of its ring with no lower bound, and
+``control="plain_rope"`` rotates the full attention layers without their
+YaRN scaling.
 """
 from __future__ import annotations
 
@@ -168,8 +173,8 @@ def _programs():
 
     # ---- one decode position a slot --------------------------------------
 
-    def step(params, kv, kc, state, packed, prev, latent=None, *, cfg,
-             backend, control, logits_out):
+    def step(params, kv, kc, state, packed, prev, latent=None, ring=None, *,
+             cfg, backend, control, logits_out):
         """``packed [S, 4 + MPs]`` int32: a slot's token, position, state
         row, whether it is live (0 no, ``LIVE``, or ``FED``: live, and
         its token is row 0 of ``prev``, the step before's result, not
@@ -185,7 +190,8 @@ def _programs():
         step makes and drops costs the engine thread a hand-off of the
         interpreter lock under load (PERF.md section 7, entry 15).
         ``FED`` is data: a step dispatched before the one ahead of it
-        was fetched is the same program as any other."""
+        was fetched is the same program as any other.  ``latent`` and
+        ``ring`` are None where the model has no such layer."""
         _TRACING.control, _TRACING.backend = control, backend
         positions, rows = packed[:, 1], packed[:, 2]
         tokens = jnp.where(packed[:, 3] == FED,
@@ -218,7 +224,7 @@ def _programs():
         c = SimpleNamespace(
             cfg=cfg, control=control, backend=backend, rs=rs,
             round_state="bfloat16" if control == "low" else None,
-            kv=kv, kc=kc, state=state, latent=latent, qpos=qpos,
+            kv=kv, kc=kc, state=state, latent=latent, ring=ring, qpos=qpos,
             live=active, rows=rows, tables=tables, packed=packed, page=page,
             done=done, jc=jc, page_a=page_a, win_at=win_at, t_page=t_page,
             n_arena=n_arena, stride=stride,
@@ -244,12 +250,12 @@ def _programs():
             out[3] = out[3].at[1].set(
                 (c.n_tile * ROW_TILE).astype(jnp.float32))
         return (jnp.stack(out), logits if logits_out else None, c.kv, c.kc,
-                c.state, c.latent)
+                c.state, c.latent, c.ring)
 
     # ---- one prefill chunk of one sequence -------------------------------
 
-    def prefill(params, kv, kc, state, packed, latent=None, *, cfg, backend,
-                control, logits_out, max_pages):
+    def prefill(params, kv, kc, state, packed, latent=None, ring=None, *,
+                cfg, backend, control, logits_out, max_pages):
         """``packed`` int32: the chunk's start, its valid length, the
         sequence's state row, its page table ``[MPs]``, then the
         bucket's tokens (``MPs`` is the store's, static)."""
@@ -286,7 +292,7 @@ def _programs():
         c = SimpleNamespace(
             cfg=cfg, control=control, backend=backend, rs=rs,
             round_state="bfloat16" if control == "low" else None,
-            kv=kv, kc=kc, state=state, latent=latent, qpos=qpos,
+            kv=kv, kc=kc, state=state, latent=latent, ring=ring, qpos=qpos,
             live=valid, row=row, n_valid=n_valid, table=table, page=page,
             n_pg=n_pg, page_before=page_before, n_k=n_k, jkc=jkc,
             k_page=k_page, t_page=t_page, n_arena=n_arena, stride=stride,
@@ -309,7 +315,7 @@ def _programs():
                 counts.append((c.n_tile * ROW_TILE).astype(jnp.float32))
             counts = jnp.stack(counts)
         return (counts, _logits(params, h, cfg) if logits_out else None,
-                c.kv, c.kc, c.state, c.latent)
+                c.kv, c.kc, c.state, c.latent, c.ring)
 
     def named(fn, scope, *more_statics):
         @functools.wraps(fn)
@@ -320,7 +326,7 @@ def _programs():
         scoped.__name__ = scoped.__qualname__ = scope.replace(".", "_")
         return jax.jit(scoped, static_argnames=(
             "cfg", "backend", "control", "logits_out") + more_statics,
-            donate_argnames=("kv", "kc", "state", "latent"))
+            donate_argnames=("kv", "kc", "state", "latent", "ring"))
     return {"step": named(step, "runner.hybrid_step"),
             "prefill": named(prefill, "runner.hybrid_prefill", "max_pages")}
 
@@ -329,29 +335,37 @@ def _programs():
 # the store and the runner
 # ---------------------------------------------------------------------------
 
-def layered_spec(cfg: TransformerConfig, state_rows: int):
+def layered_spec(cfg: TransformerConfig, state_rows: int,
+                 ring_pages: int = 0):
     from brpc_tpu.kvcache.layered import LayeredSpec
     recurrent = held(cfg.mixer_types, *keeping("state"))
+    rows = recurrent or cfg.n_window
     return LayeredSpec(
         n_sparse=cfg.n_kv_layers, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, n_linear=recurrent,
-        state_rows=int(state_rows) if recurrent else 0,
+        state_rows=int(state_rows) if rows else 0,
         state_layer_shape=state_block(cfg), n_latent=cfg.n_latent,
-        latent_dim=cfg.latent_dim, compressed=bool(cfg.n_sparse))
+        latent_dim=cfg.latent_dim, compressed=bool(cfg.n_sparse),
+        n_window=cfg.n_window,
+        ring_pages=int(ring_pages) if cfg.n_window else 0)
 
 
 def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
                        state_rows: int = 0, page_tokens: int = 0,
-                       device=None, name: str = "kv"):
+                       chunk_tokens: int = 0, device=None,
+                       name: str = "kv"):
     """A :class:`KVCacheStore` for a described architecture: page ids,
     refcounts and the radix tree as ever, ``cache_pages`` pages whose
     K/V (sparse- and full-attention layers), compressed keys, latent
-    rows (latent-attention layers) and ``state_rows`` recurrent-state
-    rows (lightning, Mamba or Mamba-2 layers) live in the store's
-    :class:`LayeredCache`.  A page
-    holds ``cfg.sparse_block`` tokens where the model selects blocks
-    (one selection block a page), else ``page_tokens``.  The pool's own
-    blocks hold the 4-byte token-id stand-in only."""
+    rows (latent-attention layers) and ``state_rows`` rows (the
+    recurrent state of lightning, Mamba or Mamba-2 layers; the ring of
+    sliding-window layers) live in the store's :class:`LayeredCache`.  A
+    page holds ``cfg.sparse_block`` tokens where the model selects
+    blocks (one selection block a page), else ``page_tokens``.  A model
+    with window layers states ``chunk_tokens``, the longest prefill
+    chunk (the engine's largest bucket): a ring holds the pages a window
+    spans beside that chunk's.  The pool's own blocks hold the 4-byte
+    token-id stand-in only."""
     from brpc_tpu.ici.block_pool import BlockPool
     from brpc_tpu.kvcache import KVCacheStore
     pt = cfg.sparse_block if cfg.n_sparse else int(page_tokens)
@@ -365,13 +379,22 @@ def make_layered_store(cfg: TransformerConfig, *, cache_pages: int,
         raise ValueError(
             "the cache's compressed-key index needs kernel_size = 2 x "
             "kernel_stride and block_size = 4 x kernel_stride")
+    ring = 0
+    if cfg.n_window:
+        from brpc_tpu.models.layers import ring_pages
+        if chunk_tokens <= 0 or chunk_tokens % pt:
+            raise ValueError(
+                f"chunk_tokens {chunk_tokens}: a model with window layers "
+                f"states its longest prefill chunk, whole pages of {pt}")
+        ring = ring_pages(cfg.window, pt, chunk_tokens)
     per_block = 64
     blocks = -(-int(cache_pages) // per_block)
     pool = BlockPool(device, classes=(per_block * pt * 4,),
                      blocks_per_class=blocks)
     return KVCacheStore(pool, device, page_bytes=pt * 4, page_tokens=pt,
                         max_blocks=blocks, vector_kv=True,
-                        layers=layered_spec(cfg, state_rows), name=name)
+                        layers=layered_spec(cfg, state_rows, ring),
+                        name=name)
 
 
 class HybridRunner(ModelRunner):
@@ -444,6 +467,11 @@ class HybridRunner(ModelRunner):
             self.store = store
 
     # ---- helpers ----
+
+    def _ring(self, lay):
+        """The window layers' ring where the model has any: None leaves
+        it out of the programs of every other model."""
+        return lay.ring if self.cfg.n_window else None
 
     def _flat_tables(self, pages) -> np.ndarray:
         pages = np.asarray(pages, np.int32)
@@ -519,12 +547,14 @@ class HybridRunner(ModelRunner):
             np.asarray([start, n, seq.state_row], np.int32), table,
             np.asarray(tokens, np.int32)])
         with lay.lock:
-            n_sel, out, lay.kv, lay.kc, lay.state, lay.latent = \
+            n_sel, out, lay.kv, lay.kc, lay.state, lay.latent, ring = \
                 self._fns["prefill"](
                     self.params, lay.kv, lay.kc, lay.state,
-                    jnp.asarray(packed), lay.latent,
+                    jnp.asarray(packed), lay.latent, self._ring(lay),
                     logits_out=bool(logits), max_pages=len(table),
                     **self._statics())
+            if ring is not None:
+                lay.ring = ring
         self.store.mark_filled(seq, start + n)
         # counted when the next step dispatched completes: the chunk has
         # run by then, and ``float(n_sel)`` here would hold the engine
@@ -577,11 +607,14 @@ class HybridRunner(ModelRunner):
                     np.zeros((3 + 2 * bool(self.cfg.n_moe), n), np.float32),
                     lay.kv.sharding)
         with lay.lock:
-            out, lg, lay.kv, lay.kc, lay.state, lay.latent = \
+            out, lg, lay.kv, lay.kc, lay.state, lay.latent, ring = \
                 self._fns["step"](
                     self.params, lay.kv, lay.kc, lay.state,
                     jnp.asarray(packed), before, lay.latent,
-                    logits_out=bool(logits), **self._statics())
+                    self._ring(lay), logits_out=bool(logits),
+                    **self._statics())
+            if ring is not None:
+                lay.ring = ring
         owed, self._uncounted = self._uncounted, []
         handle = {"out": out, "logits": lg, "live": live, "seqs": seqs,
                   "positions": np.asarray(positions), "prefills": owed}

@@ -44,15 +44,27 @@ name.  The mixers:
   ``attention``       full softmax attention over the layered cache's
                       K/V pages: ``minicpm4``'s kernel under a table of
                       ALL the sequence's pages, no compressed keys, no
-                      selection, no rotary, q/k norm or gate unless the
-                      family states them
+                      selection, no q/k norm or gate unless the family
+                      states them; rotary (plain or YaRN-scaled,
+                      :func:`rope_table`) where it states ``attn_rope``
+  ``window``          sliding-window softmax attention (Mellum 2): the
+                      ``attention`` kind's projections, each query sees
+                      the last ``window`` positions, its own included;
+                      the K/V live in the sequence's RING of the
+                      layered cache (``kvcache.layered``: a few pages a
+                      state row, logical block ``b`` at ring page ``b mod
+                      R``), read by ``sparse_attend`` with a lower bound
+                      under the name ``window_attend``; its rotary is the
+                      window layers' own (``window_rope_theta``)
   ``none``            no mixer: the block is its feed-forward alone
 
 and the feed-forwards: ``dense`` (the silu gated MLP), ``moe`` (a
-float32 sigmoid router over all experts, the top ``k`` of score +
-correction bias, the chosen scores normalised and scaled, the held
-experts' gated MLPs as ragged grouped matmuls with no capacity,
-``ops.moe``, and a shared expert), ``latent_moe`` (the same router; the
+float32 router over all experts, sigmoid scores with a correction bias
+or, where ``router_scoring`` is ``softmax``, softmax scores and no bias;
+the top ``k`` of score + bias, the chosen scores normalised and scaled,
+the held experts' gated MLPs as ragged grouped matmuls with no capacity,
+``ops.moe``, and the shared experts where ``n_shared_experts`` names
+any), ``latent_moe`` (the same router; the
 held experts two matrices around ``relu(.)^2`` in a LATENT width,
 between one projection down and one up, on a TPU the ``expert_ffn``
 kernel of ``ops.moe``; the shared expert of the same body in the
@@ -81,6 +93,7 @@ import numpy as np
 
 SPARSE, LINEAR, MLA = "minicpm4", "lightning-attn", "mla"
 MAMBA, MAMBA2, ATTN, NONE = "mamba", "mamba2", "attention", "none"
+WINDOW = "window"
 DENSE, MOE, LATENT_MOE = "dense", "moe", "latent_moe"
 # what a record's shapes give in place of a fan-in for what is neither a
 # norm weight nor a matrix in the parameters' type, all float32: a
@@ -98,6 +111,12 @@ ROUTER, BIAS, SMALL_BIAS = "router", "bias", "small_bias"
 A_LOG, A_LOG_U, ONES, DT_BIAS = "a_log", "a_log_uniform", "ones", "dt_bias"
 DT_RANGE = (1e-3, 1e-1)
 PREFILL_ROWS = 16     # positions a grid row of the latent prefill kernel
+# (row, table entry) pairs of one call of the attention kernel where a
+# row is a position: its tables and block indices ride scalar memory,
+# two int32 a pair, and a v5e's is 1 MiB (a prefill block of 64
+# positions x 4 K/V heads over tables of 816 pages asked for 1.96 MiB of
+# it); past it a row is a block of positions
+TABLE_ENTRIES = 32768
 
 
 # ---------------------------------------------------------------------------
@@ -180,6 +199,45 @@ def _rope(x, pos, theta):
                            axis=-1)
 
 
+@functools.lru_cache(maxsize=None)
+def rope_table(d: int, theta: float, yarn: tuple = ()) -> tuple:
+    """``(inverse frequencies [d/2] float32, cos/sin factor)`` of rotary
+    over a head of ``d`` at base ``theta``: ``theta^(-2i/d)``, or, where
+    ``yarn`` = ``(factor, original positions, beta_fast, beta_slow,
+    attention factor)``, YaRN's: dimension pairs below ``low`` keep their
+    frequency, those above ``high`` are divided by ``factor``, a linear
+    ramp between, with ``low = floor(d ln(original / (2 pi beta_fast)) /
+    (2 ln theta))``, ``high`` the same of ``beta_slow`` rounded up; cos
+    and sin are both multiplied by the attention factor.  Computed once
+    (float64, then float32) a configuration and kind."""
+    inv = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if not yarn:
+        return inv.astype(np.float32), 1.0
+    factor, original, fast, slow, attn = yarn
+
+    def dim_of(rotations):
+        return d * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(dim_of(fast)), 0)
+    high = min(math.ceil(dim_of(slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (inv * ((1 - ramp) + ramp / factor)).astype(np.float32), \
+        float(attn)
+
+
+def _rotary(x, pos, table):
+    """:func:`_rope` with a kind's ``(inverse frequencies, factor)``."""
+    inv, factor = table
+    d = x.shape[-1]
+    ang = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv)[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
 def _cache_round(x, control):
     """What the K/V pages hold of ``x``: bfloat16 values (under the
     low-precision control, the values of an int8 cache)."""
@@ -207,8 +265,9 @@ def moe_share(p, x, cfg, valid, ffn: str = MOE):
     """One expert layer's feed-forward (of kind ``ffn``) of the normed
     rows ``x [N, dm]``: ``(the held experts' share of the routed sum,
     the shared expert's output, group sizes [held] of this call)``.
-    The router scores ALL ``cfg.n_experts``; the share is of
-    ``cfg.experts_held``.  ``latent_moe``'s share is the held experts'
+    The router scores ALL ``cfg.n_experts`` (``cfg.router_scoring``);
+    the share is of ``cfg.experts_held``; a model with no shared expert
+    gives 0 for its output.  ``latent_moe``'s share is the held experts'
     sum in the latent width through the projection up: the shares of
     every holder add up to the whole layer's routed output."""
     from brpc_tpu.ops.moe import expert_ffn, route
@@ -219,9 +278,16 @@ def moe_share(p, x, cfg, valid, ffn: str = MOE):
             preferred_element_type=jnp.float32))
     else:
         logit = jnp.dot(x, p["router"], precision="highest")
-    idx, w = route(jax.nn.sigmoid(logit), p["router_bias"],
-                   cfg.experts_per_tok, norm=cfg.norm_topk,
-                   scale=cfg.routed_scale)
+    if cfg.router_scoring == "softmax":
+        # no correction bias: the choice is the scores' own top k
+        idx, w = route(jax.nn.softmax(logit, axis=-1),
+                       jnp.zeros((cfg.n_experts,), jnp.float32),
+                       cfg.experts_per_tok, norm=cfg.norm_topk,
+                       scale=cfg.routed_scale)
+    else:
+        idx, w = route(jax.nn.sigmoid(logit), p["router_bias"],
+                       cfg.experts_per_tok, norm=cfg.norm_topk,
+                       scale=cfg.routed_scale)
     if control == "drop":
         w = w.at[:, -1].set(0.0)
     if ffn == LATENT_MOE:
@@ -236,6 +302,8 @@ def moe_share(p, x, cfg, valid, ffn: str = MOE):
         return y, shared, sizes
     y, sizes = expert_ffn(x, idx, w, valid, p["we_gate"], p["we_up"],
                           p["we_down"], held=cfg.experts_held, mm=_rmm)
+    if not cfg.n_shared_experts:
+        return y, 0.0, sizes
     shared = _mm(jax.nn.silu(_mm(x, p["ws_gate"])) * _mm(x, p["ws_up"]),
                  p["ws_down"])
     return y, shared, sizes
@@ -292,6 +360,24 @@ def _qkv(p, x, heads, kv_heads, d, cfg):
     if cfg.qk_norm:
         q = _rms(q, p["q_norm"], cfg.rms_eps)
         k = _rms(k, p["k_norm"], cfg.rms_eps)
+    return q, k, v
+
+
+def _project(p, x, c, kind):
+    """An attention kind's ``q, k, v`` of the normed rows at positions
+    ``c.qpos``, rotated where the family states rotary: a window layer
+    by its own base, plain; any other by the model's, YaRN where it
+    states it (and not under the ``plain_rope`` control)."""
+    cfg = c.cfg
+    q, k, v = _qkv(p, x, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg)
+    if cfg.attn_rope:
+        if kind == WINDOW:
+            table = rope_table(cfg.head_dim, cfg.window_rope_theta)
+        else:
+            table = rope_table(cfg.head_dim, cfg.rope_theta,
+                               () if c.control == "plain_rope"
+                               else cfg.rope_yarn)
+        q, k = _rotary(q, c.qpos, table), _rotary(k, c.qpos, table)
     return q, k, v
 
 
@@ -378,7 +464,8 @@ def _dense_tables(table, n_pages: int, width: int):
     return pages, blocks
 
 
-def _attend_rows(q, kv, ls, pages, blocks, lengths, backend):
+def _attend_rows(q, kv, ls, pages, blocks, lengths, backend, starts=None,
+                 name="sparse_attend"):
     """``q [N, Hkv, G, D]``, ``pages``/``blocks`` ``[N, Hkv, MP]``:
     one kernel row a (position, K/V head)."""
     from brpc_tpu.ops.sparse_attention import sparse_attend
@@ -388,8 +475,87 @@ def _attend_rows(q, kv, ls, pages, blocks, lengths, backend):
         q.reshape(n * hkv, g, d), kv, ls,
         jnp.tile(jnp.arange(hkv, dtype=jnp.int32), n),
         pages.reshape(n * hkv, mp), blocks.reshape(n * hkv, mp),
-        jnp.repeat(lengths, hkv), backend=backend)
+        jnp.repeat(lengths, hkv),
+        starts=None if starts is None else jnp.repeat(starts, hkv),
+        backend=backend, name=name)
     return o.reshape(n, hkv * g * d)
+
+
+def window_pages(span: int, page_tokens: int) -> int:
+    """The most pages ``span`` consecutive positions can lie in: a
+    decode query's window (``span`` = the window), or a prefill block's
+    windows together (the window + the block - 1)."""
+    return -(-(span - 1) // page_tokens) + 1
+
+
+def ring_pages(window: int, page_tokens: int, chunk_tokens: int) -> int:
+    """R, the fewest pages a window layer's ring a sequence holds: the
+    pages behind a prefill chunk's first query (its window less its own
+    page) beside every page the chunk writes before its queries read."""
+    return window_pages(window, page_tokens) - 1 \
+        + -(-chunk_tokens // page_tokens)
+
+
+def _window_table(first_pos, span: int, rows, live, r: int, t: int, cfg,
+                  control):
+    """The ring's table of the windows of ``span`` consecutive positions
+    from ``first_pos [N]`` of the sequences in state rows ``rows [N]``:
+    ``(pages, logical blocks) [N, width]`` (logical block ``b`` of row
+    ``rr`` at ring page ``rr R + b mod R``, R = ``r``; -1 where there is
+    none or the span is not ``live``) and the first position's lower
+    bound ``[N]``.  Under the ``unbounded`` control the table is every
+    page of the ring as the sequence's latest blocks, and no bound."""
+    last = (first_pos + span - 1) // t
+    if control == "unbounded":
+        width, first, starts = r, jnp.maximum(last - (r - 1), 0), None
+    else:
+        width = window_pages(cfg.window + span - 1, t)
+        first = jnp.maximum(first_pos - (cfg.window - 1), 0) // t
+        starts = first_pos - (cfg.window - 1)
+    b = first[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    ok = live[:, None] & (b <= last[:, None])
+    return (jnp.where(ok, rows[:, None] * r + b % r, -1),
+            jnp.where(ok, b, -1), starts)
+
+
+def _attend_window(q4, ring, ls, r, rows, qpos, live, cfg, control,
+                   backend):
+    """The ``window`` mixer's decode attention for N positions of
+    sequences whose state rows are ``rows [N]``: keys ``qpos - window <
+    j <= qpos``, from the ring pages they lie in.  Keys are read from
+    the ring only: write before you attend.  ``r`` is the ring's pages a
+    row."""
+    n, hkv = q4.shape[0], q4.shape[1]
+    pages, blocks, starts = _window_table(qpos, 1, rows, live, r,
+                                          ring.shape[4], cfg, control)
+    width = pages.shape[1]
+    return _attend_rows(
+        q4, ring, ls, jnp.broadcast_to(pages[:, None, :], (n, hkv, width)),
+        jnp.broadcast_to(blocks[:, None, :], (n, hkv, width)),
+        jnp.where(live, qpos + 1, 0), backend, starts, "window_attend")
+
+
+def _attend_block(q4, kv, ls, pages, blocks, first_pos, backend,
+                  starts=None, name="sparse_attend"):
+    """Attention of a block of consecutive positions ``q4 [B, Hkv, G,
+    D]`` from ``first_pos`` under ONE table ``pages`` / ``blocks``
+    ``[MP]``: a kernel row a K/V head, its ``B x G`` query rows the
+    block's positions (``sparse_attend``'s ``heads_per_pos``), so that a
+    page is read once a block and not once a position; keys ``< t + 1``
+    (and ``>= starts + t - first_pos``) of position ``t``.  Returns ``[B,
+    Hkv G D]``."""
+    from brpc_tpu.ops.sparse_attention import sparse_attend
+    b, hkv, g, d = q4.shape
+    mp = pages.shape[0]
+    rows = jnp.full((hkv,), 1, jnp.int32)
+    o = sparse_attend(
+        q4.transpose(1, 0, 2, 3).reshape(hkv, b * g, d), kv, ls,
+        jnp.arange(hkv, dtype=jnp.int32),
+        jnp.broadcast_to(pages[None], (hkv, mp)),
+        jnp.broadcast_to(blocks[None], (hkv, mp)), rows * (first_pos + 1),
+        starts=None if starts is None else rows * starts, heads_per_pos=g,
+        backend=backend, name=name)
+    return o.reshape(hkv, b, g, d).transpose(1, 0, 2, 3).reshape(b, -1)
 
 
 def _attend_all(q4, kv, ls, tables, qpos, live, backend):
@@ -468,12 +634,11 @@ def _attn_shapes(cfg) -> dict:
                 wo=((hd, dm), hd))
 
 
-def _attn_step(p, x, i, c):
-    """Both attention kinds' projections, and the decode step's write of
+def _attn_step(p, x, i, c, kind=ATTN):
+    """The attention kinds' projections, and the decode step's write of
     each slot's K/V into its page: the queries ``[S, H, D]``."""
     from brpc_tpu.ops.sparse_attention import cache_write
-    cfg = c.cfg
-    q, k, v = _qkv(p, x, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg)
+    q, k, v = _project(p, x, c, kind)
     c.kv = cache_write(c.kv, i, c.page, c.qpos % c.t_page,
                        _cache_round(k, c.control)[:, :, None],
                        _cache_round(v, c.control)[:, :, None],
@@ -481,15 +646,16 @@ def _attn_step(p, x, i, c):
     return q
 
 
-def _chunk_write(c, i, k, v):
-    """Both attention kinds' write of a prefill chunk's K/V: a page is
-    written whole where it holds a valid position (what lies behind the
-    last valid one is masked by every reader's length and overwritten by
-    the positions that come)."""
+def _chunk_write(arr, i, pages, c, k, v):
+    """The attention kinds' write of a prefill chunk's K/V into ``arr``
+    (the K/V pages or the window layers' ring) at ``pages [chunk
+    pages]``: a page is written whole where it holds a valid position
+    (what lies behind the last valid one is masked by every reader's
+    length and overwritten by the positions that come)."""
     from brpc_tpu.ops.sparse_attention import cache_write
     n_pg, t, hkv, d = c.n_pg, c.t_page, c.cfg.n_kv_heads, c.cfg.head_dim
-    c.kv = cache_write(
-        c.kv, i, c.page, jnp.zeros((n_pg,), jnp.int32),
+    return cache_write(
+        arr, i, pages, jnp.zeros((n_pg,), jnp.int32),
         _cache_round(k, c.control).reshape(
             n_pg, t, hkv, d).transpose(0, 2, 1, 3),
         _cache_round(v, c.control).reshape(
@@ -510,7 +676,7 @@ def _sparse_decode(p, x, i, c):
     from brpc_tpu.ops.sparse_attention import KERNELS_PER_PAGE, page_keys
     cfg = c.cfg
     s_n, hkv = x.shape[0], cfg.n_kv_heads
-    q = _attn_step(p, x, i, c)
+    q = _attn_step(p, x, i, c, SPARSE)
     # the last 2 x stride keys, from the (one or two) pages they lie in,
     # as the cache now holds them
     two = page_keys(c.kv, i, jnp.concatenate([c.page_a, c.page]),
@@ -538,7 +704,7 @@ def _sparse_prefill(p, x, i, c):
     q, k, v = _qkv(p, x, cfg.n_heads, hkv, d, cfg)
     before = page_keys(c.kv, i, c.page_before[None],
                        backend=c.backend)[0]                 # [Hkv,T,D]
-    _chunk_write(c, i, k, v)
+    c.kv = _chunk_write(c.kv, i, c.page, c, k, v)
     # the page before the chunk and the chunk's own keys, at the cache's
     # values, in runs of ``stride``
     ctx = jnp.concatenate(
@@ -577,18 +743,83 @@ def _attn_decode(p, x, i, c):
 
 def _attn_prefill(p, x, i, c):
     cfg = c.cfg
-    q, k, v = _qkv(p, x, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg)
-    _chunk_write(c, i, k, v)
+    q, k, v = _project(p, x, c, ATTN)
+    c.kv = _chunk_write(c.kv, i, c.page, c, k, v)
     kv, table = c.kv, c.table
+    mps = table.shape[0]
+    if min(x.shape[0], 64) * cfg.n_kv_heads * mps <= TABLE_ENTRIES:
+        def block(args):
+            qq, pp, vv = args
+            return _attend_all(
+                qq, kv, i, jnp.broadcast_to(table[None], (pp.shape[0], mps)),
+                pp, vv, c.backend)
+    else:
+        # a long table: a row a position would re-read every page of it
+        # once a position, and its tables outgrow scalar memory; a row a
+        # block of positions reads each page once
+        blocks = jnp.where(table >= 0, jnp.arange(mps, dtype=jnp.int32), -1)
 
-    def block(args):
-        qq, pp, vv = args
-        return _attend_all(
-            qq, kv, i, jnp.broadcast_to(table[None],
-                                        (pp.shape[0],) + table.shape),
-            pp, vv, c.backend)
+        def block(args):
+            qq, pp, vv = args
+            return _attend_block(qq, kv, i, jnp.where(vv.any(), table, -1),
+                                 blocks, pp[0], c.backend)
     o = _blockwise(block, q, c)
     return _mm(o.reshape(x.shape[0], cfg.n_heads * cfg.head_dim), p["wo"])
+
+
+def _ring_size(c) -> int:
+    """Pages a state row holds of the window layers' ring (static: the
+    ring's pages over the state array's rows)."""
+    return c.ring.shape[3] // c.state.shape[0]
+
+
+def _window_decode(p, x, i, c):
+    """Each slot's K/V into its ring (block ``qpos // T``'s ring page),
+    then its query over the window."""
+    from brpc_tpu.ops.sparse_attention import cache_write
+    cfg = c.cfg
+    r = _ring_size(c)
+    q, k, v = _project(p, x, c, WINDOW)
+    page = jnp.where(c.live, c.rows * r + (c.qpos // c.t_page) % r,
+                     c.ring.shape[3])
+    c.ring = cache_write(c.ring, i, page, c.qpos % c.t_page,
+                         _cache_round(k, c.control)[:, :, None],
+                         _cache_round(v, c.control)[:, :, None],
+                         backend=c.backend)
+    hkv = cfg.n_kv_heads
+    o = _attend_window(q.reshape(x.shape[0], hkv, cfg.n_heads // hkv,
+                                 cfg.head_dim), c.ring, i, r, c.rows,
+                       c.qpos, c.live, cfg, c.control, c.backend)
+    return _mm(o, p["wo"])
+
+
+def _window_prefill(p, x, i, c):
+    """The chunk's pages into the sequence's ring, then its queries over
+    their windows: the ring holds the window before the chunk's first
+    position and every page the chunk writes at once."""
+    cfg = c.cfg
+    n, r = x.shape[0], _ring_size(c)
+    if ring_pages(cfg.window, c.t_page, n) > r:
+        raise ValueError(
+            f"a prefill chunk of {c.n_pg} pages overruns a window ring of "
+            f"{r} pages (make_layered_store's chunk_tokens)")
+    q, k, v = _project(p, x, c, WINDOW)
+    block = c.qpos[0] // c.t_page + jnp.arange(c.n_pg, dtype=jnp.int32)
+    page = jnp.where(jnp.arange(c.n_pg) * c.t_page < c.n_valid,
+                     c.row * r + block % r, c.ring.shape[3])
+    c.ring = _chunk_write(c.ring, i, page, c, k, v)
+    ring = c.ring
+
+    def attend(args):
+        qq, pp, vv = args
+        pages, blocks, starts = _window_table(
+            pp[:1], pp.shape[0], c.row[None], vv.any()[None], r, c.t_page,
+            cfg, c.control)
+        return _attend_block(qq, ring, i, pages[0], blocks[0], pp[0],
+                             c.backend, None if starts is None else starts[0],
+                             "window_attend")
+    o = _blockwise(attend, q, c)
+    return _mm(o.reshape(n, cfg.n_heads * cfg.head_dim), p["wo"])
 
 
 def _mla_shapes(cfg) -> dict:
@@ -801,11 +1032,17 @@ def _dense_shapes(cfg) -> dict:
 def _moe_shapes(cfg) -> dict:
     dm, n, fe = cfg.d_model, cfg.experts_held[1], cfg.moe_d_ff
     fs = fe * cfg.n_shared_experts
-    return dict(router=((dm, cfg.n_experts), ROUTER),
-                router_bias=((cfg.n_experts,), BIAS),
-                we_gate=((n, dm, fe), dm), we_up=((n, dm, fe), dm),
-                we_down=((n, fe, dm), fe), ws_gate=((dm, fs), dm),
-                ws_up=((dm, fs), dm), ws_down=((fs, dm), fs))
+    # a softmax router has no correction bias, and a model with no
+    # shared expert draws none
+    out = dict(router=((dm, cfg.n_experts), ROUTER))
+    if cfg.router_scoring != "softmax":
+        out["router_bias"] = ((cfg.n_experts,), BIAS)
+    out.update(we_gate=((n, dm, fe), dm), we_up=((n, dm, fe), dm),
+               we_down=((n, fe, dm), fe))
+    if fs:
+        out.update(ws_gate=((dm, fs), dm), ws_up=((dm, fs), dm),
+                   ws_down=((fs, dm), fs))
+    return out
 
 
 def _latent_moe_shapes(cfg) -> dict:
@@ -866,6 +1103,20 @@ def _positions(prefilled: str, stepped: str):
     return count
 
 
+def _count_window(r, qpos, counts, dead, step):
+    """A decode step's window and full layers: the ring pages a window
+    layer's queries visited (their table's entries that name a block),
+    the positions, and the K/V pages a full layer's queries read."""
+    if step is None:
+        return
+    cfg, t = r.cfg, r.store.page_tokens
+    first = np.maximum(qpos - (cfg.window - 1), 0) // t
+    r.window_pages_read.add(int((qpos // t - first + 1).sum())
+                            * cfg.n_window)
+    r.window_positions.add(len(qpos) * cfg.n_window)
+    r.full_pages_read.add(int((qpos // t + 1).sum()) * cfg.n_attention)
+
+
 def _count_latent(r, qpos, counts, dead, step):
     if step is None:
         return
@@ -893,8 +1144,8 @@ def _count_routed(r, qpos, counts, dead, step):
 class Mixer:
     """A mixer kind.  ``decode`` / ``prefill`` are None where the block
     has no mixer (nor its norm); ``store`` is the cache array its layers
-    index (``"kv"``, ``"latent"``, ``"state"``), ``state_block`` the
-    shape of its layer's block of a state row.  ``count(runner, qpos,
+    index (``"kv"``, ``"latent"``, ``"state"``, ``"ring"``),
+    ``state_block`` the shape of its layer's block of a state row.  ``count(runner, qpos,
     counts, dead, step)`` adds a program's positions ``qpos`` to the
     runner's ``counters`` ((attribute, bvar suffix) pairs): ``counts``
     what the program counted itself (blocks selected, then, of a model
@@ -957,6 +1208,12 @@ MIXERS = {
                   _ssd_block, _named("ssd_tokens", "ssd_steps"),
                   _positions("ssd_tokens", "ssd_steps")),
     ATTN: Mixer(_attn_shapes, _attn_decode, _attn_prefill, "kv"),
+    # ring pages a window layer's decode queries visited, the positions
+    # they were of (both summed over the window layers), and the K/V
+    # pages the full layers' decode queries read
+    WINDOW: Mixer(_attn_shapes, _window_decode, _window_prefill, "ring",
+                  counters=_named("window_pages_read", "window_positions",
+                                  "full_pages_read"), count=_count_window),
     NONE: Mixer(lambda cfg: {}),
 }
 # (token, expert) pairs routed; the distinct experts a layer a decode

@@ -67,7 +67,7 @@ import numpy as np
 
 from brpc_tpu import fault
 from brpc_tpu.models.layers import (ATTN, LINEAR, MAMBA, MAMBA2, MIXERS, MLA,
-                                    ROUTED, SPARSE, held, keeping)
+                                    ROUTED, SPARSE, WINDOW, held, keeping)
 
 DEFAULT_PREFILL_BUCKETS = (16, 64, 256, 1024, 4096)
 
@@ -275,6 +275,14 @@ class TransformerConfig:
     attn_rope: bool = False
     lin_rope: bool = True
     rope_theta: float = 10000.0
+    # YaRN of the attention layers' rotary (``layers.rope_table``):
+    # (factor, original positions, beta_fast, beta_slow, attention
+    # factor); () is plain rotary
+    rope_yarn: tuple = ()
+    # sliding-window attention (``window`` layers): the positions a
+    # query sees, its own included, and their rotary's own base
+    window: int = 0
+    window_rope_theta: float = 10000.0
     attn_output_gate: bool = False
     lin_output_gate: bool = False
     lin_output_norm: bool = False
@@ -326,6 +334,9 @@ class TransformerConfig:
     routed_scale: float = 1.0
     norm_topk: bool = True
     experts_held: tuple = ()        # (first, count) computed here
+    # the router's scores: "sigmoid" (with a correction bias for the
+    # choice) or "softmax" (no bias)
+    router_scoring: str = "sigmoid"
 
     @property
     def kv_bytes_per_token(self) -> int:
@@ -364,6 +375,7 @@ class TransformerConfig:
     # attention kinds, routed experts by either expert kind
     n_sparse = _layers_of(SPARSE)
     n_attention = _layers_of(ATTN)
+    n_window = _layers_of(WINDOW)
     n_kv_layers = _layers_of(*keeping("kv"))
     n_linear = _layers_of(LINEAR)
     n_latent = _layers_of(MLA)
@@ -414,6 +426,7 @@ class TransformerConfig:
                 + self.ssd_channels * (self.ssm_conv + 1)
                 + 3 * self.ssm_heads + self.ssd_inner + self.ssd_inner * dm,
                 "attention": 2 * dm * hd + 2 * dm * kvd,
+                "window": 2 * dm * hd + 2 * dm * kvd,
                 "moe": 3 * dm * self.moe_d_ff
                 * (self.n_experts + self.n_shared_experts)
                 + dm * self.n_experts,
@@ -437,9 +450,12 @@ def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
     attention in every layer, ``first_k_dense_replace`` gated MLPs and
     then routed experts), ``model_type`` ``jamba`` (state-space
     mixers with full attention where ``attn_layer_period`` /
-    ``attn_layer_offset`` put it), or ``model_type`` ``nemotron_h``
+    ``attn_layer_offset`` put it), ``model_type`` ``nemotron_h``
     (blocks of ONE sublayer by ``hybrid_override_pattern``: ``M``
-    Mamba-2, ``*`` attention, ``E`` latent experts); any other raises.
+    Mamba-2, ``*`` attention, ``E`` latent experts), or ``model_type``
+    ``mellum`` (``window`` or ``attention`` mixers by ``layer_types``,
+    rotary a kind by ``rope_parameters``, YaRN on the full layers, a
+    softmax router over routed experts); any other raises.
     ``layers`` =
     ``(first, count)`` holds a contiguous slice of the published
     layers (a pipeline stage); ``sparse`` gives what the published
@@ -452,14 +468,19 @@ def from_hf_config(hf: dict, *, layers: Optional[tuple] = None,
     logits and sampling are over the slice)."""
     if "mixer_types" not in hf:
         family = hf.get("model_type")
-        if family not in ("glm4_moe_lite", "jamba", "nemotron_h"):
+        if family not in ("glm4_moe_lite", "jamba", "nemotron_h", "mellum"):
             raise ValueError(
                 f"no description of model_type {family!r}: a config names "
-                f"its mixer_types or is glm4_moe_lite, jamba or nemotron_h")
+                f"its mixer_types or is glm4_moe_lite, jamba, nemotron_h or "
+                f"mellum")
         if sparse:
             raise ValueError(f"{family} has no sparse settings")
         if family == "nemotron_h":
             return _from_nemotron_h(hf, layers, experts, vocab, param_dtype)
+        if family == "mellum":
+            if vocab is not None:
+                raise ValueError("mellum holds its whole vocabulary")
+            return _from_mellum(hf, layers, experts, param_dtype)
         if vocab is not None:
             raise ValueError("a slice of the vocabulary is described for "
                              "nemotron_h only")
@@ -672,6 +693,83 @@ def _from_nemotron_h(hf: dict, layers, experts, vocab,
         norm_topk=bool(hf["norm_topk_prob"]),
         experts_held=_held("experts", experts, n_exp),
         param_dtype=param_dtype)
+
+
+def _from_mellum(hf: dict, layers, experts,
+                 param_dtype: str) -> TransformerConfig:
+    """Mellum 2's keys: layer ``i`` mixes by ``layer_types[i]``
+    (``sliding_attention``: the ``window`` kind over the last
+    ``sliding_window`` positions; ``full_attention``: ``attention``) and
+    feeds forward by ``mlp_layer_types[i]`` (``sparse``: routed experts
+    with a softmax router, top ``num_experts_per_tok`` normalised where
+    ``norm_topk_prob``, no correction bias, no shared expert; ``dense``:
+    the gated MLP); rotary over the whole head a kind by
+    ``rope_parameters`` (``default`` plain, ``yarn`` YaRN).  What this
+    runner does not compute raises, naming the key: window layers with
+    ``use_sliding_window`` false, ``max_window_layers`` other than 0,
+    biases on the projections, another activation, another rope type.
+    The multi-token-prediction head has no keys and is not built."""
+    for key, want in (("hidden_act", "silu"), ("attention_bias", False),
+                      ("max_window_layers", 0)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"mellum with {key}={hf[key]!r} is not "
+                             f"described (only {want!r})")
+    depth = int(hf["num_hidden_layers"])
+    first, count = _held("layers", layers, depth)
+    mixers = {"sliding_attention": WINDOW, "full_attention": ATTN}
+    ffns = {"sparse": "moe", "dense": "dense"}
+    types = list(hf["layer_types"])[first:first + count]
+    mlps = list(hf["mlp_layer_types"])[first:first + count]
+    for key, seen, known in (("layer_types", types, mixers),
+                             ("mlp_layer_types", mlps, ffns)):
+        if len(seen) != count or set(seen) - set(known):
+            raise ValueError(f"mellum {key} {sorted(set(seen))} of {count} "
+                             f"layers: only {sorted(known)} are described")
+    if "sliding_attention" in types and not (
+            hf.get("use_sliding_window", True) and hf.get("sliding_window")):
+        raise ValueError("mellum with use_sliding_window=False (or no "
+                         "sliding_window) and sliding_attention layers is "
+                         "not described")
+    rope = {}
+    for kind in set(types):
+        r = hf["rope_parameters"][kind]
+        if r.get("rope_type", "default") not in ("default", "yarn"):
+            raise ValueError(f"mellum rope_parameters[{kind!r}] with "
+                             f"rope_type={r['rope_type']!r} is not "
+                             f"described (only 'default' or 'yarn')")
+        rope[kind] = r
+    full = rope.get("full_attention", {})
+    yarn = ()
+    if full.get("rope_type") == "yarn":
+        yarn = (float(full["factor"]),
+                int(full["original_max_position_embeddings"]),
+                float(full.get("beta_fast", 32)),
+                float(full.get("beta_slow", 1)),
+                float(full.get("attention_factor")
+                      or 0.1 * math.log(float(full["factor"])) + 1.0))
+    window = rope.get("sliding_attention", {})
+    if window.get("rope_type", "default") != "default":
+        raise ValueError("mellum window layers with a scaled rotary are "
+                         "not described")
+    n_exp = int(hf["num_experts"])
+    return TransformerConfig(
+        vocab=int(hf["vocab_size"]), d_model=int(hf["hidden_size"]),
+        n_layers=count, n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]), d_ff=int(hf["intermediate_size"]),
+        mixer_types=tuple(mixers[t] for t in types),
+        ffn_types=tuple(ffns[t] for t in mlps),
+        depth_published=depth, layer_offset=first,
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf["tie_word_embeddings"]), attn_rope=True,
+        rope_theta=float(full.get("rope_theta", 10000.0)), rope_yarn=yarn,
+        window=int(hf.get("sliding_window") or 0),
+        window_rope_theta=float(window.get("rope_theta", 10000.0)),
+        n_experts=n_exp, experts_per_tok=int(hf["num_experts_per_tok"]),
+        moe_d_ff=int(hf["moe_intermediate_size"]),
+        norm_topk=bool(hf["norm_topk_prob"]),
+        experts_held=_held("experts", experts, n_exp),
+        router_scoring="softmax", param_dtype=param_dtype)
 
 
 def init_runner_params(cfg: TransformerConfig, key=None) -> dict:

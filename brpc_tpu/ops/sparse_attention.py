@@ -22,7 +22,11 @@ not a contiguous sequence and the causal mask needs each key's position.
                           one ``p v`` on the MXU for the whole query
                           group of a K/V head; the grid is the flat list
                           of the steps that hold a page a row sees,
-                          :func:`flat_work_list`), elsewhere a gather.
+                          :func:`flat_work_list`), elsewhere a gather;
+                          with ``starts``, keys before a row's start
+                          take no part either (a sliding window's lower
+                          bound: the ``window`` layers' ring pages, the
+                          kernel then named ``window_attend``).
 
 Geometry (fixed ratios, checked): a page holds ``4 * stride`` tokens,
 a kernel spans ``2 * stride`` tokens and starts every ``stride``: four
@@ -138,7 +142,8 @@ def _finish(o, m, l, q, extra_k, extra_v, scale):
 
 
 def sparse_attend_gather(q, kv, layer: int, heads, tables, block_ids,
-                         lengths, extra_k=None, extra_v=None):
+                         lengths, extra_k=None, extra_v=None, starts=None,
+                         heads_per_pos: int = 0):
     n, g, d = q.shape
     t = kv.shape[4]
     p = kv.shape[3]
@@ -151,8 +156,21 @@ def sparse_attend_gather(q, kv, layer: int, heads, tables, block_ids,
     qf = q.astype(f32)
     s = jnp.einsum("ngd,nmtd->ngmt", qf * scale, k, precision="highest")
     kpos = block_ids[:, :, None] * t + jnp.arange(t, dtype=jnp.int32)
-    valid = (kpos < lengths[:, None, None]) & (tables >= 0)[:, :, None]
-    s = jnp.where(valid[:, None], s, -jnp.inf).reshape(n, g, mp * t)
+    if heads_per_pos:
+        # query row j of a row is position ``j // heads_per_pos`` of its
+        # block: both bounds one later a position
+        later = (jnp.arange(g) // heads_per_pos)[None, :, None, None]
+        kq = kpos[:, None]
+        valid = (kq < lengths[:, None, None, None] + later) \
+            & (tables >= 0)[:, None, :, None]
+        if starts is not None:
+            valid = valid & (kq >= starts[:, None, None, None] + later)
+        s = jnp.where(valid, s, -jnp.inf).reshape(n, g, mp * t)
+    else:
+        valid = (kpos < lengths[:, None, None]) & (tables >= 0)[:, :, None]
+        if starts is not None:
+            valid = valid & (kpos >= starts[:, None, None])
+        s = jnp.where(valid[:, None], s, -jnp.inf).reshape(n, g, mp * t)
     m = s.max(axis=-1)
     m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
     pr = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - m_safe[..., None]))
@@ -233,9 +251,8 @@ def steps_visited(pages):
     return int(np.maximum(-(-np.asarray(pages) // PAGES_PER_STEP), 1).sum())
 
 
-def _sparse_kernel(tab_ref, blk_ref, len_ref, hd_ref, wrow_ref, wblk_ref,
-                   n_ref, q_ref, kv_ref, o_ref, m_ref, l_ref, k_buf, v_buf,
-                   sem, *, layer: int, scale: float):
+def _sparse_kernel(*refs, layer: int, scale: float, bounded: bool,
+                   heads_per_pos: int):
     """One entry of the work list: ``PAGES_PER_STEP`` pages of row
     ``wrow[w]`` as ONE key tile and one value tile.  ``kv_ref`` is the
     whole arena where it lies (HBM); the step's pages are copied by hand,
@@ -244,8 +261,19 @@ def _sparse_kernel(tab_ref, blk_ref, len_ref, hd_ref, wrow_ref, wblk_ref,
     computes).  As block operands of the grid the same 16 fetches cost
     0.93 us a step in the pipeline's own bookkeeping, twice what the
     step computes (PERF.md section 6, PR 37)."""
+    # ``bounded``: a row's start rides scalar prefetch after its length;
+    # ``heads_per_pos``: the row's query rows are consecutive positions
+    # (see :func:`sparse_attend`)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    start_ref = None
+    if bounded:
+        (tab_ref, blk_ref, len_ref, start_ref, hd_ref, wrow_ref, wblk_ref,
+         n_ref, q_ref, kv_ref, o_ref, m_ref, l_ref, k_buf, v_buf,
+         sem) = refs
+    else:
+        (tab_ref, blk_ref, len_ref, hd_ref, wrow_ref, wblk_ref, n_ref,
+         q_ref, kv_ref, o_ref, m_ref, l_ref, k_buf, v_buf, sem) = refs
     _, pps, t, d = k_buf.shape
     n_pages = kv_ref.shape[3]
     w = pl.program_id(0)
@@ -289,7 +317,17 @@ def _sparse_kernel(tab_ref, blk_ref, len_ref, hd_ref, wrow_ref, wblk_ref,
         at = jnp.where(tab_ref[r, e0 + i] >= 0, blk_ref[r, e0 + i] * t,
                        jnp.iinfo(jnp.int32).max // 2)
         off = jnp.where(lane >= i * t, at - i * t, off)
-    valid = off + lane < len_ref[r]                          # [1, pps T]
+    if heads_per_pos:
+        # query row j is position j // heads_per_pos of the row's block
+        later = jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[0], 1), 0) // heads_per_pos
+        valid = off + lane < len_ref[r] + later              # [G, pps T]
+        if start_ref is not None:
+            valid = valid & (off + lane >= start_ref[r] + later)
+    else:
+        valid = off + lane < len_ref[r]                      # [1, pps T]
+        if start_ref is not None:
+            valid = valid & (off + lane >= start_ref[r])
     for c in copies(w, slot):
         c.wait()
     k = k_buf[slot].reshape(pps * t, d)
@@ -308,13 +346,16 @@ def _sparse_kernel(tab_ref, blk_ref, len_ref, hd_ref, wrow_ref, wblk_ref,
 
 
 def sparse_attend_pallas(q, kv, layer: int, heads, tables, block_ids,
-                         lengths, extra_k=None, extra_v=None, *,
-                         interpret: Optional[bool] = None):
+                         lengths, extra_k=None, extra_v=None, starts=None,
+                         heads_per_pos: int = 0, *,
+                         interpret: Optional[bool] = None,
+                         name: str = "sparse_attend"):
     """The kernel: a grid step takes ``PAGES_PER_STEP`` entries of a
     row's table as one key tile, and the grid is the flat list of the
     steps that hold a page some key of which the row sees
     (:func:`flat_work_list`): a row whose last such entry is its ``n``-th
-    takes ``max(1, ceil(n / PAGES_PER_STEP))`` steps."""
+    takes ``max(1, ceil(n / PAGES_PER_STEP))`` steps.  ``name`` is the
+    kernel's in a trace."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     n, g, d = q.shape
@@ -331,16 +372,20 @@ def sparse_attend_pallas(q, kv, layer: int, heads, tables, block_ids,
         pad = ((0, 0), (0, -tables.shape[1] % pps))
         tables = jnp.pad(tables, pad, constant_values=-1)
         block_ids = jnp.pad(block_ids, pad, constant_values=-1)
-    live = live_pages(tables, block_ids, lengths, t)
+    # a row of consecutive positions reads as far as its last one does
+    live = live_pages(tables, block_ids, lengths + (g // heads_per_pos - 1)
+                      if heads_per_pos else lengths, t)
     rows, blocks, n_steps = flat_work_list(
         jnp.zeros((n,), i32), jnp.maximum(-(-live // pps), 1),
         tables.shape[1] // pps)
 
-    def row3(w, tab, blk, ln, hd, wrow, wblk, n_):
-        return (wrow[w], 0, 0)
+    def row3(w, *prefetched):
+        # (..., work rows, work blocks, n): the rows third from the end
+        return (prefetched[-3][w], 0, 0)
 
+    bounded = () if starts is None else (starts.astype(i32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7, grid=(n_steps,),
+        num_scalar_prefetch=7 + len(bounded), grid=(n_steps,),
         in_specs=[pl.BlockSpec((None, g, d), row3),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[pl.BlockSpec((None, g, d), row3),
@@ -350,20 +395,23 @@ def sparse_attend_pallas(q, kv, layer: int, heads, tables, block_ids,
                         pltpu.VMEM((2, pps, t, d), kv.dtype),
                         pltpu.SemaphoreType.DMA((2,))])
     o, m, l = pl.pallas_call(
-        functools.partial(_sparse_kernel, layer=layer, scale=scale),
+        functools.partial(_sparse_kernel, layer=layer, scale=scale,
+                          bounded=bool(bounded),
+                          heads_per_pos=heads_per_pos),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n, g, d), f32),
                    jax.ShapeDtypeStruct((n, g, 1), f32),
                    jax.ShapeDtypeStruct((n, g, 1), f32)],
-        interpret=interpret, name="sparse_attend",
-    )(tables, block_ids, lengths, heads.astype(i32), rows, blocks,
+        interpret=interpret, name=name,
+    )(tables, block_ids, lengths, *bounded, heads.astype(i32), rows, blocks,
       n_steps.reshape(1), qf, kv)
     return _finish(o, m[..., 0], l[..., 0], qf, extra_k, extra_v, scale)
 
 
 def sparse_attend(q, kv, layer: int, heads, tables, block_ids, lengths,
-                  extra_k=None, extra_v=None, *,
-                  backend: Optional[str] = None):
+                  extra_k=None, extra_v=None, starts=None,
+                  heads_per_pos: int = 0, *, backend: Optional[str] = None,
+                  name: str = "sparse_attend"):
     """Attention of one query GROUP a row over its selected pages.
 
     ``q``         ``[N, G, D]``: a row is (query position, K/V head),
@@ -376,18 +424,32 @@ def sparse_attend(q, kv, layer: int, heads, tables, block_ids, lengths,
                   sits at position ``block * T + i``
     ``lengths``   ``[N]`` keys at positions ``< lengths`` take part
     ``extra_k/v`` ``[N, D]`` optional self key, always visible
+    ``starts``    ``[N]`` optional: keys at positions ``< starts`` take
+                  no part either (a sliding window's lower bound); None
+                  is the program without it
+    ``heads_per_pos`` 0: a row is one position.  Else a row is a BLOCK
+                  of consecutive positions under one table (a prefill
+                  chunk's), its ``G`` query rows ``heads_per_pos`` a
+                  position: query row ``j`` is position ``j //
+                  heads_per_pos`` of the block, its length and start the
+                  row's plus that (the row's are its first position's),
+                  so a page is read once a block where a row a position
+                  reads it once a position
+    ``name``      the kernel's name in a trace (``ops.<name>`` the scope)
     Returns ``[N, G, D]`` float32; a row with no visible key gives 0."""
     if backend is None:
         backend = default_backend()
-    with jax.named_scope("ops.sparse_attend"):
+    with jax.named_scope(f"ops.{name}"):
         if backend == "gather":
             return sparse_attend_gather(q, kv, layer, heads, tables,
-                                        block_ids, lengths, extra_k, extra_v)
+                                        block_ids, lengths, extra_k, extra_v,
+                                        starts, heads_per_pos)
         # "mosaic": compiled for the chip whatever the default backend
         # is (a chip-less compile for a described TPU)
         return sparse_attend_pallas(
             q, kv, layer, heads, tables, block_ids, lengths, extra_k,
-            extra_v, interpret=False if backend == "mosaic" else None)
+            extra_v, starts, heads_per_pos,
+            interpret=False if backend == "mosaic" else None, name=name)
 
 
 # ---- writing and reading the arena -----------------------------------------

@@ -13,6 +13,7 @@ from brpc_tpu.serving import DecodeEngine
 
 T = 16               # tokens a page (MiniCPM-SALA's toy selection block)
 MAX_PAGES = 16
+CHUNK = 32           # the longest prefill chunk (the engine's last bucket)
 
 
 def tokens_of(n, seed=0):
@@ -53,7 +54,7 @@ class Rig:
     def __init__(self, cfg, params, name, pages=64, rows=6, backend=None):
         self.store = make_layered_store(cfg, cache_pages=pages,
                                         state_rows=rows, page_tokens=T,
-                                        name=name)
+                                        chunk_tokens=CHUNK, name=name)
         self.runner = HybridRunner(params, cfg, store=self.store, name=name,
                                    backend=backend)
 
@@ -63,7 +64,7 @@ class Rig:
         out[:len(ids)] = ids
         return out
 
-    def prefill(self, seq, tokens, chunk=32):
+    def prefill(self, seq, tokens, chunk=CHUNK):
         """Positions prefill_from .. len - 2, as the engine cuts them;
         returns the logits of those positions."""
         out = []

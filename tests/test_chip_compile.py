@@ -252,6 +252,9 @@ def _served(name, sds):
             os.path.abspath(__file__))), "benchmarks", "configs", name)) as f:
         c = json.load(f)
     held = c["num_hidden_layers"]     # a whole model names no slice
+    # a slice of the published layers' kinds holds them all beside it
+    c = dict(c, **{k: c["published_" + k] for k in
+                   ("layer_types", "mlp_layer_types") if "published_" + k in c})
     cuts = {}
     if "published_n_routed_experts" in c:   # a chip's share of a layer
         cuts = {"experts": (c["first_expert_held"], c["n_routed_experts"]),
@@ -636,3 +639,100 @@ def test_nemotron_prefill_compiles_without_copying_the_caches(sds, nemotron):
         assert mem.temp_size_in_bytes < 1.5e9
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             <= 0.9 * 16 * 2**30
+
+
+# ---- Mellum 2: the window layers' ring and kernel, both programs ----------
+
+def test_mellum_window_attend_lowers_at_published_widths(sds):
+    """The window layers' decode attention at ``mellum_code_mixed``'s
+    shape: 32 slots x 4 K/V heads of 8 query heads of 128, a table of the
+    17 ring pages a 1,024-position window can span, a lower bound a row,
+    over the ring of 9 layers x 74 rows x 24 pages as it lies: one
+    Mosaic kernel named ``window_attend``, no copy of the ring."""
+    from brpc_tpu.ops.sparse_attention import sparse_attend_pallas
+    i32 = jnp.int32
+    ring = sds((9, 2, 4, 74 * 24, 64, 128), jnp.bfloat16)
+    n = 32 * 4
+    compiled = jax.jit(
+        lambda q, kv, hd, tab, bid, ln, st: sparse_attend_pallas(
+            q, kv, 3, hd, tab, bid, ln, starts=st, interpret=False,
+            name="window_attend")).lower(
+        sds((n, 8, 128), jnp.float32), ring, sds((n,), i32),
+        sds((n, 17), i32), sds((n, 17), i32), sds((n,), i32),
+        sds((n,), i32)).compile()
+    text = compiled.as_text()
+    assert _calls(text, "window_attend") == 1
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert _copies(compiled, ring) == []
+
+
+@pytest.fixture(scope="module")
+def mellum(sds):
+    from brpc_tpu.models.hybrid import layered_spec
+    from brpc_tpu.models.layers import ring_pages
+    c, fns, params, statics = _served("mellum2_12b_l12_1chip.json", sds)
+    cfg = statics["cfg"]
+    pages, t, rows = c["cache_pages"], c["page_tokens"], c["state_rows"] + 2
+    r = ring_pages(cfg.window, t, max(c["prefill_buckets"]))
+    spec = layered_spec(cfg, c["state_rows"], r)
+    bf16 = jnp.bfloat16
+    caches = (sds((3, 2, 4, pages, t, 128), bf16),
+              sds((0, pages, 4, 4, 128), bf16),
+              sds((rows, 0) + spec.state_layer_shape, jnp.float32),
+              sds((0, pages, t, 0), bf16),
+              sds((9, 2, 4, rows * r, t, 128), bf16))
+    return c, fns, params, caches, statics
+
+
+def test_mellum_decode_step_compiles_at_published_widths_and_fits(
+        sds, mellum):
+    """``mellum2_12b_l12_1chip``'s decode step for a described v5e: a
+    ring write and a ``window_attend`` kernel a window layer (9 each), a
+    page write and a ``sparse_attend`` over every page a full layer (3
+    each), the experts as ragged products; neither the K/V pages nor the
+    2.09 GB ring copied; weights + cache + temporaries inside the
+    chip."""
+    c, fns, params, caches, statics = mellum
+    assert caches[4].shape == (9, 2, 4, 74 * 24, 64, 128)
+    assert params["head"].shape == (98304, 2304)
+    assert params["layers"][0]["we_gate"].shape == (64, 2304, 896)
+    assert "router_bias" not in params["layers"][0]
+    s, mp = c["num_slots"], c["max_pages_per_slot"]
+    kv, kc, state, latent, ring = caches
+    compiled = fns["step"].lower(
+        params, kv, kc, state, sds((s, 4 + mp), jnp.int32),
+        sds((5, s), jnp.float32), latent, ring, logits_out=False,
+        **statics).compile()
+    text = compiled.as_text()
+    assert _calls(text, "window_attend") == 9
+    assert _calls(text, "sparse_attend") == 3
+    assert _calls(text, "cache_write") == 12
+    assert _copies(compiled, kv) == [] and _copies(compiled, ring) == []
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 13e9 < need <= 0.9 * 16 * 2**30, \
+        f"the step needs {need / 1e9:.2f} GB of a 16 GiB chip"
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_mellum_prefill_compiles_without_copying_the_caches(sds, mellum):
+    """The largest prefill bucket of ``mellum_code_mixed`` (512
+    positions, 8 pages into a 24-page ring): each window layer writes
+    its ring and reads it in 8 blocks of 64 positions, a kernel row a
+    K/V head and block; each full layer its pages the same way (a row a
+    position would name 64 x 4 x 816 table entries, twice the scalar
+    memory); no copy of the pages or of the ring."""
+    c, fns, params, caches, statics = mellum
+    mp = c["max_pages_per_slot"]
+    kv, kc, state, latent, ring = caches
+    compiled = fns["prefill"].lower(
+        params, kv, kc, state, sds((3 + mp + 512,), jnp.int32), latent,
+        ring, logits_out=False, max_pages=mp, **statics).compile()
+    text = compiled.as_text()
+    assert _calls(text, "window_attend") >= 1
+    assert _calls(text, "sparse_attend") >= 1
+    assert _copies(compiled, kv) == [] and _copies(compiled, ring) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= 0.9 * 16 * 2**30

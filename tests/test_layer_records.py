@@ -37,7 +37,7 @@ def test_a_kind_has_a_whole_record(table, kind):
         assert shapes and all(callable(f) for f in fns)
     if table == "mixer":
         assert rec.store in ((None,) if kind == layers.NONE
-                             else ("kv", "latent", "state"))
+                             else ("kv", "latent", "state", "ring"))
         assert rec.state_block is None or rec.store == "state"
     else:
         assert rec.routed == (kind in layers.ROUTED) == bool(rec.counters)
@@ -71,7 +71,7 @@ def test_each_layer_indexes_its_kind_s_cache_array():
 
 
 @pytest.mark.parametrize("mixers,ffns,named", [
-    (("attention", "window"), (), "window"),
+    (("attention", "chunked"), (), "chunked"),
     (("attention",), ("dense_moe",), "dense_moe")])
 def test_a_kind_without_a_record_is_refused_when_the_runner_is_built(
         mixers, ffns, named):
